@@ -347,13 +347,8 @@ def _trunk(cfg: Config, params, x, *, mesh: Mesh | None):
             # 'pipe' Manual; a NamedSharding built from the concrete mesh
             # (all-Auto) is rejected there.  The bare-PartitionSpec form
             # resolves against the context mesh and constrains only the
-            # auto axes — exactly what the TP/DP specs name.  On a jax
-            # without native partial-manual (collectives lowers the
-            # region to FULL-manual), there are no auto axes left to
-            # constrain and no context mesh either — skip the hint.
-            from ..parallel import collectives
-
-            if mesh is None or not collectives.PARTIAL_MANUAL_NATIVE:
+            # auto axes — exactly what the TP/DP specs name.
+            if mesh is None:
                 return y
             return jax.lax.with_sharding_constraint(y, spec)
 

@@ -83,7 +83,7 @@ def ring_attention(q, k, v, *, axis_name: str, causal: bool = False):
     Shapes per shard: q/k/v [B, H, T_local, D]; the global sequence is the
     concatenation over the axis in index order.
     """
-    n = collectives.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = collectives.axis_index(axis_name)
     t_local = q.shape[2]
     scale = 1.0 / math.sqrt(q.shape[-1])
@@ -170,7 +170,7 @@ def _ring_flash(q, k, v, axis_name, causal, block_q, block_k):
 def _ring_flash_fwd_impl(q, k, v, axis_name, causal, block_q, block_k):
     from . import flash_attention as fa
 
-    n = collectives.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     # Shard identity is only consumed by the causal visibility test; tracing
     # it unconditionally leaves a DEAD axis_index in the jaxpr (the
     # custom_vjp boundary blocks DCE), which lowers to an unannotated
@@ -227,7 +227,7 @@ def _ring_flash_bwd_rule(axis_name, causal, block_q, block_k, res, do):
     from . import flash_attention as fa
 
     q, k, v, o, lse = res
-    n = collectives.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = collectives.axis_index(axis_name)
     B, H, T, D = q.shape
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)

@@ -21,7 +21,8 @@ Design (per /opt/skills/guides/pallas_guide.md):
   inner over k) and dk/dv (grid over k blocks, inner over q) — using the
   saved LSE and the FA2 recurrence: p = exp(s - lse); ds = p*(do.v^T - D);
   D = rowsum(do * o).
-- ``interpret=True`` off-TPU so CPU tests run the same kernels.
+- ``interpret=True`` on the CPU platform only (``interpret_mode``), so CPU
+  tests run the same kernels; on TPU they compile through Mosaic or raise.
 
 Composes with ring attention (ops.attention): the ring rotates k/v shards
 between chips; this kernel is the per-chip block compute.
@@ -75,18 +76,30 @@ def _dot_tn(a, b):
     )
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted: True on the ``cpu``
+    platform only, where a caller reaches a kernel by asking for it
+    (``attention="flash"``, ``impl="flash"``, a direct call — the auto
+    dispatch never picks it there, see ``flash_viable``).  On ``tpu`` the
+    kernels compile through Mosaic or the call raises; any other platform
+    is refused rather than interpreted under a kernel's name.  The platform
+    is the one jit places this computation's arrays on
+    (``jax.default_backend()``).  The ONE platform test every Pallas kernel
+    in the package uses (ops/bn.py included)."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise NotImplementedError(
+        f"Pallas TPU kernels compile on 'tpu' and interpret on 'cpu'; "
+        f"platform {platform!r} is neither"
+    )
 
 
 def compiler_params(semantics: tuple[str, ...]):
-    """Version shim: pallas renamed TPUCompilerParams -> CompilerParams.
-    Both vintages take the same dimension_semantics tuple, and the
-    TPUCompilerParams-era interpret mode runs these kernels correctly
-    (verified on jax 0.4.37), so resolve whichever this jax ships.  The
-    ONE spelling every TPU kernel in the package uses."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=semantics)
+    """The ONE spelling every TPU kernel in the package uses."""
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def _params():
@@ -198,7 +211,7 @@ def _fwd(q, k, v, *, causal, block_q, block_k, out_dtype=None):
             pltpu.VMEM((bq, d), jnp.float32),  # output accumulator
         ],
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(q, k, v)
     return o, lse
 
@@ -474,7 +487,7 @@ def dq_call(q, k, v, do, lse, delta, *, causal, block_q, block_k, out_dtype=None
         out_shape=jax.ShapeDtypeStruct(q.shape, out_dtype or q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(q, k, v, do, lse, delta)
 
 
@@ -511,7 +524,7 @@ def dkv_call(q, k, v, do, lse, delta, *, causal, block_q, block_k, out_dtype=Non
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(q, k, v, do, lse, delta)
 
 
@@ -628,7 +641,7 @@ def fused_bwd_call(q, k, v, do, lse, delta, *, causal, block_q, block_k, out_dty
         compiler_params=compiler_params(
             ("parallel", "arbitrary", "arbitrary")
         ),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(qs, k, v, do, lse, delta)
 
 
@@ -685,9 +698,7 @@ def flash_viable(t: int) -> bool:
     (models/transformer._use_flash) and the ring auto path
     (ops/attention.sequence_parallel_attention) so the two policies cannot
     drift."""
-    import jax as _jax
-
-    return _jax.default_backend() == "tpu" and t % 512 == 0
+    return jax.default_backend() == "tpu" and t % 512 == 0
 
 
 def flash_attention(
@@ -697,8 +708,8 @@ def flash_attention(
     """Drop-in for ``ops.attention.mha``: q/k/v [B, H, T, D] -> [B, H, T, D].
 
     Block sizes auto-shrink to the largest divisor of T (so any T traces);
-    differentiable (custom FA2 VJP); runs interpreted off-TPU.  Default
-    1024x1024 tiles: the measured optimum of the v5e sweep (BASELINE.md;
+    differentiable (custom FA2 VJP); interpreted on the CPU platform only.
+    Default 1024x1024 tiles: the measured optimum of the v5e sweep (BASELINE.md;
     ~18% faster than 512x512, and 2048 tiles blow VMEM at D=64).  The
     DTX_FLASH_BQ / DTX_FLASH_BK env vars override the defaults — the
     in-step block-sweep knob (bench.py re-runs per setting), read at

@@ -36,93 +36,25 @@ def axis_index(axis_name: str):
     return lax.axis_index(axis_name)
 
 
-def axis_size(axis_name: str):
-    """Number of devices along the named mesh axis.
-
-    Version shim: ``lax.axis_size`` is newer jax; older releases use the
-    canonical constant-folding idiom ``psum(1, axis)`` (a python-int
-    reduction, resolved statically at trace time)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def ring_permute(x, axis_name: str, *, shift: int = 1):
     """Send to the neighbor ``shift`` hops around the axis ring; the building
     block of ring attention / pipelined collectives (permuter.h role).  XLA
     lowers ``ppermute`` to neighbor ICI transfers."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm=perm)
-
-
-#: Whether this jax ships native partial-manual shard_map
-#: (``jax.shard_map`` with ``axis_names``).  False = the experimental API,
-#: where :func:`shard_map` lowers partial-manual regions to FULL-manual
-#: (see below) — bodies must then skip auto-axis sharding CONSTRAINTS
-#: (there are no auto axes left to constrain, and the old API provides no
-#: mesh context for bare PartitionSpecs inside the region).
-PARTIAL_MANUAL_NATIVE = hasattr(jax, "shard_map")
 
 
 def shard_map(
     fn, mesh, *, in_specs, out_specs, check_vma: bool = False,
     axis_names=None,
 ):
-    """Project-standard wrapper over ``jax.shard_map`` (manual SPMD regions).
-
-    Version shim: ``jax.shard_map`` (with ``check_vma`` and
-    ``axis_names``) graduated from ``jax.experimental.shard_map`` — where
-    the same knobs are ``check_rep`` and the COMPLEMENT set ``auto`` —
-    so resolve whichever this jax ships.  This wrapper is the ONE place
-    that difference lives; nothing else in the project may call the jax
-    symbol directly.  ``axis_names``: mesh axes the region is manual
-    over (None = all of them)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, **kw,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if axis_names is not None and frozenset(axis_names) != frozenset(
-        mesh.axis_names
-    ):
-        # Old jax spells partial-manual as the complement set ``auto=``,
-        # but that path hard-ABORTS the process (jaxlib CHECK failure:
-        # spmd_partitioner IsManualSubgroup mismatch) on the CPU
-        # interpret configs our tests run — so partial-manual lowers to a
-        # FULL-manual region instead.  Semantics: the would-be-auto axes
-        # become manual with their in/out specs unchanged, i.e. any array
-        # not spec-sharded over them is REPLICATED there and each of
-        # their mesh coordinates computes the region redundantly (one
-        # independent copy per coordinate) — identical results for the
-        # deterministic bodies this project writes, at the cost of the
-        # GSPMD sharding the auto axes would have inserted inside the
-        # body.  The one thing that must not leak through: a spec naming
-        # a would-be-auto axis relies on GSPMD resharding semantics this
-        # translation cannot reproduce — refuse that loudly.
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-
-        def _spec_axes(spec):
-            for part in spec:
-                if part is None:
-                    continue
-                yield from (part if isinstance(part, tuple) else (part,))
-
-        named = {
-            ax
-            for spec in list(in_specs) + [out_specs]
-            for ax in _spec_axes(spec)
-        }
-        if named & auto:
-            raise NotImplementedError(
-                f"partial-manual shard_map with specs naming auto axes "
-                f"{sorted(named & auto)} requires jax.shard_map; this jax "
-                "only ships the experimental API"
-            )
-    return _shard_map(
+    """Project-standard wrapper over ``jax.shard_map`` (manual SPMD
+    regions) — the ONE place the project calls the jax symbol, with the
+    project's default of ``check_vma=False``.  ``axis_names``: mesh axes
+    the region is manual over (None = all of them)."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma, **kw,
     )

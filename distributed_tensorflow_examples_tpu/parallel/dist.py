@@ -229,7 +229,7 @@ def start_watchdog(
     client = (
         _client
         if _client is not None
-        else getattr(jax._src.distributed.global_state, "client", None)
+        else jax._src.distributed.global_state.client
     )
     if client is None:
         return False
@@ -342,7 +342,7 @@ def stop_watchdog(*, _client=None, _idx=None) -> None:
         client = (
             _client
             if _client is not None
-            else getattr(jax._src.distributed.global_state, "client", None)
+            else jax._src.distributed.global_state.client
         )
         if client is not None:
             try:

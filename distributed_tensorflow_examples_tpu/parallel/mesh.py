@@ -171,7 +171,12 @@ def build_mesh(
                 allow_split_physical_axes=allow_split_physical_axes,
             )
         except (ValueError, NotImplementedError):
-            # Topology-unaware fallback (e.g. odd CPU device counts in tests).
+            # Topology-unaware reshape for CPU device lists only (odd virtual
+            # device counts in tests have no topology to respect); on an
+            # accelerator a refused shape is an error, not a silent
+            # topology-blind mesh.
+            if any(d.platform != "cpu" for d in devices):
+                raise
             mesh_devices = np.asarray(devices).reshape(shape)
     return Mesh(mesh_devices, axis_names)
 

@@ -50,7 +50,7 @@ import time
 import numpy as np
 
 from ..parallel import ps_shard, server_core, tenancy, wire
-from ..utils import faults, telemetry
+from ..utils import compile_cache, faults, telemetry
 from ..utils.metrics import LatencyRecorder, MetricsWriter
 from . import batcher as batcher_lib
 
@@ -261,6 +261,7 @@ class ModelReplicaServer:
         from ..parallel import reshard
         from . import registry as registry_lib
 
+        compile_cache.enable()
         total, self._unflatten = flat_param_spec(init_fn)
         self._predict = jax.jit(predict_fn)
         self.role = role if role is not None else (

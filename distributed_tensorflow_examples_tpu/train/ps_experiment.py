@@ -354,7 +354,7 @@ def run_ps_cluster_task(
     import jax
 
     from ..parallel import async_ps
-    from ..utils import faults, telemetry
+    from ..utils import compile_cache, faults, telemetry
 
     n_workers = worker_count(FLAGS)
     local_bs = max(1, FLAGS.batch_size // n_workers)
@@ -645,6 +645,7 @@ def run_ps_cluster_task(
 
     if job == "chief":
         faults.arm_process_faults()
+        compile_cache.enable()
         params = init_fn(jax.random.key(FLAGS.seed))
         if isinstance(params, tuple):
             params, model_state = params
@@ -665,7 +666,7 @@ def run_ps_cluster_task(
             "hosted in-process" if chief_hosts_service else "external PS tasks",
         )
         # Scrapable platform record: tools/ps_tpu_smoke.py asserts the chief
-        # genuinely ran the accelerator plugin (not a silent CPU fallback).
+        # genuinely ran on the chip (not a silent CPU fallback).
         print(f"CHIEF_PLATFORM={jax.devices()[0].platform}", flush=True)
         trainer = async_ps.RemotePSChief(
             acfg, loss_fn, optimizer, params,

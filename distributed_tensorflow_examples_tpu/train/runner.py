@@ -20,6 +20,7 @@ from jax.sharding import Mesh, PartitionSpec
 
 from ..data import pipeline as pipeline_lib
 from ..parallel import MeshSpec, build_mesh, dist
+from ..utils import compile_cache
 from ..utils.metrics import MetricsWriter
 from . import hooks as hooks_lib
 from .checkpoint import CheckpointManager
@@ -66,6 +67,7 @@ class Experiment:
                 "servers are not needed on TPU; exiting 0."
             )
             raise SystemExit(0)
+        compile_cache.enable()
         if getattr(flags, "watchdog", True):
             # Multi-process fail-fast (no-op single-process): a dead peer
             # must crash the job promptly so the per-task supervisor can

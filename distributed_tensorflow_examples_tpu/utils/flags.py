@@ -90,8 +90,8 @@ def define_training_flags(default_batch_size: int = 128, default_steps: int = 10
         "string",
         "platform",
         "",
-        'Force the JAX platform (e.g. "cpu") — needed for CPU fake-cluster '
-        "runs on hosts whose TPU plugin overrides the JAX_PLATFORMS env var.",
+        'Force the JAX platform (e.g. "cpu") from inside the CLI — the '
+        "flag spelling of the JAX_PLATFORMS environment variable.",
     )
     _define(
         "bool",

@@ -1784,7 +1784,7 @@ def test_campaign_plan_runs_dtxlint_as_cpu_step():
     assert "dtxlint" in steps, "campaign lost the static-analysis step"
     assert steps["dtxlint"].get("cpu_ok") is True
     assert os.path.exists(os.path.join(ROOT, steps["dtxlint"]["cmd"][1]))
-    # r16: the native TSAN gate rides the same cpu_ok pre-wait train.
+    # r16: the native TSAN gate rides the same cpu_ok train.
     assert "tsan_protocol" in steps, "campaign lost the TSAN gate"
     assert steps["tsan_protocol"].get("cpu_ok") is True
     assert os.path.exists(os.path.join(ROOT, steps["tsan_protocol"]["cmd"][1]))

@@ -1,10 +1,10 @@
-"""Render CAMPAIGN_r05.json into BASELINE.md-ready markdown.
+"""Render a measure_campaign out-file into BASELINE.md-ready markdown.
 
 The campaign writes raw per-step records (tools/measure_campaign.py); this
 turns them into the tables/sentences BASELINE.md wants, so the scarce
 minutes after a hardware window close on bookkeeping, not reformatting.
 
-Usage: python tools/campaign_report.py [CAMPAIGN_r05.json]
+Usage: python tools/campaign_report.py [chiprun_out/campaign.json]
 """
 
 from __future__ import annotations
@@ -248,7 +248,7 @@ def fmt_overload(rec: dict, ok: str) -> str:
 
 
 def main():
-    path = sys.argv[1] if len(sys.argv) > 1 else os.path.join(ROOT, "CAMPAIGN_r05.json")
+    path = sys.argv[1] if len(sys.argv) > 1 else os.path.join(ROOT, "chiprun_out", "campaign.json")
     with open(path) as f:
         state = json.load(f)
     print(f"# Campaign report — started {state.get('started')}, status {state.get('status')}")

@@ -79,6 +79,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+# A host-side rig: this process and every child it starts run JAX on the
+# CPU, whatever the machine's own JAX_PLATFORMS says (the chip machine sets
+# "tpu,cpu", and a chip belongs to one process at a time).
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 #: Verdict schema version (tests pin it).
 VERDICT_SCHEMA_VERSION = 1
 
@@ -465,7 +470,6 @@ def run_reshard(args) -> int:
     plan = "" if args.no_chaos else f"die:role=worker1,after_s={t_kill:.1f}"
     env = dict(os.environ)
     env.pop("DTX_FAULT_ROLE", None)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["DTX_FAULT_PLAN"] = plan
     procs: dict[str, subprocess.Popen] = {}
 
@@ -737,7 +741,6 @@ def run_overload(args) -> int:
     ]
     env = dict(os.environ)
     env.pop("DTX_FAULT_ROLE", None)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["DTX_FAULT_PLAN"] = ""  # overload IS the fault; no injected chaos
     procs: dict[str, subprocess.Popen] = {}
 
@@ -1040,7 +1043,6 @@ def run_multitenant(args) -> int:
     ]
     env = dict(os.environ)
     env.pop("DTX_FAULT_ROLE", None)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["DTX_FAULT_PLAN"] = ""  # the noisy neighbor IS the fault
     procs: dict[str, subprocess.Popen] = {}
 
@@ -1314,7 +1316,6 @@ def run_canary(args) -> int:
     plan = "" if args.no_chaos else f"die:role=serve1,after_s={t_kill:.1f}"
     env = dict(os.environ)
     env.pop("DTX_FAULT_ROLE", None)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["DTX_FAULT_PLAN"] = plan
     procs: dict[str, subprocess.Popen] = {}
 
@@ -1849,7 +1850,6 @@ def main(argv=None) -> int:
     # orchestrator's own exported role must NOT leak into them (it would
     # defeat every role glob in the plan).
     env.pop("DTX_FAULT_ROLE", None)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["DTX_FAULT_PLAN"] = plan
     procs: dict[str, subprocess.Popen] = {}
     spawn_t: dict[str, float] = {}

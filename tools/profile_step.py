@@ -135,6 +135,9 @@ def main():
         kw["loss_chunks"] = args.loss_chunks
     if args.n_heads is not None:
         kw["n_heads"] = args.n_heads
+    from distributed_tensorflow_examples_tpu.utils import compile_cache
+
+    compile_cache.enable()
     tdir = _trace_step(args.model, args.steps, args.batch_per_chip, **kw)
     op_table(tdir, args.top, args.steps)
     print(f"trace dir: {tdir}")

@@ -55,7 +55,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# A host-side rig: this process and every child it starts run JAX on the
+# CPU, whatever the machine's own JAX_PLATFORMS says (the chip machine sets
+# "tpu,cpu", and a chip belongs to one process at a time).
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 from distributed_tensorflow_examples_tpu import serve  # noqa: E402
 from distributed_tensorflow_examples_tpu.parallel import (  # noqa: E402
