@@ -230,9 +230,8 @@ def bench_moe(steps: int, batch_per_chip: int, **kw):
     """MoE flagship (VERDICT r3 missing #3: the expert-parallel axis needs a
     measured number, not just HLO proofs): ``bench_transformer`` with E=8
     top-2 — ~0.9B params, so the f32 AdamW state caps the single-chip batch
-    (default 4; sweep on TPU).  Dispatch-einsum share of step time:
-    ``tools/profile_step.py --model moe`` (BASELINE.md records the account
-    vs the dense flagship)."""
+    (default 4; sweep on TPU).  BASELINE.md records the dispatch-einsum
+    share of step time against the dense flagship."""
     kw.setdefault("experts", 8)
     return bench_transformer(steps, batch_per_chip, **kw)
 

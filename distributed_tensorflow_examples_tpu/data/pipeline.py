@@ -20,6 +20,12 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..parallel.sharding import batch_sharding
+from ..utils import telemetry
+
+# ``prefetch_to_mesh``'s leaf spans (handles resolved once, here).
+_SPAN_NEXT = telemetry.span("input/next")
+_SPAN_TO_DEVICE = telemetry.span("input/to_device")
+_SPAN_WAIT = telemetry.span("input/wait")
 
 
 class InMemoryPipeline:
@@ -112,19 +118,28 @@ def prefetch_to_mesh(
     transform: Callable[[dict[str, np.ndarray]], Any] | None = None,
 ) -> Iterator[Any]:
     """Background-thread infeed: keeps ``depth`` global device batches queued
-    ahead of the consumer, overlapping host->HBM DMA with step compute."""
+    ahead of the consumer, overlapping host->HBM DMA with step compute.
+
+    Three leaf spans say where its time goes: ``input/next`` (the source's
+    ``next``: the host gather), ``input/to_device`` (``as_global``) on the
+    producer's thread, ``input/wait`` (the consumer blocked on the queue)."""
     q: queue.Queue = queue.Queue(maxsize=depth)
     stop = threading.Event()
     _SENTINEL = object()
 
     def _producer():
         try:
-            for batch in it:
-                if stop.is_set():
+            source = iter(it)
+            while not stop.is_set():
+                with _SPAN_NEXT:
+                    batch = next(source, _SENTINEL)
+                if batch is _SENTINEL:
                     return
                 if transform is not None:
                     batch = transform(batch)
-                q.put(as_global(batch, mesh, spec=spec))
+                with _SPAN_TO_DEVICE:
+                    batch = as_global(batch, mesh, spec=spec)
+                q.put(batch)
         except Exception as e:  # surface producer errors at the consumer
             q.put(e)
         finally:
@@ -134,7 +149,8 @@ def prefetch_to_mesh(
     t.start()
     try:
         while True:
-            item = q.get()
+            with _SPAN_WAIT:
+                item = q.get()
             if item is _SENTINEL:
                 return
             if isinstance(item, Exception):

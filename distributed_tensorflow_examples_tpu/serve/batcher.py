@@ -340,11 +340,16 @@ class StreamTicket:
     produced its full budget) or an error (the step function raised — the
     whole active batch fails, like the row batcher's contract)."""
 
-    __slots__ = ("state", "_emits", "_done", "_error", "_cancelled",
-                 "_lock", "_event")
+    __slots__ = ("state", "opened_ns", "seated_ns", "_emits", "_done",
+                 "_error", "_cancelled", "_lock", "_event")
 
     def __init__(self, state):
         self.state = state
+        # ``time.perf_counter_ns()`` when the session was opened and when
+        # it took a slot (None while queued): what the batcher's seat-wait
+        # and first-token sums are taken from.
+        self.opened_ns = time.perf_counter_ns()
+        self.seated_ns: int | None = None
         self._emits: list = []
         self._done = False
         self._error: BaseException | None = None
@@ -417,6 +422,12 @@ class SlotBatcher:
     An exception out of ``run_step`` fails every ACTIVE session (each
     waiter sees it) and frees their slots — queued sessions then take
     slots and run; the batcher itself never dies.
+
+    The step thread's own time is kept as LEAF spans (``telemetry.span``):
+    ``<name>/fill`` seating and dropping under the lock, ``<name>/park``
+    waiting with no active slot, ``<name>/emit`` handing results to the
+    tickets; ``run_step`` adds its own between fill and emit.  None wraps
+    another and none wraps the iteration.
     """
 
     def __init__(
@@ -441,6 +452,17 @@ class SlotBatcher:
         self.steps = 0
         self.emitted = 0
         self.step_errors = 0
+        # Slot-steps that emitted nothing (an input was fed); sessions
+        # seated with their summed wait since ``open``; sessions that
+        # emitted their first item with their summed time since seating.
+        self.fed = 0
+        self.seated = 0
+        self.seat_wait_ns = 0
+        self.first_tokens = 0
+        self.first_token_ns = 0
+        self._span_fill = telemetry.span(f"{name}/fill")
+        self._span_park = telemetry.span(f"{name}/park")
+        self._span_emit = telemetry.span(f"{name}/emit")
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name=f"dtx-{name}-slots"
         )
@@ -479,6 +501,11 @@ class SlotBatcher:
                 "steps": self.steps,
                 "emitted": self.emitted,
                 "step_errors": self.step_errors,
+                "fed": self.fed,
+                "seated": self.seated,
+                "seat_wait_ns": self.seat_wait_ns,
+                "first_tokens": self.first_tokens,
+                "first_token_ns": self.first_token_ns,
             }
 
     def stop(self) -> None:
@@ -506,6 +533,9 @@ class SlotBatcher:
                 )
                 self._slots[i] = t
                 self._fresh.add(t)
+                t.seated_ns = time.perf_counter_ns()
+                self.seated += 1
+                self.seat_wait_ns += t.seated_ns - t.opened_ns
             snapshot = list(self._slots)
         return snapshot, any(s is not None for s in snapshot)
 
@@ -513,10 +543,12 @@ class SlotBatcher:
         while True:
             if self._stopped:
                 break
-            slots, active = self._fill_slots()
+            with self._span_fill:
+                slots, active = self._fill_slots()
             if not active:
-                self._work.wait(self._idle_wait_s)
-                self._work.clear()
+                with self._span_park:
+                    self._work.wait(self._idle_wait_s)
+                    self._work.clear()
                 continue
             try:
                 results = self._run(slots)
@@ -527,16 +559,24 @@ class SlotBatcher:
                         t._finish(error=e)
                 continue
             self.steps += 1
-            for i, t in enumerate(slots):
-                if t is None:
-                    continue
-                self._fresh.discard(t)
-                emits, done = results[i]
-                if emits:
-                    self.emitted += len(emits)
-                    t._emit(emits)
-                if done:
-                    t._finish()
+            with self._span_emit:
+                for i, t in enumerate(slots):
+                    if t is None:
+                        continue
+                    self._fresh.discard(t)
+                    emits, done = results[i]
+                    if emits:
+                        if not t._emits:  # only this thread appends
+                            self.first_tokens += 1
+                            self.first_token_ns += (
+                                time.perf_counter_ns() - t.seated_ns
+                            )
+                        self.emitted += len(emits)
+                        t._emit(emits)
+                    else:
+                        self.fed += 1
+                    if done:
+                        t._finish()
         # Drain: every active and queued session fails loudly instead of
         # hanging its poller.
         err = RuntimeError("slot batcher stopped")

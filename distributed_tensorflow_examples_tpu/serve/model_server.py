@@ -92,6 +92,18 @@ NO_MODEL = wire.SRV_STATUS["NO_MODEL"]
 BAD_SESSION = wire.SRV_STATUS["BAD_SESSION"]
 NO_DECODER = wire.SRV_STATUS["NO_DECODER"]
 
+# The decode engine's share of the step thread's time, as leaf spans in the
+# slot batcher's family (its ``name``, "decode").  They follow the batcher's
+# ``decode/fill`` and precede its ``decode/emit``; none wraps another.
+#: Seeding fresh slots, uploading the tokens and positions.
+_SPAN_PREPARE = telemetry.span("decode/prepare")
+#: Launching the jitted step (the runtime's ``PjitFunction(step_fn)``).
+_SPAN_DISPATCH = telemetry.span("decode/dispatch")
+#: Waiting for the device, then the logits to the host.
+_SPAN_FETCH = telemetry.span("decode/fetch")
+#: Next token and position per slot (teacher-force or ``argmax``).
+_SPAN_SELECT = telemetry.span("decode/select")
+
 
 def flat_param_spec(init_fn):
     """``(total_elems, unflatten)`` for the parameter STRUCTURE ``init_fn``
@@ -161,39 +173,43 @@ class _DecodeEngine:
     def _run_step(self, slots):
         import jax.numpy as jnp
 
-        model = self._get_model()
-        if model is None:
-            raise _NoModel()
-        _step, params = model
-        for i, t in enumerate(slots):
-            if t is not None and not t.state["seated"]:
-                # A freshly seated session starts its slot at position 0
-                # feeding its first prompt token; the cache needs no
-                # reset (see the class docstring).
-                t.state["seated"] = True
-                self._tokens[i] = t.state["prompt"][0]
-                self._pos[i] = 0
-        logits, self._cache = self._step_jit(
-            params, self._cache,
-            jnp.asarray(self._tokens), jnp.asarray(self._pos),
-        )
-        out = np.asarray(logits)
-        results: list = [None] * len(slots)
-        for i, t in enumerate(slots):
-            if t is None:
-                continue
-            st = t.state
-            p = int(self._pos[i])
-            if p + 1 < len(st["prompt"]):
-                nxt = int(st["prompt"][p + 1])  # teacher-force the prompt
-                emits: list[int] = []
-            else:
-                nxt = int(np.argmax(out[i]))  # greedy continuation
-                emits = [nxt]
-                st["emitted"] += 1
-            self._tokens[i] = nxt
-            self._pos[i] = p + 1
-            results[i] = (emits, st["emitted"] >= st["n"])
+        with _SPAN_PREPARE:
+            model = self._get_model()
+            if model is None:
+                raise _NoModel()
+            _step, params = model
+            for i, t in enumerate(slots):
+                if t is not None and not t.state["seated"]:
+                    # A freshly seated session starts its slot at position
+                    # 0 feeding its first prompt token; the cache needs no
+                    # reset (see the class docstring).
+                    t.state["seated"] = True
+                    self._tokens[i] = t.state["prompt"][0]
+                    self._pos[i] = 0
+            tokens, pos = jnp.asarray(self._tokens), jnp.asarray(self._pos)
+        with _SPAN_DISPATCH:
+            logits, self._cache = self._step_jit(
+                params, self._cache, tokens, pos
+            )
+        with _SPAN_FETCH:
+            out = np.asarray(logits)
+        with _SPAN_SELECT:
+            results: list = [None] * len(slots)
+            for i, t in enumerate(slots):
+                if t is None:
+                    continue
+                st = t.state
+                p = int(self._pos[i])
+                if p + 1 < len(st["prompt"]):
+                    nxt = int(st["prompt"][p + 1])  # teacher-force the prompt
+                    emits: list[int] = []
+                else:
+                    nxt = int(np.argmax(out[i]))  # greedy continuation
+                    emits = [nxt]
+                    st["emitted"] += 1
+                self._tokens[i] = nxt
+                self._pos[i] = p + 1
+                results[i] = (emits, st["emitted"] >= st["n"])
         return results
 
     def stats(self) -> dict:
@@ -262,6 +278,7 @@ class ModelReplicaServer:
         from . import registry as registry_lib
 
         compile_cache.enable()
+        telemetry.count_compiles()
         total, self._unflatten = flat_param_spec(init_fn)
         self._predict = jax.jit(predict_fn)
         self.role = role if role is not None else (

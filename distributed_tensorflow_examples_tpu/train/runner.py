@@ -20,7 +20,7 @@ from jax.sharding import Mesh, PartitionSpec
 
 from ..data import pipeline as pipeline_lib
 from ..parallel import MeshSpec, build_mesh, dist
-from ..utils import compile_cache
+from ..utils import compile_cache, telemetry
 from ..utils.metrics import MetricsWriter
 from . import hooks as hooks_lib
 from .checkpoint import CheckpointManager
@@ -68,6 +68,7 @@ class Experiment:
             )
             raise SystemExit(0)
         compile_cache.enable()
+        telemetry.count_compiles()
         if getattr(flags, "watchdog", True):
             # Multi-process fail-fast (no-op single-process): a dead peer
             # must crash the job promptly so the per-task supervisor can

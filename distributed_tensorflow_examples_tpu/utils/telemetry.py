@@ -1,4 +1,5 @@
-"""dtxobs core (r13): process-wide metrics registry + event flight recorder.
+"""dtxobs core (r13): process-wide metrics registry + event flight recorder
++ (PR 24) the one span primitive.
 
 Every role in the cluster (PS task, data server, serve replica, chief,
 worker) accumulates its health into two process-wide singletons:
@@ -19,6 +20,13 @@ worker) accumulates its health into two process-wide singletons:
   fatal conditions (``REPL_DIVERGED`` latches, reconnect-budget
   exhaustion, injected deaths) so a post-mortem can attribute the failure
   to its cause without having had logging configured in advance.
+
+:func:`span` puts one interval of host work on both records at once: an
+event in the profiler's trace while a session runs (on the clock of the
+device planes) and two registry counters, ``<name>/ns`` and ``<name>/n``,
+always.  :func:`count_compiles` counts the process's XLA compilations the
+same way (``jax/compiles``, ``jax/compile_ns``, a ``compile`` event).  The
+module itself imports without JAX.
 
 Naming convention: ``<family>/<metric>`` (``ps_client/reconnects``,
 ``ps_shard/pull_cache_hits``) — same family idea as
@@ -285,6 +293,99 @@ class FlightRecorder:
 
 #: The process-wide flight recorder.
 RECORDER = FlightRecorder()
+
+
+# ---------------------------------------------------------------------------
+# Spans: one interval on two records — the profiler's trace (when a session
+# is running) and the registry's running sums (always)
+# ---------------------------------------------------------------------------
+
+#: ``jax.profiler.TraceAnnotation``, resolved at the first span entered:
+#: this module must import without JAX (``tools/tsan_driver.py``).
+_annotation = None
+
+
+def _resolve_annotation():
+    global _annotation
+    from jax.profiler import TraceAnnotation
+
+    _annotation = TraceAnnotation
+    return TraceAnnotation
+
+
+class Span:
+    """A named interval of host work.  Entering it opens a
+    ``jax.profiler.TraceAnnotation(name)`` — nothing without a profiler
+    session, and with one an event on the host thread's line, on the
+    clock the device planes share — and leaving it adds the elapsed
+    ``time.perf_counter_ns()`` to counter ``<name>/ns`` and 1 to
+    ``<name>/n``.  The trace is the list of events, the counters are their
+    sums; nothing else is kept and nothing turns a span off.
+
+    One handle serves any number of threads (the open interval is
+    per-thread), but not two nested entries on one thread: spans are
+    leaves — they follow each other and never wrap another, because the
+    trace's gap labeller gives a gap to the event that covers most of it
+    and an enclosing span would take every label."""
+
+    __slots__ = ("name", "_ns", "_n", "_open")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._ns = REGISTRY.counter(f"{name}/ns")
+        self._n = REGISTRY.counter(f"{name}/n")
+        self._open = threading.local()
+
+    def __enter__(self) -> "Span":
+        a = (_annotation or _resolve_annotation())(self.name)
+        a.__enter__()
+        self._open.annotation = a
+        self._open.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter_ns() - self._open.t0
+        self._open.annotation.__exit__(*exc)
+        self._ns.inc(dt)
+        self._n.inc()
+
+
+#: ``telemetry.span(name)`` is how call sites spell it: resolve the handle
+#: once per site (a module constant, an attribute set in ``__init__``) and
+#: enter it with ``with`` each time round.
+span = Span
+
+
+#: The runtime's event for one program built (a persistent-cache load
+#: included: either way a new shape reached the compiler's front door).
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def count_compiles() -> None:
+    """Count every XLA compilation of this process from here on: counters
+    ``jax/compiles`` and ``jax/compile_ns``, and a ``compile`` event in
+    the flight recorder saying when.  Idempotent; called where a replica
+    or a training run starts, so "did anything compile in the steady
+    state" is a count read from ``STATS``, not an inference."""
+    global _compile_listener_on
+    with _compile_listener_lock:
+        if _compile_listener_on:
+            return
+        import jax.monitoring
+
+        compiles = REGISTRY.counter("jax/compiles")
+        compile_ns = REGISTRY.counter("jax/compile_ns")
+
+        def _on_duration(event: str, seconds: float, **_kw) -> None:
+            if event == _COMPILE_EVENT:
+                compiles.inc()
+                compile_ns.inc(int(seconds * 1e9))
+                record_event("compile", seconds=seconds)
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _compile_listener_on = True
 
 
 def record_event(event: str, **fields) -> None:

@@ -30,7 +30,7 @@ def test_campaign_plan_is_well_formed():
     # The r4 agenda's core steps must all be present.
     for required in (
         "flash_parity", "bench_t8192_fused", "bench_t8192_split",
-        "flash_bench_t16384_f1", "bench_moe", "profile_moe", "bench_resnet",
+        "flash_bench_t16384_f1", "bench_moe", "bench_resnet",
         "comms_measure", "ulysses_ab", "bench_decode_moe",
         "bench_decode_pipeline", "ps_tpu_smoke",
     ):

@@ -82,6 +82,116 @@ def test_registry_reset_keeps_cached_handles():
     assert reg.snapshot()["t/g"] == 0.0
 
 
+def test_span_sums_exact_under_threads(monkeypatch):
+    """``telemetry.span``: one handle entered from many threads adds
+    exactly its elapsed nanoseconds to ``<name>/ns`` and 1 to ``<name>/n``
+    per entry.  The clock is faked per thread (another base each, a fixed
+    tick a read), so a start stamp kept on the shared handle instead of
+    per thread, or a lost update, would show in the sum."""
+    import types
+
+    tick, n_threads, per = 7, 8, 2000
+    local = threading.local()
+    bases = iter(range(10**12, 10**12 * (n_threads + 2), 10**12))
+    bases_lock = threading.Lock()
+
+    def fake_ns() -> int:
+        if not hasattr(local, "now"):
+            with bases_lock:
+                local.now = next(bases)
+        local.now += tick
+        return local.now
+
+    monkeypatch.setattr(
+        telemetry, "time",
+        types.SimpleNamespace(perf_counter_ns=fake_ns, time=time.time),
+    )
+    sp = telemetry.span("t_span/exact")
+    assert sp is not telemetry.span("t_span/exact")  # a handle per site...
+    before = telemetry.snapshot()  # ...on the same two counters
+    assert before["t_span/exact/n"] == 0 and before["t_span/exact/ns"] == 0
+
+    def body():
+        for _ in range(per):
+            with sp:
+                pass
+
+    threads = [threading.Thread(target=body) for _ in range(n_threads)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    snap = telemetry.snapshot()
+    assert snap["t_span/exact/n"] == n_threads * per
+    assert snap["t_span/exact/ns"] == n_threads * per * tick
+    # An exception passes through and the interval still counts.
+    with pytest.raises(KeyError):
+        with sp:
+            raise KeyError("x")
+    assert telemetry.snapshot()["t_span/exact/n"] == n_threads * per + 1
+
+
+def test_telemetry_imports_and_resolves_spans_without_jax():
+    """``tools/tsan_driver.py`` loads ``utils.telemetry`` in a process
+    where JAX must never load: importing the module and resolving a span
+    handle may not import it (only ENTERING a span reaches for the
+    profiler's annotation)."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = """
+import importlib, os, sys, types
+sys.modules["jax"] = None  # any 'import jax' now raises ImportError
+pkg = "distributed_tensorflow_examples_tpu"
+for name, path in ((pkg, pkg), (pkg + ".utils", os.path.join(pkg, "utils"))):
+    mod = types.ModuleType(name)
+    mod.__path__ = [os.path.join(sys.argv[1], path)]
+    sys.modules[name] = mod
+t = importlib.import_module(pkg + ".utils.telemetry")
+t.span("x/y")
+assert t.snapshot() == {"x/y/ns": 0, "x/y/n": 0}, t.snapshot()
+assert not [m for m in sys.modules if m.startswith("jax.")]
+print("ok")
+"""
+    r = subprocess.run(
+        [sys.executable, "-c", code, root], capture_output=True, text=True,
+        timeout=60,
+    )
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr
+
+
+def test_compile_counter_counts_new_shapes_only():
+    """``telemetry.count_compiles``: a new shape is one compile, a repeat
+    is none, registering twice does not count twice, and the flight
+    recorder says when."""
+    import jax
+
+    telemetry.count_compiles()
+    telemetry.count_compiles()
+
+    @jax.jit
+    def f(x):
+        return x * 2 + 1
+
+    def read():
+        snap = telemetry.snapshot()
+        return snap["jax/compiles"], snap["jax/compile_ns"]
+
+    n0, ns0 = read()
+    events0 = sum(1 for e in telemetry.RECORDER.events() if e["event"] == "compile")
+    f(np.ones((3,), np.float32)).block_until_ready()
+    n1, ns1 = read()
+    assert n1 - n0 == 1 and ns1 > ns0
+    f(np.ones((3,), np.float32)).block_until_ready()
+    assert read() == (n1, ns1)
+    f(np.ones((5,), np.float32)).block_until_ready()
+    assert read()[0] - n1 == 1
+    events = [e for e in telemetry.RECORDER.events() if e["event"] == "compile"]
+    assert len(events) - events0 == 2 and events[-1]["seconds"] > 0
+
+
 def test_histogram_bounded_window_percentiles():
     h = telemetry.Histogram("w", capacity=10)
     assert h.snapshot() == {

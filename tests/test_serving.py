@@ -901,6 +901,96 @@ def test_slot_batcher_step_error_fails_active_sessions_only():
         b.stop()
 
 
+def _wait_done(tickets, timeout_s: float = 20.0):
+    deadline = time.monotonic() + timeout_s
+    while not all(t.done for t in tickets):
+        assert time.monotonic() < deadline
+        time.sleep(0.005)
+
+
+def _scripted_step(step_s: float):
+    """A ``run_step`` whose sessions feed ``state["feed"]`` inputs (no
+    emission), then emit one item a step up to ``state["n"]``; every step
+    sleeps ``step_s``."""
+
+    def run_step(slots):
+        time.sleep(step_s)
+        out = [None] * len(slots)
+        for i, t in enumerate(slots):
+            if t is None:
+                continue
+            st = t.state
+            st["seen"] = st.get("seen", 0) + 1
+            if st["seen"] <= st["feed"]:
+                out[i] = ([], False)
+            else:
+                k = st["seen"] - st["feed"]
+                out[i] = ([k], k >= st["n"])
+        return out
+
+    return run_step
+
+
+def test_slot_batcher_counts_feeding_seating_and_first_tokens():
+    """The batcher's inside counters, against a script: ``fed`` is every
+    occupied slot-step that emitted nothing, ``seated`` / ``first_tokens``
+    count sessions, and the two sums of nanoseconds are at least the
+    sleeps the script put between the stamps."""
+    step_s = 0.02
+    b = batcher_lib.SlotBatcher(_scripted_step(step_s), slots=2)
+    try:
+        t1 = b.open({"feed": 2, "n": 2})  # fed, fed, emit, emit
+        t2 = b.open({"feed": 0, "n": 1})  # emit
+        _wait_done([t1, t2])
+        assert t1.snapshot() == ([1, 2], True) and t2.snapshot() == ([1], True)
+        s = b.stats()
+        assert s["fed"] == 2 and s["emitted"] == 3
+        assert s["seated"] == 2 and s["first_tokens"] == 2
+        # Occupied slot-steps split exactly into feeding and emitting.
+        assert s["fed"] + s["emitted"] == 4 + 1
+        # t1's first token came out of its third step, t2's of its first.
+        assert s["first_token_ns"] >= (3 + 1) * step_s * 1e9
+        assert s["seat_wait_ns"] == sum(
+            t.seated_ns - t.opened_ns for t in (t1, t2)
+        )
+        # A session that never emits counts as fed only, and as no first
+        # token, when it is cancelled.
+        t3 = b.open({"feed": 10_000, "n": 1})
+        deadline = time.monotonic() + 10
+        while b.stats()["fed"] < 4:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        t3.cancel()
+        s = b.stats()
+        assert s["seated"] == 3 and s["first_tokens"] == 2
+    finally:
+        b.stop()
+
+
+def test_slot_batcher_seat_wait_is_the_queued_sessions_own():
+    """Three sessions on ONE slot: the first is seated at once, the second
+    waits out the first, the third waits out both — each stamp on its own
+    ticket, the batcher's sum exactly their total."""
+    step_s, n = 0.02, 3
+    b = batcher_lib.SlotBatcher(_scripted_step(step_s), slots=1)
+    try:
+        ts = [b.open({"feed": 0, "n": n}) for _ in range(3)]
+        _wait_done(ts)
+        waits = [t.seated_ns - t.opened_ns for t in ts]
+        one_session_ns = n * step_s * 1e9
+        assert waits[0] < waits[1] < waits[2]
+        assert waits[0] < one_session_ns  # never queued behind a session
+        assert waits[1] >= one_session_ns
+        assert waits[2] >= 2 * one_session_ns
+        s = b.stats()
+        assert s["seated"] == 3 and s["seat_wait_ns"] == sum(waits)
+        assert s["fed"] == 0 and s["first_tokens"] == 3
+        # First token = one step after seating, for each of them.
+        assert s["first_token_ns"] >= 3 * step_s * 1e9
+    finally:
+        b.stop()
+
+
 # ----------------------------------------------------------------------------
 # Decode sessions over the wire (r19)
 # ----------------------------------------------------------------------------
@@ -1047,3 +1137,108 @@ def test_hot_tracking_replica_stamps_version_zero():
         srv.stop()
         group.close()
         ps_service.stop_server()
+
+
+# ----------------------------------------------------------------------------
+# The step thread's spans and the compile counter (PR 24)
+# ----------------------------------------------------------------------------
+
+_STEP_SPANS = (
+    "decode/fill", "decode/prepare", "decode/dispatch", "decode/fetch",
+    "decode/select", "decode/emit",
+)
+
+
+def test_decode_spans_reach_the_profiler_trace_as_leaves(tmp_path):
+    """A ``jax.profiler`` trace of a live replica holds all seven span
+    names on the step thread's ``python`` line — where the benchmark's
+    ``trace.load`` collects host events — and no two of them overlap: the
+    spans are leaves that follow each other."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    srv = _pinned_decode_server(tmp_path / "reg", "trc0")
+    try:
+        c = serve.ServeClient("127.0.0.1", srv.port, role="trc_sv")
+        c.generate(np.array([1, 2], np.int32), 2)  # compile outside the trace
+        before = c.stats()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        opts.enable_hlo_proto = False
+        jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+        try:
+            time.sleep(0.25)  # idle: the step thread parks
+            out = c.generate(np.array([3, 4, 5], np.int32), 5)
+        finally:
+            jax.profiler.stop_trace()
+        assert out.tolist() == [6, 7, 8, 9, 10]
+        after = c.stats()
+        c.close()
+    finally:
+        srv.stop()
+    (path,) = glob.glob(
+        str(tmp_path / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = sorted(
+                (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                for ev in line.events if ev.name.startswith("decode/")
+            )
+            if evs:
+                assert line.name.startswith("python")
+                lines.append(evs)
+    assert len(lines) == 1, "one step thread, one line"
+    (evs,) = lines
+    assert {name for _s, _e, name in evs} == {*_STEP_SPANS, "decode/park"}
+    for (_s0, e0, n0), (s1, _e1, n1) in zip(evs, evs[1:]):
+        assert e0 <= s1, f"{n0} overlaps {n1}"
+    # The same intervals reached the registry: seven steps of the traced
+    # request, each span entered once a step.
+    steps = after["decode_steps"] - before["decode_steps"]
+    assert steps == 7
+    for name in _STEP_SPANS[1:]:
+        assert (
+            after["registry"][f"{name}/n"] - before["registry"][f"{name}/n"]
+            == steps
+        )
+        assert after["registry"][f"{name}/ns"] > before["registry"][f"{name}/ns"]
+    for key in ("fed", "seated", "seat_wait_ns", "first_tokens", "first_token_ns"):
+        assert f"decode_{key}" in after
+    assert after["decode_fed"] - before["decode_fed"] == 2  # 3-token prompt
+
+
+def test_served_decode_program_is_named_step_fn():
+    """The benchmark finds the decode step in a device trace by its
+    program's name (``decode_step_ms``'s ``module`` is ``jit_step_fn``):
+    the function the engine jits must keep the name ``step_fn``."""
+    import jax
+
+    from distributed_tensorflow_examples_tpu import models
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    cfg = models.transformer.Config(
+        vocab_size=32, dim=16, n_layers=1, n_heads=2, max_seq_len=16,
+    )
+    init_cache_fn, step_fn = models.transformer.serve_decode_fns(cfg)
+    assert step_fn.__name__ == "step_fn"
+    engine = model_server._DecodeEngine(
+        lambda: None, init_cache_fn, step_fn, slots=2, max_len=16,
+        max_sessions=4,
+    )
+    try:
+        params = jax.eval_shape(
+            lambda k: models.transformer.init(cfg, k), jax.random.key(0)
+        )
+        lowered = engine._step_jit.lower(
+            params, engine._cache, np.zeros(2, np.int32), np.zeros(2, np.int32)
+        )
+        assert "module @jit_step_fn" in lowered.as_text()
+    finally:
+        engine.stop()

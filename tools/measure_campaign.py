@@ -16,7 +16,7 @@ Order (by value — the r4 perf agenda first):
   2.  bench T=8192 fused / split end-to-end A/B, block sweeps
   3.  flash_bench kernel-table rows T=8192/16384 x fused 0/1
   4.  batch-4 via --loss-chunks 8
-  5.  MoE bench + dispatch-share profile
+  5.  MoE bench
   6.  headline re-measures (resnet, T=2048 flagship)
   7.  comms_scaling --measure (Ulysses t_step columns)
   8.  ulysses_ab (single-chip CP compute A/B)
@@ -92,7 +92,6 @@ def steps_plan() -> list[dict]:
             "--batch-per-chip", "4", "--loss-chunks", "8",
         ], env={"DTX_FUSED_BWD": "{FUSED}"}, timeout=1500),
         dict(name="bench_moe", cmd=bench + ["--model", "moe"], timeout=1500),
-        dict(name="profile_moe", cmd=[PY, "tools/profile_step.py", "--model", "moe"], timeout=1500),
         # Dispatch-share lever A/B: G=512 halves dispatch FLOPs/token vs the
         # G=1024 default (capacity semantics change with G — this is a
         # throughput A/B, not a parity pair).
