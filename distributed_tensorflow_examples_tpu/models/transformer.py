@@ -484,15 +484,17 @@ def _block_decode(cfg: Config, p, h, layer_cache, pos, *, constrain, mesh=None):
     return h, {"k": ck, "v": cv}
 
 
-def _decode_constrain(mesh: Mesh | None):
+def _decode_constrain(mesh: Mesh | None, drop: tuple = ("seq",)):
     """Constraint fn for the decode path: same specs as training, except
-    any 'seq' entry becomes None (the decode T dim is 1 and must not be
-    forced onto the sequence axis)."""
+    any entry in ``drop`` becomes None — 'seq' always (the decode T dim is
+    1, a prefill chunk's is one slot's, and neither may be forced onto the
+    sequence axis); a prefill chunk drops the data axes too (its batch dim
+    is the ONE slot it fills)."""
     if mesh is None:
         return lambda y, spec: y
 
     def constrain(y, spec):
-        spec = P(*(None if e == "seq" else e for e in spec))
+        spec = P(*(None if e in drop else e for e in spec))
         return jax.lax.with_sharding_constraint(
             y, jax.sharding.NamedSharding(mesh, spec)
         )
@@ -608,11 +610,112 @@ def decode_step_batch(
     return layers.dense(params["head"], h, dtype=cfg.dtype)[:, 0], new_cache
 
 
+def _block_prefill(
+    cfg: Config, p, h, layer_cache, slot, offset, n_valid, *, constrain,
+):
+    """One dense block for ``C`` consecutive tokens of ONE slot: h
+    [1, C, D] holds the slot's positions ``offset .. offset + C - 1``, of
+    which the first ``n_valid`` are real.  Their K/V land in the slot's
+    cache rows ``[offset, offset + n_valid)`` and in no other row; the
+    chunk's queries attend over the slot's rows ``<=`` their own position
+    (what earlier chunks wrote plus the chunk itself, causally) — the same
+    masked einsum attention as :func:`_block_decode_batch`, ``C`` rows of
+    it at once.
+
+    The write is a ``C``-row window ``dynamic_update_slice``d into the
+    cache (in place when the caller donates it).  The window starts at
+    ``min(offset, T - C)``: a chunk whose padded tail would run past the
+    cache's end is shifted back INSIDE the window instead (``roll`` +
+    the validity mask keep every row the chunk does not own as it was),
+    because ``dynamic_update_slice`` clamps a start that overruns and
+    would silently overwrite earlier rows."""
+    C = h.shape[1]
+    H, T, hd = layer_cache["k"].shape[1:]
+    y = _layernorm(p["ln1"], h)
+    qkv = layers.dense(p["qkv"], y, dtype=cfg.dtype)
+    qkv = qkv.reshape(1, C, cfg.n_heads, 3, cfg.head_dim)
+    q, k, v = [jnp.moveaxis(qkv[:, :, :, j], 2, 1) for j in range(3)]  # [1,H,C,hd]
+    q = constrain(q, P(None, "model", None, None))
+    start = jnp.clip(offset, 0, T - C)
+    shift = offset - start  # > 0 only for a chunk that would pass the end
+    i = jnp.arange(C) - shift  # chunk index of each window row
+    own = ((i >= 0) & (i < n_valid))[None, None, :, None]
+    at = (slot, 0, start, 0)
+
+    def write(cache, new):
+        old = jax.lax.dynamic_slice(cache, at, (1, H, C, hd))
+        win = jnp.where(own, jnp.roll(new, shift, axis=2), old)
+        cache = jax.lax.dynamic_update_slice(cache, win, at)
+        cache = constrain(cache, P(cfg.data_axes, "model", None, None))
+        rows = jax.lax.dynamic_slice_in_dim(cache, slot, 1, axis=0)
+        return cache, constrain(rows, P(None, "model", None, None))
+
+    ck, sk = write(layer_cache["k"], k)
+    cv, sv = write(layer_cache["v"], v)
+    s = jnp.einsum(
+        "bhqd,bhtd->bhqt", q, sk, preferred_element_type=jnp.float32
+    ) / math.sqrt(cfg.head_dim)
+    q_pos = offset + jnp.arange(C)
+    s = jnp.where(
+        jnp.arange(T)[None, None, None, :] <= q_pos[None, None, :, None],
+        s, -jnp.inf,
+    )
+    w = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
+    o = jnp.einsum("bhqt,bhtd->bhqd", w, sv)
+    o = jnp.moveaxis(o, 1, 2).reshape(1, C, cfg.dim)
+    h = h + layers.dense(p["proj"], o, dtype=cfg.dtype)
+    h = constrain(h, P(None, None, None))
+    return _mlp_tail(cfg, p, h, constrain), {"k": ck, "v": cv}
+
+
+def prefill_chunk(
+    cfg: Config, params, cache, tokens, slot, offset, n_valid, *,
+    mesh: Mesh | None = None,
+):
+    """tokens [C] int32 — ONE slot's prompt tokens at positions ``offset ..
+    offset + C - 1``, the first ``n_valid`` real, the rest padding — ->
+    new cache: one forward pass writes the K/V of the valid tokens into
+    ``cache[...][slot, :, offset:offset + n_valid]`` and touches no other
+    row of any slot.  What :func:`decode_step_batch` does for a prompt in
+    ``n_valid`` launches, row for row the same mathematics in the same
+    precision (summation order apart); no final norm, LM head or logits —
+    the caller decodes the prompt's LAST token the ordinary way and takes
+    the first new token from that step.  ``C`` is the static length of
+    ``tokens`` (at most the cache's ``max_len``); ``slot``, ``offset`` and
+    ``n_valid`` are traced scalars, so one program serves every chunk of
+    every prompt.  Dense blocks only: GShard capacity is per call, and a
+    ``C``-token call may drop tokens a one-token call keeps."""
+    if cfg.pipeline_stages > 1 or cfg.moe_experts > 0:
+        raise NotImplementedError(
+            "prefill_chunk supports the non-pipelined dense model"
+        )
+    C = tokens.shape[0]
+    constrain = _decode_constrain(mesh, drop=("seq", cfg.data_axes))
+    h = layers.embedding_lookup(params["emb"], tokens[None], dtype=cfg.dtype)
+    # Padding past the table's end reads a clamped row; nothing keeps it.
+    h = h + params["pos"]["table"][offset + jnp.arange(C)].astype(cfg.dtype)[None]
+    h = constrain(h, P(None, None, None))
+    new_cache = {}
+    for i in range(cfg.n_layers):
+        # The last block's projection and MLP feed nothing that is
+        # returned; the compiler drops them.
+        h, new_cache[f"block_{i}"] = _block_prefill(
+            cfg, params[f"block_{i}"], h, cache[f"block_{i}"], slot, offset,
+            n_valid, constrain=constrain,
+        )
+    return new_cache
+
+
 def serve_decode_fns(cfg: Config, *, mesh: Mesh | None = None):
-    """The ``(init_cache_fn, step_fn)`` pair a serving replica's decode
-    engine needs (``serve.ModelReplicaServer(decode_fns=...)``): slot-
-    shaped KV cache + the per-row-position batched step.  One definition,
-    so the served decode path and the model cannot drift."""
+    """The ``(init_cache_fn, step_fn[, prefill_fn])`` tuple a serving
+    replica's decode engine needs (``serve.ModelReplicaServer(decode_fns=
+    ...)``): slot-shaped KV cache, the per-row-position batched step and,
+    for dense blocks, :func:`prefill_chunk` as ``prefill_fn(params, cache,
+    tokens[C], slot, offset, n_valid) -> cache``, with which the engine
+    puts a seated prompt into the cache a chunk per forward pass.  An MoE
+    model gets the pair: the engine then feeds the prompt a token a step
+    (see :func:`prefill_chunk`).  One definition, so the served decode
+    path and the model cannot drift."""
 
     def init_cache_fn(slots: int, max_len: int):
         return init_cache(cfg, slots, max_len, mesh=mesh)
@@ -620,7 +723,14 @@ def serve_decode_fns(cfg: Config, *, mesh: Mesh | None = None):
     def step_fn(params, cache, tokens, pos):
         return decode_step_batch(cfg, params, cache, tokens, pos, mesh=mesh)
 
-    return init_cache_fn, step_fn
+    def prefill_fn(params, cache, tokens, slot, offset, n_valid):
+        return prefill_chunk(
+            cfg, params, cache, tokens, slot, offset, n_valid, mesh=mesh
+        )
+
+    if cfg.moe_experts > 0:
+        return init_cache_fn, step_fn
+    return init_cache_fn, step_fn, prefill_fn
 
 
 def generate(
@@ -635,10 +745,14 @@ def generate(
 ):
     """Autoregressive generation: prompt [B, Tp] -> [B, Tp + max_new_tokens].
 
-    One jitted ``lax.scan`` over positions with a static-shape KV cache —
-    prompt positions are teacher-forced (their logits discarded), then
-    greedy (temperature 0) or temperature sampling.  The framework's
-    inference surface; no reference analog (the reference trains only).
+    One jitted program with a static-shape KV cache: each row's prompt but
+    its last token enters the cache through :func:`prefill_chunk` (one
+    chunk a row, the path a serving replica takes), then a ``lax.scan``
+    over the remaining positions decodes greedily (temperature 0) or by
+    temperature sampling.  An MoE model has no prefill and teacher-forces
+    its prompt through the scan instead (logits discarded).  The
+    framework's inference surface; no reference analog (the reference
+    trains only).
     """
     prompt = jnp.asarray(prompt, jnp.int32)  # numpy prompts: traced indexing
     B, Tp = prompt.shape
@@ -650,8 +764,10 @@ def generate(
     cache = init_cache(cfg, B, total, mesh=mesh)
     run = _generate_loop(cfg, Tp, total, float(temperature), mesh)
     toks = run(params, cache, jnp.asarray(prompt), rng)
-    out = jnp.concatenate([prompt[:, :1], toks.T], axis=1)  # [B, total]
-    return out
+    # ``toks`` are the tokens after the scan's first position.
+    return jnp.concatenate(
+        [prompt[:, : total - toks.shape[0]], toks.T], axis=1
+    )  # [B, total]
 
 
 @functools.lru_cache(maxsize=32)
@@ -675,11 +791,23 @@ def _generate_loop(cfg: Config, Tp: int, total: int, temperature: float, mesh):
         nxt = jnp.where(pos + 1 < Tp, prompt[:, jnp.minimum(pos + 1, Tp - 1)], sampled)
         return (cache, nxt.astype(jnp.int32), rng, prompt), nxt.astype(jnp.int32)
 
+    # Where the scan starts: at the prompt's last token once the prefill
+    # has cached the tokens before it, else at position 0.
+    start = Tp - 1 if cfg.moe_experts == 0 else 0
+
     def run(params, cache, prompt, rng):
+        if start > 0:
+            cache = jax.lax.fori_loop(
+                0, prompt.shape[0],
+                lambda b, c: prefill_chunk(
+                    cfg, params, c, prompt[b, :start], b, 0, start, mesh=mesh
+                ),
+                cache,
+            )
         (_, _, _, _), toks = jax.lax.scan(
             lambda c, p: step(params, c, p),
-            (cache, prompt[:, 0], rng, prompt),
-            jnp.arange(total - 1),
+            (cache, prompt[:, start], rng, prompt),
+            jnp.arange(start, total - 1),
         )
         return toks
 

@@ -95,6 +95,8 @@ NO_DECODER = wire.SRV_STATUS["NO_DECODER"]
 # The decode engine's share of the step thread's time, as leaf spans in the
 # slot batcher's family (its ``name``, "decode").  They follow the batcher's
 # ``decode/fill`` and precede its ``decode/emit``; none wraps another.
+#: One prefill chunk, launch to completion; entered only when one runs.
+_SPAN_PREFILL = telemetry.span("decode/prefill")
 #: Seeding fresh slots, uploading the tokens and positions.
 _SPAN_PREPARE = telemetry.span("decode/prepare")
 #: Launching the jitted step (the runtime's ``PjitFunction(step_fn)``).
@@ -103,6 +105,20 @@ _SPAN_DISPATCH = telemetry.span("decode/dispatch")
 _SPAN_FETCH = telemetry.span("decode/fetch")
 #: Next token and position per slot (teacher-force or ``argmax``).
 _SPAN_SELECT = telemetry.span("decode/select")
+
+
+#: Prompt tokens one prefill chunk takes through the model.  The ONE size:
+#: the engine has two programs, the decode step and this chunk, whatever
+#: the prompt lengths (a cache shorter than this makes it the cache's
+#: length).  Sessions that decode wait one chunk longer for their next
+#: token whenever one runs, so the size trades the first token of a prompt
+#: longer than a chunk against how many token gaps carry a chunk.  Chosen
+#: on one v5e chip with Cerebras-GPT-1.3B, 8 slots x 2048 (my chip runs,
+#: PR 25; PERF.md section 6): a chunk reads the weights once whatever its
+#: size and takes 11.0 / 12.6 / 15.6 ms at 128 / 256 / 512 beside a decode
+#: step of 23.4 ms; the chat replay (prompts 16-768) needs 78 / 49 / 40
+#: chunks a window, i.e. 6 / 3.8 / 3.1 % of its steps carry one.
+PREFILL_CHUNK = 512
 
 
 def flat_param_spec(init_fn):
@@ -126,25 +142,48 @@ class _DecodeEngine:
     pos[S]) -> (logits [S, V], cache)`` — one jitted apply advances EVERY
     active session one position.  The engine owns the host-side slot
     state (current token and position per slot), greedy next-token
-    selection and prompt teacher-forcing, so batched decode is
+    selection and how a prompt reaches the cache, so batched decode is
     byte-identical to a session running alone: the slot array shape is
     FIXED (inactive slots compute inert rows, like the row batcher's pad
     rows), every row's math depends only on its own slot, and the
     attention mask confines each session to the cache positions it wrote
     itself — a freed slot needs no cache reset.
+
+    A model that also supplies ``prefill_fn(params, cache, tokens[C],
+    slot, offset, n_valid) -> cache`` (it writes the K/V of ONE slot's
+    positions ``[offset, offset + n_valid)`` and no other row) has its
+    prompts PREFILLED: before the decode step an iteration runs at most
+    one chunk of ``PREFILL_CHUNK`` tokens, for the longest-seated session
+    whose prompt is not yet cached, so every other session waits at most
+    one step plus one chunk for its next token, whatever the prompt
+    lengths or the burst.  A session being prefilled holds its slot with
+    an inert row; once all but its last prompt token are cached it is an
+    ordinary decode row at ``pos = P - 1`` and the next step emits its
+    first token.  Without ``prefill_fn`` the prompt is teacher-forced
+    through the decode step, a token a step.
     """
 
     def __init__(
-        self, model_getter, init_cache_fn, step_fn, *, slots: int,
-        max_len: int, max_sessions: int,
+        self, model_getter, init_cache_fn, step_fn, prefill_fn=None, *,
+        slots: int, max_len: int, max_sessions: int,
     ):
         import jax
 
         self._get_model = model_getter  # () -> (step, params) | None
+        self._init_cache = init_cache_fn
         self._cache = init_cache_fn(slots, max_len)
         self._step_jit = jax.jit(step_fn)
         self.slots = int(slots)
         self.max_len = int(max_len)
+        # The cache is donated to the chunk (this engine holds its only
+        # reference): the chunk's rows are written in place.
+        self._prefill_jit = (
+            jax.jit(prefill_fn, donate_argnums=1) if prefill_fn else None
+        )
+        self._chunk = min(PREFILL_CHUNK, self.max_len)
+        self._prefill_warm = False
+        self.prefill_chunks = 0
+        self.prefill_tokens = 0  # valid tokens; padding is not counted
         self._tokens = np.zeros((self.slots,), np.int32)
         self._pos = np.zeros((self.slots,), np.int32)
         self.batcher = batcher_lib.SlotBatcher(
@@ -166,26 +205,83 @@ class _DecodeEngine:
                 f"{prompt.size} prompt + {n} new tokens exceeds the "
                 f"replica's decode_max_len={self.max_len}"
             )
-        return self.batcher.open(
-            {"prompt": prompt, "n": n, "emitted": 0, "seated": False}
-        )
+        return self.batcher.open({
+            "prompt": prompt, "n": n, "emitted": 0, "seated": False,
+            # Prompt tokens the prefill owes the cache before the slot
+            # decodes (all but the last), and how many it has cached.
+            "prefill": prompt.size - 1 if self._prefill_jit else 0,
+            "cached": 0,
+        })
+
+    def _prefill(self, params, slot: int, tokens, offset: int, n_valid: int):
+        """Run one chunk to completion (the span around it is the chunk's
+        whole cost, and a chunk that fails fails here).  A failure may have
+        cost the engine its donated cache, so it gets a fresh one: the
+        step's failure frees every slot, and a freed slot needs no cache
+        state."""
+        import jax
+
+        buf = np.zeros((self._chunk,), np.int32)
+        buf[:n_valid] = tokens
+        try:
+            self._cache = self._prefill_jit(
+                params, self._cache, buf, np.int32(slot), np.int32(offset),
+                np.int32(n_valid),
+            )
+            jax.block_until_ready(self._cache)
+        except BaseException:
+            self._cache = None  # never two caches on the device
+            self._cache = self._init_cache(self.slots, self.max_len)
+            raise
+
+    def _prefill_one(self, params, slots) -> None:
+        """At most one chunk: the next of the longest-seated session whose
+        prompt is not yet in the cache."""
+        waiting = [
+            (t.seated_ns, i) for i, t in enumerate(slots)
+            if t is not None and t.state["cached"] < t.state["prefill"]
+        ]
+        if not self._prefill_warm:
+            # Both programs exist before the first session is answered,
+            # whatever its prompt's length: a chunk of no valid token
+            # compiles the chunk program and writes nothing.
+            self._prefill(params, 0, (), 0, 0)
+            self._prefill_warm = True
+        if not waiting:
+            return
+        with _SPAN_PREFILL:
+            _seated_ns, i = min(waiting)
+            st = slots[i].state
+            done = st["cached"]
+            n = min(self._chunk, st["prefill"] - done)
+            self._prefill(params, i, st["prompt"][done:done + n], done, n)
+            st["cached"] = done + n
+            self.prefill_chunks += 1
+            self.prefill_tokens += n
 
     def _run_step(self, slots):
         import jax.numpy as jnp
 
+        model = self._get_model()
+        if model is None:
+            raise _NoModel()
+        _step, params = model
+        if self._prefill_jit is not None:
+            self._prefill_one(params, slots)
         with _SPAN_PREPARE:
-            model = self._get_model()
-            if model is None:
-                raise _NoModel()
-            _step, params = model
             for i, t in enumerate(slots):
                 if t is not None and not t.state["seated"]:
-                    # A freshly seated session starts its slot at position
-                    # 0 feeding its first prompt token; the cache needs no
-                    # reset (see the class docstring).
+                    # A freshly seated session starts its slot where its
+                    # cached prompt ends: at position 0 feeding its first
+                    # prompt token, or (prefilled) at its last prompt
+                    # token.  While chunks are still due the row is inert:
+                    # what it writes at that position its first real step
+                    # writes again.  The cache needs no reset (see the
+                    # class docstring).
                     t.state["seated"] = True
-                    self._tokens[i] = t.state["prompt"][0]
-                    self._pos[i] = 0
+                    p0 = t.state["prefill"]
+                    self._tokens[i] = t.state["prompt"][p0]
+                    self._pos[i] = p0
             tokens, pos = jnp.asarray(self._tokens), jnp.asarray(self._pos)
         with _SPAN_DISPATCH:
             logits, self._cache = self._step_jit(
@@ -199,6 +295,9 @@ class _DecodeEngine:
                 if t is None:
                     continue
                 st = t.state
+                if st["cached"] < st["prefill"]:
+                    results[i] = ([], False)  # inert: its chunks are due
+                    continue
                 p = int(self._pos[i])
                 if p + 1 < len(st["prompt"]):
                     nxt = int(st["prompt"][p + 1])  # teacher-force the prompt
@@ -215,6 +314,8 @@ class _DecodeEngine:
     def stats(self) -> dict:
         s = self.batcher.stats()
         s["max_len"] = self.max_len
+        s["prefill_chunks"] = self.prefill_chunks
+        s["prefill_tokens"] = self.prefill_tokens
         return s
 
     def stop(self) -> None:
@@ -250,7 +351,10 @@ class ModelReplicaServer:
     the stepped KV-cache decode path — stateful sessions behind the
     sequence-slot batcher, streamed token responses over the
     DECODE_OPEN/NEXT/CLOSE wire (``serve.ServeClient.generate`` is the
-    client side).
+    client side).  A third function, ``prefill_fn`` (what
+    ``models.transformer.serve_decode_fns`` gives for a dense model), puts
+    a seated prompt into the cache a chunk per forward pass instead of a
+    token per decode step (:class:`_DecodeEngine`).
     """
 
     def __init__(
@@ -397,9 +501,8 @@ class ModelReplicaServer:
         # sweeps sessions nobody polled for ``session_idle_s``.
         self._engine = (
             _DecodeEngine(
-                lambda: self._model, decode_fns[0], decode_fns[1],
-                slots=decode_slots, max_len=decode_max_len,
-                max_sessions=decode_max_sessions,
+                lambda: self._model, *decode_fns, slots=decode_slots,
+                max_len=decode_max_len, max_sessions=decode_max_sessions,
             )
             if decode_fns is not None
             else None

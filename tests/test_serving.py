@@ -1011,7 +1011,41 @@ def _toy_decode_fns(vocab: int = 11):
     return init_cache_fn, step_fn
 
 
-def _pinned_decode_server(tmp_path, role, **kw):
+def _toy_cached_decode_fns(vocab: int = 11):
+    """A toy whose cache matters, with a prefill: the cache keeps every
+    token a slot was given at its position, and the next token is the
+    position-weighted sum of what the row's mask lets it see — a prompt
+    token missing from the cache, or cached at a wrong position or in a
+    wrong slot, changes the stream.  ``_toy_cached_stream`` is the same
+    in plain Python."""
+    import jax
+    import jax.numpy as jnp
+
+    def init_cache_fn(slots, max_len):
+        return jnp.zeros((slots, max_len), jnp.int32)
+
+    def step_fn(params, cache, tokens, pos):
+        t = jnp.arange(cache.shape[1])[None]
+        cache = jnp.where(t == pos[:, None], tokens[:, None], cache)
+        seen = jnp.where(t <= pos[:, None], cache * (t + 1), 0).sum(-1)
+        return jax.nn.one_hot(seen % vocab, vocab), cache
+
+    def prefill_fn(params, cache, tokens, slot, offset, n_valid):
+        i = jnp.arange(tokens.shape[0])
+        rows = jnp.where(i < n_valid, offset + i, cache.shape[1])
+        return cache.at[slot, rows].set(tokens, mode="drop")
+
+    return init_cache_fn, step_fn, prefill_fn
+
+
+def _toy_cached_stream(prompt, n: int, vocab: int = 11) -> list:
+    seq = [int(t) for t in prompt]
+    for _ in range(n):
+        seq.append(sum((i + 1) * t for i, t in enumerate(seq)) % vocab)
+    return seq[len(prompt):]
+
+
+def _pinned_decode_server(tmp_path, role, decode_fns=None, **kw):
     from distributed_tensorflow_examples_tpu.serve.registry import (
         ModelRegistry,
     )
@@ -1021,7 +1055,8 @@ def _pinned_decode_server(tmp_path, role, **kw):
         reg.publish("default", np.zeros(D * 4 + 4, np.float32), step=7)
     return serve.ModelReplicaServer(
         _init_fn, _predict_fn, [], registry_dir=str(tmp_path),
-        model_version=1, role=role, decode_fns=_toy_decode_fns(),
+        model_version=1, role=role,
+        decode_fns=decode_fns or _toy_decode_fns(),
         decode_slots=2, decode_max_len=32, **kw,
     )
 
@@ -1149,17 +1184,24 @@ _STEP_SPANS = (
 )
 
 
-def test_decode_spans_reach_the_profiler_trace_as_leaves(tmp_path):
+@pytest.mark.parametrize("prefill", [False, True])
+def test_decode_spans_reach_the_profiler_trace_as_leaves(tmp_path, prefill):
     """A ``jax.profiler`` trace of a live replica holds all seven span
     names on the step thread's ``python`` line — where the benchmark's
     ``trace.load`` collects host events — and no two of them overlap: the
-    spans are leaves that follow each other."""
+    spans are leaves that follow each other.  ``decode/prefill`` is an
+    eighth only where a chunk ran: an adapter of two functions feeds its
+    prompts through the step and never enters it."""
     import glob
 
     import jax
     from jax.profiler import ProfileData
 
-    srv = _pinned_decode_server(tmp_path / "reg", "trc0")
+    srv = _pinned_decode_server(
+        tmp_path / "reg", "trc0",
+        decode_fns=_toy_cached_decode_fns() if prefill else None,
+    )
+    want = _toy_cached_stream([3, 4, 5], 5) if prefill else [6, 7, 8, 9, 10]
     try:
         c = serve.ServeClient("127.0.0.1", srv.port, role="trc_sv")
         c.generate(np.array([1, 2], np.int32), 2)  # compile outside the trace
@@ -1174,7 +1216,7 @@ def test_decode_spans_reach_the_profiler_trace_as_leaves(tmp_path):
             out = c.generate(np.array([3, 4, 5], np.int32), 5)
         finally:
             jax.profiler.stop_trace()
-        assert out.tolist() == [6, 7, 8, 9, 10]
+        assert out.tolist() == want
         after = c.stats()
         c.close()
     finally:
@@ -1196,13 +1238,22 @@ def test_decode_spans_reach_the_profiler_trace_as_leaves(tmp_path):
                 lines.append(evs)
     assert len(lines) == 1, "one step thread, one line"
     (evs,) = lines
-    assert {name for _s, _e, name in evs} == {*_STEP_SPANS, "decode/park"}
+    assert {name for _s, _e, name in evs} == {
+        *_STEP_SPANS, "decode/park", *(["decode/prefill"] if prefill else []),
+    }
     for (_s0, e0, n0), (s1, _e1, n1) in zip(evs, evs[1:]):
         assert e0 <= s1, f"{n0} overlaps {n1}"
     # The same intervals reached the registry: seven steps of the traced
-    # request, each span entered once a step.
+    # request (five where one chunk cached the prompt's first two tokens),
+    # each span entered once a step.
     steps = after["decode_steps"] - before["decode_steps"]
-    assert steps == 7
+    assert steps == (5 if prefill else 7)
+    chunks = (
+        after["registry"]["decode/prefill/n"]
+        - before["registry"]["decode/prefill/n"]
+    )
+    assert chunks == (1 if prefill else 0)
+    assert after["decode_prefill_chunks"] - before["decode_prefill_chunks"] == chunks
     for name in _STEP_SPANS[1:]:
         assert (
             after["registry"][f"{name}/n"] - before["registry"][f"{name}/n"]
@@ -1211,7 +1262,8 @@ def test_decode_spans_reach_the_profiler_trace_as_leaves(tmp_path):
         assert after["registry"][f"{name}/ns"] > before["registry"][f"{name}/ns"]
     for key in ("fed", "seated", "seat_wait_ns", "first_tokens", "first_token_ns"):
         assert f"decode_{key}" in after
-    assert after["decode_fed"] - before["decode_fed"] == 2  # 3-token prompt
+    # A 3-token prompt: two steps feed it, or none once it is prefilled.
+    assert after["decode_fed"] - before["decode_fed"] == (0 if prefill else 2)
 
 
 def test_served_decode_program_is_named_step_fn():
@@ -1226,10 +1278,10 @@ def test_served_decode_program_is_named_step_fn():
     cfg = models.transformer.Config(
         vocab_size=32, dim=16, n_layers=1, n_heads=2, max_seq_len=16,
     )
-    init_cache_fn, step_fn = models.transformer.serve_decode_fns(cfg)
+    init_cache_fn, step_fn, prefill_fn = models.transformer.serve_decode_fns(cfg)
     assert step_fn.__name__ == "step_fn"
     engine = model_server._DecodeEngine(
-        lambda: None, init_cache_fn, step_fn, slots=2, max_len=16,
+        lambda: None, init_cache_fn, step_fn, prefill_fn, slots=2, max_len=16,
         max_sessions=4,
     )
     try:
@@ -1240,5 +1292,159 @@ def test_served_decode_program_is_named_step_fn():
             params, engine._cache, np.zeros(2, np.int32), np.zeros(2, np.int32)
         )
         assert "module @jit_step_fn" in lowered.as_text()
+        # The chunk program must NOT be found under that name: the step's
+        # metrics would count its launches as steps.
+        chunk = engine._prefill_jit.lower(
+            params, engine._cache, np.zeros(16, np.int32), np.int32(0),
+            np.int32(0), np.int32(0),
+        ).as_text()
+        assert "module @jit_prefill_fn" in chunk and "step_fn" not in chunk
     finally:
         engine.stop()
+
+
+# ----------------------------------------------------------------------------
+# Prefill: a seated prompt enters the cache a chunk per forward pass (PR 25)
+# ----------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("P,chunks", [(1, 0), (2, 1), (5, 1), (6, 2), (14, 4)])
+def test_prefilled_prompt_costs_its_chunks_then_one_step(
+    tmp_path, monkeypatch, P, chunks,
+):
+    """A ``P``-token prompt costs ``ceil((P - 1) / C)`` chunks — one an
+    iteration, each followed by a decode step in which the slot's row is
+    inert — and the step after the last chunk emits its first token."""
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 4)
+    srv = _pinned_decode_server(
+        tmp_path, "pf0", decode_fns=_toy_cached_decode_fns()
+    )
+    try:
+        c = serve.ServeClient("127.0.0.1", srv.port, role="pf_sv")
+        c.generate(np.array([1], np.int32), 1)  # compile both programs
+        before = c.stats()
+        prompt = (np.arange(P, dtype=np.int32) * 3 + 2) % 11
+        out = c.generate(prompt, 3)
+        after = c.stats()
+        c.close()
+    finally:
+        srv.stop()
+    assert out.tolist() == _toy_cached_stream(prompt, 3)
+    d = lambda k: after[k] - before[k]
+    assert d("decode_prefill_chunks") == chunks
+    assert d("decode_prefill_tokens") == P - 1
+    # All but the last chunk's iteration leave the row inert; the three
+    # tokens then take three steps.
+    assert d("decode_fed") == max(chunks - 1, 0)
+    assert d("decode_steps") == max(chunks - 1, 0) + 3
+    assert d("decode_emitted") == 3 and d("decode_first_tokens") == 1
+    r = lambda k: after["registry"][k] - before["registry"][k]
+    assert r("decode/prefill/n") == chunks
+    assert r("decode/dispatch/n") == d("decode_steps")
+
+
+def test_prefill_is_one_chunk_a_step_and_leaves_decoding_sessions_alone(
+    monkeypatch,
+):
+    """Several long prompts seated at once: never two chunks between two
+    decode steps, the longest-seated session's chunks first; a session
+    that decodes meanwhile gets the tokens it gets alone, and so does
+    each prefilled one."""
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 4)
+    gate = threading.Event()
+
+    def model():
+        gate.wait(10)
+        return 0, None
+
+    eng = model_server._DecodeEngine(
+        model, *_toy_cached_decode_fns(), slots=4, max_len=32, max_sessions=8,
+    )
+    log: list = []
+    step_jit, prefill_jit = eng._step_jit, eng._prefill_jit
+
+    def logged_step(*a):
+        log.append("step")
+        return step_jit(*a)
+
+    def logged_chunk(params, cache, tokens, slot, offset, n_valid):
+        if n_valid:  # not the chunk of no token that compiles the program
+            log.append((int(slot), int(offset), int(n_valid)))
+        return prefill_jit(params, cache, tokens, slot, offset, n_valid)
+
+    eng._step_jit, eng._prefill_jit = logged_step, logged_chunk
+    try:
+        # The first session is seated and the step thread held at the
+        # model; the other three queue up and are seated together.
+        prompts = [
+            np.array([7], np.int32),
+            (np.arange(14, dtype=np.int32) * 5 + 1) % 11,
+            (np.arange(10, dtype=np.int32) * 2 + 3) % 11,
+            (np.arange(6, dtype=np.int32) * 7 + 4) % 11,
+        ]
+        tickets = [eng.open(prompts[0], 12)]
+        time.sleep(0.1)
+        tickets += [eng.open(p, 4) for p in prompts[1:]]
+        gate.set()
+        deadline = time.monotonic() + 30
+        for t in tickets:
+            while not t.done:
+                assert time.monotonic() < deadline
+                t.wait(0.5)
+        outs = [t.snapshot(0)[0] for t in tickets]
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    for p, o, n in zip(prompts, outs, (12, 4, 4, 4)):
+        assert o == _toy_cached_stream(p, n), len(p)
+    chunks = [e for e in log if e != "step"]
+    # Slots 1-3 in the order seated; 13, 9 and 5 tokens in chunks of 4.
+    assert chunks == [
+        (1, 0, 4), (1, 4, 4), (1, 8, 4), (1, 12, 1),
+        (2, 0, 4), (2, 4, 4), (2, 8, 1),
+        (3, 0, 4), (3, 4, 1),
+    ]
+    assert all(
+        a == "step" or b == "step" for a, b in zip(log, log[1:])
+    ), "two chunks between two steps"
+    assert stats["prefill_chunks"] == 9 and stats["prefill_tokens"] == 27
+
+
+def test_a_failed_chunk_leaves_the_engine_a_cache():
+    """The cache is donated to the chunk; a chunk that raises fails the
+    active sessions, as any step does, and the next session is served."""
+    import jax.numpy as jnp
+
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    init_cache_fn, step_fn, prefill_fn = _toy_cached_decode_fns()
+    calls = []
+
+    def flaky_prefill_fn(params, cache, tokens, slot, offset, n_valid):
+        calls.append(1)  # runs when the program is traced
+        if len(calls) == 1:
+            raise FloatingPointError("chunk failed")
+        return prefill_fn(params, cache, tokens, slot, offset, n_valid)
+
+    eng = model_server._DecodeEngine(
+        lambda: (0, None), init_cache_fn, step_fn, flaky_prefill_fn,
+        slots=2, max_len=16, max_sessions=4,
+    )
+    try:
+        t = eng.open(np.array([1, 2, 3], np.int32), 2)
+        while not t.done:
+            t.wait(5)
+        with pytest.raises(FloatingPointError):
+            t.snapshot(0)
+        assert isinstance(eng._cache, jnp.ndarray) and not eng._cache.is_deleted()
+        t = eng.open(np.array([1, 2, 3], np.int32), 2)
+        while not t.done:
+            t.wait(5)
+        assert t.snapshot(0)[0] == _toy_cached_stream([1, 2, 3], 2)
+        assert eng.stats()["step_errors"] == 1
+    finally:
+        eng.stop()

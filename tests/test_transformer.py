@@ -5,6 +5,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import optax
+import pytest
 
 from distributed_tensorflow_examples_tpu import models, train
 from distributed_tensorflow_examples_tpu.data.pipeline import as_global
@@ -305,6 +306,85 @@ def test_decode_step_batch_rows_are_independent_sessions():
             t = jnp.asarray(np.array([n1], np.int32))
 
 
+@pytest.mark.parametrize(
+    "C,T,n,slot",
+    [
+        (8, 40, 21, 2),   # several chunks, a ragged last one, a slot other than 0
+        (8, 29, 28, 1),   # max_len no multiple of C; the prompt ends at max_len - 1
+        (8, 16, 8, 0),    # exactly one full chunk
+        (8, 32, 3, 3),    # one chunk, mostly padding
+        (16, 16, 15, 2),  # the chunk as long as the cache
+        (8, 12, 11, 0),   # every chunk but the first shifted back inside its window
+    ],
+)
+def test_prefill_chunks_match_token_by_token_decode(C, T, n, slot):
+    """``n`` prompt tokens prefilled ``C`` at a time leave the slot's cache
+    rows ``[0, n)`` as ``n`` calls of ``decode_step_batch`` leave them and
+    the following decode steps' logits equal (summation order apart),
+    and no other row of the cache is touched at all."""
+    cfg = models.transformer.Config(
+        vocab_size=97, dim=32, n_layers=2, n_heads=4, max_seq_len=64,
+        compute_dtype="float32",
+    )
+    tf = models.transformer
+    params = tf.init(cfg, jax.random.key(1))
+    S = 4
+    rng = np.random.default_rng(C * 1000 + T)
+    toks = rng.integers(0, cfg.vocab_size, size=T).astype(np.int32)
+    # What earlier sessions left behind: nothing may depend on it, and
+    # whatever the prefill does not own must keep it to the bit.
+    junk = jax.tree.map(
+        lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype),
+        tf.init_cache(cfg, S, T),
+    )
+    step = jax.jit(lambda c, t, p: tf.decode_step_batch(cfg, params, c, t, p))
+    chunk = jax.jit(
+        lambda c, t, o, nv: tf.prefill_chunk(cfg, params, c, t, slot, o, nv)
+    )
+    row = lambda v: jnp.zeros((S,), jnp.int32).at[slot].set(v)
+    ref = junk
+    for p in range(n):
+        _logits, ref = step(ref, row(toks[p]), row(p))
+    got = junk
+    for o in range(0, n, C):
+        nv = min(C, n - o)
+        buf = np.zeros((C,), np.int32)
+        buf[:nv] = toks[o:o + nv]
+        got = chunk(got, buf, o, nv)
+    for name in got:
+        for kv in ("k", "v"):
+            g, r, j = (np.asarray(c[name][kv]) for c in (got, ref, junk))
+            np.testing.assert_allclose(
+                g[slot, :, :n], r[slot, :, :n], rtol=1e-5, atol=1e-5
+            )
+            keep = np.ones(g.shape, bool)
+            keep[slot, :, :n] = False
+            assert np.array_equal(g[keep], j[keep]), (name, kv)
+    for p in range(n, min(n + 3, T)):
+        lr, ref = step(ref, row(toks[p]), row(p))
+        lg, got = step(got, row(toks[p]), row(p))
+        np.testing.assert_allclose(
+            np.asarray(lg)[slot], np.asarray(lr)[slot], rtol=1e-4, atol=1e-5
+        )
+
+
+def test_serve_decode_fns_gives_prefill_for_dense_blocks_only():
+    """The engine adapts to what it is handed: a dense model hands it the
+    chunk function, an MoE model (capacity is per call) does not."""
+    tf = models.transformer
+    fns = tf.serve_decode_fns(CFG)
+    assert [f.__name__ for f in fns] == ["init_cache_fn", "step_fn", "prefill_fn"]
+    moe = tf.Config(
+        vocab_size=128, dim=32, n_layers=1, n_heads=4, max_seq_len=64,
+        moe_experts=4,
+    )
+    assert [f.__name__ for f in tf.serve_decode_fns(moe)] == [
+        "init_cache_fn", "step_fn",
+    ]
+    with pytest.raises(NotImplementedError):
+        tf.prefill_chunk(moe, None, None, np.zeros(4, np.int32), 0, 0, 4)
+
+
 def test_transformer_served_decode_byte_identical_to_reference(tmp_path):
     """transformer_lm as a SERVED workload (r19 acceptance): stepped
     KV-cache decode through the sequence-slot batcher returns tokens
@@ -375,3 +455,75 @@ def test_transformer_served_decode_byte_identical_to_reference(tmp_path):
         c.close()
     finally:
         srv.stop()
+
+
+def test_transformer_served_with_chunked_prefill_matches_generate(
+    tmp_path, monkeypatch,
+):
+    """Prompts longer than a chunk, seated together on a live replica: the
+    engine's chunks (several a prompt, one an iteration, while the other
+    rows decode) leave every session the tokens ``generate`` gives it —
+    and ``generate`` those of the token-by-token feed."""
+    import threading
+
+    from distributed_tensorflow_examples_tpu import serve
+    from distributed_tensorflow_examples_tpu.serve import model_server
+    from distributed_tensorflow_examples_tpu.serve.registry import (
+        ModelRegistry,
+    )
+    from distributed_tensorflow_examples_tpu.train.checkpoint import (
+        flat_params_of,
+    )
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    cfg = models.transformer.Config(
+        vocab_size=211, dim=32, n_layers=2, n_heads=4, max_seq_len=48,
+        compute_dtype="float32",
+    )
+    tf = models.transformer
+    params = tf.init(cfg, jax.random.key(5))
+    v = ModelRegistry(str(tmp_path)).publish(
+        "transformer_lm", flat_params_of(params), step=3
+    )
+    srv = serve.ModelReplicaServer(
+        lambda r: tf.init(cfg, r), lambda p, b: tf.apply(cfg, p, b["x"]),
+        [], registry_dir=str(tmp_path), model_name="transformer_lm",
+        model_version=v, decode_fns=tf.serve_decode_fns(cfg),
+        decode_slots=3, decode_max_len=41, role="tsrv1",
+    )
+    rng = np.random.default_rng(11)
+    # 29 + 12 = 41: the longest session ends on the cache's last row, and
+    # its last chunk would overrun it.
+    prompts = [
+        rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+        for n in (29, 1, 12, 20)
+    ]
+    outs: list = [None] * len(prompts)
+
+    def body(i):
+        c = serve.ServeClient("127.0.0.1", srv.port, role=f"tc{i}_sv")
+        outs[i] = c.generate(prompts[i], 12)
+        c.close()
+
+    try:
+        ts = [threading.Thread(target=body, args=(i,)) for i in range(len(prompts))]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+        st = srv.stats()
+    finally:
+        srv.stop()
+    assert st["decode_prefill_tokens"] == sum(len(p) - 1 for p in prompts)
+    assert st["decode_prefill_chunks"] == 4 + 0 + 2 + 3
+    step = jax.jit(lambda c, t, p: tf.decode_step(cfg, params, c, t, p))
+    for p, o in zip(prompts, outs):
+        ref = np.asarray(tf.generate(cfg, params, p[None], max_new_tokens=12))
+        assert np.array_equal(o, ref[0, len(p):]), len(p)
+        # The feed that was: every position through the decode step.
+        cache, fed = tf.init_cache(cfg, 1, len(p) + 12), list(p)
+        for pos in range(len(p) + 11):
+            logits, cache = step(cache, jnp.asarray(fed[pos:pos + 1]), pos)
+            if pos + 1 >= len(p):
+                fed.append(int(jnp.argmax(logits[0])))
+        assert fed[len(p):] == o.tolist(), len(p)
