@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import importlib
+import sys
 import time
 
 from . import manifest, trace
@@ -10,6 +11,14 @@ from . import manifest, trace
 
 def say(msg: str) -> None:
     print(f"bench: {msg}", flush=True)
+
+
+def say_compared(msg: str) -> None:
+    """A number compared beside its limit: on standard output with the rest,
+    and on standard error, whose end the driver keeps of a run that is not
+    correct."""
+    say(f"compared {msg}")
+    print(f"bench: compared {msg}", file=sys.stderr, flush=True)
 
 
 class Phases:
@@ -26,12 +35,7 @@ class Phases:
 
 def runner_for(cell):
     """The module whose ``run`` drives a cell, by its traffic mix's kind."""
-    kind = cell.traffic["kind"]
-    if kind in ("serve-open", "serve-closed"):
-        return importlib.import_module("benchmarks.harness.serve_cell")
-    if kind == "train":
-        return importlib.import_module("benchmarks.harness.train_cell")
-    raise ValueError(f"unknown traffic kind {kind!r}")
+    return importlib.import_module(f"benchmarks.harness.{cell.path}_cell")
 
 
 def device_record(memory: list, compiled_bytes: int = 0) -> dict:
@@ -58,6 +62,9 @@ def result(cell, outcome: dict, traced: bool) -> dict:
         ev["peaks"] = manifest.peak_for(device["kind"])
         line["metrics"] = manifest.read_per_layer(cell, ev)
         busy_s, window_s = trace.busy(ev["trace"])
+        if busy_s == 0:
+            say(f"the traced window was idle: no operation ran on a device in its "
+                f"{window_s:.2f} s, so no metric of a step or a launch is reported")
         device["busy_s"], device["window_s"] = busy_s, window_s
         line["breakdown"] = {
             "device_ops": trace.device_ops(ev["trace"]),

@@ -1,5 +1,6 @@
 """A serving cell: a registry-pinned replica in this process, the load
-generator in a child, the window, the trace, the comparison.
+generator in a child, the window, the trace, the comparison.  What it serves
+it asks of the configuration's family (``families/<model>/serve.py``).
 
 Phases of set-up are printed as they end.  ``setup_s`` runs from the start
 of the process to the first measured instant; the reference comparison runs
@@ -19,8 +20,8 @@ import time
 
 import numpy as np
 
-from . import models, stats, trace, traffic as traffic_lib
-from .report import Phases, say
+from . import manifest, stats, trace, traffic as traffic_lib
+from .report import Phases, say_compared
 
 MODEL_NAME = "bench_model"
 HORIZON_SLACK_S = 5.0
@@ -32,12 +33,13 @@ def start_replica(cell, seed: int, registry_dir: str, phases: Phases):
     import jax
 
     from benchmarks.reference import weights
-    from distributed_tensorflow_examples_tpu import models as program_models, serve
+    from distributed_tensorflow_examples_tpu import serve
     from distributed_tensorflow_examples_tpu.serve.registry import ModelRegistry
     from distributed_tensorflow_examples_tpu.train.checkpoint import flat_params_of
 
     phases.mark("import_program")
-    cfg, tree_fn = models.transformer(cell.config)
+    family = cell.family
+    cfg, tree_fn = family.build(cell.config)
     key = weights.base_key(seed)
     shapes = jax.eval_shape(tree_fn, key)
     make = jax.jit(tree_fn).lower(key).compile()
@@ -56,10 +58,9 @@ def start_replica(cell, seed: int, registry_dir: str, phases: Phases):
     server = serve.ModelReplicaServer(
         # Only shapes are read from what init_fn returns.
         lambda _rng: shapes,
-        lambda p, b: program_models.transformer.apply(cfg, p, b["x"]),
+        family.apply_fn(cfg),
         [], registry_dir=registry_dir, model_name=MODEL_NAME,
-        model_version=version,
-        decode_fns=program_models.transformer.serve_decode_fns(cfg),
+        model_version=version, decode_fns=family.decode_fns(cfg),
         decode_slots=s["decode_slots"], decode_max_len=s["decode_max_len"],
         decode_max_sessions=s["decode_max_sessions"], role="bench_serve0",
     )
@@ -104,6 +105,19 @@ def hand_schedule(proc, schedule: dict, run_dir: str) -> None:
         json.dump(schedule, f)
     proc.stdin.write(schedule_path + "\n")
     proc.stdin.flush()
+
+
+def schedule_for(cell, port: int, seed: int, seconds: float) -> dict:
+    """What the generator plays: the mix's requests for the lead-in, the
+    window and some slack, their tokens drawn from the family's vocabulary."""
+    tr = cell.traffic
+    return {
+        "kind": tr["kind"], "poll_s": tr["poll_s"], "host": "127.0.0.1",
+        "port": port,
+        "requests": traffic_lib.serve_schedule(
+            tr, cell.family.token_vocab(cell.config), seed,
+            float(tr["lead_s"]) + seconds + HORIZON_SLACK_S),
+    }
 
 
 def measure(cell, server, generator, schedule: dict, seconds: float,
@@ -192,11 +206,9 @@ def widest_gap(config: dict, seed: int, sample: list, mode: str = "float32",
     ``tokens_from="served"`` reads the served tokens; ``"mode"`` reads the
     token that the reference computed in ``mode`` puts first (the control).
     """
-    from benchmarks.reference import transformer_ref
-
-    c = config["program"]
+    family = manifest.family(config["model"], "serve")
     longest = max(len(p) + len(t) - 1 for p, t in sample)
-    L = min(-(-longest // 256) * 256, c["max_seq_len"])
+    L = min(-(-longest // 256) * 256, family.max_len(config))
     toks = np.zeros((len(sample), L), np.int32)
     rows, cols, served = [], [], []
     for i, (p, t) in enumerate(sample):
@@ -208,9 +220,9 @@ def widest_gap(config: dict, seed: int, sample: list, mode: str = "float32",
     n = len(rows)
     pad = -(-n // 256) * 256 - n
     rows_p, cols_p = np.asarray(rows + [0] * pad), np.asarray(cols + [0] * pad)
-    ref = transformer_ref.logits_at(c, seed, toks, rows_p, cols_p)[:n]
+    ref = family.reference_logits_at(config, seed, toks, rows_p, cols_p)[:n]
     if tokens_from == "mode":
-        low = transformer_ref.logits_at(c, seed, toks, rows_p, cols_p, mode)[:n]
+        low = family.reference_logits_at(config, seed, toks, rows_p, cols_p, mode)[:n]
         chosen = np.argmax(low, axis=-1)
     else:
         chosen = np.asarray(served)
@@ -237,13 +249,7 @@ def run(cell, seed: int, seconds: float, traced: bool, t_proc0: float,
         server = start_replica(cell, seed, registry_dir, phases)
         warm_up(server, phases)
         tr = cell.traffic
-        schedule = {
-            "kind": tr["kind"], "poll_s": tr["poll_s"], "host": "127.0.0.1",
-            "port": server.port,
-            "requests": traffic_lib.serve_schedule(
-                tr, cell.config["published"]["vocab_size"], seed,
-                float(tr["lead_s"]) + seconds + HORIZON_SLACK_S),
-        }
+        schedule = schedule_for(cell, server.port, seed, seconds)
         ev = measure(cell, server, generator, schedule, seconds, traced, run_dir, phases, t_proc0)
         memory = [d.memory_stats() or {} for d in jax.local_devices()[: cell.chips]]
         server.stop()
@@ -275,7 +281,7 @@ def run(cell, seed: int, seconds: float, traced: bool, t_proc0: float,
                 check[f"control_{mode}"] = widest_gap(
                     cell.config, seed, sample, mode, "mode")["widest_gap"]
         limit = tr["correct"]["limits"]["widest_gap"]
-        say(f"compared widest_gap {check['widest_gap']} limit {limit} "
+        say_compared(f"widest_gap {check['widest_gap']} limit {limit} "
             f"(positions {check['positions']}, requests {check['requests']}, "
             f"reference {time.monotonic() - t_ref:.1f} s)")
         correct = (

@@ -109,39 +109,57 @@ def _work(device: dict) -> list:
     return device["ops"] + device["modules"]
 
 
+def _first_device(tr: dict) -> dict:
+    """The first device's lines; a trace in which no operation ran has no
+    device at all (``load`` keeps the planes that hold operations), and its
+    first device then holds nothing."""
+    if not tr["devices"]:
+        return {"ops": [], "modules": []}
+    return tr["devices"][sorted(tr["devices"])[0]]
+
+
 def window_of(tr: dict) -> tuple[float, float]:
     """The traced window: the ``bench.window`` span, cut to the extent of
     device work (what a trace that starts late or ends early did not record
-    is not idle time); without the span, the extent of device work."""
+    is not idle time); without the span, the extent of device work.  An IDLE
+    trace, in which no operation ran, is the whole span."""
     starts = [ev[0] for d in tr["devices"].values() for ev in _work(d)]
     ends = [ev[1] for d in tr["devices"].values() for ev in _work(d)]
+    windows = [(s, e) for s, e, name in tr["spans"] if name == WINDOW_SPAN]
     if not starts:
-        raise ValueError("no operation ran on a device in the traced window")
-    for s, e, name in tr["spans"]:
-        if name == WINDOW_SPAN and s < max(ends) and e > min(starts):
+        if not windows:
+            raise ValueError("the trace holds neither device work nor a window span")
+        return windows[0]
+    for s, e in windows:
+        if s < max(ends) and e > min(starts):
             return max(s, min(starts)), min(e, max(ends))
     return min(starts), max(ends)
 
 
 def busy(tr: dict) -> tuple[float, float]:
     """``(busy_s, window_s)``: seconds in which an operation ran, averaged
-    over the devices, and the length of the traced window."""
+    over the devices (0 in an idle trace), and the length of the traced
+    window."""
     w0, w1 = window_of(tr)
     per_device = [
         sum(e - s for s, e in union(clip(_work(d), w0, w1)))
         for d in tr["devices"].values()
     ]
-    return sum(per_device) / len(per_device), w1 - w0
+    return sum(per_device) / max(1, len(per_device)), w1 - w0
 
 
 def _label(g0: float, g1: float, events) -> str | None:
-    """The event that covers most of the gap, if it covers half of it."""
-    cover: dict[str, float] = {}
+    """The event name that covers most of the gap, if it covers half of it.
+    A name's cover is the UNION of its events: the runtime emits two nested
+    ``PjitFunction(step_fn)`` a launch, and their sum would pass for half a
+    gap of which they cover a quarter."""
+    by_name: dict[str, list] = {}
     for s, e, name in events:
         if e > g0 and s < g1:
-            cover[name] = cover.get(name, 0.0) + min(e, g1) - max(s, g0)
-    if not cover:
+            by_name.setdefault(name, []).append((max(s, g0), min(e, g1)))
+    if not by_name:
         return None
+    cover = {name: sum(e - s for s, e in union(ivs)) for name, ivs in by_name.items()}
     best = max(cover, key=cover.get)
     return best if cover[best] >= 0.5 * (g1 - g0) else None
 
@@ -152,7 +170,7 @@ def idle_gaps(tr: dict, top: int = 10) -> list[list]:
     event that does, else ``unattributed``; gaps under ``GAP_FLOOR_S`` are
     pooled."""
     w0, w1 = window_of(tr)
-    first = tr["devices"][sorted(tr["devices"])[0]]
+    first = _first_device(tr)
     merged = union(clip(_work(first), w0, w1))
     edges = [w0] + [t for iv in merged for t in iv] + [w1]
     gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
@@ -172,7 +190,7 @@ def idle_gaps(tr: dict, top: int = 10) -> list[list]:
 def device_ops(tr: dict, top: int = 10) -> list[list]:
     """The operations of the first device that took most time."""
     w0, w1 = window_of(tr)
-    first = tr["devices"][sorted(tr["devices"])[0]]
+    first = _first_device(tr)
     totals: dict[str, float] = {}
     for s, e, name in clip(first["ops"], w0, w1):
         totals[name] = totals.get(name, 0.0) + (e - s)
@@ -182,7 +200,7 @@ def device_ops(tr: dict, top: int = 10) -> list[list]:
 def module_events(tr: dict, pattern: str) -> list[tuple[float, float, str]]:
     """Launches on the first device of the program whose name holds
     ``pattern``; with several candidates, the one launched most often."""
-    first = tr["devices"][sorted(tr["devices"])[0]]
+    first = _first_device(tr)
     by_name: dict[str, list] = {}
     for ev in first["modules"]:
         if pattern in ev[2]:
@@ -196,6 +214,6 @@ def op_seconds(tr: dict, pattern: str) -> tuple[float, int]:
     """Total device seconds and number of the first device's operations
     whose name holds ``pattern``."""
     w0, w1 = window_of(tr)
-    first = tr["devices"][sorted(tr["devices"])[0]]
+    first = _first_device(tr)
     hit = [e - s for s, e, name in clip(first["ops"], w0, w1) if pattern in name]
     return sum(hit), len(hit)

@@ -1,5 +1,6 @@
 """A training cell: ``train.Experiment``'s compiled step, fed by the
-program's own input pipeline, timed by step completions.
+program's own input pipeline, timed by step completions.  What it trains it
+asks of the configuration's family (``families/<model>/train.py``).
 
 Set-up builds ONE object, the compiled step with its state, drives it from
 the seed through its first steps by the window's own call and feed (those
@@ -22,8 +23,8 @@ import numpy as np
 
 from benchmarks.reference import weights
 
-from . import flops, manifest, models, stats, trace, traffic as traffic_lib
-from .report import Phases, say
+from . import manifest, stats, trace
+from .report import Phases, say, say_compared
 
 RUN_AHEAD = 4
 WARM_STEPS = 5
@@ -52,22 +53,22 @@ def first_gradient(opt_state):
     raise ValueError("no momentum state to read the gradient from")
 
 
-def worst_leaf_gap(got: dict, ref: dict, only: str = "") -> float:
+def worst_leaf_gap(got: dict, ref: dict, keep=lambda leaf: True) -> float:
     """Largest gap between the program's norm and the reference's, against
     the reference's norm of that leaf or of the median leaf, whichever is
-    larger (some gradients are all but zero).  ``only`` keeps the leaves
-    whose name ends so."""
+    larger (some gradients are all but zero), over the leaves whose name
+    ``keep`` takes."""
     floor = statistics.median(ref.values())
-    return max(abs(got[k] - ref[k]) / max(ref[k], floor)
-               for k in ref if k.endswith(only))
+    return max(abs(got[k] - ref[k]) / max(ref[k], floor) for k in ref if keep(k))
 
 
 def build(cell, seed: int):
-    """The experiment, its data and the per-example model FLOPs."""
+    """The experiment, its data with the reader of a fed batch's rows, the
+    batches and the per-example model FLOPs."""
     t_build = time.monotonic()
     import jax
 
-    from distributed_tensorflow_examples_tpu import data, models as program_models, train
+    from distributed_tensorflow_examples_tpu import data, train
     from distributed_tensorflow_examples_tpu.parallel import MeshSpec, build_mesh
 
     say(f"setup phase import_program: {time.monotonic() - t_build:.2f} s")
@@ -79,20 +80,19 @@ def build(cell, seed: int):
         log_every_steps=10 ** 9, batch_size=tr["global_batch"],
         checkpoint_every_steps=10 ** 9, watchdog=False,
     )
-    if cell.config["model"] != "resnet":
-        raise ValueError(f"no training adapter for model {cell.config['model']!r}")
-    cfg, tree_fn = models.resnet(cell.config)
-    arrays = traffic_lib.images(tr["data"], seed)
-    per_example = flops.resnet_train_flops(cell.config["program"], tr["data"]["image_size"])
+    family = cell.family
+    cfg, tree_fn = family.build(cell.config)
+    arrays, rows_of = family.batches(cell.config, tr, seed)
+    per_example = family.train_flops_per_example(cell.config, tr)
     exp = train.Experiment(
         init_fn=lambda rng: tree_fn(jax.random.fold_in(rng, hi)),
         optimizer=optimizer_of(opt), flags=flags, mesh=mesh,
-        loss_fn=program_models.resnet.loss_fn(cfg, l2=opt["l2"]),
-        rules=program_models.resnet.sharding_rules(cfg),
+        loss_fn=family.loss_fn(cfg, cell.config),
+        rules=family.sharding_rules(cfg),
     )
     pipeline = data.pipeline.InMemoryPipeline(
         arrays, batch_size=tr["global_batch"], shuffle=True, seed=lo)
-    return exp, arrays, iter(pipeline), per_example
+    return exp, arrays, rows_of, iter(pipeline), per_example
 
 
 def make_step(exp, state, first_batch):
@@ -144,23 +144,16 @@ class Watcher:
             raise RuntimeError("a step failed on the device") from self.error
 
 
-def rows_of(batch: dict, arrays: dict) -> np.ndarray:
-    """Which rows of the data a fed batch holds, read from its content."""
-    return traffic_lib.image_rows(batch["image"], len(arrays["image"]))
-
-
 def reference_numbers(cell, seed: int, rows: list, arrays: dict, mode: str) -> dict:
-    from benchmarks.reference import resnet_ref
-
-    c = {k: cell.config["program"][k] for k in ("num_classes", "stage_sizes", "width")}
-    batches = [(arrays["image"][r], arrays["label"][r]) for r in rows]
-    return resnet_ref.train(c, cell.config["train"], seed, batches, mode)
+    """The family's reference over the rows that the compared steps were fed."""
+    batches = [{k: v[r] for k, v in arrays.items()} for r in rows]
+    return cell.family.reference_train(cell.config, seed, batches, mode)
 
 
-def gradient_cosines(got, ref) -> dict:
+def gradient_cosines(got, ref, keep) -> dict:
     """The cosine between the program's first gradient and the reference's,
-    kernel by kernel: unlike a gap between norms, first order in rounding
-    noise."""
+    leaf by leaf over those whose name ``keep`` takes: unlike a gap between
+    norms, first order in rounding noise."""
     import jax
     import jax.numpy as jnp
 
@@ -169,23 +162,22 @@ def gradient_cosines(got, ref) -> dict:
         return jnp.sum(a * b) / jnp.sqrt(jnp.sum(jnp.square(a)) * jnp.sum(jnp.square(b)))
 
     errs = jax.jit(lambda g, r: jax.tree.map(cos, g, r))(got, ref)
-    return {
+    named = {
         "/".join(str(getattr(p, "key", p)) for p in path): float(v)
         for path, v in jax.tree_util.tree_leaves_with_path(errs)
-        if str(getattr(path[-1], "key", "")) == "kernel"
     }
+    return {k: v for k, v in named.items() if keep(k)}
 
 
-def compare(got: dict, ref: dict) -> dict:
-    """Every number compared.  The norms' gaps are taken over the kernels:
-    a batch-norm scale's or bias's gradient is a sum with heavy cancellation,
-    a fifth to a third off in ANY precision under float32 (PERF.md section 2);
-    the all-leaf gaps are printed beside them."""
-    cos = gradient_cosines(got["first_grads"], ref["first_grads"])
+def compare(got: dict, ref: dict, kernels) -> dict:
+    """Every number compared.  The norms' gaps and the cosine are taken over
+    the leaves the family names (``compared_kernels``); the all-leaf gaps are
+    printed beside them."""
+    cos = gradient_cosines(got["first_grads"], ref["first_grads"], kernels)
     return {
         "loss_gap": max(abs(a - b) / abs(b) for a, b in zip(got["losses"], ref["losses"])),
-        "grad_gap_kernels": worst_leaf_gap(got["grad_norms"], ref["grad_norms"], "kernel"),
-        "delta_gap_kernels": worst_leaf_gap(got["delta_norms"], ref["delta_norms"], "kernel"),
+        "grad_gap_kernels": worst_leaf_gap(got["grad_norms"], ref["grad_norms"], kernels),
+        "delta_gap_kernels": worst_leaf_gap(got["delta_norms"], ref["delta_norms"], kernels),
         # Higher is better: held to "at least" its limit (see AT_LEAST).
         "grad_cosine_median": statistics.median(cos.values()),
         "grad_cosine_worst": min(cos.values()),
@@ -202,7 +194,7 @@ def run(cell, seed: int, seconds: float, traced: bool, t_proc0: float,
     phases = Phases(t_proc0)
     phases.mark("runtime_start")
     tr = cell.traffic
-    exp, arrays, host_batches, per_example = build(cell, seed)
+    exp, arrays, rows_of, host_batches, per_example = build(cell, seed)
     jax.block_until_ready(exp.state)
     phases.mark("init")
 
@@ -212,7 +204,7 @@ def run(cell, seed: int, seconds: float, traced: bool, t_proc0: float,
     def noting_rows(it):
         for b in it:
             if len(fed_rows) < check_steps:
-                fed_rows.append(rows_of(b, arrays))
+                fed_rows.append(rows_of(b))
             yield b
 
     batches = exp.batches(noting_rows(host_batches))
@@ -316,7 +308,8 @@ def run(cell, seed: int, seconds: float, traced: bool, t_proc0: float,
 
     t_ref = time.monotonic()
     ref = reference_numbers(cell, seed, fed_rows, arrays, "float32")
-    check = compare(got, ref)
+    kernels = cell.family.compared_kernels(cell.config)
+    check = compare(got, ref, kernels)
     check["losses"] = got["losses"]
     for mode in (control or "").split(",") if control else ():
         if mode == "half_batch":  # the fault the loss is there to catch
@@ -324,7 +317,7 @@ def run(cell, seed: int, seconds: float, traced: bool, t_proc0: float,
             low = reference_numbers(cell, seed, half, arrays, "float32")
         else:
             low = reference_numbers(cell, seed, fed_rows, arrays, mode)
-        check[f"control_{mode}"] = compare(low, ref)
+        check[f"control_{mode}"] = compare(low, ref, kernels)
         del low
     del got["first_grads"], ref["first_grads"]
     limits = tr["correct"]["limits"]
@@ -332,7 +325,7 @@ def run(cell, seed: int, seconds: float, traced: bool, t_proc0: float,
     verdicts = []
     for name, value in check.items():
         if isinstance(value, float):
-            say(f"compared {name} {value} "
+            say_compared(f"{name} {value} "
                 f"{'at least' if name in AT_LEAST else 'limit'} {limits.get(name)}")
     for name, limit in limits.items():
         if limit is None:
@@ -341,7 +334,7 @@ def run(cell, seed: int, seconds: float, traced: bool, t_proc0: float,
             verdicts.append(check[name] >= limit)
         else:
             verdicts.append(check[name] <= limit)
-    say(f"compared window losses finite {finite} (reference "
+    say_compared(f"window losses finite {finite} (reference "
         f"{time.monotonic() - t_ref:.1f} s)")
     evidence = {
         "cell": cell, "window_s": window_s, "memory": memory,

@@ -25,50 +25,28 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-TINY_TRANSFORMER = {
-    "vocab_size": 256, "dim": 64, "n_layers": 2, "n_heads": 4, "mlp_ratio": 4,
-    "max_seq_len": 128,
-}
-#: At batch 8 the program's bf16 elementwise rounding drowns any int8
-#: control, so the tiny ResNet states float32 and its control is bfloat16.
-TINY_RESNET = {"num_classes": 10, "stage_sizes": [1, 1], "width": 8,
-               "stem": "s2d", "bn_momentum": 0.9, "compute_dtype": "float32"}
-
-
-#: Limits of the tiny sizes, read on the CPU (benchmarks/tests/test_control.py
-#: holds the readings): above the sound runs, below the int8 control.
-TINY_LIMITS = {"widest_gap": 0.006, "loss_gap": 1e-5, "grad_gap_kernels": 0.003,
-               "delta_gap_kernels": 0.5, "grad_cosine_median": 0.999}
-
 
 def shrink(cell):
     """The cell at a size a CPU runs in seconds; every branch the real
-    size takes is still taken."""
-    cell.config = copy.deepcopy(cell.config)
+    size takes is still taken.  The configuration's size is its family's
+    (``tiny``), with the limits read at that size; the traffic's is here."""
+    cell.config = cell.family.tiny(cell.config)
     cell.traffic = tr = copy.deepcopy(cell.traffic)
-    if cell.config["model"] == "transformer":
-        cell.config["program"] = dict(TINY_TRANSFORMER)
-        cell.config["published"]["vocab_size"] = 250
-    else:
-        cell.config["program"] = dict(TINY_RESNET)
-        cell.config["precision"] = {"params": "float32", "compute": "float32",
-                                    "control": "bfloat16"}
-    if tr["kind"].startswith("serve"):
+    rehearsal = cell.config["rehearsal"]
+    tr["trace_s"] = 1.0
+    tr["correct"]["limits"] = {k: rehearsal["limits"][k] for k in tr["correct"]["limits"]}
+    if cell.path == "serve":
         for key in ("prompt_len", "output_len"):
             for k in ("median", "min", "max"):
                 if k in tr[key]:
                     tr[key][k] = max(2, tr[key][k] // 8)
-        tr["server"]["decode_max_len"] = 128
+        tr["server"]["decode_max_len"] = cell.family.max_len(cell.config)
         tr["lead_s"] = 1.0
-        tr["trace_s"] = 1.0
         if "arrivals" in tr:
             tr["arrivals"].update(rate_per_s=4.0, period_s=3.0)
-        tr["correct"]["limits"] = {"widest_gap": TINY_LIMITS["widest_gap"]}
     else:
-        tr["data"].update(n=64, image_size=32, num_classes=10)
+        tr["data"].update(rehearsal["data"])
         tr["global_batch"] = 8
-        tr["trace_s"] = 1.0
-        tr["correct"]["limits"] = {k: TINY_LIMITS[k] for k in tr["correct"]["limits"]}
     return cell
 
 

@@ -51,7 +51,7 @@ def test_the_lower_precision_fails_a_training_number_and_the_sound_step_passes()
     cell = tiny("resnet50-train-b256")
     out = train_cell.run(cell, 5, 2.0, False, time.monotonic(),
                          control=cell.config["precision"]["control"])
-    limits = rehearse.TINY_LIMITS
+    limits = cell.traffic["correct"]["limits"]
     assert out["correct"] is True
     c = out["check"]["control_bfloat16"]
     assert c["grad_gap_kernels"] > limits["grad_gap_kernels"]

@@ -47,6 +47,9 @@ def test_every_cell_finds_its_files_and_reports_enough(workload):
     cell = manifest.Cell(workload)
     assert cell.config["name"] == cell.workload["config"]
     assert cell.traffic["kind"] in ("train", "serve-open", "serve-closed")
+    # families/<model>/<path>.py is there with every function of its path's
+    # interface: manifest.family names a missing file or function.
+    assert cell.family is manifest.family(cell.config["model"], cell.path)
     reported = {e["name"] for e in cell.end_to_end}
     assert "setup_s" in reported and len(reported) >= 2
     assert cell.per_layer, "a cell reports at least one per-layer metric"
@@ -79,3 +82,17 @@ def test_a_reader_that_finds_nothing_returns_nothing():
     for p in M["per_layer"]:
         spec = manifest.layer_metric(p["name"])
         assert manifest.reader(spec["reader"])({}, **spec.get("args", {})) is None
+
+
+def test_a_missing_family_file_or_function_is_named(tmp_path, monkeypatch):
+    with pytest.raises(FileNotFoundError, match="benchmarks/families/no_such_model/serve.py"):
+        manifest.family("no_such_model", "serve")
+    # The transformer has no train path yet: a new FILE, not an edit.
+    with pytest.raises(FileNotFoundError, match="families/transformer/train.py"):
+        manifest.family("transformer", "train")
+    family_dir = tmp_path / "families" / "half"
+    family_dir.mkdir(parents=True)
+    (family_dir / "serve.py").write_text("def build(config, overrides=None): ...\nmax_len = 7\n")
+    monkeypatch.setattr(manifest, "BENCH_DIR", str(tmp_path))
+    with pytest.raises(AttributeError, match="lacks apply_fn, decode_fns, max_len, token_vocab"):
+        manifest.family("half", "serve")

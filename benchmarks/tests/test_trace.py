@@ -4,10 +4,11 @@ apart, each wait under ``bench.input_wait``) and on hand-made intervals."""
 
 import os
 import statistics
+import time
 
 import pytest
 
-from benchmarks.harness import manifest, trace
+from benchmarks.harness import manifest, report, trace
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixture.xplane.pb")
 
@@ -107,3 +108,59 @@ def test_without_a_window_span_the_window_is_the_extent_of_device_work():
     assert trace.busy(tr) == (2.0, 4.0)
     with pytest.raises(ValueError):
         trace.window_of(_made(ops=[]))
+
+
+def test_nested_events_of_one_name_cover_a_gap_once():
+    # The runtime emits two nested PjitFunction(step_fn) a launch: together
+    # they cover 0.3 of this gap, not 0.6.
+    tr = _made(
+        ops=[(0.0, 1.0, "a"), (2.0, 3.0, "a")],
+        host=[(1.0, 1.3, "PjitFunction(step_fn)"), (1.05, 1.3, "PjitFunction(step_fn)")],
+    )
+    assert dict(trace.idle_gaps(tr)) == pytest.approx({"unattributed": 1.0})
+    tr["host"].append((1.3, 1.6, "PjitFunction(step_fn)"))
+    assert dict(trace.idle_gaps(tr)) == pytest.approx({"PjitFunction(step_fn)": 1.0})
+
+
+def test_an_idle_trace_is_reported_as_idle():
+    # No operation in the traced seconds: the window is the span, nothing ran.
+    tr = {"devices": {}, "spans": [(10.0, 14.0, "bench.window")],
+          "host": [(9.0, 15.0, "decode/park")]}
+    assert trace.window_of(tr) == (10.0, 14.0)
+    assert trace.busy(tr) == (0.0, 4.0)
+    assert trace.module_events(tr, "jit_step_fn") == []
+    assert trace.device_ops(tr) == []
+    assert trace.op_seconds(tr, "fusion") == (0, 0)
+    assert trace.idle_gaps(tr) == [["decode/park", 4.0]]
+
+
+@pytest.fixture(scope="module")
+def recorded_idle(tmp_path_factory):
+    """A trace recorded here, on the CPU, beside the chip's fixture: no TPU
+    plane, so no device work at all, under a ``bench.window`` span."""
+    import jax
+
+    log_dir = str(tmp_path_factory.mktemp("idle_trace"))
+    trace.start(log_dir)
+    with jax.profiler.TraceAnnotation(trace.WINDOW_SPAN):
+        with jax.profiler.TraceAnnotation("decode/park"):
+            time.sleep(0.05)
+    jax.profiler.stop_trace()
+    return trace.load(trace.find_xplane(log_dir))
+
+
+def test_a_recorded_idle_trace_gives_a_result_line_and_no_step_metric(
+        recorded_idle, monkeypatch, capsys):
+    assert recorded_idle["devices"] == {}
+    busy_s, window_s = trace.busy(recorded_idle)
+    assert busy_s == 0.0 and 0.05 <= window_s < 0.5
+    monkeypatch.setattr(manifest, "peak_for", lambda kind: {"hbm_bytes_per_s": 1.0})
+    cell = manifest.Cell("cgpt13b-serve-chat")
+    outcome = {"correct": True, "attempted": 38, "failed": 0, "end_to_end": {},
+               "evidence": {"cell": cell, "memory": [], "trace": recorded_idle}}
+    line = report.result(cell, outcome, traced=True)
+    assert line["device"]["busy_s"] == 0.0 and line["device"]["window_s"] == window_s
+    assert line["metrics"] == {"decode_device_idle_share": {"value": 100.0, "unit": "%"}}
+    assert line["breakdown"]["device_ops"] == []
+    assert sum(s for _name, s in line["breakdown"]["idle_gaps"]) == pytest.approx(window_s)
+    assert "the traced window was idle" in capsys.readouterr().out

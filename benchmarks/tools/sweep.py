@@ -31,7 +31,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args(argv)
     from benchmarks import run as run_mod
-    from benchmarks.harness import manifest, report, serve_cell, stats, traffic as traffic_lib
+    from benchmarks.harness import manifest, report, serve_cell, stats
 
     run_mod.place_compile_cache()
     cell = manifest.Cell(args.workload)
@@ -49,13 +49,7 @@ def main(argv=None) -> int:
             tr["arrivals"]["rate_per_s"] = rate
             sub = os.path.join(run_dir, f"rate{i}")
             os.makedirs(sub)
-            schedule = {
-                "kind": tr["kind"], "poll_s": tr["poll_s"], "host": "127.0.0.1",
-                "port": server.port,
-                "requests": traffic_lib.serve_schedule(
-                    tr, cell.config["published"]["vocab_size"], args.seed + i,
-                    float(tr["lead_s"]) + args.seconds + serve_cell.HORIZON_SLACK_S),
-            }
+            schedule = serve_cell.schedule_for(c, server.port, args.seed + i, args.seconds)
             gen = serve_cell.start_generator(sub)
             ev = serve_cell.measure(
                 c, server, gen, schedule, args.seconds, False, sub, phases,
