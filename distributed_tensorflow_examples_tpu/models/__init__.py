@@ -12,6 +12,8 @@ traced function XLA can fuse end-to-end.
 - ``resnet``   — W3 ResNet-50 ImageNet (ref: MirroredStrategy/NCCL)
 - ``word2vec`` — W4 skip-gram with mesh-sharded embedding (ref: PS-sharded)
 - ``lstm``     — W5 PTB LSTM LM (ref: MultiWorkerMirroredStrategy)
+- ``transformer`` — GPT-2-style decoder LM, trained and served
+- ``jamba``    — Mamba-1 + attention hybrid (AI21 Jamba), served
 """
 
 from . import layers  # noqa: F401
@@ -21,3 +23,4 @@ from . import resnet  # noqa: F401
 from . import word2vec  # noqa: F401
 from . import lstm  # noqa: F401
 from . import transformer  # noqa: F401
+from . import jamba  # noqa: F401

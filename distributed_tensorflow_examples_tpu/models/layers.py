@@ -4,7 +4,10 @@ The building blocks the reference gets from TF ops/Keras (dense, conv2d,
 batch-norm, LSTM cell, embedding — SURVEY.md section 1 L4) rebuilt as pure
 functions.  Compute-dtype policy: params live in float32; ``apply`` functions
 accept a ``dtype`` to run activations/matmuls in bfloat16 on the MXU while
-accumulating in float32 (``preferred_element_type``).
+accumulating in float32 (``preferred_element_type``).  ``dense`` without a
+``dtype`` multiplies what it is given and returns float32: handed bfloat16
+activations and bfloat16 kernels (a model served in the type it is
+published in, models/jamba.py) that is the MXU's native product.
 """
 
 from __future__ import annotations
@@ -88,6 +91,46 @@ def dense(params, x, *, dtype=None):
     if "bias" in params:
         y = y + params["bias"]
     return y
+
+
+# ----------------------------------------------------------------------------
+# RMSNorm, gated SiLU feed-forward (the block of today's open models)
+# ----------------------------------------------------------------------------
+
+
+def rmsnorm_init(d: int, dtype=jnp.float32):
+    return {"scale": jnp.ones((d,), dtype)}
+
+
+def rmsnorm(params, x, eps: float = 1e-6):
+    """``x / rms(x) * scale`` over the last axis, in float32 whatever comes
+    in (the mean of squares is what a low precision loses first); returns
+    float32."""
+    x = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * lax.rsqrt(ms + eps) * params["scale"].astype(jnp.float32)
+
+
+def gated_mlp_init(rng, dim: int, hidden: int, *, std: float = 0.02,
+                   out_std: float | None = None, dtype=jnp.float32):
+    """``down(silu(gate(x)) * up(x))``, no biases.  ``out_std`` is the scale
+    of the projection back into the residual stream."""
+    kg, ku, kd = jax.random.split(rng, 3)
+    normal = lambda k, shape, s: (s * jax.random.normal(k, shape)).astype(dtype)
+    return {
+        "gate": {"kernel": normal(kg, (dim, hidden), std)},
+        "up": {"kernel": normal(ku, (dim, hidden), std)},
+        "down": {"kernel": normal(kd, (hidden, dim), std if out_std is None else out_std)},
+    }
+
+
+def gated_mlp(params, x, *, dtype):
+    """Products in ``dtype`` accumulated in float32; the gate's SiLU and
+    its product with ``up`` in float32; returns float32."""
+    x = x.astype(dtype)
+    g = dense(params["gate"], x)
+    u = dense(params["up"], x)
+    return dense(params["down"], (jax.nn.silu(g) * u).astype(dtype))
 
 
 # ----------------------------------------------------------------------------

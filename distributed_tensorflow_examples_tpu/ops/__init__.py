@@ -8,3 +8,4 @@ implementation of each op serves CPU tests and autodiff checks.
 
 from . import attention  # noqa: F401
 from . import flash_attention  # noqa: F401
+from . import selective_scan  # noqa: F401

@@ -41,6 +41,7 @@ misinterpreted; the HELLO service identity makes even the refusal loud.
 
 from __future__ import annotations
 
+import inspect
 import json
 import logging
 import os
@@ -135,7 +136,8 @@ def flat_param_spec(init_fn):
 
 
 class _DecodeEngine:
-    """Stepped KV-cache decode behind the sequence-slot batcher (r19).
+    """Stepped decode over a per-slot cache behind the sequence-slot
+    batcher (r19).
 
     Model-agnostic: the model supplies ``init_cache_fn(slots, max_len)``
     (a per-slot cache pytree) and ``step_fn(params, cache, tokens[S],
@@ -144,23 +146,43 @@ class _DecodeEngine:
     state (current token and position per slot), greedy next-token
     selection and how a prompt reaches the cache, so batched decode is
     byte-identical to a session running alone: the slot array shape is
-    FIXED (inactive slots compute inert rows, like the row batcher's pad
-    rows), every row's math depends only on its own slot, and the
-    attention mask confines each session to the cache positions it wrote
-    itself — a freed slot needs no cache reset.
+    FIXED, every row's math depends only on its own slot, and a session
+    reads only what it wrote itself.
+
+    What a row may do to its slot.  A row is LIVE when its session decodes
+    in this step; an empty slot's row and the row of a seated session
+    whose prompt chunks are still due are not.  Two kinds of model:
+
+    - A model whose cache holds keys and values (the four-argument
+      ``step_fn`` above) is not told: its rows that are not live compute
+      inert rows, like the row batcher's pad rows - what such a row writes
+      at its position the session's first real step writes again, and the
+      attention mask confines each session to the positions it wrote
+      itself.  A freed slot needs no cache reset.
+    - A model whose cache holds a STATE that every step overwrites (a
+      state-space layer: models/jamba.py) cannot compute an inert row: it
+      would advance the state by a token that is not there.  Such a model
+      asks for the live rows by giving its ``step_fn`` a FIFTH argument,
+      ``live[S]`` bool, and promises: (a) a row that is not live leaves
+      everything its slot owns unchanged; (b) a session starts from the
+      zero state - the step at ``pos == 0`` and the chunk at ``offset ==
+      0`` start there whatever the slot held, so a freed slot still needs
+      no reset pass; (c) a chunk carries on from what the chunk before it
+      left in the slot.  The engine uploads ``live`` beside tokens and
+      positions for such a model and for no other.
 
     A model that also supplies ``prefill_fn(params, cache, tokens[C],
-    slot, offset, n_valid) -> cache`` (it writes the K/V of ONE slot's
-    positions ``[offset, offset + n_valid)`` and no other row) has its
-    prompts PREFILLED: before the decode step an iteration runs at most
-    one chunk of ``PREFILL_CHUNK`` tokens, for the longest-seated session
-    whose prompt is not yet cached, so every other session waits at most
-    one step plus one chunk for its next token, whatever the prompt
-    lengths or the burst.  A session being prefilled holds its slot with
-    an inert row; once all but its last prompt token are cached it is an
-    ordinary decode row at ``pos = P - 1`` and the next step emits its
-    first token.  Without ``prefill_fn`` the prompt is teacher-forced
-    through the decode step, a token a step.
+    slot, offset, n_valid) -> cache`` (it enters ONE slot's positions
+    ``[offset, offset + n_valid)`` into that slot's cache and touches no
+    other slot) has its prompts PREFILLED: before the decode step an
+    iteration runs at most one chunk of ``PREFILL_CHUNK`` tokens, for the
+    longest-seated session whose prompt is not yet cached, so every other
+    session waits at most one step plus one chunk for its next token,
+    whatever the prompt lengths or the burst.  A session being prefilled
+    holds its slot with a row that is not live; once all but its last
+    prompt token are cached it is an ordinary decode row at ``pos = P - 1``
+    and the next step emits its first token.  Without ``prefill_fn`` the
+    prompt is teacher-forced through the decode step, a token a step.
     """
 
     def __init__(
@@ -173,6 +195,12 @@ class _DecodeEngine:
         self._init_cache = init_cache_fn
         self._cache = init_cache_fn(slots, max_len)
         self._step_jit = jax.jit(step_fn)
+        # A fifth parameter is the model asking for the live rows.
+        self._wants_live = len(inspect.signature(step_fn).parameters) == 5
+        # What the engine holds for its slots (state, keys and values).
+        self.state_bytes = sum(
+            int(a.nbytes) for a in jax.tree.leaves(self._cache)
+        )
         self.slots = int(slots)
         self.max_len = int(max_len)
         # The cache is donated to the chunk (this engine holds its only
@@ -184,6 +212,8 @@ class _DecodeEngine:
         self._prefill_warm = False
         self.prefill_chunks = 0
         self.prefill_tokens = 0  # valid tokens; padding is not counted
+        # Slot-steps a seated session was not live: its chunks were due.
+        self.held_rows = 0
         self._tokens = np.zeros((self.slots,), np.int32)
         self._pos = np.zeros((self.slots,), np.int32)
         self.batcher = batcher_lib.SlotBatcher(
@@ -274,19 +304,21 @@ class _DecodeEngine:
                     # A freshly seated session starts its slot where its
                     # cached prompt ends: at position 0 feeding its first
                     # prompt token, or (prefilled) at its last prompt
-                    # token.  While chunks are still due the row is inert:
-                    # what it writes at that position its first real step
-                    # writes again.  The cache needs no reset (see the
-                    # class docstring).
+                    # token.  While chunks are still due the row is not
+                    # live.  The cache needs no reset (see the class
+                    # docstring).
                     t.state["seated"] = True
                     p0 = t.state["prefill"]
                     self._tokens[i] = t.state["prompt"][p0]
                     self._pos[i] = p0
-            tokens, pos = jnp.asarray(self._tokens), jnp.asarray(self._pos)
+            args = [jnp.asarray(self._tokens), jnp.asarray(self._pos)]
+            if self._wants_live:
+                args.append(jnp.asarray(np.fromiter(
+                    (t is not None and t.state["cached"] >= t.state["prefill"]
+                     for t in slots), bool, len(slots),
+                )))
         with _SPAN_DISPATCH:
-            logits, self._cache = self._step_jit(
-                params, self._cache, tokens, pos
-            )
+            logits, self._cache = self._step_jit(params, self._cache, *args)
         with _SPAN_FETCH:
             out = np.asarray(logits)
         with _SPAN_SELECT:
@@ -296,7 +328,8 @@ class _DecodeEngine:
                     continue
                 st = t.state
                 if st["cached"] < st["prefill"]:
-                    results[i] = ([], False)  # inert: its chunks are due
+                    results[i] = ([], False)  # held: its chunks are due
+                    self.held_rows += 1
                     continue
                 p = int(self._pos[i])
                 if p + 1 < len(st["prompt"]):
@@ -316,6 +349,8 @@ class _DecodeEngine:
         s["max_len"] = self.max_len
         s["prefill_chunks"] = self.prefill_chunks
         s["prefill_tokens"] = self.prefill_tokens
+        s["held_rows"] = self.held_rows
+        s["state_bytes"] = self.state_bytes
         return s
 
     def stop(self) -> None:
@@ -354,7 +389,9 @@ class ModelReplicaServer:
     client side).  A third function, ``prefill_fn`` (what
     ``models.transformer.serve_decode_fns`` gives for a dense model), puts
     a seated prompt into the cache a chunk per forward pass instead of a
-    token per decode step (:class:`_DecodeEngine`).
+    token per decode step; a ``step_fn`` with a fifth argument ``live``
+    (``models.jamba.serve_decode_fns``) is told which rows may change what
+    their slots own (:class:`_DecodeEngine`).
     """
 
     def __init__(

@@ -48,6 +48,11 @@ log = logging.getLogger("dtx.registry")
 MANIFEST_SCHEMA_VERSION = 1
 
 _NAME_RE = re.compile(r"^[A-Za-z0-9._-]{1,128}$")
+
+#: Types ``np.save`` does not bring back (it writes an extension type as
+#: bytes of no type): stored as the unsigned integers of their width, and
+#: viewed as what the manifest's ``dtype`` says on load.
+_STORED_AS = {"bfloat16": "uint16"}
 _VERSION_DIR_RE = re.compile(r"^v(\d{6})$")
 
 
@@ -174,6 +179,10 @@ class ModelRegistry:
             self._version_dir(name, version), m.get("params_file", "params.npy")
         )
         flat = np.load(path)
+        if m["dtype"] in _STORED_AS and str(flat.dtype) == _STORED_AS[m["dtype"]]:
+            import ml_dtypes
+
+            flat = flat.view(getattr(ml_dtypes, m["dtype"]))
         if flat.shape != (int(m["num_elems"]),) or str(flat.dtype) != m["dtype"]:
             raise RegistryError(
                 f"{name}/v{version}: params blob is {flat.shape}/{flat.dtype}, "
@@ -207,7 +216,7 @@ class ModelRegistry:
         params_tmp = os.path.join(vdir, "params.npy.tmp")
         f = open(params_tmp, "wb")
         try:
-            np.save(f, flat)
+            np.save(f, flat.view(_STORED_AS.get(str(flat.dtype), flat.dtype)))
             f.flush()
             os.fsync(f.fileno())
         finally:

@@ -59,19 +59,24 @@ def data_to_keys(restored: Any, template: Any) -> Any:
 
 
 def flat_params_of(state_or_params: Any):
-    """The flat f32 parameter vector of a params pytree (or a TrainState —
-    its ``params`` half), in the shared ``ps_shard.flat_param_spec`` leaf
+    """The flat parameter vector of a params pytree (or a TrainState — its
+    ``params`` half), in the shared ``ps_shard.flat_param_spec`` leaf
     order — the bridge from a restored checkpoint to the serve plane's
     flat-vector substrate (the model registry publishes exactly this
-    shape, and a serving replica's ``unflatten`` inverts it)."""
+    shape, and a serving replica's ``unflatten`` inverts it).  In the
+    tree's own type where every leaf shares one (a model held in bfloat16
+    is published, loaded and served in bfloat16); float32 for a tree of
+    mixed types."""
     import numpy as np
 
     params = getattr(state_or_params, "params", state_or_params)
     leaves = jax.tree.leaves(params)
     if not leaves:
         raise ValueError("no parameter leaves to flatten")
+    dtypes = {np.dtype(l.dtype) for l in leaves}
+    dtype = dtypes.pop() if len(dtypes) == 1 else np.dtype(np.float32)
     return np.concatenate(
-        [np.asarray(jax.device_get(l), np.float32).reshape(-1) for l in leaves]
+        [np.asarray(jax.device_get(l), dtype).reshape(-1) for l in leaves]
     )
 
 

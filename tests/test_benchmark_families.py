@@ -1,0 +1,33 @@
+"""The benchmark's family files against the program, in tier-1: the
+interface check, ``tiny`` and the rehearsal of EVERY cell through its family
+(``benchmarks/tests/test_families.py``'s cases, run here as they are), and
+the stand-in family that comes as files and entries only.  A program PR that
+renames ``serve_decode_fns`` or a ``Config`` field, or changes what the
+engine hands a step, fails here and not in a chip run.
+
+The cases are the benchmark's own functions, imported: each parametrised
+case counts and none is written twice.
+"""
+
+import importlib.util
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+_spec = importlib.util.spec_from_file_location(
+    "benchmarks_tests_test_families",
+    os.path.join(ROOT, "benchmarks", "tests", "test_families.py"),
+)
+_cases = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_cases)
+
+test_every_family_file_has_its_paths_whole_interface = (
+    _cases.test_every_family_file_has_its_paths_whole_interface)
+test_tiny_keeps_the_configuration_and_states_the_rehearsals_limits = (
+    _cases.test_tiny_keeps_the_configuration_and_states_the_rehearsals_limits)
+test_every_cell_rehearses_correct_through_its_family = (
+    _cases.test_every_cell_rehearses_correct_through_its_family)
+test_a_new_family_is_files_and_entries_only = (
+    _cases.test_a_new_family_is_files_and_entries_only)

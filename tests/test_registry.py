@@ -515,3 +515,45 @@ def test_dtxtop_serve_version_rollup(tmp_path):
     finally:
         for s in srvs:
             s.stop()
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_a_tree_round_trips_in_the_type_it_is_held_in(tmp_path, dtype):
+    """``flat_params_of`` -> ``publish`` -> ``load`` -> ``unflatten``: a tree
+    held in bfloat16 comes back bfloat16, equal bit for bit (``np.save``
+    alone writes an extension type as bytes of no type), and a float32 tree
+    as it always did; a tree of mixed types is flattened to float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_tensorflow_examples_tpu.parallel import ps_shard
+    from distributed_tensorflow_examples_tpu.train.checkpoint import (
+        flat_params_of,
+    )
+
+    k1, k2 = jax.random.split(jax.random.key(2))
+    tree = {
+        "emb": {"table": jax.random.normal(k1, (5, 3)).astype(dtype)},
+        "layer_0": {"A_log": jnp.log(jnp.arange(1.0, 8.0)).astype(dtype),
+                    "w": (1e-3 * jax.random.normal(k2, (3, 4))).astype(dtype)},
+    }
+    flat = flat_params_of(tree)
+    assert str(flat.dtype) == dtype and flat.shape == (15 + 7 + 12,)
+    reg = ModelRegistry(str(tmp_path))
+    v = reg.publish("typed", flat, step=3)
+    step, back, man = reg.load("typed", v)
+    assert step == 3 and man["dtype"] == dtype and str(back.dtype) == dtype
+    assert back.tobytes() == flat.tobytes()
+    total, unflatten = ps_shard.flat_param_spec(tree)
+    assert total == flat.size
+    jax.tree.map(
+        lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a).view(np.uint8), np.asarray(b).view(np.uint8)),
+        tree, unflatten(back),
+    )
+    mixed = flat_params_of({"a": np.ones(2, np.float32), "b": jnp.ones(3, jnp.bfloat16)})
+    assert mixed.dtype == np.float32
+    if dtype == "float32":
+        # To the byte what it was before: the leaves, concatenated.
+        assert flat.tobytes() == b"".join(
+            np.asarray(l).tobytes() for l in jax.tree.leaves(tree))

@@ -1,0 +1,94 @@
+"""The selective-scan kernel (ops/selective_scan.py): interpreted on the CPU
+against the same recurrence as a ``lax.scan``, and compiled - not run - for
+a described v5e chip at the widths AI21-Jamba2-3B gives it.
+
+Tolerances: the state is computed in the same order of operations by both
+(``exp(dt A) * h + u B``), so it agrees to the last bit or two; ``y`` sums
+the ``N`` state rows in another order, so it may differ by float32 rounding
+of a sum of ``N`` terms of the state's size.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_tensorflow_examples_tpu.ops import selective_scan as ss
+
+
+def _inputs(C, D, N, seed=0, h0=True):
+    k = jax.random.split(jax.random.key(seed), 7)
+    x = jax.random.normal(k[0], (C, D))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (C, D)) - 2.0)
+    a = -jnp.exp(0.5 * jax.random.normal(k[2], (N, D)))
+    b, c = jax.random.normal(k[3], (C, N)), jax.random.normal(k[4], (C, N))
+    d = jax.random.normal(k[5], (D,))
+    h = jax.random.normal(k[6], (N, D)) if h0 else jnp.zeros((N, D))
+    return x, dt, a, b, c, d, h
+
+
+@pytest.mark.parametrize(
+    "C,D,N,n_valid,h0",
+    [
+        (24, 200, 4, 24, False),     # channels far from a block of 1024
+        (24, 200, 4, 7, True),       # carried state, padding after 7 tokens
+        (300, 1100, 16, 290, True),  # three time blocks, two channel blocks
+        (16, 1024, 16, 0, True),     # nothing valid: the state comes back
+        (128, 2048, 16, 128, True),  # whole blocks, nothing padded
+    ],
+)
+def test_kernel_against_a_scan(C, D, N, n_valid, h0):
+    args = _inputs(C, D, N, seed=C + D, h0=h0)
+    y, h = ss.selective_scan(*args, n_valid)
+    y_ref, h_ref = ss.selective_scan_reference(*args, n_valid)
+    assert y.shape == (C, D) and h.shape == (N, D)
+    np.testing.assert_allclose(h, h_ref, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(y[:n_valid], y_ref[:n_valid], rtol=1e-5, atol=1e-5)
+    if n_valid == 0:
+        np.testing.assert_array_equal(h, args[-1])
+
+
+def test_padding_does_not_advance_the_state():
+    """The state after a chunk padded beyond ``n_valid`` is the state after
+    a chunk that ends there, and two chunks chained are one."""
+    x, dt, a, b, c, d, h0 = _inputs(40, 300, 8, seed=3)
+    _, whole = ss.selective_scan(x, dt, a, b, c, d, h0, 40)
+    _, padded = ss.selective_scan(x, dt, a, b, c, d, h0, 25)
+    _, cut = ss.selective_scan(x[:25], dt[:25], a, b[:25], c[:25], d, h0, 25)
+    np.testing.assert_array_equal(padded, cut)
+    y2, chained = ss.selective_scan(x[25:], dt[25:], a, b[25:], c[25:], d, padded, 15)
+    np.testing.assert_allclose(chained, whole, rtol=1e-6, atol=1e-6)
+    y, _ = ss.selective_scan(x, dt, a, b, c, d, h0, 40)
+    np.testing.assert_allclose(y2, y[25:], rtol=1e-5, atol=1e-5)
+
+
+# -- compiled for the chip, not run -------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip: the TPU's compiler without a TPU.  Only this
+    file of the suite loads the TPU's library (one process at a time may)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps the library away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_kernel_compiles_for_a_v5e_at_the_served_widths(one_chip, monkeypatch):
+    """Mosaic takes the kernel at ``C`` 512, ``d_inner`` 5120, ``N`` 16 (what
+    interpret mode cannot show: tiling, VMEM and SMEM), and the operation
+    carries the kernel's name, which the benchmark's readers look for."""
+    monkeypatch.setattr(ss, "interpret_mode", lambda: False)
+    C, D, N = 512, 5120, 16
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    n_valid = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(ss.selective_scan.__wrapped__).lower(
+        s(C, D), s(C, D), s(N, D), s(C, N), s(C, N), s(D), s(N, D), n_valid
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{ss.KERNEL_NAME}" in text

@@ -1448,3 +1448,150 @@ def test_a_failed_chunk_leaves_the_engine_a_cache():
         assert eng.stats()["step_errors"] == 1
     finally:
         eng.stop()
+
+
+# ----------------------------------------------------------------------------
+# A cache that holds a STATE: the step is told which rows are live (PR 27)
+# ----------------------------------------------------------------------------
+
+
+def _toy_state_decode_fns(vocab: int = 11):
+    """A toy whose slot owns ONE number that every token overwrites: ``s <-
+    (3 s + token + 1) mod 1009`` from zero, the next token ``s mod vocab``.
+    A step that advanced a row that is not live, a session that started
+    from what its slot's last session left, or a chunk that did not carry
+    on from the chunk before it changes the stream.  The step takes the
+    fifth argument.  ``_toy_state_stream`` is the same in plain Python."""
+    import jax
+    import jax.numpy as jnp
+
+    def init_cache_fn(slots, max_len):
+        return jnp.zeros((slots,), jnp.int32)
+
+    def step_fn(params, cache, tokens, pos, live):
+        s = (3 * jnp.where(pos == 0, 0, cache) + tokens + 1) % 1009
+        return jax.nn.one_hot(s % vocab, vocab), jnp.where(live, s, cache)
+
+    def prefill_fn(params, cache, tokens, slot, offset, n_valid):
+        def body(i, s):
+            return jnp.where(i < n_valid, (3 * s + tokens[i] + 1) % 1009, s)
+
+        s = jax.lax.fori_loop(
+            0, tokens.shape[0], body, jnp.where(offset == 0, 0, cache[slot]))
+        return cache.at[slot].set(s)
+
+    return init_cache_fn, step_fn, prefill_fn
+
+
+def _toy_state_stream(prompt, n: int, vocab: int = 11) -> list:
+    s, out = 0, []
+    for t in prompt:
+        s = (3 * s + int(t) + 1) % 1009
+    for _ in range(n):
+        out.append(s % vocab)
+        s = (3 * s + out[-1] + 1) % 1009
+    return out
+
+
+def _run_sessions(eng, prompts, budgets, gap_s: float = 0.0) -> list:
+    tickets = []
+    for p, n in zip(prompts, budgets):
+        tickets.append(eng.open(np.asarray(p, np.int32), n))
+        time.sleep(gap_s)
+    deadline = time.monotonic() + 120
+    for t in tickets:
+        while not t.done:
+            assert time.monotonic() < deadline
+            t.wait(0.5)
+    return [t.snapshot(0)[0] for t in tickets]
+
+
+@pytest.mark.parametrize("prefill", [True, False])
+def test_a_state_is_advanced_by_live_rows_only(monkeypatch, prefill):
+    """Two slots, five sessions: long prompts are held (not live) over
+    several steps while another decodes, sessions are seated into slots
+    others just left, one has a one-token prompt (no chunk: the step at
+    ``pos == 0`` starts the state) - each gets the stream it gets alone.
+    With the prompt fed through the step instead (no ``prefill_fn``) the
+    same holds."""
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 4)
+    fns = _toy_state_decode_fns()
+    eng = model_server._DecodeEngine(
+        lambda: (0, None), *(fns if prefill else fns[:2]),
+        slots=2, max_len=48, max_sessions=8,
+    )
+    assert eng._wants_live
+    prompts = [
+        [7, 3, 9], (np.arange(14) * 5 + 1) % 11, [4], (np.arange(10) * 2 + 3) % 11,
+        [1, 2],
+    ]
+    budgets = [9, 4, 5, 3, 6]
+    try:
+        outs = _run_sessions(eng, prompts, budgets, gap_s=0.02)
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    for p, o, n in zip(prompts, outs, budgets):
+        assert o == _toy_state_stream(p, n), list(p)
+    assert stats["state_bytes"] == 2 * 4
+    if prefill:
+        # 13 tokens in chunks of 4 hold their slot for three steps before
+        # the fourth chunk's step decodes; 9 tokens for two.
+        assert stats["prefill_chunks"] == 4 + 3 + 1 + 0 + 1
+        assert stats["held_rows"] == 3 + 2
+    else:
+        assert stats["held_rows"] == 0
+
+
+def test_jamba_sessions_through_the_engine_get_the_tokens_they_get_alone(
+    monkeypatch,
+):
+    """models/jamba.py behind ``_DecodeEngine``: a two-chunk prompt held
+    while another session decodes, sessions seated into slots others just
+    left, a one-token prompt - each session's tokens are those it gets as
+    the only session of a fresh engine, to the token."""
+    import jax
+
+    from distributed_tensorflow_examples_tpu.models import jamba
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    cfg = jamba.Config(
+        vocab_size=97, hidden_size=32, num_hidden_layers=4,
+        attn_layer_period=4, attn_layer_offset=1, num_attention_heads=2,
+        num_key_value_heads=1, intermediate_size=64, mamba_dt_rank=4,
+        mamba_d_state=8,
+    )
+    params = jamba.init(cfg, jax.random.key(5))
+    # Larger weights than the initialisation's: logits far enough apart
+    # that a token is no matter of rounding.
+    params = jax.tree.map(
+        lambda a: a * 6 if a.ndim == 2 and a.shape[0] > 8 else a, params)
+    fns = jamba.serve_decode_fns(cfg)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 97, size=n) for n in (5, 20, 3, 1, 11)]
+    budgets = [10, 5, 4, 6, 3]
+
+    def engine():
+        return model_server._DecodeEngine(
+            lambda: (0, params), *fns, slots=2, max_len=40, max_sessions=8)
+
+    eng = engine()
+    try:
+        assert eng._wants_live
+        together = _run_sessions(eng, prompts, budgets, gap_s=0.05)
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    assert stats["held_rows"] >= 2  # the 20-token prompt's first two chunks
+    assert stats["state_bytes"] == sum(
+        a.nbytes for a in jax.tree.leaves(jamba.init_cache(cfg, 2, 40)))
+    for p, n, got in zip(prompts, budgets, together):
+        eng = engine()
+        try:
+            alone = _run_sessions(eng, [p], [n])[0]
+        finally:
+            eng.stop()
+        assert got == alone, len(p)
