@@ -310,7 +310,7 @@ def bench_decode(
     - ``dense``: the flagship config (the r2 row).
     - ``moe``: same dims with E=8 top-2 GShard FFNs — decode routes each
       position through the SAME dispatch/combine einsums as training
-      (models/transformer.py _block_decode), so this prices MoE serving's
+      (models/transformer.py _block_decode_batch), so this prices MoE serving's
       per-token routing overhead against the dense row.
     - ``pipeline``: a pipeline-trained checkpoint (stacked ``blocks``
       layout, stages=4) collapsed to the flat serving layout via

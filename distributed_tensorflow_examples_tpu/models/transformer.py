@@ -441,49 +441,6 @@ def init_cache(cfg: Config, batch: int, max_len: int, *, mesh: Mesh | None = Non
     }
 
 
-def _block_decode(cfg: Config, p, h, layer_cache, pos, *, constrain, mesh=None):
-    """One block for ONE new token: h [B, 1, D], cache updated at ``pos``.
-
-    Static shapes throughout (cache is max_len long, masked beyond ``pos``)
-    so the jitted step never recompiles as decoding advances.  ``constrain``
-    pins activations/cache to the decode shardings (heads on 'model', batch
-    on the data axes — ('data','expert') for MoE; the T=1 dim never touches
-    'seq') — identity without a mesh.
-
-    MoE blocks route their single position through the SAME GShard
-    dispatch/combine einsums as training (ops/moe.py; aux loss unused at
-    inference).  Decode capacity is per-step — with only B tokens in
-    flight nothing realistically drops, whereas a training forward at full
-    T may drop overflow tokens; per-position parity therefore holds
-    whenever training capacity is not exceeded (tested)."""
-    B = h.shape[0]
-    da = cfg.data_axes
-    y = _layernorm(p["ln1"], h)
-    qkv = layers.dense(p["qkv"], y, dtype=cfg.dtype)
-    qkv = qkv.reshape(B, 1, cfg.n_heads, 3, cfg.head_dim)
-    q, k, v = [jnp.moveaxis(qkv[:, :, :, j], 2, 1) for j in range(3)]  # [B,H,1,hd]
-    q = constrain(q, P(da, "model", None, None))
-    ck = jax.lax.dynamic_update_slice(layer_cache["k"], k, (0, 0, pos, 0))
-    cv = jax.lax.dynamic_update_slice(layer_cache["v"], v, (0, 0, pos, 0))
-    ck = constrain(ck, P(da, "model", None, None))
-    cv = constrain(cv, P(da, "model", None, None))
-    s = jnp.einsum(
-        "bhqd,bhtd->bhqt", q, ck, preferred_element_type=jnp.float32
-    ) / math.sqrt(cfg.head_dim)
-    t_idx = jnp.arange(ck.shape[2])
-    s = jnp.where(t_idx[None, None, None, :] <= pos, s, -jnp.inf)
-    w = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
-    o = jnp.einsum("bhqt,bhtd->bhqd", w, cv)
-    o = jnp.moveaxis(o, 1, 2).reshape(B, 1, cfg.dim)
-    h = h + layers.dense(p["proj"], o, dtype=cfg.dtype)
-    h = constrain(h, P(da, None, None))
-    if "moe" in p:
-        h, _ = _moe_tail(cfg, p, h, constrain, mesh)
-    else:
-        h = _mlp_tail(cfg, p, h, constrain)
-    return h, {"k": ck, "v": cv}
-
-
 def _decode_constrain(mesh: Mesh | None, drop: tuple = ("seq",)):
     """Constraint fn for the decode path: same specs as training, except
     any entry in ``drop`` becomes None — 'seq' always (the decode T dim is
@@ -502,8 +459,98 @@ def _decode_constrain(mesh: Mesh | None, drop: tuple = ("seq",)):
     return constrain
 
 
+#: Cache positions one trip of the decode step's attention loop reads (or
+#: the whole cache, where that is shorter).  The step reads whole blocks up
+#: to its deepest row, so the size trades positions read past that row
+#: (half a block on average, of every slot) against trips of a loop whose
+#: body is a dozen small operations a layer.  Chosen on one v5e chip with
+#: Cerebras-GPT-1.3B, 8 slots x 2048 (my chip run, PR 28; PERF.md section
+#: 6): with the logits fetched every step, the bare step with its deepest
+#: row at 200 / 367 / 900 / 2040 takes 11.7 / 12.2 / 14.3 / 17.7 ms at
+#: 128, 11.7 / 12.3 / 13.6 / 16.4 at 256 and 12.5 / 12.4 / 13.2 / 15.5 at
+#: 512 (23.4 for the step that read it all): 256 is best where chat
+#: sessions stand, 512 gains only on a cache that is nearly full.
+DECODE_BLOCK = 256
+
+
+def decode_rows_read(max_pos, max_len: int):
+    """Cache positions of EVERY slot that one decode step reads when its
+    deepest row stands at ``max_pos``: whole blocks of :data:`DECODE_BLOCK`
+    up to the one that holds that position, at most the cache.  The host's
+    count of what :func:`_decode_attention`'s loop does on the device."""
+    blk = min(DECODE_BLOCK, max_len)
+    return min(max_len, (max_pos // blk + 1) * blk)
+
+
+def _write_rows(cache, new, pos):
+    """cache [B, H, T, hd] with ``new[b]`` ([B, H, 1, hd]) written at
+    position ``pos[b]`` of row ``b`` and nothing else changed: one
+    ``dynamic_update_slice`` a row (``B`` is static), each in place in a
+    donated cache.  On one v5e chip with Cerebras-GPT-1.3B, 8 slots x 2048,
+    the 384 of a step cost 1.0 ms less than the same rows written as one
+    batched scatter a layer, which the compiler turns into a loop of 8 (my
+    chip run, PR 28: the bare step 9.2 against 10.2 ms)."""
+    for b in range(cache.shape[0]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, new[b:b + 1], (b, 0, pos[b], 0)
+        )
+    return cache
+
+
+def _decode_attention(cfg: Config, q, ck, cv, pos):
+    """One query per row against that row's cache: q [B, H, 1, hd], ck / cv
+    [B, H, T, hd], ``pos`` [B] -> [B, H, 1, hd]; row ``b`` attends over its
+    positions ``<= pos[b]``.
+
+    The cache is read a block of positions at a time as a running softmax
+    (maximum, sum and weighted values carried in float32), in a loop whose
+    trip count ``max(pos) // block + 1`` is computed in the program: one
+    compiled step reads no further than its deepest row, whatever the
+    cache's length.  A block that lies wholly past a row's position is an
+    exact no-op for that row (weights 0, maximum unchanged, rescale by
+    ``exp(0) = 1``), so a row's result is the same to the bit whatever the
+    other rows' positions.  Block ``i`` holds positions ``[i * block,
+    (i + 1) * block)``; where the cache's length is no multiple of the
+    block the last one is read shifted back inside the cache and what it
+    shares with the block before is masked."""
+    B, H, T, hd = ck.shape
+    blk = min(DECODE_BLOCK, T)
+
+    def body(i, carry):
+        m, l, acc = carry
+        start = jnp.minimum(i * blk, T - blk)
+        kb = jax.lax.dynamic_slice_in_dim(ck, start, blk, axis=2)
+        vb = jax.lax.dynamic_slice_in_dim(cv, start, blk, axis=2)
+        s = jnp.einsum(
+            "bhqd,bhtd->bhqt", q, kb, preferred_element_type=jnp.float32
+        ) / math.sqrt(hd)
+        t = start + jnp.arange(blk)
+        own = (t >= i * blk)[None, :] & (t[None, :] <= pos[:, None])
+        s = jnp.where(own[:, None, None, :], s, -jnp.inf)
+        # Block 0 holds position 0, which every row owns: the maximum is
+        # finite from the first trip on.
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        w = jnp.exp(s - m_new)
+        r = jnp.exp(m - m_new)
+        l = l * r + w.sum(axis=-1, keepdims=True)
+        acc = acc * r + jnp.einsum(
+            "bhqt,bhtd->bhqd", w.astype(cfg.dtype), vb,
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l, acc
+
+    stat = jnp.zeros((B, H, 1, 1), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        0, jnp.max(pos) // blk + 1, body,
+        (stat - jnp.inf, stat, jnp.zeros((B, H, 1, hd), jnp.float32)),
+    )
+    return (acc / l).astype(cfg.dtype)
+
+
 def decode_step(cfg: Config, params, cache, token, pos, *, mesh: Mesh | None = None):
-    """token [B] int32 at position ``pos`` -> (logits [B, V], new cache).
+    """token [B] int32 at position ``pos`` -> (logits [B, V], new cache):
+    :func:`decode_step_batch` with every row at the ONE position ``pos``
+    (what :func:`generate`'s scan runs).
 
     With ``mesh``: runs TP-sharded — KV cache and attention heads on the
     'model' axis, Megatron dense sharding via the weight shardings +
@@ -514,25 +561,10 @@ def decode_step(cfg: Config, params, cache, token, pos, *, mesh: Mesh | None = N
     (a pipelined decode would bubble O(stages) per token — serve those
     with the stages collapsed).
     """
-    if cfg.pipeline_stages > 1:
-        raise NotImplementedError(
-            "decode supports the non-pipelined model (dense or MoE)"
-        )
-    constrain = _decode_constrain(mesh)
-    da = cfg.data_axes
-    h = layers.embedding_lookup(params["emb"], token[:, None], dtype=cfg.dtype)
-    h = h + jax.lax.dynamic_slice_in_dim(
-        params["pos"]["table"], pos, 1, axis=0
-    ).astype(cfg.dtype)[None]
-    h = constrain(h, P(da, None, None))
-    new_cache = {}
-    for i in range(cfg.n_layers):
-        h, new_cache[f"block_{i}"] = _block_decode(
-            cfg, params[f"block_{i}"], h, cache[f"block_{i}"], pos,
-            constrain=constrain, mesh=mesh,
-        )
-    h = _layernorm(params["ln_f"], h)
-    return layers.dense(params["head"], h, dtype=cfg.dtype)[:, 0], new_cache
+    return decode_step_batch(
+        cfg, params, cache, token,
+        jnp.full(token.shape, pos, jnp.int32), mesh=mesh,
+    )
 
 
 def _block_decode_batch(cfg: Config, p, h, layer_cache, pos, *, constrain, mesh=None):
@@ -541,37 +573,40 @@ def _block_decode_batch(cfg: Config, p, h, layer_cache, pos, *, constrain, mesh=
     (models/transformer.py's half of serve/batcher.SlotBatcher): each row
     is an independent decode session at its own depth.
 
-    Identical math to :func:`_block_decode` row-for-row: the cache write
-    is a one-hot ``where`` at each row's position (same values
-    ``dynamic_update_slice`` writes at a shared position), and the causal
-    mask bounds each row at ITS ``pos`` — so a session's row depends only
-    on cache positions that session wrote itself, which is what lets a
-    freed slot be reseated with no cache reset and keeps batched decode
-    byte-identical to a session running alone (tested)."""
+    What a step touches of the cache.  It WRITES row ``b``'s new key and
+    value into ``cache[b, :, pos[b], :]`` and into no other element
+    (:func:`_write_rows`; in place when the caller donates the cache, as
+    the serve engine does - a cache that is not donated is copied whole by
+    the runtime first).  It READS whole blocks of positions up to its
+    deepest row and no further (:func:`_decode_attention`,
+    :func:`decode_rows_read`), and the causal mask bounds each row at ITS
+    ``pos`` — so a session's row depends only on cache positions that
+    session wrote itself, to the bit whatever the other rows hold or where
+    they stand, which is what lets a freed slot be reseated with no cache
+    reset and keeps batched decode byte-identical to a session running
+    alone (tested).
+
+    Static shapes throughout, so the jitted step never recompiles as
+    decoding advances.  ``constrain`` pins activations/cache to the decode
+    shardings (heads on 'model', batch on the data axes — ('data','expert')
+    for MoE; the T=1 dim never touches 'seq') — identity without a mesh.
+
+    MoE blocks route their single position through the SAME GShard
+    dispatch/combine einsums as training (ops/moe.py; aux loss unused at
+    inference).  Decode capacity is per-step — with only B tokens in
+    flight nothing realistically drops, whereas a training forward at full
+    T may drop overflow tokens; per-position parity therefore holds
+    whenever training capacity is not exceeded (tested)."""
     B = h.shape[0]
-    T = layer_cache["k"].shape[2]
     da = cfg.data_axes
     y = _layernorm(p["ln1"], h)
     qkv = layers.dense(p["qkv"], y, dtype=cfg.dtype)
     qkv = qkv.reshape(B, 1, cfg.n_heads, 3, cfg.head_dim)
     q, k, v = [jnp.moveaxis(qkv[:, :, :, j], 2, 1) for j in range(3)]  # [B,H,1,hd]
     q = constrain(q, P(da, "model", None, None))
-    onehot = (
-        jnp.arange(T)[None, :] == pos[:, None]
-    )[:, None, :, None]  # [B,1,T,1]
-    ck = jnp.where(onehot, k, layer_cache["k"])
-    cv = jnp.where(onehot, v, layer_cache["v"])
-    ck = constrain(ck, P(da, "model", None, None))
-    cv = constrain(cv, P(da, "model", None, None))
-    s = jnp.einsum(
-        "bhqd,bhtd->bhqt", q, ck, preferred_element_type=jnp.float32
-    ) / math.sqrt(cfg.head_dim)
-    t_idx = jnp.arange(T)
-    s = jnp.where(
-        t_idx[None, None, None, :] <= pos[:, None, None, None], s, -jnp.inf
-    )
-    w = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
-    o = jnp.einsum("bhqt,bhtd->bhqd", w, cv)
+    ck = constrain(_write_rows(layer_cache["k"], k, pos), P(da, "model", None, None))
+    cv = constrain(_write_rows(layer_cache["v"], v, pos), P(da, "model", None, None))
+    o = _decode_attention(cfg, q, ck, cv, pos)
     o = jnp.moveaxis(o, 1, 2).reshape(B, 1, cfg.dim)
     h = h + layers.dense(p["proj"], o, dtype=cfg.dtype)
     h = constrain(h, P(da, None, None))
@@ -587,10 +622,10 @@ def decode_step_batch(
 ):
     """token [B] int32, pos [B] int32 (PER-ROW positions) -> (logits
     [B, V], new cache) — the sequence-slot batched decode step: row b
-    advances its own session at position ``pos[b]``.  Same math as
-    :func:`decode_step` per row (which requires ONE shared position); the
-    serving engine jits this once at the fixed slot shape and every
-    active session rides one apply."""
+    advances its own session at position ``pos[b]``.  The serving engine
+    jits this once at the fixed slot shape, donates it the cache, and
+    every active session rides one apply; what a step reads and writes of
+    the cache is in :func:`_block_decode_batch`."""
     if cfg.pipeline_stages > 1:
         raise NotImplementedError(
             "decode supports the non-pipelined model (dense or MoE)"
@@ -722,6 +757,9 @@ def serve_decode_fns(cfg: Config, *, mesh: Mesh | None = None):
 
     def step_fn(params, cache, tokens, pos):
         return decode_step_batch(cfg, params, cache, tokens, pos, mesh=mesh)
+
+    # How far into the cache a step reads: the engine counts it.
+    step_fn.cache_rows_read = decode_rows_read
 
     def prefill_fn(params, cache, tokens, slot, offset, n_valid):
         return prefill_chunk(

@@ -41,6 +41,7 @@ misinterpreted; the HELLO service identity makes even the refusal loud.
 
 from __future__ import annotations
 
+import contextlib
 import inspect
 import json
 import logging
@@ -158,7 +159,11 @@ class _DecodeEngine:
       inert rows, like the row batcher's pad rows - what such a row writes
       at its position the session's first real step writes again, and the
       attention mask confines each session to the positions it wrote
-      itself.  A freed slot needs no cache reset.
+      itself.  A freed slot needs no cache reset.  Such a ``step_fn`` may
+      say how far into the cache its step reads by an attribute
+      ``cache_rows_read(max_pos, max_len) -> int`` (the positions of every
+      slot read when the deepest row stands at ``max_pos``); without it a
+      step is taken to read all ``max_len`` (counter ``cache_rows_read``).
     - A model whose cache holds a STATE that every step overwrites (a
       state-space layer: models/jamba.py) cannot compute an inert row: it
       would advance the state by a token that is not there.  Such a model
@@ -183,6 +188,15 @@ class _DecodeEngine:
     prompt token are cached it is an ordinary decode row at ``pos = P - 1``
     and the next step emits its first token.  Without ``prefill_fn`` the
     prompt is teacher-forced through the decode step, a token a step.
+
+    What the engine hands over.  The cache is DONATED to the step and to
+    the chunk alike (the engine holds its only reference), so a program
+    that writes a row in place costs that row and not a second cache; a
+    program that raises may have cost the engine its cache, so it gets a
+    fresh one - the failure frees every slot, and a freed slot needs no
+    cache state.  An empty slot's row is stepped with token 0 at position
+    0, not where its last session stood: a step that reads no further than
+    its deepest row is held to the sessions that are seated.
     """
 
     def __init__(
@@ -194,17 +208,19 @@ class _DecodeEngine:
         self._get_model = model_getter  # () -> (step, params) | None
         self._init_cache = init_cache_fn
         self._cache = init_cache_fn(slots, max_len)
-        self._step_jit = jax.jit(step_fn)
+        self._step_jit = jax.jit(step_fn, donate_argnums=1)
         # A fifth parameter is the model asking for the live rows.
         self._wants_live = len(inspect.signature(step_fn).parameters) == 5
+        # How far into the cache a step reads: all of it, unless told.
+        self._rows_read = getattr(
+            step_fn, "cache_rows_read", lambda max_pos, max_len: max_len
+        )
         # What the engine holds for its slots (state, keys and values).
         self.state_bytes = sum(
             int(a.nbytes) for a in jax.tree.leaves(self._cache)
         )
         self.slots = int(slots)
         self.max_len = int(max_len)
-        # The cache is donated to the chunk (this engine holds its only
-        # reference): the chunk's rows are written in place.
         self._prefill_jit = (
             jax.jit(prefill_fn, donate_argnums=1) if prefill_fn else None
         )
@@ -214,6 +230,8 @@ class _DecodeEngine:
         self.prefill_tokens = 0  # valid tokens; padding is not counted
         # Slot-steps a seated session was not live: its chunks were due.
         self.held_rows = 0
+        # Cache positions of each slot the steps' attention read, summed.
+        self.cache_rows_read = 0
         self._tokens = np.zeros((self.slots,), np.int32)
         self._pos = np.zeros((self.slots,), np.int32)
         self.batcher = batcher_lib.SlotBatcher(
@@ -243,26 +261,31 @@ class _DecodeEngine:
             "cached": 0,
         })
 
+    @contextlib.contextmanager
+    def _cache_donated(self):
+        """Around a program the cache is donated to: a failure may have
+        cost the engine its cache, so it gets a fresh one (the step's
+        failure frees every slot, and a freed slot needs no cache state)."""
+        try:
+            yield
+        except BaseException:
+            self._cache = None  # never two caches on the device
+            self._cache = self._init_cache(self.slots, self.max_len)
+            raise
+
     def _prefill(self, params, slot: int, tokens, offset: int, n_valid: int):
         """Run one chunk to completion (the span around it is the chunk's
-        whole cost, and a chunk that fails fails here).  A failure may have
-        cost the engine its donated cache, so it gets a fresh one: the
-        step's failure frees every slot, and a freed slot needs no cache
-        state."""
+        whole cost, and a chunk that fails fails here)."""
         import jax
 
         buf = np.zeros((self._chunk,), np.int32)
         buf[:n_valid] = tokens
-        try:
+        with self._cache_donated():
             self._cache = self._prefill_jit(
                 params, self._cache, buf, np.int32(slot), np.int32(offset),
                 np.int32(n_valid),
             )
             jax.block_until_ready(self._cache)
-        except BaseException:
-            self._cache = None  # never two caches on the device
-            self._cache = self._init_cache(self.slots, self.max_len)
-            raise
 
     def _prefill_one(self, params, slots) -> None:
         """At most one chunk: the next of the longest-seated session whose
@@ -300,7 +323,9 @@ class _DecodeEngine:
             self._prefill_one(params, slots)
         with _SPAN_PREPARE:
             for i, t in enumerate(slots):
-                if t is not None and not t.state["seated"]:
+                if t is None:
+                    self._tokens[i] = self._pos[i] = 0
+                elif not t.state["seated"]:
                     # A freshly seated session starts its slot where its
                     # cached prompt ends: at position 0 feeding its first
                     # prompt token, or (prefilled) at its last prompt
@@ -317,10 +342,12 @@ class _DecodeEngine:
                     (t is not None and t.state["cached"] >= t.state["prefill"]
                      for t in slots), bool, len(slots),
                 )))
-        with _SPAN_DISPATCH:
-            logits, self._cache = self._step_jit(params, self._cache, *args)
-        with _SPAN_FETCH:
-            out = np.asarray(logits)
+            rows_read = self._rows_read(int(self._pos.max()), self.max_len)
+        with self._cache_donated():
+            with _SPAN_DISPATCH:
+                logits, self._cache = self._step_jit(params, self._cache, *args)
+            with _SPAN_FETCH:
+                out = np.asarray(logits)
         with _SPAN_SELECT:
             results: list = [None] * len(slots)
             for i, t in enumerate(slots):
@@ -342,6 +369,8 @@ class _DecodeEngine:
                 self._tokens[i] = nxt
                 self._pos[i] = p + 1
                 results[i] = (emits, st["emitted"] >= st["n"])
+        # Counted where the batcher counts the step: when it has run.
+        self.cache_rows_read += rows_read
         return results
 
     def stats(self) -> dict:
@@ -350,6 +379,7 @@ class _DecodeEngine:
         s["prefill_chunks"] = self.prefill_chunks
         s["prefill_tokens"] = self.prefill_tokens
         s["held_rows"] = self.held_rows
+        s["cache_rows_read"] = self.cache_rows_read
         s["state_bytes"] = self.state_bytes
         return s
 

@@ -1451,6 +1451,157 @@ def test_a_failed_chunk_leaves_the_engine_a_cache():
 
 
 # ----------------------------------------------------------------------------
+# The step is donated its cache and reads no further than its deepest row
+# ----------------------------------------------------------------------------
+
+
+def test_a_failed_step_leaves_the_engine_a_cache():
+    """The cache is donated to the step as it is to the chunk; a step that
+    raises fails the active sessions and the next session is served from a
+    live cache."""
+    import jax.numpy as jnp
+
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    init_cache_fn, step_fn, prefill_fn = _toy_cached_decode_fns()
+    calls = []
+
+    def flaky_step_fn(params, cache, tokens, pos):
+        calls.append(1)  # runs when the program is traced
+        if len(calls) == 1:
+            raise FloatingPointError("step failed")
+        return step_fn(params, cache, tokens, pos)
+
+    eng = model_server._DecodeEngine(
+        lambda: (0, None), init_cache_fn, flaky_step_fn, prefill_fn,
+        slots=2, max_len=16, max_sessions=4,
+    )
+    try:
+        first = eng._cache
+        t = eng.open(np.array([1, 2, 3], np.int32), 2)
+        while not t.done:
+            t.wait(5)
+        with pytest.raises(FloatingPointError):
+            t.snapshot(0)
+        assert isinstance(eng._cache, jnp.ndarray) and not eng._cache.is_deleted()
+        assert eng._cache is not first
+        t = eng.open(np.array([1, 2, 3], np.int32), 2)
+        while not t.done:
+            t.wait(5)
+        assert t.snapshot(0)[0] == _toy_cached_stream([1, 2, 3], 2)
+        assert eng.stats()["step_errors"] == 1
+    finally:
+        eng.stop()
+
+
+def _tiny_transformer_engine(max_len: int = 16, slots: int = 2):
+    import jax
+
+    from distributed_tensorflow_examples_tpu import models
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    cfg = models.transformer.Config(
+        vocab_size=32, dim=16, n_layers=2, n_heads=2, max_seq_len=max_len,
+    )
+    params = models.transformer.init(cfg, jax.random.key(0))
+    return model_server._DecodeEngine(
+        lambda: (0, params), *models.transformer.serve_decode_fns(cfg),
+        slots=slots, max_len=max_len, max_sessions=4,
+    ), params
+
+
+def test_the_step_is_donated_the_cache_it_returns():
+    """Donation is real: the lowered step aliases every leaf of the cache
+    to the output that replaces it, and after a step the cache the engine
+    held before is gone (a second 3.2 GB array in the served cell, were
+    it not)."""
+    eng, params = _tiny_transformer_engine()
+    try:
+        lowered = eng._step_jit.lower(
+            params, eng._cache, np.zeros(2, np.int32), np.zeros(2, np.int32)
+        )
+        assert lowered.as_text().count("tf.aliasing_output") == 2 * 2
+        assert lowered.compile().memory_analysis().alias_size_in_bytes == eng.state_bytes
+        before = eng._cache["block_0"]["k"]
+        _run_sessions(eng, [[1, 2, 3]], [2])
+        assert before.is_deleted() and not eng._cache["block_0"]["k"].is_deleted()
+    finally:
+        eng.stop()
+
+
+def test_a_freed_slot_is_stepped_at_position_zero():
+    """A slot whose session ended stands at token 0, position 0 in every
+    later step, not where that session left it: the step's read is held to
+    the sessions that are seated."""
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    gate = threading.Event()
+
+    def model():
+        gate.wait(10)
+        return 0, None
+
+    eng = model_server._DecodeEngine(
+        model, *_toy_cached_decode_fns()[:2], slots=2, max_len=32, max_sessions=4,
+    )
+    log: list = []
+    step_jit = eng._step_jit
+
+    def logged_step(params, cache, tokens, pos):
+        log.append((np.asarray(tokens).copy(), np.asarray(pos).copy()))
+        return step_jit(params, cache, tokens, pos)
+
+    eng._step_jit = logged_step
+    try:
+        long = eng.open(np.array([1, 2, 3], np.int32), 12)  # holds the thread
+        time.sleep(0.1)
+        short = eng.open(np.array([4, 5, 6, 7], np.int32), 2)
+        gate.set()
+        for t in (long, short):
+            while not t.done:
+                t.wait(5)
+        assert short.snapshot(0)[0] == _toy_cached_stream([4, 5, 6, 7], 2)
+        assert long.snapshot(0)[0] == _toy_cached_stream([1, 2, 3], 12)
+    finally:
+        eng.stop()
+    theirs = [int(p[1]) for _t, p in log]
+    # The short session's five steps at positions 0-4, then the freed slot.
+    last = max(i for i, p in enumerate(theirs) if p)
+    assert theirs[last] == 4 and len(theirs) > last + 3
+    assert all(int(t[1]) == 0 and int(p[1]) == 0 for t, p in log[last + 1:])
+
+
+@pytest.mark.parametrize("bounded", [True, False])
+def test_cache_rows_read_counts_what_a_step_reads(monkeypatch, bounded):
+    """``cache_rows_read`` grows a step by the positions of each slot the
+    step's attention read: the model's own bound where its ``step_fn``
+    gives one (whole blocks up to the deepest row), ``max_len`` for a model
+    that does not say."""
+    from distributed_tensorflow_examples_tpu import models
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    if bounded:
+        monkeypatch.setattr(models.transformer, "DECODE_BLOCK", 4)
+        eng, _params = _tiny_transformer_engine(max_len=14)
+    else:
+        eng = model_server._DecodeEngine(
+            lambda: (0, None), *_toy_cached_decode_fns(), slots=2, max_len=14,
+            max_sessions=4,
+        )
+    try:
+        # One session alone: a one-token prompt, then positions 0-12, the
+        # last in a block that the cache cuts short.
+        _run_sessions(eng, [[3]], [13])
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    assert stats["steps"] == 13 and stats["max_len"] == 14
+    assert stats["cache_rows_read"] == (
+        4 * 4 + 4 * 8 + 4 * 12 + 14 if bounded else 13 * 14
+    )
+
+
+# ----------------------------------------------------------------------------
 # A cache that holds a STATE: the step is told which rows are live (PR 27)
 # ----------------------------------------------------------------------------
 

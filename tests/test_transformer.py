@@ -243,9 +243,8 @@ def test_generate_tp_sharded_matches_replicated(mesh_4x2):
 
 def test_decode_step_batch_matches_scalar_pos_bitwise():
     """r19 sequence-slot decode: with every row at the SAME position the
-    per-row-pos batched step is byte-identical to decode_step — the
-    one-hot cache write and per-row mask are the same math as
-    dynamic_update_slice + the scalar mask."""
+    per-row-pos batched step is byte-identical to decode_step, which is
+    that step with its one position given to every row."""
     import numpy as np
 
     cfg = models.transformer.Config(
@@ -306,6 +305,143 @@ def test_decode_step_batch_rows_are_independent_sessions():
             t = jnp.asarray(np.array([n1], np.int32))
 
 
+def _junk_cache(tf, cfg, S, T, seed):
+    """What earlier sessions left behind: nothing may depend on it, and
+    whatever a step does not own must keep it to the bit."""
+    rng = np.random.default_rng(seed)
+    return jax.tree.map(
+        lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype),
+        tf.init_cache(cfg, S, T),
+    )
+
+
+_BLOCKED = models.transformer.Config(
+    vocab_size=97, dim=32, n_layers=2, n_heads=4, max_seq_len=32,
+    compute_dtype="float32",
+)
+
+
+@pytest.mark.parametrize("other", [0, 7, 8, 19])
+def test_a_decode_row_is_bit_equal_wherever_another_row_stands(monkeypatch, other):
+    """The step's attention reads whole blocks up to its DEEPEST row: a
+    row's logits and the key and value it wrote are the same to the bit
+    whether the other row stands before it, at a block's last position,
+    at a block's first or at the cache's end (more trips of the loop, each
+    an exact no-op for this row) - whatever the other row's slot holds."""
+    tf = models.transformer
+    monkeypatch.setattr(tf, "DECODE_BLOCK", 8)
+    params = tf.init(_BLOCKED, jax.random.key(1))
+    T, mine = 20, 9
+    step = jax.jit(lambda c, t, p: tf.decode_step_batch(_BLOCKED, params, c, t, p))
+    tok = jnp.asarray(np.array([5, 11], np.int32))
+    want_l, want_c = step(
+        _junk_cache(tf, _BLOCKED, 2, T, 0), tok, jnp.asarray([mine, mine], jnp.int32)
+    )
+    junk = _junk_cache(tf, _BLOCKED, 2, T, 0)
+    junk = jax.tree.map(lambda a: a.at[1].set(a[1] * 3 + 1), junk)
+    got_l, got_c = step(junk, tok, jnp.asarray([mine, other], jnp.int32))
+    assert np.array_equal(np.asarray(got_l)[0], np.asarray(want_l)[0])
+    for name in got_c:
+        for kv in ("k", "v"):
+            assert np.array_equal(
+                np.asarray(got_c[name][kv])[0], np.asarray(want_c[name][kv])[0]
+            ), (name, kv)
+
+
+@pytest.mark.parametrize(
+    "T,pos",
+    [
+        (20, (0, 7, 8, 19)),   # block edges; the cache's end, T no multiple of 8
+        (20, (19, 16, 15, 3)),  # the shifted last block's first and shared rows
+        (16, (15, 0, 8, 7)),   # T a multiple of the block
+        (5, (4, 0, 2, 1)),     # a cache shorter than a block
+    ],
+)
+def test_a_decode_step_writes_one_cache_row_a_slot_and_no_other(monkeypatch, T, pos):
+    """A step over a cache of junk changes ``cache[b, :, pos[b], :]`` and
+    no other element (what ``prefill_chunk`` is held to for its rows)."""
+    tf = models.transformer
+    monkeypatch.setattr(tf, "DECODE_BLOCK", 8)
+    params = tf.init(_BLOCKED, jax.random.key(1))
+    junk = _junk_cache(tf, _BLOCKED, 4, T, T)
+    tok = jnp.asarray(np.array([5, 11, 2, 90], np.int32))
+    logits, got = jax.jit(
+        lambda c, t, p: tf.decode_step_batch(_BLOCKED, params, c, t, p)
+    )(junk, tok, jnp.asarray(pos, jnp.int32))
+    assert np.all(np.isfinite(np.asarray(logits)))
+    for name in got:
+        for kv in ("k", "v"):
+            g, j = np.asarray(got[name][kv]), np.asarray(junk[name][kv])
+            keep = np.ones(g.shape, bool)
+            for b, p in enumerate(pos):
+                keep[b, :, p] = False
+                assert not np.array_equal(g[b, :, p], j[b, :, p]), (name, kv, b)
+            assert np.array_equal(g[keep], j[keep]), (name, kv)
+
+
+@pytest.mark.parametrize("T", [20, 16])
+def test_blocked_decode_matches_full_forward_at_every_depth(monkeypatch, T):
+    """Rows three positions apart walk the whole cache, every block edge
+    and position ``T - 1`` among them (``T`` a multiple of the block and
+    not): each step's logits are the training forward's at that row's
+    position."""
+    tf = models.transformer
+    monkeypatch.setattr(tf, "DECODE_BLOCK", 8)
+    params = tf.init(_BLOCKED, jax.random.key(0))
+    S = 3
+    x = np.asarray(jax.random.randint(jax.random.key(1), (S, T), 0, 97))
+    ref = np.asarray(tf.apply(_BLOCKED, params, jnp.asarray(x)))  # [S, T, V]
+    cache = _junk_cache(tf, _BLOCKED, S, T, 1)
+    step = jax.jit(lambda c, t, p: tf.decode_step_batch(_BLOCKED, params, c, t, p))
+    assert tf.decode_rows_read(7, T) == 8 and tf.decode_rows_read(8, T) == 16
+    assert tf.decode_rows_read(T - 1, T) == T
+    for s in range(T + 3 * (S - 1)):
+        pos = np.clip(s - 3 * np.arange(S), 0, T - 1).astype(np.int32)
+        logits, cache = step(cache, jnp.asarray(x[np.arange(S), pos]), jnp.asarray(pos))
+        np.testing.assert_allclose(
+            np.asarray(logits), ref[np.arange(S), pos], rtol=2e-4, atol=2e-4
+        )
+
+
+def test_blocked_decode_on_a_mesh_matches_one_device(monkeypatch, mesh_4x2):
+    """Rows at their own depths on a data=4 x model=2 mesh (the cache's
+    batch and heads sharded, its positions not: a block is a slice on
+    every device, a row's write lands on the device that holds the row):
+    logits and cache agree with the one-device step."""
+    import optax
+
+    tf = models.transformer
+    monkeypatch.setattr(tf, "DECODE_BLOCK", 8)
+    state, _ = train.create_sharded_state(
+        lambda r: tf.init(_BLOCKED, r), optax.sgd(0.1), jax.random.key(0),
+        mesh=mesh_4x2, rules=tf.SHARDING_RULES,
+    )
+    local = jax.device_get(state.params)
+    T = 20
+    junk = jax.device_get(_junk_cache(tf, _BLOCKED, 4, T, 3))
+    tok = jnp.asarray(np.array([5, 11, 2, 90], np.int32))
+    pos = jnp.asarray(np.array([0, 7, 8, 19], np.int32))
+    want_l, want_c = jax.jit(
+        lambda p, c: tf.decode_step_batch(_BLOCKED, p, c, tok, pos)
+    )(local, junk)
+    sharded = jax.tree.map(
+        lambda a, like: jax.device_put(a, like.sharding),
+        junk, tf.init_cache(_BLOCKED, 4, T, mesh=mesh_4x2),
+    )
+    got_l, got_c = jax.jit(
+        lambda p, c: tf.decode_step_batch(_BLOCKED, p, c, tok, pos, mesh=mesh_4x2)
+    )(state.params, sharded)
+    np.testing.assert_allclose(np.asarray(got_l), np.asarray(want_l), atol=2e-4)
+    for name in want_c:
+        for kv in ("k", "v"):
+            assert got_c[name][kv].sharding.is_equivalent_to(
+                sharded[name][kv].sharding, 4
+            )
+            np.testing.assert_allclose(
+                np.asarray(got_c[name][kv]), np.asarray(want_c[name][kv]), atol=1e-5
+            )
+
+
 @pytest.mark.parametrize(
     "C,T,n,slot",
     [
@@ -331,12 +467,7 @@ def test_prefill_chunks_match_token_by_token_decode(C, T, n, slot):
     S = 4
     rng = np.random.default_rng(C * 1000 + T)
     toks = rng.integers(0, cfg.vocab_size, size=T).astype(np.int32)
-    # What earlier sessions left behind: nothing may depend on it, and
-    # whatever the prefill does not own must keep it to the bit.
-    junk = jax.tree.map(
-        lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype),
-        tf.init_cache(cfg, S, T),
-    )
+    junk = _junk_cache(tf, cfg, S, T, C * 1000 + T + 1)
     step = jax.jit(lambda c, t, p: tf.decode_step_batch(cfg, params, c, t, p))
     chunk = jax.jit(
         lambda c, t, o, nv: tf.prefill_chunk(cfg, params, c, t, slot, o, nv)
