@@ -188,10 +188,9 @@ def _batchnorm_ghost(
 
     Full SyncBN reduces batch statistics over the WHOLE data axis — on a
     multi-slice deployment that is 2 tiny all-reduces per BN layer
-    CROSSING DCN (98 per ResNet-50 step, the honest caveat in BASELINE.md
-    r3's hybrid table).  Here the batch dim is reshaped [B] -> [S, B/S]
-    with S pinned to the mesh's outermost ('slice') axis, so the
-    statistics reduce runs only over the slice-LOCAL sub-axis of data
+    CROSSING DCN (98 per ResNet-50 step).  Here the batch dim is reshaped
+    [B] -> [S, B/S] with S pinned to the mesh's outermost ('slice') axis,
+    so the statistics reduce runs only over the slice-LOCAL sub-axis of data
     (rides ICI) and each slice normalises with its own "ghost batch"
     (batch/S) statistics — the standard mitigation, with the standard
     statistics change (normalisation noise of a batch/S batch; quantified
@@ -242,38 +241,17 @@ def batchnorm(
     all-reduce) — matching SyncBatchNorm semantics, which is what mirrored
     data-parallel training wants.
 
-    ``mesh`` (TPU): opts into the EXPERIMENTAL fused statistics path
-    (ops/bn.py — Pallas kernels or MXU-matmul forms, gradient-exact vs this
-    path).  Measured end-to-end in r3 (BASELINE.md) it was SLOWER
-    than the XLA path (layout-conversion copies / algebraic re-simplification
-    — BASELINE.md r3 table), so no shipped model threads a mesh in by
-    default; the code is retained as measured evidence and for stacks where
-    those compiler behaviors change.  Callers without a mesh always get the
-    XLA path (a pallas_call on an implicitly-sharded array would force a
-    gather).
+    ``mesh``: used by the ghost-batch path alone, which pins its shardings
+    with it.
 
-    ``relu``: apply ReLU to the output INSIDE this layer.  On the fused
-    path the backward then recomputes the mask in-kernel instead of
-    materialising the masked gradient (the r3 profile's +29 ms trap);
-    semantically identical to relu(batchnorm(x))."""
+    ``relu``: apply ReLU to the output inside this layer; identical to
+    relu(batchnorm(x))."""
     if train and ghost_slices > 0:
         return _batchnorm_ghost(
             params, stats, x, momentum=momentum, eps=eps, mesh=mesh,
             relu=relu, ghost_slices=ghost_slices,
         )
     if train:
-        from ..ops import bn as bn_ops
-
-        if mesh is not None and bn_ops._use_pallas():
-            y, mean, var = bn_ops.batchnorm_train(
-                params["scale"], params["bias"], x, eps, mesh, relu
-            )
-            mean, var = jax.lax.stop_gradient((mean, var))
-            new_stats = {
-                "mean": momentum * stats["mean"] + (1 - momentum) * mean,
-                "var": momentum * stats["var"] + (1 - momentum) * var,
-            }
-            return y, new_stats
         axes = tuple(range(x.ndim - 1))
         # One-pass stats: E[x] and E[x^2] share a single read of the
         # activation (XLA fuses sibling reductions), where mean+var is two

@@ -153,8 +153,8 @@ def _stem_conv(cfg: Config, kernel, x):
 def apply(cfg: Config, params, model_state, x, *, train: bool, mesh=None):
     """x: [B, H, W, 3] -> (logits [B, num_classes], new_model_state).
 
-    ``mesh`` opts the BatchNorms into the fused Pallas statistics path
-    (layers.batchnorm / ops/bn.py) with explicit SyncBN psums."""
+    ``mesh``: what the ghost-batch BatchNorms (``cfg.bn_ghost_slices``) pin
+    their shardings with; unused otherwise."""
     new_state: dict = {}
     y = _stem_conv(cfg, params["stem"]["kernel"], x)
     y, new_state["bn_stem"] = layers.batchnorm(
@@ -186,7 +186,7 @@ def apply(cfg: Config, params, model_state, x, *, train: bool, mesh=None):
 
 def loss_fn(cfg: Config, *, l2: float = 1e-4, mesh=None):
     """Softmax CE + L2 weight decay on conv/dense kernels (the tutorial-
-    standard ResNet objective).  ``mesh`` -> fused-Pallas BN (see apply)."""
+    standard ResNet objective).  ``mesh``: for ghost-batch BN (see apply)."""
 
     def f(params, model_state, batch, rng):
         logits, new_state = apply(

@@ -75,8 +75,8 @@ class Config:
     #: [E, C_g], C_g ~ k*G/E), so G is THE dispatch-share knob — smaller G
     #: cuts dispatch FLOPs but shrinks the expert matmul tiles and changes
     #: routing semantics (capacity is per-group).  1024 = GShard's default
-    #: regime; sweep via bench.py --moe-group-size if the profiled dispatch
-    #: share exceeds the ~15%% budget (VERDICT r3/r4).
+    #: regime; sweep it if the profiled dispatch share exceeds the ~15%%
+    #: budget (VERDICT r3/r4).
     moe_group_size: int = 1024
     #: Rematerialise each block in the backward pass (jax.checkpoint): trades
     #: ~1/3 more FLOPs for activation memory ~O(n_layers) smaller — the knob

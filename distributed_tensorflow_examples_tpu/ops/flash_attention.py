@@ -85,7 +85,7 @@ def interpret_mode() -> bool:
     is refused rather than interpreted under a kernel's name.  The platform
     is the one jit places this computation's arrays on
     (``jax.default_backend()``).  The ONE platform test every Pallas kernel
-    in the package uses (ops/bn.py included)."""
+    in the package uses."""
     platform = jax.default_backend()
     if platform == "tpu":
         return False
@@ -323,9 +323,9 @@ _FUSED_BWD_OVERRIDE: bool | None = None
 #: window every grid step with last-write-wins ordering — semantics CPU
 #: interpret mode cannot validate.  Until ``tools/flash_parity.py`` has
 #: PASSED on a real chip, auto-dispatch stays on the split kernels; opt in
-#: per-process with DTX_FUSED_BWD=1 (the measurement campaign does, after
-#: running the parity gate first).  Flip to True once BASELINE.md records
-#: the TPU parity + bitwise-determinism pass.
+#: per-process with DTX_FUSED_BWD=1, after running the parity gate first.
+#: Flip to True once PERF.md records the TPU parity + bitwise-determinism
+#: pass.
 _FUSED_BWD_VALIDATED = False
 
 #: Upper bound on the fused kernel's [tq, d] f32 dq accumulator (VMEM
@@ -712,7 +712,7 @@ def flash_attention(
     Default 1024x1024 tiles: the measured optimum of the v5e sweep (BASELINE.md;
     ~18% faster than 512x512, and 2048 tiles blow VMEM at D=64).  The
     DTX_FLASH_BQ / DTX_FLASH_BK env vars override the defaults — the
-    in-step block-sweep knob (bench.py re-runs per setting), read at
+    in-step block-sweep knob (one fresh process per setting), read at
     trace time.
     """
     import os
@@ -741,8 +741,8 @@ def flash_attention(
     if "DTX_FLASH_BQ" in os.environ or "DTX_FLASH_BK" in os.environ:
         # Env overrides are read at TRACE time and do not key the jit cache:
         # an in-process sweep that re-sets them silently reuses the first
-        # trace (ADVICE r4).  Each sweep point must be a fresh process
-        # (bench.py is); this line only prints when a trace actually
+        # trace (ADVICE r4).  Each sweep point must be a fresh process;
+        # this line only prints when a trace actually
         # happens, so a sweep log with a missing line is a stale-cache run.
         import sys
 

@@ -834,8 +834,10 @@ class PSClient:
                 return
             except _StateLost:
                 lost_retries += 1
+                # A replica not yet seen lost; with all of them lost, the
+                # primary (see _post_reconnect).
                 nxt = next(
-                    i for i in range(len(self._addrs)) if i not in lost
+                    (i for i in range(len(self._addrs)) if i not in lost), 0
                 )
                 self._switch_replica(nxt)
                 immediate = True
@@ -883,7 +885,13 @@ class PSClient:
                 return
             if not force_rebuild and lost is not None:
                 lost.add(self._cur)
-                if len(lost) < len(self._addrs):
+                # With the state lost on EVERY replica, rebuild on the
+                # primary and nowhere else.  Attempts alternate the
+                # replicas from wherever each client stood when its dial
+                # failed, so "the replica tried last" differs from client
+                # to client: the chief then reseeds and pops on one
+                # replica while a worker pushes to the other, for ever.
+                if len(lost) < len(self._addrs) or self._cur != 0:
                     raise _StateLost()
         else:
             # Legacy (token-less) server, or first contact: incarnation

@@ -73,14 +73,11 @@ def main(argv):
         import optax as _optax
 
         mode = "sync_replicas" if FLAGS.sync_replicas else "async"
-        # Short LR warmup (r19 convergence fix, default 20 applies): the
-        # first async applies land on stale params at full magnitude; a
-        # linear ramp keeps them from collapsing the relu stack onto the
-        # uniform plateau (the ROADMAP bench note's fix shape — a
-        # training-quality change, not a looser test).  Measured at the
-        # e2e gate's flags (lr 0.05, 200 steps, seed 0): warmup 20 + the
-        # He/small-softmax init reaches loss 1.93 / accuracy 0.51 where
-        # the pre-fix run plateaued at 2.18 / 0.28.
+        # Short LR warmup (default 20 applies): the first async applies
+        # land on stale params at full magnitude; a linear ramp keeps them
+        # from collapsing the relu stack onto the uniform plateau.  The
+        # stack is stable at lr 0.01 (the e2e gate's flag; 200 applies
+        # reach accuracy 0.67-0.98 over seeds 0-2) and on the edge at 0.05.
         warmup = FLAGS.warmup_steps if FLAGS.warmup_steps > 0 else 20
         lr = _optax.linear_schedule(
             FLAGS.learning_rate / 10.0, FLAGS.learning_rate, warmup

@@ -82,7 +82,7 @@ flags.DEFINE_integer(
     "moe_group_size",
     1024,
     "GShard routing-group size G (dispatch FLOPs/token ~ G; capacity is "
-    "per-group) — the dispatch-share knob, see bench.py --moe-group-size.",
+    "per-group) — the dispatch-share knob (Config.moe_group_size).",
 )
 
 FLAGS = flags.FLAGS

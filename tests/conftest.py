@@ -14,6 +14,17 @@ os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
 )
 os.environ.setdefault("JAX_ENABLE_X64", "0")
+# XLA's CPU client runs every virtual device's share of a step on one thread
+# pool, sized by NPROC where it is set and else by the cores it may use - 8
+# here, as many as the devices.  A step with a collective blocks 8 threads
+# until all 8 have joined; steps are dispatched back to back, the pool steals
+# work out of order, and under six busy workers a later step's shares take
+# threads the earlier step still needs: a deadlock, which XLA ends after 40 s
+# by aborting the process ("Termination timeout ... only 5 of them arrived";
+# it took a whole worker, and xdist then never finished the run).  With room
+# for four steps in the pool the stress that aborted 5 of 6 processes in two
+# minutes ran 240,000 steps clean.  Children of the tests inherit it.
+os.environ.setdefault("NPROC", "32")
 
 import jax  # noqa: E402
 

@@ -1,7 +1,7 @@
 """chip_smoke.py on the CPU: the smoke's body at tiny size, and the guards
 that keep a run that did not reach the chip from reading as one that did
-(the smoke's own refusal, bench.py's, the device-peak table, build_mesh's
-accelerator rule, the compile-cache placement rule).
+(the smoke's own refusal, the benchmark runner's, build_mesh's accelerator
+rule, the compile-cache placement rule).
 """
 
 from __future__ import annotations
@@ -91,25 +91,21 @@ def test_smoke_cli_fails_fast_without_a_chip():
     assert "not 'tpu'" in p.stderr.strip().splitlines()[-1]
 
 
-# -- bench.py ----------------------------------------------------------------
+# -- benchmarks/run.py -------------------------------------------------------
 
 
-def test_bench_prints_no_metric_off_tpu():
+def test_benchmark_runner_prints_no_result_off_tpu():
+    """No CPU run can print a metric: the one runner refuses before it
+    builds anything, with its own exit code and an empty standard output."""
     p = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py")],
+        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
+         "--workload", "resnet50-train-b256", "--seed", "0",
+         "--seconds", "1", "--trace", "0"],
         capture_output=True, text=True, env=_cpu_env(), cwd=ROOT, timeout=120,
     )
-    assert p.returncode != 0
-    assert "metric" not in p.stdout and p.stdout.strip() == ""
-    assert "not 'tpu'" in p.stderr
-
-
-def test_bench_unknown_device_kind_is_an_error():
-    import bench
-
-    assert bench._peak_tflops("TPU v5 lite") == 197.0
-    with pytest.raises(ValueError, match="no published peak"):
-        bench._peak_tflops("TPU v99")
+    assert p.returncode == 3, p.stderr[-2000:]  # run.py's NO_CHIP
+    assert p.stdout.strip() == ""
+    assert "nothing was run" in p.stderr.strip().splitlines()[-1]
 
 
 # -- build_mesh --------------------------------------------------------------

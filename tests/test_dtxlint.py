@@ -1150,7 +1150,7 @@ def test_baseline_keys_are_line_stable(tmp_path):
 
 def test_cli_json_schema_and_repo_is_clean(capsys):
     """THE tier-1 gate: the real repo lints clean, and the --json document
-    holds the schema campaign_report and external consumers parse."""
+    holds the schema external consumers parse."""
     rc = dtxlint_main(["--json", "--root", ROOT])
     report = json.loads(capsys.readouterr().out)
     assert rc == 0, report["findings"]
@@ -1774,25 +1774,9 @@ def test_cli_changed_mode_lints_only_the_diff(tmp_path, capsys):
     # contract; exercised against the real repo in the CLI tests above).
 
 
-def test_campaign_plan_runs_dtxlint_as_cpu_step():
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    try:
-        import measure_campaign as mc
-    finally:
-        sys.path.pop(0)
-    steps = {s["name"]: s for s in mc.steps_plan()}
-    assert "dtxlint" in steps, "campaign lost the static-analysis step"
-    assert steps["dtxlint"].get("cpu_ok") is True
-    assert os.path.exists(os.path.join(ROOT, steps["dtxlint"]["cmd"][1]))
-    # r16: the native TSAN gate rides the same cpu_ok train.
-    assert "tsan_protocol" in steps, "campaign lost the TSAN gate"
-    assert steps["tsan_protocol"].get("cpu_ok") is True
-    assert os.path.exists(os.path.join(ROOT, steps["tsan_protocol"]["cmd"][1]))
-
-
 def test_perf_gate_enforces_dtxlint_wall_time_budget():
     """The lint runs inside tier-1 on every PR: a silently slower pass
-    must fail the campaign's perf gate, and the checked-in baseline must
+    must fail the perf gate, and the checked-in baseline must
     stay auto-selectable from the step's metric field."""
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     try:
@@ -1820,7 +1804,7 @@ def test_perf_gate_enforces_dtxlint_wall_time_budget():
 
 
 def test_dtxlint_step_emits_gated_metric():
-    """The campaign shim's single JSON line carries the metric + seconds
+    """The shim's single JSON line carries the metric + seconds
     perf_gate keys off, on top of the full --json document shape."""
     import subprocess
 

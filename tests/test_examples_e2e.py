@@ -111,13 +111,17 @@ def test_mnist_ps_emulation_sync_replicas(tmp_path):
 def test_cifar10_async_ps(tmp_path):
     """W2: --sync_replicas=false selects the true-async apply path.
 
-    r4 (VERDICT r3 next-step #8): ``--deterministic`` runs the async
-    applies on the FIXED round-robin interleave — every gradient still
-    applies at stale params (W2 semantics, asserted in
+    ``--deterministic`` runs the async applies on the FIXED round-robin
+    interleave — every gradient still applies at stale params (W2
+    semantics, asserted in
     test_async_ps.py::test_async_fixed_interleave_deterministic_and_stale)
     but the trajectory is reproducible, so this gate is ONE run with ONE
-    threshold (measured 0.46 accuracy / loss 2.30->1.83 at these flags; no
-    seed-retry OR).  Free-running thread mode stays the CLI default; its
+    threshold (no seed-retry OR).  The learning rate is one this stack is
+    stable at: at 0.01 seeds 0-2 all learn (accuracy 0.67-0.98, loss
+    2.3 -> ~1.0 in 200 applies); at 0.05 the same run sits on the edge —
+    accuracy 0.20 / 0.42 by seed, and 400 applies blow up onto the 2.303
+    plateau — so a threshold there measured the init draw, not the async
+    path.  Free-running thread mode stays the CLI default; its
     cross-process learning gate is
     tests/test_ps_remote.py::test_async_across_processes.
     """
@@ -127,7 +131,7 @@ def test_cifar10_async_ps(tmp_path):
         "--worker_hosts=a:1,b:1",
         "--batch_size=128",
         "--train_steps=200",
-        "--learning_rate=0.05",
+        "--learning_rate=0.01",
         "--max_staleness=4",
         "--deterministic",
         "--seed=0",

@@ -131,8 +131,8 @@ def test_fused_bwd_dispatch_gate(monkeypatch):
 def test_fused_bwd_validation_latch(monkeypatch):
     """ADVICE r4 (medium): until tools/flash_parity.py passes on real
     Mosaic, the in-regime shapes must NOT auto-dispatch to the fused kernel
-    — opt-in is per-process via DTX_FUSED_BWD=1 (what the measurement
-    campaign sets after running the parity gate)."""
+    — opt-in is per-process via DTX_FUSED_BWD=1 (set after running the
+    parity gate)."""
     from distributed_tensorflow_examples_tpu.ops import flash_attention as F
     from distributed_tensorflow_examples_tpu.ops.flash_attention import _use_fused_bwd
 
@@ -142,7 +142,7 @@ def test_fused_bwd_validation_latch(monkeypatch):
     monkeypatch.setenv("DTX_FUSED_BWD", "1")
     assert _use_fused_bwd(4, 4, 4096, 128)
     assert not _use_fused_bwd(2, 2, 2048, 128)  # opt-in keeps the regime gate
-    # The explicit override (tests, flash_bench --fused) beats everything:
+    # The explicit override (tests) beats everything:
     monkeypatch.setenv("DTX_FUSED_BWD", "0")
     monkeypatch.setattr(F, "_FUSED_BWD_OVERRIDE", True)
     assert _use_fused_bwd(2, 2, 2048, 128)
@@ -290,3 +290,23 @@ def test_fused_bwd_segmented_deterministic(monkeypatch):
     b = grad(q, k, v)
     for x, y in zip(a, b):
         assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_flash_parity_case_runs_in_interpret_mode():
+    """run_case at a tiny shape: parity + bitwise determinism hold in
+    interpret mode (the TPU run reuses this exact code path)."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    try:
+        import flash_parity
+    finally:
+        sys.path.pop(0)
+
+    rec = flash_parity.run_case(1, 2, 128, 16, jnp.float32, True, check_ref=True)
+    assert rec["ok"], rec
+    assert rec["bitwise_deterministic"]
+    rec = flash_parity.run_case(1, 2, 128, 16, jnp.bfloat16, False, check_ref=False)
+    assert rec["ok"], rec

@@ -138,8 +138,7 @@ def test_checked_in_loadsim_baseline_is_a_passing_verdict():
 @pytest.mark.slow
 def test_loadsim_chaos_smoke_e2e(tmp_path):
     """THE acceptance smoke: a short real-cluster run with the full
-    kill/join/leave cycle must pass its SLO gate end to end (this is the
-    same invocation the measure_campaign cpu_ok step runs, trimmed)."""
+    kill/join/leave cycle must pass its SLO gate end to end."""
     out = tmp_path / "verdict.json"
     env = dict(os.environ)
     env.pop("DTX_FAULT_PLAN", None)

@@ -39,7 +39,9 @@ def test_cnn_shapes_and_loss_falls(mesh8):
     ds = data.datasets.cifar10(None, seed=0)
     pipe = data.InMemoryPipeline(ds.train, batch_size=64, seed=0)
     it = iter(pipe)
-    opt = optax.sgd(0.1)
+    # lr 0.05: at 0.1 this stack sits on the edge of stability (half of
+    # the init draws collapse onto the 2.303 plateau within 45 steps).
+    opt = optax.sgd(0.05)
     state, sh = train.create_sharded_state(
         lambda r: models.cnn.init(cfg, r), opt, jax.random.key(0), mesh=mesh8, rules=()
     )
@@ -50,13 +52,15 @@ def test_cnn_shapes_and_loss_falls(mesh8):
     for _ in range(45):
         state, m = step(state, as_global(next(it), mesh8))
         losses.append(float(m["loss"]))
-    # The small-stddev (1/fan_in) softmax init starts the loss NEAR ln(10)
-    # — tiny-but-nonzero logits, so every layer below gets gradients from
-    # step 1 (the r19 convergence fix; a glorot-scale head would start at
-    # ~4.6 and its ~50x first gradients collapse the relu stack).  Any
-    # drop below the plateau is real learning.  Average the tail:
-    # single-batch losses are noisy at this scale.
-    assert abs(losses[0] - 2.3026) < 0.05, losses[0]
+    # The small-stddev (1/fan_in) softmax init keeps the first logits small
+    # — tiny-but-nonzero, so every layer below gets gradients from step 1
+    # (a glorot-scale head would start at ~4.6 and its ~50x first
+    # gradients collapse the relu stack).  How near ln(10) the first loss
+    # lands depends on the draw: the He-scaled features under the head
+    # have an RMS of ~3, so on this 32-wide head it reads 2.29-2.59 over
+    # init keys 0-3.  Any drop below the plateau is real learning.
+    # Average the tail: single-batch losses are noisy at this scale.
+    assert 2.2 < losses[0] < 3.0, losses[0]
     assert sum(losses[-10:]) / 10 < 2.27, losses[-10:]
 
 
@@ -289,73 +293,71 @@ def test_batchnorm_one_pass_stats_match_two_pass():
     )
 
 
-import pytest
-
-
-@pytest.mark.parametrize("impl", ["pallas", "matmul"])
-def test_fused_bn_parity_with_xla_path(mesh8, impl):
-    """ops/bn.py (BOTH stats implementations: Pallas kernels + custom VJP
-    with SyncBN psum via shard_map, and the MXU-matmul forms) must match
-    the XLA batchnorm path — y, running stats, and gradients — on a
-    sharded multi-device mesh.  FORCE_PALLAS runs the same code
-    interpreted on CPU."""
-    import numpy as np
-    import jax
-    import jax.numpy as jnp
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("relu", [False, True])
+def test_batchnorm_sharded_matches_unsharded(mesh8, relu, dtype):
+    """SyncBN over the global batch, the property the ResNet step relies on:
+    ``layers.batchnorm(train=True)`` with the batch sharded on ``data`` gives
+    the y, the new running statistics and the gradients of the same call on
+    one device; and ``relu=True`` is relu(batchnorm(x))."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from distributed_tensorflow_examples_tpu.models import layers
-    from distributed_tensorflow_examples_tpu.ops import bn as bn_ops
 
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(16, 4, 4, 24)).astype(np.float32))
+    x = jnp.asarray(rng.normal(size=(16, 4, 4, 24)) * 2 + 0.5, dtype)
     params = {"scale": jnp.linspace(0.5, 1.5, 24), "bias": jnp.linspace(-1, 1, 24)}
     stats = {"mean": jnp.zeros((24,)), "var": jnp.ones((24,))}
-    xs = jax.device_put(x, NamedSharding(mesh8, P("data")))
+    w = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
 
-    def run(use_mesh, relu=False):
+    def run(x, relu):
         def f(params, x):
             y, new_stats = layers.batchnorm(
-                params, stats, x, train=True,
-                mesh=mesh8 if use_mesh else None, relu=relu,
+                params, stats, x, train=True, relu=relu
             )
-            return jnp.sum(y * y), (y, new_stats)
+            y = y.astype(jnp.float32)
+            # Weighted, since sum(y * y) is constant in x up to rounding.
+            return jnp.sum(y * w), (y, new_stats)
 
-        (loss, (y, ns)), grads = jax.jit(
-            jax.value_and_grad(f, has_aux=True)
-        )(params, xs)
-        return loss, y, ns, grads
+        (_, (y, ns)), grads = jax.jit(
+            jax.value_and_grad(f, argnums=(0, 1), has_aux=True)
+        )(params, x)
+        return y, ns, grads
 
-    bn_ops.FORCE_PALLAS = True
-    old_impl = bn_ops.IMPL
-    bn_ops.IMPL = impl
-    try:
-        l_fast, y_fast, ns_fast, g_fast = run(True)
-        l_fr, y_fr, ns_fr, g_fr = run(True, relu=True)
-    finally:
-        bn_ops.FORCE_PALLAS = False
-        bn_ops.IMPL = old_impl
-    l_ref, y_ref, ns_ref, g_ref = run(False)
-    l_rr, y_rr, ns_rr, g_rr = run(False, relu=True)
-
-    # relu-fused path (in-kernel mask recompute) vs XLA relu(batchnorm(x)).
-    np.testing.assert_allclose(float(l_fr), float(l_rr), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(y_fr), np.asarray(y_rr), atol=1e-5)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4),
-        g_fr, g_rr,
+    y_one, ns_one, g_one = run(x, relu)
+    y_sh, ns_sh, g_sh = run(
+        jax.device_put(x, NamedSharding(mesh8, P("data"))), relu
     )
-
-    np.testing.assert_allclose(float(l_fast), float(l_ref), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(y_fast), np.asarray(y_ref), atol=1e-5)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5),
-        ns_fast, ns_ref,
+    # bf16 rounds y to 8 bits of mantissa, and sums the gradients in it:
+    # another reduction order moves those by a few percent of the largest.
+    tol, gtol = (1e-5, 1e-4) if dtype == "float32" else (2e-2, 5e-2)
+    np.testing.assert_allclose(
+        np.asarray(y_sh), np.asarray(y_one), rtol=tol, atol=tol
     )
     jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4),
-        g_fast, g_ref,
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
+        ns_sh, ns_one,
     )
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            rtol=gtol, atol=gtol * float(jnp.max(jnp.abs(b))),
+        ),
+        g_sh, g_one,
+    )
+    assert g_sh[1].dtype == x.dtype
+    # The statistics are the global batch's, not a shard's.
+    xf = np.asarray(x, np.float32)
+    np.testing.assert_allclose(
+        np.asarray(ns_sh["mean"]), 0.1 * xf.mean(axis=(0, 1, 2)),
+        rtol=1e-4, atol=1e-5,
+    )
+    if relu:
+        y_plain, _, _ = run(x, False)
+        np.testing.assert_allclose(
+            np.asarray(y_one), np.maximum(np.asarray(y_plain), 0.0),
+            rtol=tol, atol=tol,
+        )
 
 
 def test_resnet_ghost_bn_slice_local_stats_and_parity():

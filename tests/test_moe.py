@@ -326,7 +326,7 @@ def test_moe_composes_with_ulysses():
 
 def test_moe_group_size_plumbs_from_transformer_config():
     """r5: Config.moe_group_size reaches ops.moe.MoEConfig (the dispatch-
-    share knob the campaign sweeps) — and both group sizes train finite."""
+    share knob) — and both group sizes train finite."""
     import numpy as np
 
     from distributed_tensorflow_examples_tpu import models

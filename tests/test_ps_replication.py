@@ -239,7 +239,12 @@ def test_client_fails_over_to_backup_without_rebuild(caplog):
     c.close()
 
 
-def test_both_replicas_restarted_empty_runs_reseed_path(caplog):
+@pytest.mark.parametrize("stood_on", [0, 1])
+def test_both_replicas_restarted_empty_runs_reseed_path(caplog, stood_on):
+    """Total loss: the last resort runs, and it runs on the PRIMARY whichever
+    replica the client stood on when the loss came — a chief that had failed
+    over and a worker that had not must not rebuild on different replicas
+    (the chief would pop a queue no worker pushes to)."""
     caplog.set_level("INFO", logger="dtx.faults")
     pa, pb = _pair()
     fired = []
@@ -250,6 +255,14 @@ def test_both_replicas_restarted_empty_runs_reseed_path(caplog):
     st = ps_service.RemoteParamStore(c, "params", 4)
     st.set(5, np.arange(4, dtype=np.float32))
     c.on_reincarnation(lambda: fired.append("reseed"))
+    if stood_on == 1:
+        # An earlier incident: the primary died, the client failed over,
+        # the primary came back and synced from the survivor.
+        ps_service.stop_server(pa)
+        assert st.get()[0] == 5
+        ps_service.start_server(pa, peer=("127.0.0.1", pb), sync_wait_s=10.0)
+        assert fired == []
+    assert c._cur == stood_on
     # Kill BOTH, restart BOTH empty on the same ports (fresh lineage).
     ps_service.stop_server(pa)
     ps_service.stop_server(pb)
@@ -259,6 +272,7 @@ def test_both_replicas_restarted_empty_runs_reseed_path(caplog):
     step, _ = st.get()
     assert step == -1  # empty store: the owner must reseed
     assert fired == ["reseed"], "total state loss must run the last resort"
+    assert c._cur == 0, "the rebuild belongs on the primary"
     c.close()
 
 

@@ -61,8 +61,20 @@ def no_fault_plan(monkeypatch):
 # ----------------------------------------------------------------------------
 
 
-def _dial(port: int, service: str = "", timeout: float = 10.0) -> socket.socket:
-    s = socket.create_connection(("127.0.0.1", port), timeout=timeout)
+def _dial(port: int, service: str = "", timeout: float = 10.0,
+          rcvbuf: int | None = None) -> socket.socket:
+    """``rcvbuf`` is set BEFORE the connect, so the small window is the one
+    the handshake advertises.  Shrinking SO_RCVBUF on an established
+    loopback connection leaves the sender holding the 64 KB window it first
+    saw: its silly-window avoidance then waits for half of THAT to open,
+    which an 8 KB buffer never does, and bytes move only when the persist
+    timer fires (measured here: 40 KB/s, 20 s an 800 KB reply, then
+    stalls past 30 s as the timer backs off)."""
+    s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+    if rcvbuf is not None:
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+    s.settimeout(timeout)
+    s.connect(("127.0.0.1", port))
     s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
     if service:
         st, _ = _call(s, wire.HELLO_OP, a=wire.WIRE_VERSION,
@@ -278,11 +290,26 @@ def test_native_ps_passes_the_same_high_concurrency_gate():
 # ----------------------------------------------------------------------------
 
 
+class _Limit:
+    """A test's own time limit: ``left()`` is what remains of it, and fails
+    the test once nothing does — so a reply that never comes costs this
+    many seconds of a worker, not a socket timeout per read."""
+
+    def __init__(self, seconds: float):
+        self._end = time.monotonic() + seconds
+
+    def left(self) -> float:
+        left = self._end - time.monotonic()
+        assert left > 0, "over the test's own time limit"
+        return left
+
+
 def test_slow_reader_buffers_instead_of_wedging_a_worker():
     """Stalled peers holding unread responses > the worker count must not
     stop other clients from being served — the reply path buffers on the
     connection (flushed by the selector), never blocks a worker in
     sendall."""
+    limit = _Limit(30.0)
     payload = {"x": np.zeros(200_000, np.float32)}  # ~800 KB per answer
     core = server_core.ServerCore(name="slow", workers=2)
     core.add_service(server_core.Service(
@@ -295,8 +322,7 @@ def test_slow_reader_buffers_instead_of_wedging_a_worker():
         # responses outstanding: under thread-per-connection-with-sendall
         # (or worker-pool-with-sendall) this wedges the whole service.
         for _ in range(4):
-            s = _dial(core.port, "dsvc")
-            s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
+            s = _dial(core.port, "dsvc", rcvbuf=4096)
             for _ in range(8):
                 _send_req(s, 64)
             stalled.append(s)
@@ -311,13 +337,13 @@ def test_slow_reader_buffers_instead_of_wedging_a_worker():
         # The stalled peers' responses are all still delivered in full
         # once they start reading (nothing dropped, framing intact).
         for s in stalled:
-            got = 0
-            s.settimeout(30.0)
             for _ in range(8):
+                s.settimeout(min(5.0, limit.left()))
                 status, raw = _read_resp(s)
                 assert status == 0
-                got += 1
-            assert got == 8
+                assert np.array_equal(
+                    wire.decode_batch_bytes(raw)["x"], payload["x"]
+                )
     finally:
         for s in stalled:
             s.close()
@@ -325,27 +351,38 @@ def test_slow_reader_buffers_instead_of_wedging_a_worker():
 
 
 def test_slow_reader_past_the_buffer_bound_is_dropped_not_served():
+    limit = _Limit(20.0)
     core = server_core.ServerCore(
         name="cap", workers=1, max_buffered_bytes=64 * 1024,
         slow_reader_grace_s=0.3,
     )
-    big = {"x": np.zeros(100_000, np.float32)}
+    # 12 unread replies of ~1 MB: more than the kernel will take off the
+    # server's hands (the send buffer grows to tcp_wmem's ceiling, 4 MB
+    # here and by Linux's default, and took the old 8 x 400 KB whole), so
+    # the rest stands on the connection, past the bound.
+    big = {"x": np.zeros(250_000, np.float32)}
     core.add_service(server_core.Service(
         "dsvc", lambda conn, op, name, a, b, p: (0, wire.encode_batch(big))
     ))
     core.start()
     s = None
     try:
-        s = _dial(core.port, "dsvc")
-        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
-        for _ in range(8):
+        s = _dial(core.port, "dsvc", rcvbuf=4096)
+        for _ in range(12):
             _send_req(s, 64)
-        deadline = time.monotonic() + 10.0
-        while time.monotonic() < deadline:
-            if core.core_stats()["dropped_slow_readers"]:
-                break
-            time.sleep(0.05)
-        assert core.core_stats()["dropped_slow_readers"] >= 1
+        while not core.core_stats()["dropped_slow_readers"]:
+            time.sleep(min(0.05, limit.left()))
+        # Dropped means cut: the peer reads what the kernel already held
+        # and then the end of the stream, never the twelve replies.
+        got = 0
+        try:
+            for _ in range(12):
+                s.settimeout(min(5.0, limit.left()))
+                _read_resp(s)
+                got += 1
+        except ConnectionError:
+            pass
+        assert got < 12
     finally:
         if s is not None:
             s.close()

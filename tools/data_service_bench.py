@@ -15,8 +15,7 @@ local filestream at 1 MB+ batches — the gate enforces
 alone, plus the usual normalized-throughput floor vs the checked-in
 ``tools/data_service_baseline.json``.
 
-Runs on any CPU box — no accelerator, no jax — so it is a ``cpu_ok``
-campaign step (tools/measure_campaign.py) like the transport bench.
+Runs on any CPU box — no accelerator, no jax — like the transport bench.
 
 Usage:
   python tools/data_service_bench.py                 # 512-row (~1.5 MB raw) batches

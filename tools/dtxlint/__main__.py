@@ -94,8 +94,8 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true", dest="as_json")
     ap.add_argument(
         "--compact", action="store_true",
-        help="with --json: one line of JSON (campaign steps parse the "
-        "last stdout line)",
+        help="with --json: one line of JSON (tools/dtxlint_step.py's "
+        "shape: callers parse the last stdout line)",
     )
     ap.add_argument(
         "--pass", dest="only", default=None, choices=PASS_NAMES,

@@ -1,16 +1,12 @@
-"""Campaign entry point for dtxlint (r11; wall-time metric r16).
+"""dtxlint as one timed command (r11; wall-time metric r16), run by tier-1
+(``tests/test_dtxlint.py``) and by an operator by hand.
 
-The campaign plan invokes steps as ``python <script path>`` (the plan
-smoke test asserts every target exists on disk), but dtxlint is a package
-with relative imports, so ``python tools/dtxlint/__main__.py`` would not
-import.  This shim bridges the two: it runs the passes through the
-library, emits the ``--json --compact`` document EXTENDED with ``metric:
-"dtxlint"`` and the run's ``seconds`` as its single output line (what
-``measure_campaign.last_json_line`` records for ``campaign_report``), and
-exits with the CLI's code.  ``tools/perf_gate.py`` gates ``seconds``
-against the checked-in budget (``tools/dtxlint_time_baseline.json``), so
-a new pass that silently blows up lint wall-time — and with it tier-1's
-repo-gate — fails the campaign loudly instead.
+It runs the passes through the library, emits the ``--json --compact``
+document EXTENDED with ``metric: "dtxlint"`` and the run's ``seconds`` as
+its single output line, and exits with the CLI's code.
+``tools/perf_gate.py`` gates ``seconds`` against the checked-in budget
+(``tools/dtxlint_time_baseline.json``), so a new pass that silently blows
+up lint wall-time — and with it tier-1's repo-gate — fails loudly instead.
 """
 
 from __future__ import annotations

@@ -14,8 +14,8 @@ Checks, at flagship-regime shapes (bf16, d=128, causal, nq/nk >= 4):
   3. bitwise determinism: two identical fused grads agree exactly
 
 Prints ONE JSON line {"parity_ok": bool, ...} and exits 0 (pass) / 1 (fail).
-The measurement campaign runs this first and falls back to the split
-kernels (DTX_FUSED_BWD=0) for every later step if it fails.
+A run that sets DTX_FUSED_BWD=1 runs this first, and stays on the split
+kernels (DTX_FUSED_BWD=0) if it fails.
 
 Off-TPU it exits 2 without running a case — a CPU run says nothing about
 Mosaic — unless ``--interpret`` declares the run an interpret-mode check of
@@ -111,8 +111,7 @@ def main():
     ap.add_argument(
         "--segmented", action="store_true",
         help="add a T=32768 case exercising the r5 segmented fused path "
-        "(two 16384-row segments) — slow; the campaign runs it as its own "
-        "step before the T=32768 bench rows",
+        "(two 16384-row segments) — slow",
     )
     ap.add_argument(
         "--interpret", action="store_true",
@@ -154,7 +153,7 @@ def main():
         case(1, 2, 2048, 128, jnp.float32, False, check_ref=True),
     ]
     if not args.quick:
-        # flagship regime: the exact shape bench.py --seq-len 8192 dispatches
+        # flagship regime: the shape a T=8192 train step of the flagship dispatches
         cases.append(case(1, 8, 8192, 128, jnp.bfloat16, True, check_ref=False))
     if args.segmented:
         # past the VMEM cap: auto-dispatch routes through fused_bwd_segmented

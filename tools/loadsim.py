@@ -34,8 +34,8 @@ The run ends in a machine-readable SLO VERDICT (last stdout line, and
 - the joined worker's lease was observed by the mid-run dtxtop scrape.
 
 Exit code 0 iff every gate holds — the standing acceptance rig ROADMAP
-items 1–4 gate on, runnable on any CPU dev box (``cpu_ok`` in
-``measure_campaign``; baseline gated by ``tools/perf_gate.py``).
+items 1–4 gate on, runnable on any CPU dev box (baseline gated by
+``tools/perf_gate.py``).
 
 Usage::
 
@@ -2032,7 +2032,7 @@ def main(argv=None) -> int:
         gates["leave_fired"] = verdict["leave_fired"]
     verdict["gates"] = gates
     verdict["slo_pass"] = all(gates.values())
-    # The perf-gate metric field: campaign baselines key off it.
+    # The perf-gate metric field: the checked-in baselines key off it.
     verdict["loadsim_p99_ms"] = load["p99_ms"]
     if args.out:
         with open(args.out, "w") as f:

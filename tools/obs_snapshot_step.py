@@ -1,4 +1,5 @@
-"""Campaign step: observability-plane acceptance on a live mini cluster.
+"""Observability-plane acceptance on a live mini cluster, run by an operator
+by hand; tier-1 (``tests/test_observability.py``) holds its counter check.
 
 Boots a small train-and-serve cluster IN THIS PROCESS (2 PS shard
 servers, a data server over in-RAM splits, one serve replica on the tiny
@@ -6,11 +7,9 @@ MLP), drives real load over every wire (publishes, predicts, batch
 pulls), then takes a ``tools/dtxtop.py`` snapshot and FAILS on any
 missing role or any role whose STATS table lacks its required counters —
 the "one scraper sees the whole cluster" contract the loadsim SLO gate
-(ROADMAP item 5) will stand on.  Accelerator-free (JAX on CPU), so it
-runs as a ``cpu_ok`` pre-wait step like the other host-side benches.
+(ROADMAP item 5) will stand on.  Accelerator-free (JAX on CPU).
 
-The last stdout line is compact JSON for ``measure_campaign`` /
-``campaign_report``.
+The last stdout line is compact JSON.
 """
 
 from __future__ import annotations

@@ -1,4 +1,5 @@
-"""Transport perf regression gate (r7 satellite; data-service rows r8).
+"""Transport perf regression gate (r7 satellite; data-service rows r8), run
+by tier-1 tests on the rigs' quick rows and by an operator by hand.
 
 Compares a ``tools/ps_transport_bench.py`` or ``tools/data_service_bench.py``
 result against its checked-in host baseline (``tools/ps_transport_baseline
@@ -113,7 +114,7 @@ BASELINES = {
     "loadsim_multitenant_slo": "loadsim_multitenant_baseline.json",
     # r16 static-analysis wall-time budget (tools/dtxlint_step.py): the
     # lint's repo gate runs inside tier-1, so a pass whose cost silently
-    # explodes taxes every future test run — the campaign fails first.
+    # explodes taxes every future test run — this gate fails first.
     "dtxlint": "dtxlint_time_baseline.json",
 }
 
@@ -134,8 +135,8 @@ def gate(
     # The r16 dtxlint wall-time budget: a hard per-run bound from the
     # checked-in baseline (generous cross-host headroom lives IN the
     # budget — no tolerance multiplier on top), plus the verdict itself —
-    # a lint that stopped exiting clean is a campaign failure regardless
-    # of how fast it failed.
+    # a lint that stopped exiting clean is a failure regardless of how
+    # fast it failed.
     if "budget_s" in base:
         secs = res.get("seconds")
         if secs is None:

@@ -5,8 +5,7 @@ measures the socket hot path the cross-process PS emulation lives on:
 set/get/push round-trip latency and MB/s at small and large payloads, f32
 vs bf16 wire encoding, and cold full pulls vs unchanged-step
 ``get_if_newer`` pulls.  Runs on any CPU box — no accelerator, no jax; a
-host metric, never a device one (measure_campaign runs it as a cpu_ok
-step).
+host metric, never a device one.
 
 Throughputs are also reported normalized by the host's memcpy bandwidth
 (``*_frac_memcpy``): a copy-per-send regression costs a fixed multiple of

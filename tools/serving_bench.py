@@ -31,8 +31,7 @@ best-of-3 trials; MB/s counts request+response payload bytes so the
 ``*_frac_memcpy`` normalization is comparable across hosts (same
 convention as the transport/data benches).
 
-Runs on any CPU box — JAX on CPU, no accelerator — so it is a ``cpu_ok``
-campaign step (tools/measure_campaign.py).
+Runs on any CPU box — JAX on CPU, no accelerator.
 
 Usage:
   python tools/serving_bench.py                  # full rows

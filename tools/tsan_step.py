@@ -1,4 +1,5 @@
-"""Native ThreadSanitizer gate (r16) — a ``cpu_ok`` measure_campaign step.
+"""Native ThreadSanitizer gate (r16), run by an operator by hand on a host
+with a TSAN toolchain; needs no accelerator.
 
 Builds ``native/libdtx_native_tsan.so`` (the ``tsan`` Makefile target:
 ``-fsanitize=thread -O1 -g``), then runs ``tools/tsan_driver.py`` — the
@@ -11,14 +12,13 @@ Suppressions live in ``tools/tsan_suppressions.txt`` (standard TSAN
 syntax, one justified entry per line) — same contract as the dtxlint
 baseline: a suppression is a documented design decision with a reason in
 the comment above it, and this step counts them in its verdict so a
-growing pile is visible in every campaign report.
+growing pile is visible in every run.
 
 Hosts without a TSAN toolchain (no ``libtsan`` next to g++) record a LOUD
 ``skipped`` verdict and exit 0 — an environmental gap is not a race, and
-must not fail a campaign the way a genuine finding does.
+must not fail the way a genuine finding does.
 
-Output: one compact JSON line (``metric: tsan_protocol``) for
-``measure_campaign.last_json_line`` / ``campaign_report.fmt_tsan``.
+Output: one compact JSON line (``metric: tsan_protocol``).
 """
 
 from __future__ import annotations
@@ -92,13 +92,13 @@ def main() -> int:
     except subprocess.TimeoutExpired:
         # The one-compact-JSON-line contract holds on EVERY exit path —
         # a hung build must still produce a diagnosable verdict, not a
-        # traceback the campaign records as NO JSON.
+        # traceback with NO JSON.
         return emit({"ok": False, "error": "tsan build timed out"}, 1)
     if build.returncode != 0:
         # The toolchain is PRESENT (libtsan found above), so a failing
         # build is a code/Makefile regression, not an environmental gap —
         # it must fail the step, or one bad commit disables the race gate
-        # forever with a green campaign.
+        # forever behind a green verdict.
         return emit({
             "ok": False,
             "error": f"tsan build failed (rc {build.returncode}): "
