@@ -406,11 +406,18 @@ class StreamTicket:
 class SlotBatcher:
     """The sequence-slot step loop.  ``run_step(slots)`` runs on the one
     step thread with ``slots`` a fixed-length list — ``StreamTicket`` for
-    an occupied slot, None for a free one — and returns a same-length
-    list whose occupied entries are ``(emits, done)``; a free slot's
-    entry is ignored.  The step function owns all cross-step state (KV
-    caches, positions) keyed by SLOT INDEX; the batcher owns occupancy,
-    admission and streaming.
+    an occupied slot, None for a free one — and returns the results of ONE
+    step as ``(ticket, emits, done)`` triples, a triple for each session
+    that step stepped, or None when it has no step's results to hand over.
+    The step whose results a call returns need not be the one it launched
+    for ``slots``: a step function may launch that one and hand over the
+    results of the step it launched a call earlier (``serve.model_server.
+    _DecodeEngine`` does), which is why a result names its ticket and not
+    its slot; while any slot is occupied the loop keeps calling, so a step
+    function that holds results back is asked for them before the loop
+    parks.  The step function owns all cross-step state (KV caches,
+    positions) keyed by SLOT INDEX; the batcher owns occupancy, admission
+    and streaming.
 
     ``slots``         fixed batch width of one step (the jit shape).
     ``max_sessions``  admission bound on in-system sessions (active +
@@ -421,7 +428,8 @@ class SlotBatcher:
 
     An exception out of ``run_step`` fails every ACTIVE session (each
     waiter sees it) and frees their slots — queued sessions then take
-    slots and run; the batcher itself never dies.
+    slots and run; the batcher itself never dies.  Results the step
+    function still held back are lost with it.
 
     The step thread's own time is kept as LEAF spans (``telemetry.span``):
     ``<name>/fill`` seating and dropping under the lock, ``<name>/park``
@@ -449,7 +457,7 @@ class SlotBatcher:
         # Counters (stats(); mutate under _lock or on the step thread).
         self.sessions = 0
         self.overloads = 0
-        self.steps = 0
+        self.steps = 0  # steps whose results were handed over and emitted
         self.emitted = 0
         self.step_errors = 0
         # Slot-steps that emitted nothing (an input was fed); sessions
@@ -558,13 +566,12 @@ class SlotBatcher:
                     if t is not None:
                         t._finish(error=e)
                 continue
+            if results is None:
+                continue
             self.steps += 1
             with self._span_emit:
-                for i, t in enumerate(slots):
-                    if t is None:
-                        continue
+                for t, emits, done in results:
                     self._fresh.discard(t)
-                    emits, done = results[i]
                     if emits:
                         if not t._emits:  # only this thread appends
                             self.first_tokens += 1

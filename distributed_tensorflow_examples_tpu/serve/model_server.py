@@ -48,6 +48,7 @@ import logging
 import os
 import threading
 import time
+import typing
 
 import numpy as np
 
@@ -99,13 +100,15 @@ NO_DECODER = wire.SRV_STATUS["NO_DECODER"]
 # ``decode/fill`` and precede its ``decode/emit``; none wraps another.
 #: One prefill chunk, launch to completion; entered only when one runs.
 _SPAN_PREFILL = telemetry.span("decode/prefill")
-#: Seeding fresh slots, uploading the tokens and positions.
+#: Each row's token or its source, position and liveness, uploaded.
 _SPAN_PREPARE = telemetry.span("decode/prepare")
 #: Launching the jitted step (the runtime's ``PjitFunction(step_fn)``).
 _SPAN_DISPATCH = telemetry.span("decode/dispatch")
-#: Waiting for the device, then the logits to the host.
+#: Reading the selection of the step launched a call earlier: a wait on a
+#: device that is mostly busy (with that step, then with the one just
+#: launched), then ``[S]`` int32 to the host.
 _SPAN_FETCH = telemetry.span("decode/fetch")
-#: Next token and position per slot (teacher-force or ``argmax``).
+#: Each stepped session's token, count and ``done`` from what was read.
 _SPAN_SELECT = telemetry.span("decode/select")
 
 
@@ -136,6 +139,16 @@ def flat_param_spec(init_fn):
     return ps_shard.flat_param_spec(template)
 
 
+class _Flight(typing.NamedTuple):
+    """One launched decode step whose selection the host has not read."""
+
+    selection: object  # the step's ``[S]`` int32 on the device
+    rows: list  # (ticket, row it emits from | None: emits nothing, done)
+    held: int  # rows of sessions whose chunks were due
+    rows_read: int  # cache positions of each slot the step's attention read
+    params: object  # the served model's parameters it was launched with
+
+
 class _DecodeEngine:
     """Stepped decode over a per-slot cache behind the sequence-slot
     batcher (r19).
@@ -143,16 +156,41 @@ class _DecodeEngine:
     Model-agnostic: the model supplies ``init_cache_fn(slots, max_len)``
     (a per-slot cache pytree) and ``step_fn(params, cache, tokens[S],
     pos[S]) -> (logits [S, V], cache)`` — one jitted apply advances EVERY
-    active session one position.  The engine owns the host-side slot
-    state (current token and position per slot), greedy next-token
-    selection and how a prompt reaches the cache, so batched decode is
-    byte-identical to a session running alone: the slot array shape is
-    FIXED, every row's math depends only on its own slot, and a session
-    reads only what it wrote itself.
+    active session one position.  The engine owns the host-side session
+    state (each session's position and counts), how a prompt reaches the
+    cache and what is in flight, so batched decode is byte-identical to a
+    session running alone: the slot array shape is FIXED, every row's math
+    depends only on its own slot, and a session reads only what it wrote
+    itself.
+
+    Who picks the token.  The compiled step does: the engine jits ONE
+    function, still named ``step_fn``, that hands the model's ``step_fn``
+    ``where(from_host, tokens, prev)`` and returns ``(argmax(logits, -1)
+    as int32 [S], cache)`` - greedy selection, the lowest index among
+    equals as NumPy's has it, over the model's own float32 logits, which
+    never leave the device.  ``prev`` is the step before's selection,
+    still on the device (not donated: the host reads it after the next
+    launch); ``from_host`` marks the rows whose token the host knows and
+    the device does not - a prompt's token (a freshly seated row, a
+    teacher-forced row, a held row) or token 0 (an empty row).  Everything
+    else a step needs - positions, which rows are live, which sessions end -
+    follows from COUNTS the host has without reading a token's value, so
+    the engine launches step N+1 and only then reads step N's selection:
+    the device runs N+1 while the host emits N and prepares N+2.  At most
+    one step is in flight beyond the one being read (``ahead_steps``
+    counts the launches made ahead).  Nothing is launched ahead of a
+    prefill chunk or across a change of the served model: a call that
+    finds one due while a step is in flight only collects, and the next
+    call runs the chunk with nothing queued ahead of it; a call with no row
+    left to step only collects too, so a session's last token is out
+    before the loop parks.  The launch that is already out when a session's
+    last token is read steps that session's row once past its end: an
+    inert row (``idle_rows``), token 0 at position 0 and not live.
 
     What a row may do to its slot.  A row is LIVE when its session decodes
-    in this step; an empty slot's row and the row of a seated session
-    whose prompt chunks are still due are not.  Two kinds of model:
+    in this step; an empty slot's row, the row of a seated session whose
+    prompt chunks are still due and the row of a session stepped past its
+    end are not.  Two kinds of model:
 
     - A model whose cache holds keys and values (the four-argument
       ``step_fn`` above) is not told: its rows that are not live compute
@@ -179,8 +217,8 @@ class _DecodeEngine:
     A model that also supplies ``prefill_fn(params, cache, tokens[C],
     slot, offset, n_valid) -> cache`` (it enters ONE slot's positions
     ``[offset, offset + n_valid)`` into that slot's cache and touches no
-    other slot) has its prompts PREFILLED: before the decode step an
-    iteration runs at most one chunk of ``PREFILL_CHUNK`` tokens, for the
+    other slot) has its prompts PREFILLED: before the decode step a call
+    runs at most one chunk of ``PREFILL_CHUNK`` tokens, for the
     longest-seated session whose prompt is not yet cached, so every other
     session waits at most one step plus one chunk for its next token,
     whatever the prompt lengths or the burst.  A session being prefilled
@@ -191,12 +229,13 @@ class _DecodeEngine:
 
     What the engine hands over.  The cache is DONATED to the step and to
     the chunk alike (the engine holds its only reference), so a program
-    that writes a row in place costs that row and not a second cache; a
-    program that raises may have cost the engine its cache, so it gets a
-    fresh one - the failure frees every slot, and a freed slot needs no
-    cache state.  An empty slot's row is stepped with token 0 at position
-    0, not where its last session stood: a step that reads no further than
-    its deepest row is held to the sessions that are seated.
+    that writes a row in place costs that row and not a second cache.  A
+    launch, a read or a chunk that raises fails every active session and
+    may have cost the engine its cache, so it is left a fresh cache, a
+    fresh ``prev`` and NOTHING in flight - a freed slot needs no cache
+    state.  An empty slot's row is stepped with token 0 at position 0, not
+    where its last session stood: a step that reads no further than its
+    deepest row is held to the sessions that are seated.
     """
 
     def __init__(
@@ -208,7 +247,7 @@ class _DecodeEngine:
         self._get_model = model_getter  # () -> (step, params) | None
         self._init_cache = init_cache_fn
         self._cache = init_cache_fn(slots, max_len)
-        self._step_jit = jax.jit(step_fn, donate_argnums=1)
+        self._step_jit = jax.jit(_selecting(step_fn), donate_argnums=1)
         # A fifth parameter is the model asking for the live rows.
         self._wants_live = len(inspect.signature(step_fn).parameters) == 5
         # How far into the cache a step reads: all of it, unless told.
@@ -232,11 +271,22 @@ class _DecodeEngine:
         self.held_rows = 0
         # Cache positions of each slot the steps' attention read, summed.
         self.cache_rows_read = 0
-        self._tokens = np.zeros((self.slots,), np.int32)
-        self._pos = np.zeros((self.slots,), np.int32)
+        # Steps launched while the step before them had not been read, and
+        # slot-steps of sessions stepped past their last token.
+        self.ahead_steps = 0
+        self.idle_rows = 0
+        # The last launch's selection, on the device, and that launch while
+        # the host has not read it.
+        self._selection = self._no_selection()
+        self._flight: _Flight | None = None
         self.batcher = batcher_lib.SlotBatcher(
             self._run_step, slots=self.slots, max_sessions=max_sessions,
         )
+
+    def _no_selection(self):
+        import jax.numpy as jnp
+
+        return jnp.zeros((self.slots,), jnp.int32)
 
     def open(self, prompt: np.ndarray, max_new_tokens: int):
         """Admit one greedy decode session; returns its StreamTicket.
@@ -253,24 +303,34 @@ class _DecodeEngine:
                 f"{prompt.size} prompt + {n} new tokens exceeds the "
                 f"replica's decode_max_len={self.max_len}"
             )
+        # Prompt tokens the prefill owes the cache before the slot decodes
+        # (all but the last).
+        prefill = prompt.size - 1 if self._prefill_jit else 0
         return self.batcher.open({
-            "prompt": prompt, "n": n, "emitted": 0, "seated": False,
-            # Prompt tokens the prefill owes the cache before the slot
-            # decodes (all but the last), and how many it has cached.
-            "prefill": prompt.size - 1 if self._prefill_jit else 0,
-            "cached": 0,
+            "prompt": prompt, "prefill": prefill,
+            "cached": 0,  # how many of them are in the cache
+            # Where the session's next step stands: it starts where its
+            # cached prompt ends - at position 0 feeding its first prompt
+            # token, or (prefilled) at its last prompt token - and its last
+            # step, which emits its n-th token, stands one short of ``end``.
+            # The cache needs no reset (see the class docstring).
+            "pos": prefill, "end": prompt.size - 1 + n,
         })
 
     @contextlib.contextmanager
     def _cache_donated(self):
-        """Around a program the cache is donated to: a failure may have
-        cost the engine its cache, so it gets a fresh one (the step's
-        failure frees every slot, and a freed slot needs no cache state)."""
+        """Around a program the cache is donated to, and around the read of
+        what one selected: a failure may have cost the engine its cache and
+        the selection the next launch would be fed, so it gets fresh ones
+        and nothing stays in flight (the failure frees every slot, and a
+        freed slot needs no cache state)."""
         try:
             yield
         except BaseException:
+            self._flight = None
             self._cache = None  # never two caches on the device
             self._cache = self._init_cache(self.slots, self.max_len)
+            self._selection = self._no_selection()
             raise
 
     def _prefill(self, params, slot: int, tokens, offset: int, n_valid: int):
@@ -287,23 +347,26 @@ class _DecodeEngine:
             )
             jax.block_until_ready(self._cache)
 
-    def _prefill_one(self, params, slots) -> None:
-        """At most one chunk: the next of the longest-seated session whose
-        prompt is not yet in the cache."""
+    def _chunk_due(self, slots) -> int | None:
+        """The slot whose chunk runs next: the longest-seated session's
+        whose prompt is not yet in the cache."""
         waiting = [
             (t.seated_ns, i) for i, t in enumerate(slots)
             if t is not None and t.state["cached"] < t.state["prefill"]
         ]
+        return min(waiting)[1] if waiting else None
+
+    def _prefill_one(self, params, slots, i: int | None) -> None:
+        """At most one chunk: the next of slot ``i``'s prompt."""
         if not self._prefill_warm:
             # Both programs exist before the first session is answered,
             # whatever its prompt's length: a chunk of no valid token
             # compiles the chunk program and writes nothing.
             self._prefill(params, 0, (), 0, 0)
             self._prefill_warm = True
-        if not waiting:
+        if i is None:
             return
         with _SPAN_PREFILL:
-            _seated_ns, i = min(waiting)
             st = slots[i].state
             done = st["cached"]
             n = min(self._chunk, st["prefill"] - done)
@@ -313,64 +376,92 @@ class _DecodeEngine:
             self.prefill_tokens += n
 
     def _run_step(self, slots):
-        import jax.numpy as jnp
-
+        """One call of the batcher's loop: launch the step for ``slots``,
+        THEN read the step launched by the call before and hand over its
+        results - ``(ticket, emits, done)`` for each session that step
+        stepped - or None when there is no such step yet."""
         model = self._get_model()
+        flight = self._flight
+        chunk = self._chunk_due(slots) if self._prefill_jit else None
+        stepping = any(
+            t is not None and t.state["pos"] < t.state["end"] for t in slots
+        )
+        if flight is not None and (
+            chunk is not None or not stepping
+            or model is None or model[1] is not flight.params
+        ):
+            self._flight = None
+            return self._collect(flight)
         if model is None:
             raise _NoModel()
         _step, params = model
         if self._prefill_jit is not None:
-            self._prefill_one(params, slots)
+            self._prefill_one(params, slots, chunk)
+        self._flight = self._launch(params, slots)
+        if flight is None:
+            return None
+        self.ahead_steps += 1
+        return self._collect(flight)
+
+    def _launch(self, params, slots) -> _Flight:
+        import jax.numpy as jnp
+
         with _SPAN_PREPARE:
-            for i, t in enumerate(slots):
-                if t is None:
-                    self._tokens[i] = self._pos[i] = 0
-                elif not t.state["seated"]:
-                    # A freshly seated session starts its slot where its
-                    # cached prompt ends: at position 0 feeding its first
-                    # prompt token, or (prefilled) at its last prompt
-                    # token.  While chunks are still due the row is not
-                    # live.  The cache needs no reset (see the class
-                    # docstring).
-                    t.state["seated"] = True
-                    p0 = t.state["prefill"]
-                    self._tokens[i] = t.state["prompt"][p0]
-                    self._pos[i] = p0
-            args = [jnp.asarray(self._tokens), jnp.asarray(self._pos)]
-            if self._wants_live:
-                args.append(jnp.asarray(np.fromiter(
-                    (t is not None and t.state["cached"] >= t.state["prefill"]
-                     for t in slots), bool, len(slots),
-                )))
-            rows_read = self._rows_read(int(self._pos.max()), self.max_len)
-        with self._cache_donated():
-            with _SPAN_DISPATCH:
-                logits, self._cache = self._step_jit(params, self._cache, *args)
-            with _SPAN_FETCH:
-                out = np.asarray(logits)
-        with _SPAN_SELECT:
-            results: list = [None] * len(slots)
+            # Fresh arrays every launch: the host goes on to the next
+            # launch while this one's uploads may still be read.
+            tokens = np.zeros((self.slots,), np.int32)
+            pos = np.zeros((self.slots,), np.int32)
+            from_host = np.ones((self.slots,), bool)
+            live = np.zeros((self.slots,), bool)
+            rows, held = [], 0
             for i, t in enumerate(slots):
                 if t is None:
                     continue
                 st = t.state
-                if st["cached"] < st["prefill"]:
-                    results[i] = ([], False)  # held: its chunks are due
-                    self.held_rows += 1
+                p, prompt = st["pos"], st["prompt"]
+                if p >= st["end"]:
+                    # Its last token is asked for and not yet read, so the
+                    # batcher still seats it: the row stays inert.
+                    self.idle_rows += 1
                     continue
-                p = int(self._pos[i])
-                if p + 1 < len(st["prompt"]):
-                    nxt = int(st["prompt"][p + 1])  # teacher-force the prompt
-                    emits: list[int] = []
+                pos[i] = p
+                if p < len(prompt):
+                    tokens[i] = prompt[p]  # seated, teacher-forced or held
                 else:
-                    nxt = int(np.argmax(out[i]))  # greedy continuation
-                    emits = [nxt]
-                    st["emitted"] += 1
-                self._tokens[i] = nxt
-                self._pos[i] = p + 1
-                results[i] = (emits, st["emitted"] >= st["n"])
+                    from_host[i] = False  # the step before selected it
+                if st["cached"] < st["prefill"]:
+                    rows.append((t, None, False))  # held: its chunks are due
+                    held += 1
+                    continue
+                live[i] = True
+                st["pos"] = p + 1
+                # The step that is fed the prompt's last token, and every
+                # one after it, emits what it selects; the one that stands
+                # one short of ``end`` is the session's last.
+                rows.append(
+                    (t, i if p + 1 >= len(prompt) else None, p + 1 == st["end"])
+                )
+            args = [jnp.asarray(tokens), jnp.asarray(from_host), jnp.asarray(pos)]
+            if self._wants_live:
+                args.append(jnp.asarray(live))
+            rows_read = self._rows_read(int(pos.max()), self.max_len)
+        with self._cache_donated(), _SPAN_DISPATCH:
+            self._selection, self._cache = self._step_jit(
+                params, self._cache, self._selection, *args
+            )
+        return _Flight(self._selection, rows, held, rows_read, params)
+
+    def _collect(self, flight: _Flight) -> list:
+        with self._cache_donated(), _SPAN_FETCH:
+            selected = np.asarray(flight.selection)
+        with _SPAN_SELECT:
+            results = [
+                (t, [] if i is None else [int(selected[i])], done)
+                for t, i, done in flight.rows
+            ]
         # Counted where the batcher counts the step: when it has run.
-        self.cache_rows_read += rows_read
+        self.held_rows += flight.held
+        self.cache_rows_read += flight.rows_read
         return results
 
     def stats(self) -> dict:
@@ -380,11 +471,48 @@ class _DecodeEngine:
         s["prefill_tokens"] = self.prefill_tokens
         s["held_rows"] = self.held_rows
         s["cache_rows_read"] = self.cache_rows_read
+        s["ahead_steps"] = self.ahead_steps
+        s["idle_rows"] = self.idle_rows
         s["state_bytes"] = self.state_bytes
         return s
 
     def stop(self) -> None:
+        import jax
+
         self.batcher.stop()
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            # The step thread is gone and its sessions failed: leave no
+            # work on the device behind.
+            try:
+                jax.block_until_ready(flight.selection)
+            except Exception:  # noqa: BLE001 — a stop goes on to the end
+                log.warning("the decode step in flight at stop failed",
+                            exc_info=True)
+
+
+def _selecting(model_step):
+    """The decode program the engine compiles: ``model_step`` fed each
+    row's token from the host or from the step before, returning what it
+    selects and not its logits.  Named ``step_fn``: the compiled program's
+    name, ``jit_step_fn``, is how a device trace's readers find it."""
+    import jax
+    import jax.numpy as jnp
+
+    def step_fn(params, cache, prev, tokens, from_host, pos, *live):
+        logits, cache = model_step(
+            params, cache, jnp.where(from_host, tokens, prev), pos, *live
+        )
+        # The selection is over the logits AS THE MODEL RETURNS THEM, in
+        # their type: fused into the product that makes them, the compiler
+        # compares that product's float32 sums and not their bfloat16
+        # roundings, and picks another token wherever two of those tie
+        # (most sessions of Cerebras-GPT-1.3B within some tens of tokens;
+        # my chip runs, PR 30).  The barrier has them written out first.
+        logits = jax.lax.optimization_barrier(logits)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+
+    return step_fn
 
 
 class ModelReplicaServer:

@@ -31,3 +31,33 @@ test_every_cell_rehearses_correct_through_its_family = (
     _cases.test_every_cell_rehearses_correct_through_its_family)
 test_a_new_family_is_files_and_entries_only = (
     _cases.test_a_new_family_is_files_and_entries_only)
+
+
+def test_an_altered_selection_is_caught_by_the_serve_cells_comparison(monkeypatch):
+    """The control of the served tokens, where the token is picked since
+    PR 30: the engine's compiled step made to select the index after the
+    best one has to come out of a serve cell's comparison as not correct.
+    (``benchmarks/tests/test_control.py::test_an_altered_token_is_caught``
+    alters the host's ``np.argmax``, which the engine no longer calls.)"""
+    import time
+
+    from benchmarks import rehearse
+    from benchmarks.harness import manifest, serve_cell
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    selecting = model_server._selecting
+
+    def altered(model_step):
+        step = selecting(model_step)
+
+        def step_fn(*args):
+            selection, cache = step(*args)
+            return (selection + 1) % 250, cache
+
+        return step_fn
+
+    monkeypatch.setattr(model_server, "_selecting", altered)
+    cell = rehearse.shrink(manifest.Cell("cgpt13b-serve-batch"))
+    out = serve_cell.run(cell, 4, 3.0, False, time.monotonic())
+    assert out["check"]["positions"] > 0 and out["failed"] == 0
+    assert out["correct"] is False

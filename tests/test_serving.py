@@ -20,6 +20,7 @@ tests pin the pieces the fault matrix (tests/test_faults.py) then composes:
 
 from __future__ import annotations
 
+import inspect
 import threading
 import time
 
@@ -831,13 +832,13 @@ def test_slot_batcher_advances_sessions_and_frees_slots():
     session's emission stream is cursor-replayable."""
 
     def run_step(slots):
-        out = [None] * len(slots)
-        for i, t in enumerate(slots):
+        out = []
+        for t in slots:
             if t is None:
                 continue
             st = t.state
             st["count"] = st.get("count", 0) + 1
-            out[i] = ([st["count"]], st["count"] >= st["n"])
+            out.append((t, [st["count"]], st["count"] >= st["n"]))
         return out
 
     b = batcher_lib.SlotBatcher(run_step, slots=2, max_sessions=3)
@@ -870,9 +871,7 @@ def test_slot_batcher_step_error_fails_active_sessions_only():
     def run_step(slots):
         if fail.is_set():
             raise ValueError("bad step")
-        return [
-            (["x"], True) if t is not None else None for t in slots
-        ]
+        return [(t, ["x"], True) for t in slots if t is not None]
 
     b = batcher_lib.SlotBatcher(run_step, slots=1)
     try:
@@ -915,17 +914,17 @@ def _scripted_step(step_s: float):
 
     def run_step(slots):
         time.sleep(step_s)
-        out = [None] * len(slots)
-        for i, t in enumerate(slots):
+        out = []
+        for t in slots:
             if t is None:
                 continue
             st = t.state
             st["seen"] = st.get("seen", 0) + 1
             if st["seen"] <= st["feed"]:
-                out[i] = ([], False)
+                out.append((t, [], False))
             else:
                 k = st["seen"] - st["feed"]
-                out[i] = ([k], k >= st["n"])
+                out.append((t, [k], k >= st["n"]))
         return out
 
     return run_step
@@ -1184,6 +1183,14 @@ _STEP_SPANS = (
 )
 
 
+def _step_args(slots: int, live: bool = False) -> tuple:
+    """What the engine's compiled step takes after ``(params, cache)``:
+    ``prev``, ``tokens``, ``from_host``, ``pos`` and, for a model that
+    asks, ``live``."""
+    z = np.zeros(slots, np.int32)
+    return (z, z, np.ones(slots, bool), z) + ((np.ones(slots, bool),) * live)
+
+
 @pytest.mark.parametrize("prefill", [False, True])
 def test_decode_spans_reach_the_profiler_trace_as_leaves(tmp_path, prefill):
     """A ``jax.profiler`` trace of a live replica holds all seven span
@@ -1288,9 +1295,7 @@ def test_served_decode_program_is_named_step_fn():
         params = jax.eval_shape(
             lambda k: models.transformer.init(cfg, k), jax.random.key(0)
         )
-        lowered = engine._step_jit.lower(
-            params, engine._cache, np.zeros(2, np.int32), np.zeros(2, np.int32)
-        )
+        lowered = engine._step_jit.lower(params, engine._cache, *_step_args(2))
         assert "module @jit_step_fn" in lowered.as_text()
         # The chunk program must NOT be found under that name: the step's
         # metrics would count its launches as steps.
@@ -1517,9 +1522,7 @@ def test_the_step_is_donated_the_cache_it_returns():
     it not)."""
     eng, params = _tiny_transformer_engine()
     try:
-        lowered = eng._step_jit.lower(
-            params, eng._cache, np.zeros(2, np.int32), np.zeros(2, np.int32)
-        )
+        lowered = eng._step_jit.lower(params, eng._cache, *_step_args(2))
         assert lowered.as_text().count("tf.aliasing_output") == 2 * 2
         assert lowered.compile().memory_analysis().alias_size_in_bytes == eng.state_bytes
         before = eng._cache["block_0"]["k"]
@@ -1547,9 +1550,11 @@ def test_a_freed_slot_is_stepped_at_position_zero():
     log: list = []
     step_jit = eng._step_jit
 
-    def logged_step(params, cache, tokens, pos):
-        log.append((np.asarray(tokens).copy(), np.asarray(pos).copy()))
-        return step_jit(params, cache, tokens, pos)
+    def logged_step(params, cache, prev, tokens, from_host, pos):
+        # What the row is fed: the host's token, or (not read here) the
+        # selection of the step before.
+        log.append((np.where(from_host, tokens, -1), np.asarray(pos).copy()))
+        return step_jit(params, cache, prev, tokens, from_host, pos)
 
     eng._step_jit = logged_step
     try:
@@ -1746,3 +1751,324 @@ def test_jamba_sessions_through_the_engine_get_the_tokens_they_get_alone(
         finally:
             eng.stop()
         assert got == alone, len(p)
+
+
+# ----------------------------------------------------------------------------
+# The compiled step selects, and the engine launches one step ahead (PR 30)
+# ----------------------------------------------------------------------------
+
+
+def _tied(step_fn):
+    """``step_fn`` with every row's best logit met again three places on
+    (mod the vocabulary): what is selected is then the LOWER of two equal
+    indices, on the host and on the device alike."""
+    import jax.numpy as jnp
+
+    def step(*args):
+        logits, cache = step_fn(*args)
+        return jnp.maximum(logits, jnp.roll(logits, 3, axis=-1)), cache
+
+    # The engine reads the model's wishes off its step: keep them.
+    step.__signature__ = inspect.signature(step_fn)
+    return step
+
+
+def _plain_stream(fns, prompt, n: int, max_len: int, chunk: int):
+    """One session alone, a step at a time, the token picked by
+    ``np.argmax`` on the host from the logits ``step_fn`` returns: what the
+    engine did before it selected on the device.  Returns the tokens and
+    what the session left in its (only) slot."""
+    import jax.numpy as jnp
+
+    init_cache_fn, step_fn, *prefill_fn = fns
+    live = (np.ones(1, bool),) * (
+        len(inspect.signature(step_fn).parameters) == 5)
+    prompt = np.asarray(prompt, np.int32)
+    cache, p = init_cache_fn(1, max_len), 0
+    if prefill_fn:
+        p = len(prompt) - 1
+        for off in range(0, p, chunk):
+            buf = np.zeros(chunk, np.int32)
+            k = min(chunk, p - off)
+            buf[:k] = prompt[off:off + k]
+            cache = prefill_fn[0](
+                None, cache, jnp.asarray(buf), np.int32(0), np.int32(off),
+                np.int32(k))
+    tok, out = int(prompt[p]), []
+    while len(out) < n:
+        logits, cache = step_fn(
+            None, cache, np.array([tok], np.int32), np.array([p], np.int32),
+            *live)
+        p += 1
+        if p < len(prompt):
+            tok = int(prompt[p])
+        else:
+            tok = int(np.argmax(np.asarray(logits)[0]))
+            out.append(tok)
+    return out, cache
+
+
+#: Unequal sessions on two slots: a long one that decodes throughout, a
+#: short one that ends under it, one with a prompt of three chunks seated
+#: into the slot the short one left, and a one-token prompt asking for one
+#: token, last.
+_AHEAD_PROMPTS = (
+    [7, 3, 9], [4, 5, 6, 7, 1], (np.arange(10) * 2 + 3) % 11, [8],
+)
+_AHEAD_BUDGETS = (14, 2, 4, 1)
+
+
+def _ahead_engine(monkeypatch, fns, gate=None):
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 4)
+
+    def model():
+        if gate is not None:
+            gate.wait(10)
+        return 0, None
+
+    return model_server._DecodeEngine(
+        model, *fns, slots=2, max_len=32, max_sessions=8)
+
+
+def _watch_calls(eng) -> list:
+    """Record, for every call of the batcher's loop into the engine, the
+    tickets seated and the engine's counters before and after it."""
+    calls: list = []
+    run = eng.batcher._run
+
+    def watched(slots):
+        before = (eng.prefill_chunks, eng.ahead_steps)
+        try:
+            return run(slots)
+        finally:
+            calls.append(
+                (list(slots), before, (eng.prefill_chunks, eng.ahead_steps)))
+
+    eng.batcher._run = watched
+    return calls
+
+
+@pytest.mark.parametrize("family", ["prefilled", "teacher_forced", "state"])
+def test_the_engine_serves_what_a_plain_loop_selects_on_the_host(
+    monkeypatch, family,
+):
+    """Sessions of unequal lengths, seated while others are mid-flight,
+    through an engine whose compiled step selects and which launches a step
+    before it has read the last one: every session's tokens, their count
+    and ``done`` equal, token for token, a plain loop of the same
+    ``step_fn`` one step at a time with ``np.argmax`` on the host - ties
+    included; a state model's slot holds, after its row was stepped past
+    its session's end, what that session left there; and the last token of
+    the last session is out with nothing left in flight."""
+    import jax
+
+    fns = {
+        "prefilled": _toy_cached_decode_fns(),
+        "teacher_forced": _toy_cached_decode_fns()[:2],
+        "state": _toy_state_decode_fns(),
+    }[family]
+    fns = (fns[0], _tied(fns[1])) + tuple(fns[2:])
+    gate = threading.Event()
+    eng = _ahead_engine(monkeypatch, fns, gate)
+    calls = _watch_calls(eng)
+    launches: list = []
+    step_jit = eng._step_jit
+
+    def counted_step(*a):
+        launches.append(1)
+        return step_jit(*a)
+
+    eng._step_jit = counted_step
+    try:
+        tickets = [
+            eng.open(np.asarray(p, np.int32), n)
+            for p, n in zip(_AHEAD_PROMPTS, _AHEAD_BUDGETS)
+        ]
+        gate.set()
+        _wait_done(tickets, 60)
+        # Nothing more arrives: the last token came out on the loop's own
+        # account, and the loop then parks with nothing in flight.
+        deadline = time.monotonic() + 10
+        while eng.stats()["slots_active"]:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        assert eng._flight is None
+        stats = eng.stats()
+        state = jax.device_get(eng._cache)
+    finally:
+        eng.stop()
+    want = [
+        _plain_stream(fns, p, n, 32, 4)
+        for p, n in zip(_AHEAD_PROMPTS, _AHEAD_BUDGETS)
+    ]
+    for t, n, (tokens, _left) in zip(tickets, _AHEAD_BUDGETS, want):
+        assert t.error is None
+        assert t.snapshot(0) == (tokens, True) and len(tokens) == n
+    assert stats["emitted"] == sum(_AHEAD_BUDGETS)
+    # Every launch was read and counted as a step, most of them launched
+    # ahead; the short session ended under the long one, whose next launch
+    # was already out: its row was stepped once more, inert.
+    assert stats["steps"] == len(launches)
+    assert 0 < stats["ahead_steps"] < stats["steps"]
+    assert 1 <= stats["idle_rows"] <= len(tickets)
+    if family == "state":
+        # What the last session of each slot left there is still there.
+        last = {}
+        for slots, _before, _after in calls:
+            for i, t in enumerate(slots):
+                if t is not None:
+                    last[i] = tickets.index(t)
+        assert len(last) == 2
+        for i, k in last.items():
+            assert int(state[i]) == int(want[k][1][0]), (i, k)
+
+
+@pytest.mark.parametrize("live", [False, True])
+def test_the_compiled_step_returns_its_selection_and_no_logits(live):
+    """What leaves the compiled step besides the cache is ``[S]`` int32:
+    no ``[S, V]`` array is among its outputs, for the four-argument and
+    for the five-argument (``live``) model alike."""
+    import jax
+
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    if live:
+        eng = model_server._DecodeEngine(
+            lambda: (0, None), *_toy_state_decode_fns(), slots=2, max_len=16,
+            max_sessions=4,
+        )
+        params = None
+    else:
+        eng, params = _tiny_transformer_engine()
+    shapes = lambda tree: jax.tree.map(lambda a: (a.shape, a.dtype), tree)
+    try:
+        args = (params, eng._cache, *_step_args(2, live=live))
+        out = jax.eval_shape(eng._step_jit, *args)
+        held = shapes(eng._cache)
+        # The logits are written out in their own type before the selection
+        # reads them: fused into the head's product, a TPU's compiler picks
+        # among float32 sums where the host picked among their roundings.
+        assert "optimization_barrier" in eng._step_jit.lower(*args).as_text()
+    finally:
+        eng.stop()
+    # Two outputs: the selection, and the cache as the engine holds it.
+    selection, cache = out
+    assert (selection.shape, selection.dtype) == ((2,), np.int32)
+    assert shapes(cache) == held
+
+
+@pytest.mark.parametrize("sessions,ahead", [([(3, 1)], False), ([(3, 6), (2, 5)], True)])
+def test_ahead_steps_counts_the_launches_made_before_the_read(
+    monkeypatch, sessions, ahead,
+):
+    """A session of one token is launched once, with nothing before it to
+    be ahead of; two concurrent sessions are launched ahead."""
+    eng = _ahead_engine(monkeypatch, _toy_cached_decode_fns())
+    try:
+        outs = _run_sessions(
+            eng, [list(range(1, p + 1)) for p, _n in sessions],
+            [n for _p, n in sessions])
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    for (p, n), out in zip(sessions, outs):
+        assert out == _toy_cached_stream(list(range(1, p + 1)), n)
+    assert (stats["ahead_steps"] > 0) == ahead
+    assert stats["ahead_steps"] <= stats["steps"]
+
+
+@pytest.mark.parametrize("kth", [1, 4])
+def test_a_failed_launch_fails_the_active_sessions_and_leaves_nothing_in_flight(
+    monkeypatch, kth,
+):
+    """The compiled step raises on its k-th launch - with nothing in
+    flight (the first) or with the step before it launched and not yet
+    read: every active session fails, the engine is left a fresh cache and
+    nothing in flight, and the next session is served token for token."""
+    import jax.numpy as jnp
+
+    gate = threading.Event()
+    eng = _ahead_engine(monkeypatch, _toy_cached_decode_fns(), gate)
+    step_jit = eng._step_jit
+    n_calls, in_flight = [], []
+
+    def flaky_step(*a):
+        n_calls.append(1)
+        if len(n_calls) == kth:
+            in_flight.append(eng._flight)
+            raise FloatingPointError("step failed")
+        return step_jit(*a)
+
+    eng._step_jit = flaky_step
+    try:
+        first = eng._cache
+        tickets = [
+            eng.open(np.array([1, 2, 3], np.int32), 9),
+            eng.open(np.array([5], np.int32), 7),
+        ]
+        gate.set()
+        _wait_done(tickets)
+        for t in tickets:
+            with pytest.raises(FloatingPointError):
+                t.snapshot(0)
+        # The step before was out and not read when the k-th was launched.
+        assert in_flight == [None] if kth == 1 else in_flight[0] is not None
+        assert eng._flight is None
+        assert isinstance(eng._cache, jnp.ndarray) and not eng._cache.is_deleted()
+        assert eng._cache is not first and not eng._selection.is_deleted()
+        again = _run_sessions(eng, [[1, 2, 3], [4, 2]], [5, 3])
+        assert again == [
+            _toy_cached_stream([1, 2, 3], 5), _toy_cached_stream([4, 2], 3)]
+        assert eng.stats()["step_errors"] == 1
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("family", ["prefilled", "state"])
+def test_a_call_with_a_chunk_due_collects_and_launches_nothing_ahead(
+    monkeypatch, family,
+):
+    """A chunk runs with nothing queued ahead of it: the call that finds
+    one due while a step is in flight only reads that step; the next runs
+    the chunk and launches, not ahead.  So ``prefill_chunks`` and
+    ``ahead_steps`` never both rise in one call, and no step is in flight
+    when a chunk is launched."""
+    fns = _toy_cached_decode_fns() if family == "prefilled" else _toy_state_decode_fns()
+    gate = threading.Event()
+    eng = _ahead_engine(monkeypatch, fns, gate)
+    calls = _watch_calls(eng)
+    prefill_jit = eng._prefill_jit
+    in_flight: list = []
+
+    def watched_chunk(params, cache, tokens, slot, offset, n_valid):
+        if n_valid:  # not the chunk of no token that compiles the program
+            in_flight.append(eng._flight)
+        return prefill_jit(params, cache, tokens, slot, offset, n_valid)
+
+    eng._prefill_jit = watched_chunk
+    stream = _toy_cached_stream if family == "prefilled" else _toy_state_stream
+    try:
+        tickets = [
+            eng.open(np.asarray(p, np.int32), n)
+            for p, n in zip(_AHEAD_PROMPTS, _AHEAD_BUDGETS)
+        ]
+        gate.set()
+        _wait_done(tickets, 60)
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    for t, p, n in zip(tickets, _AHEAD_PROMPTS, _AHEAD_BUDGETS):
+        assert t.snapshot(0) == (stream(p, n), True)
+    # 2, 4 and 9 prompt tokens in chunks of 4; the long session decodes
+    # under every one of them but the first.
+    assert stats["prefill_chunks"] == 1 + 1 + 3 == len(in_flight)
+    assert in_flight == [None] * 5
+    assert stats["ahead_steps"] > 0
+    rose = [
+        (after[0] > before[0], after[1] > before[1])
+        for _slots, before, after in calls
+    ]
+    assert (True, False) in rose and (False, True) in rose
+    assert (True, True) not in rose
