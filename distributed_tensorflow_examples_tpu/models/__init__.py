@@ -14,6 +14,8 @@ traced function XLA can fuse end-to-end.
 - ``lstm``     — W5 PTB LSTM LM (ref: MultiWorkerMirroredStrategy)
 - ``transformer`` — GPT-2-style decoder LM, trained and served
 - ``jamba``    — Mamba-1 + attention hybrid (AI21 Jamba), served
+- ``longcat``  — latent attention + a chip's share of a dropless expert
+  layer with zero-compute experts (Meituan LongCat-Flash), served
 """
 
 from . import layers  # noqa: F401
@@ -24,3 +26,4 @@ from . import word2vec  # noqa: F401
 from . import lstm  # noqa: F401
 from . import transformer  # noqa: F401
 from . import jamba  # noqa: F401
+from . import longcat  # noqa: F401

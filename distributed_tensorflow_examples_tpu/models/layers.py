@@ -134,6 +134,30 @@ def gated_mlp(params, x, *, dtype):
 
 
 # ----------------------------------------------------------------------------
+# Rotary positions
+# ----------------------------------------------------------------------------
+
+
+def rope_angles(pos, dim: int, theta: float):
+    """``(cos, sin)``, each ``pos.shape + (dim // 2,)`` float32: pair ``i``
+    of a ``dim``-wide vector at position ``p`` turns by ``p * theta ** (-2 i
+    / dim)``.  No scaling of long contexts."""
+    inv = jnp.exp(-jnp.log(theta) * jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    a = jnp.asarray(pos, jnp.float32)[..., None] * inv
+    return jnp.cos(a), jnp.sin(a)
+
+
+def rope_interleaved(x, cos, sin):
+    """Rotate ``x [..., dim]`` in float32 with the pairs INTERLEAVED - pair
+    ``i`` is elements ``(2 i, 2 i + 1)``, as LongCat's and DeepSeek's
+    checkpoints store them, not ``(i, i + dim / 2)`` as GPT-NeoX's do.
+    ``cos``, ``sin`` broadcast against ``x[..., 0::2]``."""
+    x = x.astype(jnp.float32)
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape)
+
+
+# ----------------------------------------------------------------------------
 # Conv2D (NHWC x HWIO -> NHWC; the MXU-friendly layout)
 # ----------------------------------------------------------------------------
 

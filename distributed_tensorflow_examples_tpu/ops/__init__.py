@@ -9,3 +9,4 @@ implementation of each op serves CPU tests and autodiff checks.
 from . import attention  # noqa: F401
 from . import flash_attention  # noqa: F401
 from . import selective_scan  # noqa: F401
+from . import grouped_ffn  # noqa: F401

@@ -97,9 +97,11 @@ def interpret_mode() -> bool:
     )
 
 
-def compiler_params(semantics: tuple[str, ...]):
+def compiler_params(semantics: tuple[str, ...], vmem_limit_bytes: int | None = None):
     """The ONE spelling every TPU kernel in the package uses."""
-    return pltpu.CompilerParams(dimension_semantics=semantics)
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics, vmem_limit_bytes=vmem_limit_bytes
+    )
 
 
 def _params():

@@ -20,6 +20,13 @@ Components:
 - load-balance auxiliary loss (Switch eq. 4): E * sum_e f_e * p_e,
 - expert FFN: per-expert GELU MLP, weights stacked [E, ...] and sharded
   ``P('expert', ...)`` so each rank holds only its experts (rules below).
+
+A second layer beside it, :func:`apply_share`, is ONE CHIP'S SHARE of a
+wide expert-parallel deployment, dropless: it is told which of the model's
+experts it holds, routes over all of them, and computes the part of the
+result that its own experts (and the zero-compute ones, which need no
+exchange) give - ops/grouped_ffn.py does the products.  The two share
+nothing but this file; a model calls the one it is.
 """
 
 from __future__ import annotations
@@ -33,6 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..models import layers
+from . import grouped_ffn as grouped_ffn_lib
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,3 +233,101 @@ SHARDING_RULES: tuple = (
     (r".*moe/w2", P("expert", "model", None)),
     (r".*moe/b2", P("expert", None)),
 )
+
+
+# ----------------------------------------------------------------------------
+# One chip's share of a dropless expert layer
+# ----------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class ShareConfig:
+    """What the router ranges over and what of it lives here."""
+
+    n_experts: int  # routed experts of the MODEL (ids 0 .. n_experts - 1)
+    n_zero: int  # zero-compute experts after them: E(u) = u
+    top_k: int
+    scale: float  # every chosen score is multiplied by it; no renormalising
+    first: int  # the routed experts held here are first .. first + held - 1
+    held: int
+
+
+#: What :func:`apply_share` counts, each an int32 scalar.
+SHARE_COUNTS = ("choices", "choices_held", "choices_zero", "experts_touched", "calls")
+
+
+def share_rows_block(tokens: int) -> int:
+    """Rows of one block of the grouped product: a prefill chunk's hundreds
+    of tokens fill MXU-high blocks, a decode step's few dozen rows would
+    pay for 128 and use two."""
+    return 128 if tokens >= 128 else 32
+
+
+def apply_share(p, u, share: ShareConfig, live=None, *, dtype):
+    """u ``[T, D]`` float32 (normed) -> ``(m [T, D] float32, counts)``: the
+    part of ``sum_i w_i E_i(u)`` that this chip's experts and the
+    zero-compute experts give.
+
+    Router in float32 throughout (the product at the highest precision): ``s
+    = softmax(u . router)`` over all ``n_experts + n_zero``; the ``top_k``
+    largest of ``s + bias`` (the bias enters the CHOICE only); weights
+    ``scale * s`` of the chosen, not renormalised.  A choice on a held expert
+    becomes a row of that expert's group (sorted by expert, each group on a
+    block boundary: ops/grouped_ffn.py); a choice on a zero-compute expert
+    adds ``w u`` where the token lives; a choice on an expert that lives on
+    another chip adds NOTHING - no capacity, no dropped token, no stand-in
+    for the other chips' part.  ``p``: ``router/kernel [D, n_experts +
+    n_zero]``, ``router/bias``, ``gate, up [held, D, F]``, ``down [held, F,
+    D]``.  ``live [T]`` bool: a row that is not live (an empty slot, padding)
+    gets no expert row, a zero result and no count.  The row buffer is
+    static and holds the worst case, ``top_k x T`` choices all held.
+
+    ``counts`` (:data:`SHARE_COUNTS`): the live rows' choices, those on
+    held and on zero-compute experts, the held experts with at least one
+    row, and 1 for the call."""
+    T, D = u.shape
+    k, E, held = share.top_k, share.n_experts, share.held
+    f32 = jnp.float32
+    live = jnp.ones((T,), bool) if live is None else live
+    with jax.named_scope("moe/route"):
+        logits = jnp.dot(
+            u.astype(f32), p["router"]["kernel"].astype(f32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        s = jax.nn.softmax(logits, axis=-1)
+        _, choice = jax.lax.top_k(s + p["router"]["bias"].astype(f32), k)  # [T, k]
+        w = share.scale * jnp.take_along_axis(s, choice, axis=1)
+        local = choice - share.first
+        on_held = (local >= 0) & (local < held) & live[:, None]
+        on_zero = (choice >= E) & live[:, None]
+        # Each held choice's row: its group's start plus how many choices on
+        # the same expert come before it.
+        block = share_rows_block(T)
+        rows = (-(-T * k // block) + held) * block
+        flat = jnp.where(on_held, local, held).reshape(-1)  # [T k]
+        hit = flat[:, None] == jnp.arange(held)[None, :]  # [T k, held]
+        sizes = jnp.sum(hit, axis=0, dtype=jnp.int32)
+        before = jnp.cumsum(hit, axis=0, dtype=jnp.int32) - 1
+        starts, _ = grouped_ffn_lib.group_starts(sizes, block)
+        dest = jnp.sum(jnp.where(hit, starts[None, :] + before, 0), axis=1)
+        dest = jnp.where(flat < held, dest, rows)  # past the end: dropped
+        token_of_row = jnp.full((rows,), T - 1, jnp.int32).at[dest].set(
+            jnp.arange(T * k, dtype=jnp.int32) // k, mode="drop")
+    with jax.named_scope("moe/experts"):
+        y = grouped_ffn_lib.grouped_ffn(
+            jnp.take(u.astype(dtype), token_of_row, axis=0), sizes,
+            p["gate"], p["up"], p["down"], block_rows=block,
+        )
+        # A row outside the groups may hold anything: selected away, never
+        # multiplied by a zero.
+        mine = jnp.take(y, jnp.minimum(dest, rows - 1), axis=0).reshape(T, k, D)
+        m = jnp.sum(jnp.where(on_held[..., None], w[..., None] * mine, 0.0), axis=1)
+    with jax.named_scope("moe/zero"):
+        m = m + jnp.sum(jnp.where(on_zero, w, 0.0), axis=1, keepdims=True) * u
+    count = lambda x: jnp.sum(x, dtype=jnp.int32)
+    counts = {
+        "choices": k * count(live), "choices_held": count(on_held),
+        "choices_zero": count(on_zero), "experts_touched": count(sizes > 0),
+        "calls": jnp.int32(1),
+    }
+    return m, counts

@@ -227,6 +227,23 @@ class _DecodeEngine:
     and the next step emits its first token.  Without ``prefill_fn`` the
     prompt is teacher-forced through the decode step, a token a step.
 
+    What a model counts on the device.  A model whose cache tree has an
+    entry ``counters`` - a dict of small int32 arrays that its step and its
+    chunk add to, in place, on the device (models/longcat.py: which experts
+    a step's tokens chose is known only there) - has them reported by
+    ``stats()`` as ``model_<name>`` (the replica's ``decode_model_<name>``;
+    an array as the sum of its elements), summed over everything launched
+    since the engine started.  ``stats()``
+    only ASKS: the read is made on the step thread, at the top of its next
+    call or when it has no row left to step (so a parked engine's numbers
+    are already there), where that thread holds the cache and no program
+    has been handed it - never from a handler's thread against a buffer
+    that a launch may have donated meanwhile.  No step fetches anything
+    for it; the asked-for read waits for the step in flight like any read
+    of the cache would.  The device's int32 sums may wrap: the host keeps
+    the totals and adds each read's difference modulo 2**32.  A cache
+    without the entry is driven exactly as before and reports none.
+
     What the engine hands over.  The cache is DONATED to the step and to
     the chunk alike (the engine holds its only reference), so a program
     that writes a row in place costs that row and not a second cache.  A
@@ -279,6 +296,15 @@ class _DecodeEngine:
         # the host has not read it.
         self._selection = self._no_selection()
         self._flight: _Flight | None = None
+        # What the model counts on the device (class docstring): the host's
+        # totals, the device's values at the last read, and the handshake.
+        self._counts = isinstance(self._cache, dict) and "counters" in self._cache
+        self.model_counters: dict = {}
+        self._counters_seen: dict = {}
+        self._counters_asked = threading.Event()
+        self._counters_fresh = threading.Event()
+        if self._counts:
+            self._read_counters()
         self.batcher = batcher_lib.SlotBatcher(
             self._run_step, slots=self.slots, max_sessions=max_sessions,
         )
@@ -317,6 +343,22 @@ class _DecodeEngine:
             "pos": prefill, "end": prompt.size - 1 + n,
         })
 
+    def _read_counters(self) -> None:
+        """Step thread only (or before it exists): fold what the device has
+        counted since the last read into the host's totals."""
+        import jax
+
+        self._counters_asked.clear()  # one that comes during the read waits
+        now = jax.device_get(self._cache["counters"])
+        for name, value in now.items():
+            value = np.asarray(value).astype(np.int64)
+            seen = self._counters_seen.get(name, 0)
+            self.model_counters[name] = (
+                self.model_counters.get(name, 0) + int(((value - seen) % 2**32).sum())
+            )
+            self._counters_seen[name] = value
+        self._counters_fresh.set()
+
     @contextlib.contextmanager
     def _cache_donated(self):
         """Around a program the cache is donated to, and around the read of
@@ -330,6 +372,7 @@ class _DecodeEngine:
             self._flight = None
             self._cache = None  # never two caches on the device
             self._cache = self._init_cache(self.slots, self.max_len)
+            self._counters_seen = {}  # the fresh cache counts from zero
             self._selection = self._no_selection()
             raise
 
@@ -386,6 +429,9 @@ class _DecodeEngine:
         stepping = any(
             t is not None and t.state["pos"] < t.state["end"] for t in slots
         )
+        if self._counts and (self._counters_asked.is_set() or not stepping):
+            with self._cache_donated():
+                self._read_counters()
         if flight is not None and (
             chunk is not None or not stepping
             or model is None or model[1] is not flight.params
@@ -474,6 +520,14 @@ class _DecodeEngine:
         s["ahead_steps"] = self.ahead_steps
         s["idle_rows"] = self.idle_rows
         s["state_bytes"] = self.state_bytes
+        if self._counts:
+            # Ask the step thread and give it a step and a chunk's time; a
+            # parked engine read its counters as its last row finished.
+            self._counters_fresh.clear()
+            self._counters_asked.set()
+            if s["slots_active"]:
+                self._counters_fresh.wait(2.0)
+            s.update({f"model_{k}": v for k, v in self.model_counters.items()})
         return s
 
     def stop(self) -> None:
@@ -489,6 +543,9 @@ class _DecodeEngine:
             except Exception:  # noqa: BLE001 — a stop goes on to the end
                 log.warning("the decode step in flight at stop failed",
                             exc_info=True)
+        # A stopped engine holds nothing on the device (see the replica's
+        # ``stop``).
+        self._cache = self._selection = None
 
 
 def _selecting(model_step):
@@ -823,6 +880,15 @@ class ModelReplicaServer:
         self._batcher.stop()
         if self._engine is not None:
             self._engine.stop()
+        # A stopped replica holds nothing on the device: its parameters and
+        # its engine's cache go HERE, not with the garbage collector's next
+        # pass - the replica, its core's handler and its engine refer to
+        # each other, so dropping the last outside reference frees nothing
+        # until the cycle is collected, and what runs next in the process
+        # (a reference pass over the served tokens) finds the chip full of
+        # a replica that is gone: 12.8 of 16 GB in half the runs of the
+        # largest served model (my chip runs, PR 31).
+        self._model = None
         if self._pinned:
             # Release the registry pin LAST: GC must not reclaim the
             # served version while in-flight work could still touch it.

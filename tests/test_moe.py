@@ -342,3 +342,150 @@ def test_moe_group_size_plumbs_from_transformer_config():
         batch = {"x": np.zeros((2, 64), np.int32), "y": np.zeros((2, 64), np.int32)}
         loss, _ = models.transformer.loss_fn(cfg)(p, None, batch, jax.random.key(1))
         assert np.isfinite(float(loss))
+
+
+# ----------------------------------------------------------------------------
+# One chip's share of a dropless expert layer (``apply_share``)
+# ----------------------------------------------------------------------------
+# Seeded weights and the plain reference of benchmarks/reference/
+# longcat_ref.py, float32 operands on both sides so that a choice at a
+# near-tie falls the same way: what is left is the order of the sums, and
+# the results (of unit size) agree to 2e-5.
+
+import os  # noqa: E402
+import sys  # noqa: E402
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.reference import longcat_ref, weights as ref_weights  # noqa: E402
+
+SHARE_TOL = 2e-5
+#: 32 routed experts beside 16 zero-compute ones, 6 choices a token.
+C_SHARE = dict(
+    hidden_size=64, ffn_hidden_size=128, expert_ffn_hidden_size=32, num_layers=1,
+    num_attention_heads=4, kv_lora_rank=32, q_lora_rank=48, qk_rope_head_dim=8,
+    qk_nope_head_dim=16, v_head_dim=16, routed_scaling_factor=6, n_routed_experts=32,
+    zero_expert_num=16, moe_topk=6, vocab_size=100, rms_norm_eps=1e-5, init_std=0.125,
+)
+
+
+def _share_params(first, held, seed=11):
+    """The expert layer's leaves for ``held`` experts from ``first`` on, as
+    the reference seeds them (an expert's by its global id), in float32."""
+    key = ref_weights.base_key(seed)
+    p = longcat_ref.build(longcat_ref.layer_spec(C_SHARE), key, layer=0)["moe"]
+    p.update(jax.vmap(lambda e: longcat_ref.expert(C_SHARE, key, 0, e))(
+        first + jnp.arange(held)))
+    return jax.tree.map(lambda a: a.astype(jnp.float32), p)
+
+
+def _share(first, held):
+    return moe_ops.ShareConfig(
+        n_experts=32, n_zero=16, top_k=6, scale=6.0, first=first, held=held)
+
+
+def _reference_layer(c, u, seed=11):
+    key = ref_weights.base_key(seed)
+    p = jax.tree.map(
+        lambda a: a.astype(jnp.float32),
+        longcat_ref.build(longcat_ref.layer_spec(c), key, layer=0)["moe"])
+    expert = lambda e: jax.tree.map(
+        lambda a: a.astype(jnp.float32), longcat_ref.expert(c, key, 0, e))
+    return p, np.asarray(longcat_ref.moe(c, p, expert, u, "float32"))
+
+
+def test_the_shares_of_four_ranks_add_up_to_the_uncut_layer():
+    """THE SHARE TIES TO THE MODEL: the routed parts that ranks 0-3 compute,
+    8 of the 32 experts each from one seed, plus the zero-compute part
+    counted once, are the uncut reference layer."""
+    u = jax.random.normal(jax.random.key(3), (40, 64))
+    p_ref, whole = _reference_layer(dict(C_SHARE), u)
+    # The zero-compute part alone, from the reference's own routing.
+    choice, w = longcat_ref.route(C_SHARE, p_ref, u)
+    zero = np.asarray(jnp.sum(jnp.where(choice >= 32, w, 0.0), -1, keepdims=True) * u)
+    routed = np.zeros_like(whole)
+    counts = []
+    for rank in range(4):
+        m, c = moe_ops.apply_share(
+            _share_params(8 * rank, 8), u, _share(8 * rank, 8), dtype=jnp.float32)
+        # Each rank's own result against the reference given the same share.
+        _, part = _reference_layer(dict(C_SHARE, experts_held=8, expert_first=8 * rank), u)
+        assert np.abs(np.asarray(m) - part).max() < SHARE_TOL
+        routed += np.asarray(m) - zero
+        counts.append({k: int(v) for k, v in c.items()})
+    assert np.abs(whole).max() > 0.5
+    assert np.abs(routed + zero - whole).max() < 4 * SHARE_TOL
+    # Every choice is on some rank's expert or on a zero-compute one.
+    assert all(c["choices"] == 40 * 6 and c["calls"] == 1 for c in counts)
+    assert sum(c["choices_held"] for c in counts) + counts[0]["choices_zero"] == 40 * 6
+    assert all(c["choices_zero"] == counts[0]["choices_zero"] for c in counts)
+    assert all(0 < c["experts_touched"] <= 8 for c in counts)
+
+
+def _by_hand(p, u, share, live=None):
+    """The layer in NumPy, a token and a choice at a time."""
+    u = np.asarray(u, np.float64)
+    router = np.asarray(p["router"]["kernel"], np.float64)
+    z = u @ router
+    s = np.exp(z - z.max(-1, keepdims=True))
+    s /= s.sum(-1, keepdims=True)
+    out = np.zeros_like(u)
+    silu = lambda x: x / (1 + np.exp(-x))
+    chosen = []
+    for t in range(u.shape[0]):
+        order = np.argsort(-(s[t] + np.asarray(p["router"]["bias"], np.float64)), kind="stable")
+        chosen.append(order[:share.top_k].tolist())
+        if live is not None and not live[t]:
+            continue
+        for e in order[:share.top_k]:
+            w = share.scale * s[t, e]
+            if e >= share.n_experts:
+                out[t] += w * u[t]
+            elif share.first <= e < share.first + share.held:
+                g, up, down = (np.asarray(p[k][e - share.first], np.float64)
+                               for k in ("gate", "up", "down"))
+                out[t] += w * ((silu(u[t] @ g) * (u[t] @ up)) @ down)
+    return out, chosen
+
+
+def test_the_bias_moves_a_choice_and_not_a_weight():
+    """With a bias that outweighs every score, each token's choices are the
+    biased experts - and each still weighs ``scale x s``, its own score."""
+    u = jax.random.normal(jax.random.key(5), (12, 64))
+    share = _share(8, 8)
+    p = _share_params(8, 8)
+    plain, chosen_plain = _by_hand(p, u, share)
+    favoured = [9, 40, 2, 47, 15, 33]  # held, zero, absent, zero, held, zero
+    p_biased = dict(p, router=dict(
+        p["router"], bias=jnp.zeros((48,)).at[jnp.asarray(favoured)].set(10.0)))
+    want, chosen = _by_hand(p_biased, u, share)
+    assert all(sorted(c) == sorted(favoured) for c in chosen)
+    assert any(sorted(c) != sorted(favoured) for c in chosen_plain)
+    m, counts = moe_ops.apply_share(p_biased, u, share, dtype=jnp.float32)
+    assert np.abs(np.asarray(m) - want).max() < SHARE_TOL
+    assert np.abs(want - plain).max() > 0.05
+    assert int(counts["choices_held"]) == 2 * 12 and int(counts["choices_zero"]) == 3 * 12
+    assert int(counts["experts_touched"]) == 2
+    # Without the bias too, the layer is the sum by hand.
+    m, _ = moe_ops.apply_share(p, u, share, dtype=jnp.float32)
+    assert np.abs(np.asarray(m) - plain).max() < SHARE_TOL
+
+
+def test_a_token_with_no_held_choice_gets_exactly_its_zero_compute_part():
+    """Every choice steered onto absent and zero-compute experts: no expert
+    row, no expert read, and the result is ``(w_a + w_b) u`` - and a row
+    that is not live gets nothing and is not counted."""
+    u = jax.random.normal(jax.random.key(6), (10, 64))
+    share = _share(8, 8)
+    p = _share_params(8, 8)
+    steer = jnp.zeros((48,)).at[jnp.asarray([0, 1, 2, 30, 36, 45])].set(10.0)
+    p = dict(p, router=dict(p["router"], bias=steer))
+    live = np.array([True] * 7 + [False] * 3)
+    m, counts = moe_ops.apply_share(p, u, share, jnp.asarray(live), dtype=jnp.float32)
+    s = jax.nn.softmax(u @ p["router"]["kernel"], axis=-1)
+    want = 6.0 * (s[:, 36] + s[:, 45])[:, None] * u
+    np.testing.assert_allclose(np.asarray(m)[:7], np.asarray(want)[:7], rtol=1e-5, atol=1e-7)
+    assert not np.asarray(m)[7:].any()
+    assert {k: int(v) for k, v in counts.items()} == {
+        "choices": 42, "choices_held": 0, "choices_zero": 14,
+        "experts_touched": 0, "calls": 1}

@@ -92,3 +92,29 @@ def test_kernel_compiles_for_a_v5e_at_the_served_widths(one_chip, monkeypatch):
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and f"%{ss.KERNEL_NAME}" in text
+
+
+@pytest.mark.parametrize("rows,block", [(896, 32), (8192, 128)])
+def test_grouped_ffn_compiles_for_a_v5e_at_the_served_widths(
+    one_chip, monkeypatch, rows, block,
+):
+    """Mosaic takes ops/grouped_ffn.py at ``D`` 6144, ``F`` 2048, 16 experts
+    held, with the row buffers of a decode step (32 slots x 12 choices, in
+    blocks of 32) and of a prefill chunk (512 x 12, in blocks of 128): its
+    39 MB of VMEM, the scalar-prefetched index maps and the kernel's name,
+    which the benchmark's readers look for.  (Kept in this file: the one
+    that loads the TPU's library.)"""
+    from distributed_tensorflow_examples_tpu.ops import grouped_ffn as gf
+
+    monkeypatch.setattr(gf, "interpret_mode", lambda: False)
+    D, F, E = 6144, 2048, 16
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(
+        lambda r, n, g, u, d: gf.grouped_ffn.__wrapped__(r, n, g, u, d, block_rows=block)
+    ).lower(
+        s((rows, D), bf16), s((E,), jnp.int32), s((E, D, F), bf16),
+        s((E, D, F), bf16), s((E, F, D), bf16),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{gf.KERNEL_NAME}" in text

@@ -2072,3 +2072,154 @@ def test_a_call_with_a_chunk_due_collects_and_launches_nothing_ahead(
     ]
     assert (True, False) in rose and (False, True) in rose
     assert (True, True) not in rose
+
+
+# ----------------------------------------------------------------------------
+# What a model counts on the device (PR 31)
+# ----------------------------------------------------------------------------
+
+
+def _tiny_longcat():
+    import jax
+
+    from distributed_tensorflow_examples_tpu.models import longcat
+
+    cfg = longcat.Config(
+        vocab_size=97, hidden_size=32, ffn_hidden_size=64, expert_ffn_hidden_size=16,
+        num_layers=2, num_attention_heads=2, kv_lora_rank=16, q_lora_rank=24,
+        qk_rope_head_dim=8, qk_nope_head_dim=8, v_head_dim=8, n_routed_experts=8,
+        zero_expert_num=4, moe_topk=3, experts_held=4, expert_first=2,
+        param_dtype="float32",
+    )
+    params = longcat.init(cfg, jax.random.key(5))
+    # Larger weights than the initialisation's: logits far enough apart
+    # that a token is no matter of rounding.
+    params = jax.tree.map(lambda a: a * 6 if a.ndim >= 2 else a, params)
+    return cfg, params, longcat.serve_decode_fns(cfg)
+
+
+def test_longcat_sessions_through_the_engine_and_its_counters_add_up(monkeypatch):
+    """models/longcat.py behind ``_DecodeEngine``: a three-chunk prompt held
+    while others decode, a one-token prompt, slots reseated - each session
+    gets the tokens it gets alone; and ``model_moe_*`` say what the expert
+    layer did for the LIVE rows: every live token of every layer made
+    ``top_k`` choices (a chunk's last layer calls no expert layer), each
+    held, zero-compute or absent."""
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    cfg, params, fns = _tiny_longcat()
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 97, size=n) for n in (5, 20, 3, 1, 11)]
+    budgets = [10, 5, 4, 6, 3]
+
+    def engine():
+        return model_server._DecodeEngine(
+            lambda: (0, params), *fns, slots=2, max_len=40, max_sessions=8)
+
+    eng = engine()
+    try:
+        assert eng._wants_live and eng._counts
+        before = eng.stats()
+        together = _run_sessions(eng, prompts, budgets, gap_s=0.05)
+        stats = eng.stats()
+        again = eng.stats()  # parked: read as the last row finished
+    finally:
+        eng.stop()
+    assert all(before[f"model_moe_{k}"] == 0 for k in (
+        "choices", "choices_held", "choices_zero", "experts_touched", "calls"))
+    assert stats["held_rows"] >= 2  # the 20-token prompt's first chunks
+    # Live token-layers: a prompt of P tokens is P - 1 tokens through every
+    # layer but the last in chunks, then 1 + (n - 1) steps through both.
+    chunked = sum(len(p) - 1 for p in prompts)
+    stepped = sum(budgets)
+    assert stats["prefill_tokens"] == chunked
+    token_layers = chunked * (cfg.num_layers - 1) + stepped * cfg.num_layers
+    assert stats["model_moe_choices"] == cfg.moe_topk * token_layers
+    # The engine's warm-up chunk of no valid token is a call with no choice.
+    assert stats["model_moe_calls"] == (
+        (stats["prefill_chunks"] + 1) * (cfg.num_layers - 1)
+        + stats["steps"] * cfg.num_layers)
+    held, zero = stats["model_moe_choices_held"], stats["model_moe_choices_zero"]
+    assert 0 < held and 0 < zero and held + zero < stats["model_moe_choices"]
+    assert 0 < stats["model_moe_experts_touched"] <= held
+    assert {k: v for k, v in again.items() if k.startswith("model_")} == {
+        k: v for k, v in stats.items() if k.startswith("model_")}
+    for p, n, got in zip(prompts, budgets, together):
+        eng = engine()
+        try:
+            alone = _run_sessions(eng, [p], [n])[0]
+        finally:
+            eng.stop()
+        assert got == alone, len(p)
+
+
+def test_counters_are_read_on_the_step_thread_and_survive_a_lost_cache(monkeypatch):
+    """``stats()`` from another thread only asks: every read of the cache's
+    counters is made by the step thread (or before it exists).  A failed
+    step costs the engine its cache; the fresh one counts from zero and the
+    host's totals keep what was read."""
+    import threading
+
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    cfg, params, fns = _tiny_longcat()
+    eng = model_server._DecodeEngine(
+        lambda: (0, params), *fns, slots=2, max_len=40, max_sessions=8)
+    readers = []
+    read = eng._read_counters
+    monkeypatch.setattr(
+        eng, "_read_counters",
+        lambda: (readers.append(threading.current_thread().name), read())[1])
+    try:
+        ticket = eng.open(np.arange(1, 6, dtype=np.int32), 30)
+        seen = []
+        while not ticket.done:
+            seen.append(eng.stats()["model_moe_choices"])
+            ticket.wait(0.05)
+        first = eng.stats()["model_moe_choices"]
+        assert first == cfg.moe_topk * (4 * (cfg.num_layers - 1) + 30 * cfg.num_layers)
+        assert seen == sorted(seen) and seen[-1] <= first
+        assert readers and set(readers) == {"dtx-decode-slots"}
+        # Lose the cache: the next launch raises.
+        step = eng._step_jit
+        monkeypatch.setattr(eng, "_step_jit", lambda *a: (_ for _ in ()).throw(RuntimeError("x")))
+        bad = eng.open(np.arange(1, 3, dtype=np.int32), 2)
+        while not bad.done:
+            bad.wait(0.05)
+        assert bad.error is not None
+        monkeypatch.setattr(eng, "_step_jit", step)
+        _run_sessions(eng, [np.arange(1, 3)], [2])
+        assert eng.stats()["model_moe_choices"] == first + cfg.moe_topk * (
+            1 * (cfg.num_layers - 1) + 2 * cfg.num_layers)
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("family", ["transformer", "toy", "toy_state"])
+def test_a_model_without_counters_reports_none(family):
+    """No ``counters`` entry in the cache tree: no ``model_*`` key, no read,
+    and the engine's other numbers as before."""
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    if family == "transformer":
+        eng, _params = _tiny_transformer_engine()
+    else:
+        fns = _toy_cached_decode_fns() if family == "toy" else _toy_state_decode_fns()
+        eng = model_server._DecodeEngine(
+            lambda: (0, {"w": np.float32(1.0)}), *fns, slots=2, max_len=16,
+            max_sessions=4)
+    try:
+        assert not eng._counts
+        out = _run_sessions(eng, [[1, 2, 3]], [4])
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    assert len(out[0]) == 4
+    assert not [k for k in stats if k.startswith("model_")]
+    assert stats["emitted"] == 4
+    if family == "toy":
+        assert out[0] == _toy_cached_stream([1, 2, 3], 4)
+    if family == "toy_state":
+        assert out[0] == _toy_state_stream([1, 2, 3], 4)
