@@ -98,8 +98,14 @@ NO_DECODER = wire.SRV_STATUS["NO_DECODER"]
 # The decode engine's share of the step thread's time, as leaf spans in the
 # slot batcher's family (its ``name``, "decode").  They follow the batcher's
 # ``decode/fill`` and precede its ``decode/emit``; none wraps another.
-#: One prefill chunk, launch to completion; entered only when one runs.
+#: The wait for the chunk dispatched a call earlier, at the top of the next
+#: call, once a chunk.  The engine adds to the span's ``/ns`` the host's
+#: launch of the chunk (``_prefill_one``) and the time the chunk had been
+#: on the device before the span was entered (``_await_chunk``), so the sum
+#: over ``/n`` stays what it was while one span held both: a chunk's launch
+#: and its time on the device.
 _SPAN_PREFILL = telemetry.span("decode/prefill")
+_PREFILL_NS = telemetry.REGISTRY.counter("decode/prefill/ns")
 #: Each row's token or its source, position and liveness, uploaded.
 _SPAN_PREPARE = telemetry.span("decode/prepare")
 #: Launching the jitted step (the runtime's ``PjitFunction(step_fn)``).
@@ -178,14 +184,24 @@ class _DecodeEngine:
     the engine launches step N+1 and only then reads step N's selection:
     the device runs N+1 while the host emits N and prepares N+2.  At most
     one step is in flight beyond the one being read (``ahead_steps``
-    counts the launches made ahead).  Nothing is launched ahead of a
-    prefill chunk or across a change of the served model: a call that
-    finds one due while a step is in flight only collects, and the next
-    call runs the chunk with nothing queued ahead of it; a call with no row
-    left to step only collects too, so a session's last token is out
-    before the loop parks.  The launch that is already out when a session's
-    last token is read steps that session's row once past its end: an
-    inert row (``idle_rows``), token 0 at position 0 and not live.
+    counts the launches made ahead).  A prefill chunk is one more member
+    of the device's queue: it needs nothing from the host but its tokens,
+    slot, offset and count, and the step behind it needs its cache, which
+    the runtime orders (each program is donated the cache the one before
+    it returns).  So a call that finds a chunk due dispatches the chunk
+    BEHIND the step in flight, launches the next step BEHIND the chunk and
+    only then reads the step in flight (``queued_chunks`` counts the chunks
+    dispatched so: all but a parked engine's first and the one after a
+    change of model).  The call after it first waits for that chunk, by the
+    small array the chunk program returns beside the cache, before it
+    dispatches anything: at most one chunk and two steps are ever on the
+    device unread.  Nothing is launched across a change of the served
+    model: a call that finds one while a step is in flight only collects;
+    a call with no row left to step only collects too, so a session's last
+    token is out before the loop parks.  The launch that is already out
+    when a session's last token is read steps that session's row once past
+    its end: an inert row (``idle_rows``), token 0 at position 0 and not
+    live.
 
     What a row may do to its slot.  A row is LIVE when its session decodes
     in this step; an empty slot's row, the row of a seated session whose
@@ -247,12 +263,13 @@ class _DecodeEngine:
     What the engine hands over.  The cache is DONATED to the step and to
     the chunk alike (the engine holds its only reference), so a program
     that writes a row in place costs that row and not a second cache.  A
-    launch, a read or a chunk that raises fails every active session and
-    may have cost the engine its cache, so it is left a fresh cache, a
-    fresh ``prev`` and NOTHING in flight - a freed slot needs no cache
-    state.  An empty slot's row is stepped with token 0 at position 0, not
-    where its last session stood: a step that reads no further than its
-    deepest row is held to the sessions that are seated.
+    launch, a read or a chunk that raises - a chunk at its dispatch or at
+    the wait for it - fails every active session and may have cost the
+    engine its cache, so it is left a fresh cache, a fresh ``prev``,
+    NOTHING in flight and no chunk outstanding - a freed slot needs no
+    cache state.  An empty slot's row is stepped with token 0 at position
+    0, not where its last session stood: a step that reads no further than
+    its deepest row is held to the sessions that are seated.
     """
 
     def __init__(
@@ -278,12 +295,20 @@ class _DecodeEngine:
         self.slots = int(slots)
         self.max_len = int(max_len)
         self._prefill_jit = (
-            jax.jit(prefill_fn, donate_argnums=1) if prefill_fn else None
+            jax.jit(_echoing(prefill_fn), donate_argnums=1)
+            if prefill_fn else None
         )
         self._chunk = min(PREFILL_CHUNK, self.max_len)
         self._prefill_warm = False
         self.prefill_chunks = 0
         self.prefill_tokens = 0  # valid tokens; padding is not counted
+        # Chunks dispatched while a step launched earlier had not been read.
+        self.queued_chunks = 0
+        # The chunk on the device that the host has not waited for: what its
+        # program echoes (the cache went on to the step behind it) and when
+        # it began there.
+        self._chunk_echo = None
+        self._chunk_began_ns = 0
         # Slot-steps a seated session was not live: its chunks were due.
         self.held_rows = 0
         # Cache positions of each slot the steps' attention read, summed.
@@ -369,7 +394,7 @@ class _DecodeEngine:
         try:
             yield
         except BaseException:
-            self._flight = None
+            self._flight = self._chunk_echo = None
             self._cache = None  # never two caches on the device
             self._cache = self._init_cache(self.slots, self.max_len)
             self._counters_seen = {}  # the fresh cache counts from zero
@@ -377,18 +402,32 @@ class _DecodeEngine:
             raise
 
     def _prefill(self, params, slot: int, tokens, offset: int, n_valid: int):
-        """Run one chunk to completion (the span around it is the chunk's
-        whole cost, and a chunk that fails fails here)."""
-        import jax
-
+        """Dispatch one chunk behind whatever is on the device and hand its
+        cache on; returns what the program echoes, for the host to wait on.
+        Inside ``_cache_donated()``."""
         buf = np.zeros((self._chunk,), np.int32)
         buf[:n_valid] = tokens
-        with self._cache_donated():
-            self._cache = self._prefill_jit(
-                params, self._cache, buf, np.int32(slot), np.int32(offset),
-                np.int32(n_valid),
-            )
-            jax.block_until_ready(self._cache)
+        self._cache, echo = self._prefill_jit(
+            params, self._cache, buf, np.int32(slot), np.int32(offset),
+            np.int32(n_valid),
+        )
+        return echo
+
+    def _await_chunk(self) -> None:
+        """Top of a call, before anything is dispatched: wait for the chunk
+        the call before dispatched.  The device is busy with it and then
+        with the step queued behind it, so the wait idles nothing, and a
+        chunk that failed on the device fails here.  The span holds the
+        wait; the chunk had been running since ``_chunk_began_ns``, under
+        the host's emit and fill, and that stretch is added to its sum."""
+        echo, self._chunk_echo = self._chunk_echo, None
+        if echo is None:
+            return
+        import jax
+
+        _PREFILL_NS.inc(time.perf_counter_ns() - self._chunk_began_ns)
+        with self._cache_donated(), _SPAN_PREFILL:
+            jax.block_until_ready(echo)
 
     def _chunk_due(self, slots) -> int | None:
         """The slot whose chunk runs next: the longest-seated session's
@@ -400,32 +439,44 @@ class _DecodeEngine:
         return min(waiting)[1] if waiting else None
 
     def _prefill_one(self, params, slots, i: int | None) -> None:
-        """At most one chunk: the next of slot ``i``'s prompt."""
-        if not self._prefill_warm:
-            # Both programs exist before the first session is answered,
-            # whatever its prompt's length: a chunk of no valid token
-            # compiles the chunk program and writes nothing.
-            self._prefill(params, 0, (), 0, 0)
-            self._prefill_warm = True
-        if i is None:
-            return
-        with _SPAN_PREFILL:
+        """At most one chunk, dispatched and not waited for: the next of
+        slot ``i``'s prompt."""
+        import jax
+
+        with self._cache_donated():
+            if not self._prefill_warm:
+                # Both programs exist before the first session is answered,
+                # whatever its prompt's length: a chunk of no valid token
+                # compiles the chunk program and writes nothing.
+                jax.block_until_ready(self._prefill(params, 0, (), 0, 0))
+                self._prefill_warm = True
+            if i is None:
+                return
             st = slots[i].state
             done = st["cached"]
             n = min(self._chunk, st["prefill"] - done)
-            self._prefill(params, i, st["prompt"][done:done + n], done, n)
+            t0 = time.perf_counter_ns()
+            self._chunk_echo = self._prefill(
+                params, i, st["prompt"][done:done + n], done, n)
+            # With nothing ahead of it the chunk begins now; behind a step
+            # in flight, when that step's read returns (``_run_step``).
+            self._chunk_began_ns = time.perf_counter_ns()
+            # The host's launch, under the step in flight (see _SPAN_PREFILL).
+            _PREFILL_NS.inc(self._chunk_began_ns - t0)
             st["cached"] = done + n
             self.prefill_chunks += 1
             self.prefill_tokens += n
 
     def _run_step(self, slots):
-        """One call of the batcher's loop: launch the step for ``slots``,
-        THEN read the step launched by the call before and hand over its
-        results - ``(ticket, emits, done)`` for each session that step
-        stepped - or None when there is no such step yet."""
+        """One call of the batcher's loop: wait for the chunk the call
+        before dispatched, dispatch the chunk that is due, launch the step
+        for ``slots`` behind it, THEN read the step launched by the call
+        before and hand over its results - ``(ticket, emits, done)`` for
+        each session that step stepped - or None when there is no such step
+        yet."""
+        self._await_chunk()
         model = self._get_model()
         flight = self._flight
-        chunk = self._chunk_due(slots) if self._prefill_jit else None
         stepping = any(
             t is not None and t.state["pos"] < t.state["end"] for t in slots
         )
@@ -433,21 +484,26 @@ class _DecodeEngine:
             with self._cache_donated():
                 self._read_counters()
         if flight is not None and (
-            chunk is not None or not stepping
-            or model is None or model[1] is not flight.params
+            not stepping or model is None or model[1] is not flight.params
         ):
             self._flight = None
             return self._collect(flight)
         if model is None:
             raise _NoModel()
         _step, params = model
+        chunk = None
         if self._prefill_jit is not None:
+            chunk = self._chunk_due(slots)
             self._prefill_one(params, slots, chunk)
         self._flight = self._launch(params, slots)
         if flight is None:
             return None
         self.ahead_steps += 1
-        return self._collect(flight)
+        results = self._collect(flight)
+        if chunk is not None:
+            self.queued_chunks += 1
+            self._chunk_began_ns = time.perf_counter_ns()
+        return results
 
     def _launch(self, params, slots) -> _Flight:
         import jax.numpy as jnp
@@ -515,6 +571,7 @@ class _DecodeEngine:
         s["max_len"] = self.max_len
         s["prefill_chunks"] = self.prefill_chunks
         s["prefill_tokens"] = self.prefill_tokens
+        s["queued_chunks"] = self.queued_chunks
         s["held_rows"] = self.held_rows
         s["cache_rows_read"] = self.cache_rows_read
         s["ahead_steps"] = self.ahead_steps
@@ -535,17 +592,33 @@ class _DecodeEngine:
 
         self.batcher.stop()
         flight, self._flight = self._flight, None
-        if flight is not None:
+        echo, self._chunk_echo = self._chunk_echo, None
+        if flight is not None or echo is not None:
             # The step thread is gone and its sessions failed: leave no
             # work on the device behind.
             try:
-                jax.block_until_ready(flight.selection)
+                jax.block_until_ready(
+                    (echo, flight.selection if flight is not None else None))
             except Exception:  # noqa: BLE001 — a stop goes on to the end
-                log.warning("the decode step in flight at stop failed",
+                log.warning("what was on the device at stop failed",
                             exc_info=True)
         # A stopped engine holds nothing on the device (see the replica's
         # ``stop``).
         self._cache = self._selection = None
+
+
+def _echoing(model_prefill):
+    """The chunk program the engine compiles: ``model_prefill`` returning,
+    beside the cache, ``n_valid`` as it was given.  The cache is donated on
+    to the step launched behind the chunk; the echo is what the host can
+    still wait on, and where a failed chunk surfaces.  Named ``prefill_fn``:
+    the compiled program's name, ``jit_prefill_fn``, is how a device
+    trace's readers find it."""
+
+    def prefill_fn(params, cache, tokens, slot, offset, n_valid):
+        return model_prefill(params, cache, tokens, slot, offset, n_valid), n_valid
+
+    return prefill_fn
 
 
 def _selecting(model_step):

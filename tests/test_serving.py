@@ -2026,28 +2026,36 @@ def test_a_failed_launch_fails_the_active_sessions_and_leaves_nothing_in_flight(
         eng.stop()
 
 
+def _watch_chunks(eng) -> list:
+    """Record, for every chunk dispatched (not the chunk of no token that
+    compiles the program), its slot, offset and count and the step that was
+    in flight at its dispatch."""
+    chunks: list = []
+    prefill_jit = eng._prefill_jit
+
+    def watched(params, cache, tokens, slot, offset, n_valid):
+        if n_valid:
+            chunks.append((int(slot), int(offset), int(n_valid), eng._flight))
+        return prefill_jit(params, cache, tokens, slot, offset, n_valid)
+
+    eng._prefill_jit = watched
+    return chunks
+
+
 @pytest.mark.parametrize("family", ["prefilled", "state"])
-def test_a_call_with_a_chunk_due_collects_and_launches_nothing_ahead(
+def test_a_chunk_due_is_queued_behind_the_step_in_flight_and_the_next_step_behind_it(
     monkeypatch, family,
 ):
-    """A chunk runs with nothing queued ahead of it: the call that finds
-    one due while a step is in flight only reads that step; the next runs
-    the chunk and launches, not ahead.  So ``prefill_chunks`` and
-    ``ahead_steps`` never both rise in one call, and no step is in flight
-    when a chunk is launched."""
+    """A chunk is one more member of the device's queue: the call that finds
+    one due dispatches it behind the step in flight, launches the next step
+    behind it and then reads the step in flight - so ``prefill_chunks`` and
+    ``ahead_steps`` rise in ONE call.  Only a parked engine's first chunk
+    finds nothing in flight.  The tokens are the plain stream's."""
     fns = _toy_cached_decode_fns() if family == "prefilled" else _toy_state_decode_fns()
     gate = threading.Event()
     eng = _ahead_engine(monkeypatch, fns, gate)
     calls = _watch_calls(eng)
-    prefill_jit = eng._prefill_jit
-    in_flight: list = []
-
-    def watched_chunk(params, cache, tokens, slot, offset, n_valid):
-        if n_valid:  # not the chunk of no token that compiles the program
-            in_flight.append(eng._flight)
-        return prefill_jit(params, cache, tokens, slot, offset, n_valid)
-
-    eng._prefill_jit = watched_chunk
+    chunks = _watch_chunks(eng)
     stream = _toy_cached_stream if family == "prefilled" else _toy_state_stream
     try:
         tickets = [
@@ -2057,21 +2065,238 @@ def test_a_call_with_a_chunk_due_collects_and_launches_nothing_ahead(
         gate.set()
         _wait_done(tickets, 60)
         stats = eng.stats()
+        assert eng._chunk_echo is None
     finally:
         eng.stop()
     for t, p, n in zip(tickets, _AHEAD_PROMPTS, _AHEAD_BUDGETS):
         assert t.snapshot(0) == (stream(p, n), True)
     # 2, 4 and 9 prompt tokens in chunks of 4; the long session decodes
     # under every one of them but the first.
-    assert stats["prefill_chunks"] == 1 + 1 + 3 == len(in_flight)
-    assert in_flight == [None] * 5
-    assert stats["ahead_steps"] > 0
+    assert stats["prefill_chunks"] == 1 + 1 + 3 == len(chunks)
+    in_flight = [flight for _slot, _offset, _n, flight in chunks]
+    assert in_flight[0] is None and None not in in_flight[1:]
+    assert stats["queued_chunks"] == 4
     rose = [
         (after[0] > before[0], after[1] > before[1])
         for _slots, before, after in calls
     ]
-    assert (True, False) in rose and (False, True) in rose
-    assert (True, True) not in rose
+    assert [r for r in rose if r[0]] == [(True, False)] + [(True, True)] * 4
+    assert (False, True) in rose  # and ordinary calls launch ahead as before
+
+
+class _FailedEcho:
+    """What a chunk that failed on the device leaves the host to wait on."""
+
+    def block_until_ready(self):
+        raise FloatingPointError("chunk failed on the device, seen at the wait")
+
+
+@pytest.mark.parametrize("where", ["dispatch", "wait"])
+def test_a_failed_queued_chunk_fails_the_sessions_and_leaves_nothing_outstanding(
+    monkeypatch, where,
+):
+    """A chunk queued behind a step in flight that raises at its dispatch,
+    or that fails on the device and surfaces at the next call's wait: every
+    active session fails, the engine is left a fresh cache, nothing in
+    flight and no chunk outstanding, and the next session is served token
+    for token."""
+    import jax.numpy as jnp
+
+    gate = threading.Event()
+    eng = _ahead_engine(monkeypatch, _toy_cached_decode_fns(), gate)
+    prefill_jit = eng._prefill_jit
+    in_flight: list = []
+
+    def flaky_chunk(params, cache, tokens, slot, offset, n_valid):
+        if n_valid and eng._flight is not None and not in_flight:
+            in_flight.append(eng._flight)
+            if where == "dispatch":
+                raise FloatingPointError("chunk failed at its dispatch")
+            return prefill_jit(params, cache, tokens, slot, offset, n_valid)[0], _FailedEcho()
+        return prefill_jit(params, cache, tokens, slot, offset, n_valid)
+
+    eng._prefill_jit = flaky_chunk
+    try:
+        first = eng._cache
+        tickets = [
+            eng.open(np.array([1, 2, 3], np.int32), 9),
+            eng.open((np.arange(10, dtype=np.int32) * 2 + 3) % 11, 4),
+        ]
+        gate.set()
+        _wait_done(tickets)
+        for t in tickets:
+            with pytest.raises(FloatingPointError, match=where):
+                t.snapshot(0)
+        assert len(in_flight) == 1  # a step was out and not read
+        assert eng._flight is None and eng._chunk_echo is None
+        assert isinstance(eng._cache, jnp.ndarray) and not eng._cache.is_deleted()
+        assert eng._cache is not first and not eng._selection.is_deleted()
+        prompts = [[1, 2, 3], (np.arange(10) * 2 + 3) % 11]
+        again = _run_sessions(eng, prompts, [5, 3])
+        assert again == [_toy_cached_stream(p, n) for p, n in zip(prompts, [5, 3])]
+        assert eng.stats()["step_errors"] == 1
+    finally:
+        eng.stop()
+
+
+def test_every_chunk_is_measured_once_and_queued_unless_the_engine_was_parked(
+    monkeypatch,
+):
+    """``decode/prefill/n`` rises by exactly ``prefill_chunks`` - each chunk
+    is waited for once, at the top of the call after its dispatch - with its
+    time on ``decode/prefill/ns``; ``queued_chunks`` is ``prefill_chunks``
+    less the chunks that found the engine parked."""
+    from distributed_tensorflow_examples_tpu.utils import telemetry
+
+    eng = _ahead_engine(monkeypatch, _toy_cached_decode_fns())
+    chunks = _watch_chunks(eng)
+    n, ns = (telemetry.REGISTRY.counter(f"decode/prefill/{k}") for k in ("n", "ns"))
+    n0, ns0 = n.value, ns.value
+    try:
+        # Two waves with a park between them: the second's first chunk finds
+        # nothing in flight, its later ones the step launched behind the
+        # chunk before them.
+        for prompts, budgets in (
+            (_AHEAD_PROMPTS, _AHEAD_BUDGETS), ([np.arange(14) % 11], [3]),
+        ):
+            _run_sessions(eng, prompts, budgets)
+            deadline = time.monotonic() + 10
+            while eng.stats()["slots_active"]:
+                assert time.monotonic() < deadline
+                time.sleep(0.005)
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    parked = sum(1 for *_c, flight in chunks if flight is None)
+    assert stats["prefill_chunks"] == len(chunks) == 5 + 4
+    assert n.value - n0 == stats["prefill_chunks"] and ns.value > ns0
+    assert 1 <= parked <= 3  # a wave's first chunk; _run_sessions opens one at a time
+    assert stats["queued_chunks"] == stats["prefill_chunks"] - parked
+
+
+def test_a_held_row_is_not_live_in_any_step_dispatched_before_its_last_chunk(
+    monkeypatch,
+):
+    """With chunks and steps queued behind one another, a state model's row
+    is live only in steps DISPATCHED after its prompt's last chunk: in every
+    step between a session's first chunk and its last the row is held, and
+    the step right behind the last chunk decodes it."""
+    eng = _ahead_engine(monkeypatch, _toy_state_decode_fns())
+    order: list = []
+    step_jit, prefill_jit = eng._step_jit, eng._prefill_jit
+
+    def logged_step(*a):
+        order.append(("step", np.asarray(a[-1]).copy()))
+        return step_jit(*a)
+
+    def logged_chunk(params, cache, tokens, slot, offset, n_valid):
+        if n_valid:
+            order.append(("chunk", int(slot), int(offset) + int(n_valid)))
+        return prefill_jit(params, cache, tokens, slot, offset, n_valid)
+
+    eng._step_jit, eng._prefill_jit = logged_step, logged_chunk
+    prompts = [[7, 3, 9], (np.arange(14) * 5 + 1) % 11, (np.arange(10) * 2 + 3) % 11]
+    budgets = [16, 3, 4]
+    try:
+        outs = _run_sessions(eng, prompts, budgets)
+    finally:
+        eng.stop()
+    for p, o, n in zip(prompts, outs, budgets):
+        assert o == _toy_state_stream(p, n)
+    # The two long prompts pass through slot 1, 13 and 9 tokens in chunks of
+    # 4, while slot 0 decodes.
+    sessions, first = [], None
+    for k, entry in enumerate(order):
+        if entry[0] == "chunk" and entry[1] == 1:
+            first = k if first is None else first
+            if entry[2] in (13, 9) and (first, k) not in sessions:
+                sessions.append((first, k))
+                first = None
+    assert [order[last][2] for _first, last in sessions] == [13, 9]
+    for first, last in sessions:
+        assert last - first >= 4  # chunks and steps alternate
+        between = [e[1] for e in order[first:last] if e[0] == "step"]
+        assert between and not any(live[1] for live in between)
+        assert order[last + 1][0] == "step" and order[last + 1][1][1]
+
+
+def test_stop_with_a_chunk_outstanding_leaves_nothing_on_the_device(monkeypatch):
+    """The loop ends between the call that dispatched a chunk and the call
+    that would have waited for it: ``stop()`` waits for the chunk and for the
+    step launched behind it, and the engine holds nothing afterwards."""
+    eng = _ahead_engine(monkeypatch, _toy_cached_decode_fns())
+    prefill_jit, run = eng._prefill_jit, eng.batcher._run
+    waited: list = []
+
+    class Echo:
+        def __init__(self, echo):
+            self.echo = echo
+
+        def block_until_ready(self):
+            waited.append(self.echo.block_until_ready())
+
+    def echoing(params, cache, tokens, slot, offset, n_valid):
+        cache, echo = prefill_jit(params, cache, tokens, slot, offset, n_valid)
+        return cache, (Echo(echo) if n_valid else echo)
+
+    def last_call(slots):
+        try:
+            return run(slots)
+        finally:
+            if eng._chunk_echo is not None:
+                eng.batcher._stopped = True  # the loop ends before its next call
+
+    eng._prefill_jit, eng.batcher._run = echoing, last_call
+    ticket = eng.open(np.arange(1, 11, dtype=np.int32), 3)
+    _wait_done([ticket])
+    assert ticket.error is not None  # the stopped batcher failed it
+    eng.batcher._thread.join(10)
+    assert not eng.batcher._thread.is_alive()
+    flight = eng._flight
+    assert eng._chunk_echo is not None and flight is not None and not waited
+    eng.stop()
+    assert [int(n) for n in waited] == [4]
+    assert flight.selection.is_ready()
+    assert eng._chunk_echo is None and eng._flight is None and eng._cache is None
+
+
+@pytest.mark.parametrize("metric", ["chunk_queued_share", "batch_chunk_queued_share"])
+def test_the_chunk_queued_metrics_name_a_reader_and_counters_that_exist(
+    tmp_path, metric,
+):
+    """The metric files this engine's counter came with: the reader each
+    names is there, and ``server.stats()`` of a replica with a ``prefill_fn``
+    carries the counters it divides - so the reader finds something to read
+    (and nothing, without raising, where the counter is absent)."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.harness import manifest
+
+    spec = manifest.layer_metric(metric)
+    read = manifest.reader(spec["reader"])
+    assert spec["args"] == {
+        "num": "decode_queued_chunks", "den": "decode_prefill_chunks"}
+    srv = _pinned_decode_server(tmp_path, "cq0", decode_fns=_toy_cached_decode_fns())
+    try:
+        c = serve.ServeClient("127.0.0.1", srv.port, role="cq_sv")
+        start = c.stats()
+        c.generate(np.arange(1, 30, dtype=np.int32) % 11, 2)
+        end = c.stats()
+        c.close()
+    finally:
+        srv.stop()
+    for key in spec["args"].values():
+        assert key in end
+    # 28 prompt tokens in one chunk of the replica's 32 positions, parked.
+    assert end["decode_prefill_chunks"] - start["decode_prefill_chunks"] == 1
+    assert read({"counters": {"start": start, "end": end}}, **spec["args"]) == 0.0
+    for stats in (start, end):
+        del stats["decode_queued_chunks"]  # the parent's replica: no such counter
+    assert read({"counters": {"start": start, "end": end}}, **spec["args"]) is None
 
 
 # ----------------------------------------------------------------------------
