@@ -16,6 +16,12 @@ traced function XLA can fuse end-to-end.
 - ``jamba``    — Mamba-1 + attention hybrid (AI21 Jamba), served
 - ``longcat``  — latent attention + a chip's share of a dropless expert
   layer with zero-compute experts (Meituan LongCat-Flash), served
+- ``deepseek`` — latent attention with scaled rotary positions, a choice of
+  experts limited to groups, shared experts (DeepSeek-V2), served
+- ``mla``      — the latent-attention sub-layer ``longcat`` and ``deepseek``
+  share
+- ``decoding`` — the generate loop of a model served by chunks and steps,
+  which the same two call
 """
 
 from . import layers  # noqa: F401
@@ -26,4 +32,7 @@ from . import word2vec  # noqa: F401
 from . import lstm  # noqa: F401
 from . import transformer  # noqa: F401
 from . import jamba  # noqa: F401
+from . import mla  # noqa: F401
+from . import decoding  # noqa: F401
 from . import longcat  # noqa: F401
+from . import deepseek  # noqa: F401

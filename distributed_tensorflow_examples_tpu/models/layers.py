@@ -12,6 +12,7 @@ published in, models/jamba.py) that is the MXU's native product.
 
 from __future__ import annotations
 
+import math
 from typing import Sequence
 
 import jax
@@ -138,13 +139,53 @@ def gated_mlp(params, x, *, dtype):
 # ----------------------------------------------------------------------------
 
 
-def rope_angles(pos, dim: int, theta: float):
-    """``(cos, sin)``, each ``pos.shape + (dim // 2,)`` float32: pair ``i``
-    of a ``dim``-wide vector at position ``p`` turns by ``p * theta ** (-2 i
-    / dim)``.  No scaling of long contexts."""
-    inv = jnp.exp(-jnp.log(theta) * jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    a = jnp.asarray(pos, jnp.float32)[..., None] * inv
+def rope_frequencies(dim: int, theta: float):
+    """``[dim // 2]`` float32: pair ``i`` of a ``dim``-wide vector turns by
+    ``theta ** (-2 i / dim)`` a position.  No scaling of long contexts."""
+    return jnp.exp(-jnp.log(theta) * jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+
+
+def yarn_mscale(factor: float, a: float) -> float:
+    """``m(a) = 0.1 a ln(factor) + 1`` (1 where nothing is stretched): what
+    YaRN multiplies attention's logits by, once from the query's side and
+    once from the key's."""
+    return 1.0 if factor <= 1 else 0.1 * a * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, original_max: int,
+                          beta_fast: float, beta_slow: float) -> tuple[int, int]:
+    """``(low, high)``: the pairs that turn ``beta_fast`` / ``beta_slow``
+    times over ``original_max`` positions, rounded outwards and clipped to
+    the vector."""
+    corr = lambda r: dim * math.log(original_max / (2 * math.pi * r)) / (2 * math.log(theta))
+    return max(math.floor(corr(beta_fast)), 0), min(math.ceil(corr(beta_slow)), dim - 1)
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float, original_max: int,
+                     beta_fast: float, beta_slow: float):
+    """:func:`rope_frequencies` scaled for contexts ``factor`` times the
+    ``original_max`` positions trained on (YaRN, as DeepSeek-V2 publishes
+    it): pairs up to ``low`` keep their frequency (they turn often enough
+    over the original context to be told apart at any length), pairs from
+    ``high`` on turn ``factor`` times slower (plain interpolation), those
+    between are blended linearly."""
+    f = rope_frequencies(dim, theta)
+    low, high = yarn_correction_range(dim, theta, original_max, beta_fast, beta_slow)
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return f * (1.0 - ramp) + (f / factor) * ramp
+
+
+def rope_angles_at(pos, inv_freq):
+    """``(cos, sin)``, each ``pos.shape + inv_freq.shape`` float32, of the
+    angles ``pos x inv_freq``."""
+    a = jnp.asarray(pos, jnp.float32)[..., None] * inv_freq
     return jnp.cos(a), jnp.sin(a)
+
+
+def rope_angles(pos, dim: int, theta: float):
+    """:func:`rope_angles_at` the unscaled frequencies of ``(dim, theta)``."""
+    return rope_angles_at(pos, rope_frequencies(dim, theta))
 
 
 def rope_interleaved(x, cos, sin):

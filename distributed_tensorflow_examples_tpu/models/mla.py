@@ -1,0 +1,310 @@
+"""Latent attention (MLA): one sub-layer, as the models that have it share it.
+
+``H`` heads, for a normed ``h [.., D]``:
+
+    cq        = N(q_a . h) * q_scale                       [q_lora_rank]
+    q         = q_b . cq                 -> H x (nope + rope)
+    ckv | kr  = kv_a . h                 [kv_lora_rank + rope]
+    c         = N(ckv) * kv_scale
+    k_nope|v  = kv_b . c                 -> H x (nope + v_dim)
+    k         = [k_nope | rope(kr)]      (ONE kr for all heads)
+    o         = o . softmax(softmax_scale x rope-d q . k) v
+
+(scaling ``cq`` scales both parts of ``q``).  Rotary pairs are interleaved
+(``layers.rope_interleaved``) and turn by ``pos x inv_freq``.  What differs
+between the models is in :class:`Spec`: the widths, the two rank scales, the
+rotary frequencies, the softmax's scale, the blocks.
+
+What a position leaves behind is ``c`` (normed and scaled) and the rotated
+``kr``: ``kv_lora_rank + rope`` values (:attr:`Spec.latent`).  A sub-layer's
+cache is ``[slots, max_len, latent]`` in ``dtype`` and NEVER holds an
+expanded key or value.
+
+Two forms of the same attention:
+
+- the one-token step ABSORBS ``kv_b`` into the query and the output
+  (:func:`attend_absorbed`): ``q_nope . W_uk`` against ``c``, the weighted
+  sum of ``c`` then through ``W_uv`` - ``H`` query heads over ONE latent row
+  a position, whose first ``kv_lora_rank`` columns are the values too.  The
+  cache is read a block of positions at a time as a running softmax, no
+  further than the deepest row (:func:`decode_rows_read`); the new row is
+  written in place (:func:`write_rows`).
+- the prefill chunk and the full forward EXPAND a block of cached latents
+  at a time into keys and values (``kv_b . c``) and attend as published
+  (:func:`attend_expanded`; :func:`chunk_write` puts a chunk's rows into
+  one slot).
+
+Precision: parameters in ``dtype``; products in it with float32
+accumulation; norms, rotary and softmax in float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from . import layers
+
+
+@dataclasses.dataclass(frozen=True)
+class Spec:
+    """One model's latent attention.  ``inv_freq`` is an array (build the
+    spec inside the traced program that uses it); nothing hashes a spec."""
+
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    nope: int  # a head's query/key values that carry no position
+    rope: int  # ... and those that are rotated
+    v_dim: int
+    q_scale: float  # multiplies the normed query latent
+    kv_scale: float  # multiplies the normed key/value latent
+    softmax_scale: float
+    inv_freq: Any  # [rope // 2] float32: radians a position, by pair
+    eps: float
+    dtype: Any
+    #: Cache positions one trip of the step's loop reads (or the whole
+    #: cache, where that is shorter): a larger block trades positions read
+    #: past the deepest row (half a block of every slot) against trips.
+    decode_block: int
+    #: Cached positions a chunk expands and attends over at a time.
+    prefill_block: int
+
+    @property
+    def latent(self) -> int:
+        """Values a position leaves in the cache."""
+        return self.kv_lora_rank + self.rope
+
+
+def init(spec: Spec, hidden: int, rng: jax.Array, *, std: float, out_std: float):
+    """A sub-layer's leaves: kernels normal ``std`` (``o``, which writes the
+    residual stream, ``out_std``), norms 1; all in ``spec.dtype``."""
+    dt, H = spec.dtype, spec.heads
+    k = jax.random.split(rng, 5)
+    normal = lambda key, shape, s=std: (s * jax.random.normal(key, shape)).astype(dt)
+    return {
+        "q_a": {"kernel": normal(k[0], (hidden, spec.q_lora_rank))},
+        "q_norm": layers.rmsnorm_init(spec.q_lora_rank, dt),
+        "q_b": {"kernel": normal(k[1], (spec.q_lora_rank, H * (spec.nope + spec.rope)))},
+        "kv_a": {"kernel": normal(k[2], (hidden, spec.latent))},
+        "kv_norm": layers.rmsnorm_init(spec.kv_lora_rank, dt),
+        "kv_b": {"kernel": normal(k[3], (spec.kv_lora_rank, H * (spec.nope + spec.v_dim)))},
+        "o": {"kernel": normal(k[4], (H * spec.v_dim, hidden), out_std)},
+    }
+
+
+def _mm(spec: Spec, p, x):
+    """``x @ kernel``: operands in ``dtype``, float32 out."""
+    return layers.dense(p, x.astype(spec.dtype))
+
+
+def out_proj(spec: Spec, p, o):
+    """The heads' results ``[.., H x v_dim]`` back to the residual stream."""
+    return _mm(spec, p["o"], o)
+
+
+def query_and_latent(spec: Spec, p, h, pos):
+    """From the normed ``h [.., D]`` at positions ``pos [..]``: the query
+    ``q_nope [.., H, nope]``, ``q_rope [.., H, rope]`` (rotated) and the
+    position's cache row ``[.., latent]`` = ``c | rotated kr``, all in
+    ``dtype``."""
+    H, nope, rope, R = spec.heads, spec.nope, spec.rope, spec.kv_lora_rank
+    cq = layers.rmsnorm(p["q_norm"], _mm(spec, p["q_a"], h), spec.eps) * spec.q_scale
+    q = _mm(spec, p["q_b"], cq).reshape(h.shape[:-1] + (H, nope + rope))
+    ckv = _mm(spec, p["kv_a"], h)
+    c = layers.rmsnorm(p["kv_norm"], ckv[..., :R], spec.eps) * spec.kv_scale
+    cos, sin = layers.rope_angles_at(pos, spec.inv_freq)
+    q_rope = layers.rope_interleaved(q[..., nope:], cos[..., None, :], sin[..., None, :])
+    kr = layers.rope_interleaved(ckv[..., R:], cos, sin)
+    row = jnp.concatenate([c, kr], axis=-1).astype(spec.dtype)
+    return q[..., :nope].astype(spec.dtype), q_rope.astype(spec.dtype), row
+
+
+def _kv_b(spec: Spec, p):
+    """``kv_b`` by head: ``(W_uk [R, H, nope], W_uv [R, H, v_dim])``."""
+    w = p["kv_b"]["kernel"].reshape(spec.kv_lora_rank, spec.heads, spec.nope + spec.v_dim)
+    return w[..., :spec.nope], w[..., spec.nope:]
+
+
+def attend_expanded(spec: Spec, p, q_nope, q_rope, rows, q_pos, n_blocks, block):
+    """Queries ``[C, H, .]`` at positions ``q_pos [C]`` of ONE sequence
+    against its cached rows ``rows [T, latent]``: the first ``n_blocks``
+    blocks of ``block`` positions (``n_blocks`` may be traced), each expanded
+    into keys and values and folded into a running softmax; a query sees the
+    positions ``<=`` its own.  Returns ``[C, H x v_dim]`` float32.  Block
+    ``i`` holds positions ``[i block, (i + 1) block)``; where ``T`` is no
+    multiple of the block the last one is read shifted back inside the cache
+    and what it shares with the block before is masked."""
+    T, R = rows.shape[0], spec.kv_lora_rank
+    C, H = q_nope.shape[:2]
+    w_uk, w_uv = _kv_b(spec, p)
+    f32 = jnp.float32
+
+    def body(i, carry):
+        m, l, acc = carry
+        start = jnp.minimum(i * block, T - block)
+        blk = jax.lax.dynamic_slice_in_dim(rows, start, block, axis=0)
+        c, kr = blk[:, :R], blk[:, R:]
+        k = jnp.einsum("tr,rhd->thd", c, w_uk,
+                       preferred_element_type=f32).astype(spec.dtype)
+        v = jnp.einsum("tr,rhd->thd", c, w_uv,
+                       preferred_element_type=f32).astype(spec.dtype)
+        s = jnp.einsum("qhd,thd->hqt", q_nope, k, preferred_element_type=f32)
+        s = s + jnp.einsum("qhd,td->hqt", q_rope, kr, preferred_element_type=f32)
+        s = s * spec.softmax_scale
+        t = start + jnp.arange(block)
+        own = (t >= i * block)[None, :] & (t[None, :] <= q_pos[:, None])
+        s = jnp.where(own[None], s, -jnp.inf)
+        # Block 0 holds position 0, which every query sees: the maximum is
+        # finite from the first trip on.
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        w = jnp.exp(s - m_new)
+        r = jnp.exp(m - m_new)
+        l = l * r + w.sum(axis=-1, keepdims=True)
+        acc = acc * r + jnp.einsum(
+            "hqt,thd->hqd", w.astype(spec.dtype), v, preferred_element_type=f32)
+        return m_new, l, acc
+
+    stat = jnp.zeros((H, C, 1), f32)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_blocks, body,
+        (stat - jnp.inf, stat, jnp.zeros((H, C, spec.v_dim), f32)),
+    )
+    return jnp.moveaxis(acc / l, 0, 1).reshape(C, -1)
+
+
+def decode_rows_read(block: int, max_pos, max_len: int):
+    """Cache positions of EVERY slot that one decode step reads when its
+    deepest row stands at ``max_pos`` and its loop takes ``block`` positions
+    a trip: whole blocks up to the one that holds that position, at most the
+    cache.  The host's count of what :func:`attend_absorbed`'s loop does on
+    the device."""
+    blk = min(block, max_len)
+    return min(max_len, (max_pos // blk + 1) * blk)
+
+
+def write_rows(cache, new, pos):
+    """cache ``[S, T, latent]`` with ``new[b]`` written at position
+    ``pos[b]`` of slot ``b`` and nothing else changed: one
+    ``dynamic_update_slice`` a slot, each in place in a donated cache
+    (models/transformer.py ``_write_rows`` has the chip reading that chose
+    this over a scatter)."""
+    for b in range(cache.shape[0]):
+        cache = jax.lax.dynamic_update_slice(cache, new[b:b + 1, None], (b, pos[b], 0))
+    return cache
+
+
+def attend_absorbed(spec: Spec, p, q_nope, q_rope, cache, pos):
+    """One query a slot against that slot's latent rows: ``q_nope [S, H,
+    nope]``, ``q_rope [S, H, rope]``, ``cache [S, T, latent]``, ``pos [S]``
+    -> ``[S, H x v_dim]`` float32; slot ``b`` attends over its positions
+    ``<= pos[b]``.  ``kv_b`` never touches the cache: its key half goes into
+    the query (``q_nope . W_uk``, then ONE product of ``[q_lat | q_rope]``
+    against the latent-wide row), its value half comes after the weighted
+    sum of the rows' first ``kv_lora_rank`` columns.  The loop is
+    models/transformer.py ``_decode_attention``'s: a block of positions a
+    trip, ``max(pos) // block + 1`` trips, a block wholly past a row's
+    position an exact no-op for that row."""
+    S, T, _ = cache.shape
+    R, H = spec.kv_lora_rank, spec.heads
+    blk = min(spec.decode_block, T)
+    f32 = jnp.float32
+    w_uk, w_uv = _kv_b(spec, p)
+    q_lat = jnp.einsum("shd,rhd->shr", q_nope, w_uk, preferred_element_type=f32)
+    q = jnp.concatenate([q_lat.astype(spec.dtype), q_rope], axis=-1)  # [S, H, latent]
+
+    def body(i, carry):
+        m, l, acc = carry
+        start = jnp.minimum(i * blk, T - blk)
+        rows = jax.lax.dynamic_slice_in_dim(cache, start, blk, axis=1)
+        s = jnp.einsum("shc,stc->sht", q, rows, preferred_element_type=f32)
+        s = s * spec.softmax_scale
+        t = start + jnp.arange(blk)
+        own = (t >= i * blk)[None, :] & (t[None, :] <= pos[:, None])
+        s = jnp.where(own[:, None, :], s, -jnp.inf)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        w = jnp.exp(s - m_new)
+        r = jnp.exp(m - m_new)
+        l = l * r + w.sum(axis=-1, keepdims=True)
+        acc = acc * r + jnp.einsum(
+            "sht,str->shr", w.astype(spec.dtype), rows[..., :R],
+            preferred_element_type=f32)
+        return m_new, l, acc
+
+    stat = jnp.zeros((S, H, 1), f32)
+    _, l, acc = jax.lax.fori_loop(
+        0, jnp.max(pos) // blk + 1, body,
+        (stat - jnp.inf, stat, jnp.zeros((S, H, R), f32)),
+    )
+    o = jnp.einsum("shr,rhd->shd", (acc / l).astype(spec.dtype), w_uv,
+                   preferred_element_type=f32)
+    return o.reshape(S, -1)
+
+
+def chunk_write(cache, new, slot, offset, n_valid):
+    """Write ``new [C, latent]`` rows ``[0, n_valid)`` into ``cache [S, T,
+    latent]`` at ``[slot, offset:offset + n_valid]`` and touch nothing else;
+    returns the cache and the slot's rows ``[T, latent]``.  The window
+    starts at ``min(offset, T - C)`` (``dynamic_update_slice`` clamps a start
+    that overruns and would overwrite earlier rows), the chunk rolled inside
+    it; as models/transformer.py ``_block_prefill``."""
+    C, W = new.shape
+    T = cache.shape[1]
+    start = jnp.clip(offset, 0, T - C)
+    shift = offset - start
+    i = jnp.arange(C) - shift
+    own = ((i >= 0) & (i < n_valid))[None, :, None]
+    at = (slot, start, 0)
+    old = jax.lax.dynamic_slice(cache, at, (1, C, W))
+    win = jnp.where(own, jnp.roll(new[None], shift, axis=1), old)
+    cache = jax.lax.dynamic_update_slice(cache, win, at)
+    return cache, jax.lax.dynamic_slice_in_dim(cache, slot, 1, axis=0)[0]
+
+
+# ----------------------------------------------------------------------------
+# The sub-layer on each of the three paths
+# ----------------------------------------------------------------------------
+
+
+def forward(spec: Spec, p, h):
+    """The full forward's sub-layer: the normed ``h [B, L, D]`` -> ``[B, L,
+    D]`` float32, causal; the expanded form, a sequence at a time."""
+    L = h.shape[1]
+    pos = jnp.arange(L)
+    block = min(spec.prefill_block, L)
+    n_blocks = -(-L // block)
+    q_nope, q_rope, rows = query_and_latent(spec, p, h, pos[None])
+    with jax.named_scope("mla/prefill"):
+        o = jax.vmap(lambda qn, qr, r: attend_expanded(
+            spec, p, qn, qr, r, pos, n_blocks, block))(q_nope, q_rope, rows)
+    return out_proj(spec, p, o)
+
+
+def decode(spec: Spec, p, h, cache, pos):
+    """The one-token step's sub-layer: the normed ``h [S, D]`` at per-row
+    positions ``pos [S]`` -> (``[S, D]`` float32, the cache with each row's
+    latent written at its position)."""
+    q_nope, q_rope, row = query_and_latent(spec, p, h, pos)
+    with jax.named_scope("mla/decode"):
+        cache = write_rows(cache, row, pos)
+        o = attend_absorbed(spec, p, q_nope, q_rope, cache, pos)
+    return out_proj(spec, p, o), cache
+
+
+def prefill(spec: Spec, p, h, cache, slot, offset, n_valid):
+    """The prefill chunk's sub-layer: the normed ``h [C, D]`` of ONE slot's
+    positions ``offset .. offset + C - 1``, the first ``n_valid`` real ->
+    (``[C, D]`` float32, the cache with the valid rows written); reads no
+    further than ``offset + C``."""
+    C, T = h.shape[0], cache.shape[1]
+    block = min(spec.prefill_block, T)
+    q_pos = offset + jnp.arange(C)
+    n_blocks = jnp.minimum(offset + C - 1, T - 1) // block + 1
+    q_nope, q_rope, new = query_and_latent(spec, p, h, q_pos)
+    with jax.named_scope("mla/prefill"):
+        cache, rows = chunk_write(cache, new, slot, offset, n_valid)
+        o = attend_expanded(spec, p, q_nope, q_rope, rows, q_pos, n_blocks, block)
+    return out_proj(spec, p, o), cache

@@ -250,10 +250,60 @@ class ShareConfig:
     scale: float  # every chosen score is multiplied by it; no renormalising
     first: int  # the routed experts held here are first .. first + held - 1
     held: int
+    #: The choice limited to groups (:func:`share_choice`): expert ``e`` is
+    #: of group ``e // (n_experts // n_group)`` - a device of the deployment
+    #: - and a token chooses among its ``top_groups`` best groups only.
+    #: ``top_groups`` 0: the free choice over everything.
+    n_group: int = 0
+    top_groups: int = 0
+
+    def __post_init__(self):
+        if not self.top_groups:
+            return
+        if self.n_zero or self.n_group <= 0 or self.n_experts % self.n_group:
+            raise ValueError(
+                f"a choice limited to groups needs n_experts ({self.n_experts}) "
+                f"in n_group ({self.n_group}) equal groups and no zero-compute "
+                f"experts ({self.n_zero})")
+        per = self.n_experts // self.n_group
+        if not 0 < self.top_groups <= self.n_group or self.top_groups * per < self.top_k:
+            raise ValueError(
+                f"top_groups ({self.top_groups}) of {self.n_group} groups of {per} "
+                f"cannot give top_k ({self.top_k}) choices")
+        if self.first % per or self.held % per:
+            raise ValueError(
+                f"the held experts {self.first} .. {self.first + self.held - 1} are "
+                f"not whole groups of {per}: the share would be no device's")
 
 
 #: What :func:`apply_share` counts, each an int32 scalar.
-SHARE_COUNTS = ("choices", "choices_held", "choices_zero", "experts_touched", "calls")
+SHARE_COUNTS = ("choices", "choices_held", "choices_zero", "experts_touched",
+                "calls", "tokens_reaching")
+
+
+def share_choice(s, share: ShareConfig, bias=None):
+    """``(choice, score)``, each ``[T, top_k]``: the experts each row of the
+    scores ``s [T, n_experts + n_zero]`` float32 chooses and the score each
+    is weighted by.  Free (``top_groups`` 0): the largest of ``s + bias``
+    (``bias`` enters the CHOICE only; None: of ``s`` alone).  Limited to
+    groups (DeepSeek-V2's ``group_limited_greedy``, its device-limited
+    routing): a group's score is the largest ``s`` in it, the
+    ``top_groups`` best groups are kept, ``s`` outside them is set to 0 and
+    the ``top_k`` largest of what is left are chosen, with the scores they
+    have there."""
+    if not share.top_groups:
+        _, choice = jax.lax.top_k(s if bias is None else s + bias, share.top_k)
+        return choice, jnp.take_along_axis(s, choice, axis=1)
+    if bias is not None:
+        raise ValueError("the choice limited to groups is on the scores alone: "
+                         "the router has a bias")
+    T, G = s.shape[0], share.n_group
+    group_score = s.reshape(T, G, -1).max(axis=-1)
+    _, best = jax.lax.top_k(group_score, share.top_groups)  # [T, top_groups]
+    kept = jnp.any(best[:, :, None] == jnp.arange(G)[None, None, :], axis=1)  # [T, G]
+    kept = jnp.repeat(kept, share.n_experts // G, axis=1)
+    score, choice = jax.lax.top_k(jnp.where(kept, s, 0.0), share.top_k)
+    return choice, score
 
 
 def share_rows_block(tokens: int) -> int:
@@ -269,22 +319,24 @@ def apply_share(p, u, share: ShareConfig, live=None, *, dtype):
     zero-compute experts give.
 
     Router in float32 throughout (the product at the highest precision): ``s
-    = softmax(u . router)`` over all ``n_experts + n_zero``; the ``top_k``
-    largest of ``s + bias`` (the bias enters the CHOICE only); weights
-    ``scale * s`` of the chosen, not renormalised.  A choice on a held expert
-    becomes a row of that expert's group (sorted by expert, each group on a
-    block boundary: ops/grouped_ffn.py); a choice on a zero-compute expert
-    adds ``w u`` where the token lives; a choice on an expert that lives on
-    another chip adds NOTHING - no capacity, no dropped token, no stand-in
-    for the other chips' part.  ``p``: ``router/kernel [D, n_experts +
-    n_zero]``, ``router/bias``, ``gate, up [held, D, F]``, ``down [held, F,
-    D]``.  ``live [T]`` bool: a row that is not live (an empty slot, padding)
-    gets no expert row, a zero result and no count.  The row buffer is
-    static and holds the worst case, ``top_k x T`` choices all held.
+    = softmax(u . router)`` over all ``n_experts + n_zero``; the choice is
+    :func:`share_choice`'s (free over ``s + bias``, or limited to groups);
+    weights ``scale * s`` of the chosen, not renormalised.  A choice on a
+    held expert becomes a row of that expert's group (sorted by expert, each
+    group on a block boundary: ops/grouped_ffn.py); a choice on a
+    zero-compute expert adds ``w u`` where the token lives; a choice on an
+    expert that lives on another chip adds NOTHING - no capacity, no dropped
+    token, no stand-in for the other chips' part.  ``p``: ``router/kernel
+    [D, n_experts + n_zero]``, ``router/bias`` (or none: the choice is on
+    ``s`` alone), ``gate, up [held, D, F]``, ``down [held, F, D]``.  ``live
+    [T]`` bool: a row that is not live (an empty slot, padding) gets no
+    expert row, a zero result and no count.  The row buffer is static and
+    holds the worst case, ``top_k x T`` choices all held.
 
     ``counts`` (:data:`SHARE_COUNTS`): the live rows' choices, those on
     held and on zero-compute experts, the held experts with at least one
-    row, and 1 for the call."""
+    row, 1 for the call, and the live rows with at least one held choice
+    (``tokens_reaching``: what the deployment's exchange would send here)."""
     T, D = u.shape
     k, E, held = share.top_k, share.n_experts, share.held
     f32 = jnp.float32
@@ -295,8 +347,10 @@ def apply_share(p, u, share: ShareConfig, live=None, *, dtype):
             precision=jax.lax.Precision.HIGHEST,
         )
         s = jax.nn.softmax(logits, axis=-1)
-        _, choice = jax.lax.top_k(s + p["router"]["bias"].astype(f32), k)  # [T, k]
-        w = share.scale * jnp.take_along_axis(s, choice, axis=1)
+        bias = p["router"].get("bias")
+        choice, chosen = share_choice(  # [T, k]
+            s, share, None if bias is None else bias.astype(f32))
+        w = share.scale * chosen
         local = choice - share.first
         on_held = (local >= 0) & (local < held) & live[:, None]
         on_zero = (choice >= E) & live[:, None]
@@ -328,6 +382,26 @@ def apply_share(p, u, share: ShareConfig, live=None, *, dtype):
     counts = {
         "choices": k * count(live), "choices_held": count(on_held),
         "choices_zero": count(on_zero), "experts_touched": count(sizes > 0),
-        "calls": jnp.int32(1),
+        "calls": jnp.int32(1), "tokens_reaching": count(jnp.any(on_held, axis=1)),
     }
     return m, counts
+
+
+def share_counters(counts, chunk_counts=()) -> dict:
+    """What a model that calls :func:`apply_share_counted` keeps in its
+    cache tree's ``counters`` entry: a zeroed ``moe_<name>`` for each of
+    ``counts`` (of :data:`SHARE_COUNTS`) and a ``moe_chunk_<name>`` for each
+    the prefill chunk keeps a second time."""
+    names = [f"moe_{n}" for n in counts] + [f"moe_chunk_{n}" for n in chunk_counts]
+    return {name: jnp.zeros((), jnp.int32) for name in names}
+
+
+def apply_share_counted(p, u, share: ShareConfig, live, counters, *,
+                        chunk_counts=(), dtype):
+    """:func:`apply_share` and ``counters`` (:func:`share_counters`) with
+    this call's counts added to the entries it has - a prefill chunk names
+    the ``chunk_counts`` it keeps a second time."""
+    m, counts = apply_share(p, u, share, live, dtype=dtype)
+    added = {f"moe_{k}": v for k, v in counts.items()}
+    added.update({f"moe_chunk_{k}": counts[k] for k in chunk_counts})
+    return m, {k: v + added.get(k, 0) for k, v in counters.items()}

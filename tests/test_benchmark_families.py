@@ -13,6 +13,8 @@ import importlib.util
 import os
 import sys
 
+import pytest
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
@@ -60,4 +62,38 @@ def test_an_altered_selection_is_caught_by_the_serve_cells_comparison(monkeypatc
     cell = rehearse.shrink(manifest.Cell("cgpt13b-serve-batch"))
     out = serve_cell.run(cell, 4, 3.0, False, time.monotonic())
     assert out["check"]["positions"] > 0 and out["failed"] == 0
+    assert out["correct"] is False
+
+
+@pytest.mark.parametrize("roll", [r"layer_\d+/moe/down", r"layer_1/moe/down"])
+def test_mixed_up_expert_weights_are_caught_by_the_deepseek_cells_comparison(roll):
+    """The control of the ROUTED part, which weighs a fifth of the shared
+    experts' in this cell's logits (its configuration's ``assumed``): the
+    rehearsal served with every held expert computing with its neighbour's
+    matrix (``benchmarks/tools/planted.py``: the matched leaves rolled by
+    one along the experts' axis) - ``down`` in both expert layers, or in
+    one - has to come out of the cell's comparison as not correct.  At this
+    size the sound program reads 0.001-0.025 and these 0.25-0.37 over three
+    seeds each against the limit of 0.1 (``gate`` mixed up in ONE layer
+    reads 0.10-0.19, too near the limit to hold a test); what the real size
+    reads is in ``PERF.md`` section 2."""
+    import time
+
+    from benchmarks import rehearse
+    from benchmarks.harness import manifest, serve_cell
+    from benchmarks.tools import planted
+
+    cell = rehearse.shrink(manifest.Cell("deepseek-v2-serve-gen"))
+    family, build, seen = cell.family, cell.family.build, []
+
+    def build_planted(config, *a, **kw):
+        cfg, tree_fn = build(config, *a, **kw)
+        return cfg, planted.rolled(tree_fn, roll, seen)
+
+    family.build = build_planted
+    try:
+        out = serve_cell.run(cell, 5, 3.0, False, time.monotonic())
+    finally:
+        family.build = build
+    assert seen and out["check"]["positions"] > 0 and out["failed"] == 0
     assert out["correct"] is False

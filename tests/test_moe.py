@@ -488,4 +488,212 @@ def test_a_token_with_no_held_choice_gets_exactly_its_zero_compute_part():
     assert not np.asarray(m)[7:].any()
     assert {k: int(v) for k, v in counts.items()} == {
         "choices": 42, "choices_held": 0, "choices_zero": 14,
-        "experts_touched": 0, "calls": 1}
+        "experts_touched": 0, "calls": 1, "tokens_reaching": 0}
+
+
+# ----------------------------------------------------------------------------
+# The choice limited to groups (DeepSeek-V2's device-limited routing)
+# ----------------------------------------------------------------------------
+
+import dataclasses  # noqa: E402
+
+from benchmarks.reference import deepseek_ref  # noqa: E402
+from distributed_tensorflow_examples_tpu.models import layers  # noqa: E402
+
+#: 32 routed experts in 4 groups (a rank each), 2 groups and 6 choices a
+#: token, two shared experts.
+C_GROUPS = dict(
+    hidden_size=64, intermediate_size=128, moe_intermediate_size=32, num_hidden_layers=2,
+    first_k_dense_replace=1, moe_layer_freq=1, num_attention_heads=4, kv_lora_rank=32,
+    q_lora_rank=48, qk_rope_head_dim=8, qk_nope_head_dim=16, v_head_dim=16,
+    n_routed_experts=32, n_shared_experts=2, n_group=4, topk_group=2,
+    num_experts_per_tok=6, routed_scaling_factor=16.0, vocab_size=100, rms_norm_eps=1e-6,
+    init_std=0.125,
+)
+
+
+def _grouped(first=0, held=32, **over):
+    return moe_ops.ShareConfig(**dict(dict(
+        n_experts=32, n_zero=0, top_k=6, scale=16.0, first=first, held=held,
+        n_group=4, top_groups=2), **over))
+
+
+def _grouped_layer(first, held, seed=13):
+    """Layer 1's ``moe`` leaves for ``held`` experts from ``first`` on and
+    its ``shared`` leaves, as the reference seeds them, in float32."""
+    key = ref_weights.base_key(seed)
+    p = deepseek_ref.build(deepseek_ref.layer_spec(C_GROUPS, "moe"), key, layer=1)
+    p["moe"].update(jax.vmap(lambda e: deepseek_ref.expert(C_GROUPS, key, 1, e))(
+        first + jnp.arange(held)))
+    return jax.tree.map(lambda a: a.astype(jnp.float32), p)
+
+
+def test_the_grouped_shares_of_four_ranks_and_the_shared_expert_once_are_the_uncut_layer():
+    """THE SHARE TIES TO THE MODEL: the routed parts that ranks 0-3 compute,
+    a group of 8 experts each from one seed, plus the shared experts counted
+    ONCE, are the uncut reference layer; every token's held choices lie on
+    at most ``top_groups`` = 2 ranks.  In float32 on both sides: 2e-5 is
+    the order of the sums, where a wrongly kept group changes the layer by
+    0.1 and more."""
+    u = jax.random.normal(jax.random.key(3), (40, 64))
+    whole_p = _grouped_layer(0, 32)
+    key = ref_weights.base_key(13)
+    expert = lambda e: jax.tree.map(
+        lambda a: a.astype(jnp.float32), deepseek_ref.expert(C_GROUPS, key, 1, e))
+    whole = np.asarray(deepseek_ref.moe(C_GROUPS, whole_p, expert, u, "float32"))
+    shared = np.asarray(layers.gated_mlp(whole_p["shared"], u, dtype=jnp.float32))
+    total = shared.copy()
+    reached = np.zeros((40, 4), bool)
+    counts = []
+    for rank in range(4):
+        share = _grouped(8 * rank, 8)
+        p = _grouped_layer(8 * rank, 8)["moe"]
+        m, c = moe_ops.apply_share(p, u, share, dtype=jnp.float32)
+        part = np.asarray(deepseek_ref.routed(
+            dict(C_GROUPS, experts_held=8, expert_first=8 * rank), p, expert, u, "float32"))
+        assert np.abs(np.asarray(m) - part).max() < SHARE_TOL
+        total += np.asarray(m)
+        reached[:, rank] = np.abs(np.asarray(m)).max(axis=-1) > 0
+        counts.append({k: int(v) for k, v in c.items()})
+        assert counts[-1]["tokens_reaching"] == reached[:, rank].sum()
+    assert np.abs(whole - shared).max() > 0.1  # the routed part weighs
+    assert np.abs(total - whole).max() < 4 * SHARE_TOL
+    assert (reached.sum(axis=1) <= 2).all() and (reached.sum(axis=1) == 2).any()
+    assert all(c["choices"] == 40 * 6 and c["choices_zero"] == 0 for c in counts)
+    assert sum(c["choices_held"] for c in counts) == 40 * 6  # each on some rank
+    for c in counts:
+        assert c["tokens_reaching"] <= c["choices_held"] <= 6 * c["tokens_reaching"]
+
+
+def _choice_by_hand(s, share):
+    """The source's form in NumPy: group maxima, the best groups, the
+    scores outside them zeroed, the largest of what is left (ties to the
+    lower id, as ``lax.top_k`` breaks them)."""
+    T, G = s.shape[0], share.n_group
+    per = share.n_experts // G
+    out = []
+    for t in range(T):
+        best = np.argsort(-s[t].reshape(G, per).max(axis=1), kind="stable")[:share.top_groups]
+        masked = np.where(np.isin(np.arange(share.n_experts) // per, best), s[t], 0.0)
+        out.append(np.argsort(-masked, kind="stable")[:share.top_k])
+    return np.asarray(out)
+
+
+@pytest.mark.parametrize("near_ties", [False, True])
+def test_the_grouped_choice_is_the_sources_reshape_max_topk_form(near_ties):
+    """On random scores, and on scores rounded to three digits so that a
+    third of the rows have equal group maxima or equal candidates: the
+    program's choice, the reference's (which writes the source's form) and
+    NumPy's by hand are the same experts in the same order."""
+    s = jax.nn.softmax(2.0 * jax.random.normal(jax.random.key(8), (200, 32)), axis=-1)
+    if near_ties:
+        s = jnp.round(s, 2)
+        tied = np.asarray([len(set(r.reshape(4, 8).max(1).tolist())) < 4 for r in np.asarray(s)])
+        assert tied.sum() > 20
+    share = _grouped()
+    got, score = (np.asarray(a) for a in moe_ops.share_choice(s, share))
+    ref, w = deepseek_ref.choose(C_GROUPS, s)
+    np.testing.assert_array_equal(got, np.asarray(ref))
+    np.testing.assert_array_equal(got, _choice_by_hand(np.asarray(s), share))
+    np.testing.assert_array_equal(score, np.asarray(w))
+    # At most two groups a row wherever the kept groups have six scores
+    # above zero (a choice past them weighs nothing), and not the free
+    # choice's experts.
+    assert all(len(set(r[v > 0] // 8)) <= 2 for r, v in zip(got, score))
+    np.testing.assert_array_equal(
+        score[score > 0], np.take_along_axis(np.asarray(s), got, axis=1)[score > 0])
+    free, _ = moe_ops.share_choice(s, _grouped(top_groups=0, n_group=0))
+    assert (np.sort(np.asarray(free), 1) != np.sort(got, 1)).any()
+
+
+def test_top_groups_zero_is_the_free_choice_bit_for_bit():
+    """LongCat's path: with no groups the choice is ``top_k`` of ``s +
+    bias`` and nothing else, whatever ``n_group`` says, and a router
+    without a bias is chosen on ``s`` alone."""
+    s = jax.nn.softmax(jax.random.normal(jax.random.key(9), (50, 48)), axis=-1)
+    bias = 0.01 * jax.random.normal(jax.random.key(10), (48,))
+    for share in (_share(8, 8), dataclasses.replace(_share(8, 8), n_group=4)):
+        choice, score = moe_ops.share_choice(s, share, bias)
+        np.testing.assert_array_equal(
+            np.asarray(choice), np.asarray(jax.lax.top_k(s + bias, 6)[1]))
+        np.testing.assert_array_equal(  # weighted by its score, not score + bias
+            np.asarray(score), np.take_along_axis(np.asarray(s), np.asarray(choice), axis=1))
+        np.testing.assert_array_equal(
+            np.asarray(moe_ops.share_choice(s, share)[0]), np.asarray(jax.lax.top_k(s, 6)[1]))
+    # The layer's result with a zero bias is the layer's without one.
+    u = jax.random.normal(jax.random.key(5), (12, 64))
+    p = _share_params(8, 8)
+    p0 = dict(p, router=dict(p["router"], bias=jnp.zeros((48,))))
+    bare = dict(p, router={"kernel": p["router"]["kernel"]})
+    a, ca = moe_ops.apply_share(p0, u, _share(8, 8), dtype=jnp.float32)
+    b, cb = moe_ops.apply_share(bare, u, _share(8, 8), dtype=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert {k: int(v) for k, v in ca.items()} == {k: int(v) for k, v in cb.items()}
+
+
+def test_a_token_whose_groups_exclude_the_held_one_gets_exactly_its_shared_part():
+    """The router steered so that rows 0-5 keep groups 0 and 3 and rows 6-9
+    groups 1 and 2, group 1 held: the first get no expert row and EXACTLY
+    the shared experts' result, the rest reach this device; a row that is
+    not live gets nothing and is not counted."""
+    u = jax.random.normal(jax.random.key(6), (10, 64))
+    layer = _grouped_layer(8, 8)
+    # The steering direction is kept out of u: the router reads it alone.
+    steer = np.zeros((64, 32), np.float32)
+    steer[0, :8] = steer[0, 24:] = 5.0   # u[:, 0] > 0: groups 0 and 3
+    steer[0, 8:24] = -5.0                # u[:, 0] < 0: groups 1 and 2
+    u = u.at[:6, 0].set(1.0).at[6:, 0].set(-1.0)
+    p = dict(layer["moe"], router={"kernel": layer["moe"]["router"]["kernel"] + steer})
+    live = np.array([True] * 5 + [False] + [True] * 3 + [False])
+    m, counts = moe_ops.apply_share(p, u, _grouped(8, 8), jnp.asarray(live), dtype=jnp.float32)
+    m = np.asarray(m)
+    assert not m[:6].any() and not m[9].any() and np.abs(m[6:9]).max(axis=1).min() > 0
+    shared = np.asarray(layers.gated_mlp(layer["shared"], u, dtype=jnp.float32))
+    np.testing.assert_array_equal((m + shared)[:6], shared[:6])
+    assert int(counts["choices"]) == 6 * 8 and int(counts["tokens_reaching"]) == 3
+    assert 3 <= int(counts["choices_held"]) <= 18 and int(counts["choices_zero"]) == 0
+
+
+def test_a_held_range_that_is_not_whole_groups_raises():
+    for first, held in ((4, 8), (8, 4), (0, 12)):
+        with pytest.raises(ValueError, match="not whole groups"):
+            _grouped(first, held)
+    with pytest.raises(ValueError, match="equal groups"):
+        _grouped(n_group=5)
+    with pytest.raises(ValueError, match="equal groups"):
+        moe_ops.ShareConfig(n_experts=32, n_zero=8, top_k=6, scale=1.0, first=0, held=8,
+                            n_group=4, top_groups=2)
+    with pytest.raises(ValueError, match="cannot give"):
+        _grouped(top_groups=1, top_k=9)
+    with pytest.raises(ValueError, match="on the scores alone"):
+        moe_ops.share_choice(jnp.ones((2, 32)) / 32, _grouped(), bias=jnp.zeros((32,)))
+    assert _grouped(8, 16).held == 16  # two whole groups are a share
+
+
+def test_a_counted_call_adds_to_the_entries_a_model_keeps_and_to_no_other():
+    """``apply_share_counted`` is ``apply_share`` with its counts added to
+    the counters a model keeps (models/longcat.py and models/deepseek.py
+    keep different ones); a chunk's call adds to its own entries too, a
+    step's leaves them as they were."""
+    u = jax.random.normal(jax.random.key(8), (12, 64))
+    p, share = _grouped_layer(8, 8)["moe"], _grouped(8, 8)
+    live = jnp.arange(12) < 9
+    kept, chunk = ("choices", "choices_held", "tokens_reaching"), ("choices_held",)
+    zero = moe_ops.share_counters(kept, chunk)
+    assert sorted(zero) == ["moe_choices", "moe_choices_held", "moe_chunk_choices_held",
+                            "moe_tokens_reaching"]
+    assert all(v.dtype == jnp.int32 and int(v) == 0 for v in zero.values())
+    m, counts = moe_ops.apply_share(p, u, share, live, dtype=jnp.float32)
+    m1, step = moe_ops.apply_share_counted(p, u, share, live, zero, dtype=jnp.float32)
+    m2, both = moe_ops.apply_share_counted(
+        p, u, share, live, step, chunk_counts=chunk, dtype=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(m1), np.asarray(m))
+    np.testing.assert_array_equal(np.asarray(m2), np.asarray(m))
+    assert {k: int(v) for k, v in step.items()} == {
+        "moe_choices": 6 * 9, "moe_choices_held": int(counts["choices_held"]),
+        "moe_tokens_reaching": int(counts["tokens_reaching"]), "moe_chunk_choices_held": 0}
+    assert {k: int(v) for k, v in both.items()} == {
+        "moe_choices": 2 * 6 * 9, "moe_choices_held": 2 * int(counts["choices_held"]),
+        "moe_tokens_reaching": 2 * int(counts["tokens_reaching"]),
+        "moe_chunk_choices_held": int(counts["choices_held"])}
+    assert int(counts["choices_held"]) > 0

@@ -2448,3 +2448,74 @@ def test_a_model_without_counters_reports_none(family):
         assert out[0] == _toy_cached_stream([1, 2, 3], 4)
     if family == "toy_state":
         assert out[0] == _toy_state_stream([1, 2, 3], 4)
+
+
+# ----------------------------------------------------------------------------
+# A wide engine on an expert model with a grouped choice (PR 33)
+# ----------------------------------------------------------------------------
+
+
+def test_deepseek_at_64_slots_through_the_engine_and_its_counters_add_up(monkeypatch):
+    """models/deepseek.py behind ``_DecodeEngine`` at the width of its cell,
+    64 slots, at a toy size: 70 sessions (more than there are slots, so some
+    are seated into used slots), prompts of no, one and several chunks -
+    ``model_moe_*`` say what the expert layers did for the LIVE rows: every
+    live token of every expert layer made 6 choices (the leading dense layer
+    none; a chunk's last layer calls no expert layer), a token that reaches
+    this device (``tokens_reaching``) brings 1 to 6 of them, and a session
+    gets the tokens it gets alone."""
+    import jax
+
+    from distributed_tensorflow_examples_tpu.models import deepseek
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    cfg = deepseek.Config(
+        vocab_size=97, hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
+        num_hidden_layers=3, num_attention_heads=2, kv_lora_rank=16, q_lora_rank=24,
+        qk_rope_head_dim=8, qk_nope_head_dim=8, v_head_dim=8, n_routed_experts=32,
+        n_group=4, topk_group=2, num_experts_per_tok=6,
+        rope_original_max_position_embeddings=16, experts_held=8, expert_first=16,
+        param_dtype="float32")
+    params = deepseek.init(cfg, jax.random.key(5))
+    # Larger weights than the initialisation's: logits far enough apart
+    # that a token is no matter of rounding.
+    params = jax.tree.map(lambda a: a * 2 if a.ndim >= 2 else a, params)
+    fns = deepseek.serve_decode_fns(cfg)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 97, size=n) for n in rng.integers(1, 21, size=70)]
+    prompts[0], prompts[1] = prompts[0][:1], rng.integers(0, 97, size=20)
+    budgets = [int(n) for n in rng.integers(2, 7, size=70)]
+
+    def engine(slots):
+        return model_server._DecodeEngine(
+            lambda: (0, params), *fns, slots=slots, max_len=32, max_sessions=128)
+
+    eng = engine(64)
+    try:
+        assert eng._wants_live and eng._counts
+        together = _run_sessions(eng, prompts, budgets)
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    moe_layers = cfg.layer_kinds.count("moe")
+    chunked = sum(len(p) - 1 for p in prompts)
+    stepped = sum(budgets)
+    assert stats["prefill_tokens"] == chunked and stats["emitted"] == stepped
+    # A chunk's last layer is an expert layer and is skipped.
+    token_layers = chunked * (moe_layers - 1) + stepped * moe_layers
+    assert stats["model_moe_choices"] == 6 * token_layers
+    assert stats["model_moe_calls"] == (
+        (stats["prefill_chunks"] + 1) * (moe_layers - 1) + stats["steps"] * moe_layers)
+    held, reaching = stats["model_moe_choices_held"], stats["model_moe_tokens_reaching"]
+    assert 0 < reaching <= held <= 6 * reaching and reaching < token_layers
+    assert 0 < stats["model_moe_experts_touched"] <= held
+    assert stats["model_moe_chunk_calls"] == (stats["prefill_chunks"] + 1) * (moe_layers - 1)
+    assert "model_moe_choices_zero" not in stats
+    for i in (0, 1, 17, 69):
+        eng = engine(2)
+        try:
+            alone = _run_sessions(eng, [prompts[i]], [budgets[i]])[0]
+        finally:
+            eng.stop()
+        assert together[i] == alone, i
