@@ -53,7 +53,8 @@ float32.
 
 What a session owns in the cache: its rows of every layer's latents; the
 engine's key/value contract holds as in models/longcat.py, and the step
-asks for ``live`` because it COUNTS: the cache tree's ``counters`` entry
+asks for ``live`` because its attention reads the cache of the live rows
+only (models/mla.py) and because it COUNTS: the cache tree's ``counters`` entry
 holds int32 sums over layers and launches (``moe_*``: :data:`COUNTS` of
 ops/moe.py ``SHARE_COUNTS``; the chunk's part of three of them once more as
 ``moe_chunk_*``), and only live rows are counted or get expert rows.
@@ -74,16 +75,16 @@ import jax.numpy as jnp
 from ..ops import moe as moe_ops
 from . import decoding, layers, mla
 
-#: Cache positions one trip of the step's attention loop reads
-#: (``mla.Spec.decode_block``).  Chosen on one v5e chip at the served widths
-#: - 128 heads, 64 slots x 4096, every row live (my chip run, PR 33; PERF.md
-#: section 6): with the deepest row at 1000 / 2500 / 3900 the step takes
-#: 18.8 / 22.8 / 26.5 ms at 256, 18.0 / 21.6 / 24.1 at 512, 18.5 / 22.0 /
-#: 23.9 at 1024 (models/longcat.py's choice at 64 heads, 32 x 8192) and 20.5
-#: / 23.6 / 23.7 at 2048: with answers of 512-1536 tokens on prompts of
-#: 256-2048 the deepest of 64 rows stands at 3100 in the mean of a window
-#: and never past 3584, where 512 and 1024 are level (23.0 ms by either
-#: pair of readings) and 512 reads less past it.
+#: Cache positions the step's attention reads at a time
+#: (``mla.Spec.decode_block``): one block of ONE live slot an item of the
+#: kernel's grid (ops/latent_decode.py).  Chosen on one v5e chip at the
+#: served widths - 128 heads, 64 slots x 4096, every row live (my chip run,
+#: PR 35; PERF.md section 6): with all rows at 1000 / 2500 / 3900 the step
+#: takes 17.2 / 19.3 / 21.3 ms at 512 and 17.4 / 19.5 / 20.6 at 1024 (the
+#: loop this kernel replaced: 18.1 / 21.4 / 24.3 at 512, same run); at
+#: depths drawn like the cell's (mean 1,740, the deepest at 2,970 or 3,500)
+#: 18.46 / 18.48 at 512 and 18.59 / 18.61 at 1024 (the loop: 22.25 /
+#: 23.30): level where the cell stands, 512 reads less past a slot's row.
 DECODE_BLOCK = 512
 #: Cached positions a prefill chunk expands and attends over at a time: at
 #: offsets 0 / 1536 / 3072 a chunk takes 29.5 / 44.9 / 60.3 ms at 64, 29.6 /
@@ -335,7 +336,8 @@ def decode_step_batch(cfg: Config, params, cache, token, pos, live):
     and attends over its slot's rows ``<= pos``.  A row that is not live is
     inert the key/value way (what it writes is written again by the
     session's first real step, its logits mean nothing); ``live`` keeps it
-    out of the routed experts and out of the counters."""
+    out of the attention's read of the cache (it attends over nothing), out
+    of the routed experts and out of the counters."""
     spec = cfg.mla()
     counters = cache["counters"]
     new_cache = {}
@@ -345,7 +347,8 @@ def decode_step_batch(cfg: Config, params, cache, token, pos, live):
         written = {}
 
         def attn(pa, y):
-            o, written["attn"] = mla.decode(spec, pa, y, cache[f"layer_{i}"]["attn"], pos)
+            o, written["attn"] = mla.decode(
+                spec, pa, y, cache[f"layer_{i}"]["attn"], pos, live)
             return o
 
         def routed(u):
@@ -403,7 +406,8 @@ def prefill_chunk(cfg: Config, params, cache, tokens, slot, offset, n_valid):
 def serve_decode_fns(cfg: Config):
     """``(init_cache_fn, step_fn, prefill_fn)`` for ``serve.
     ModelReplicaServer(decode_fns=...)``.  ``step_fn`` takes ``live`` (it
-    counts live rows) and says how far a step reads (``cache_rows_read``)."""
+    reads and counts live rows only) and says what a step reads of the cache
+    (``cache_rows_read``: ``mla.decode_rows_read`` at this model's block)."""
 
     def init_cache_fn(slots: int, max_len: int):
         return init_cache(cfg, slots, max_len)

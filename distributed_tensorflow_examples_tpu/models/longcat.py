@@ -46,7 +46,9 @@ What a session owns in the cache: its rows of every sub-layer's latents.
 A row is written once and may be written again (a row that is not live
 writes at its position what the session's first real step writes anew), so
 the engine's key/value contract holds; the step still asks for ``live``
-(serve/model_server.py ``_DecodeEngine``) because it COUNTS: the cache
+(serve/model_server.py ``_DecodeEngine``): its attention reads the cache
+of the live rows only, each to its own position (models/mla.py), and it
+COUNTS: the cache
 tree's ``counters`` entry holds int32 sums over layers and launches of what
 the expert layer did (``moe_*``: ops/moe.py ``SHARE_COUNTS``; the chunk's
 part of three of them once more as ``moe_chunk_*``), added to on the device
@@ -68,19 +70,21 @@ import jax.numpy as jnp
 from ..ops import moe as moe_ops
 from . import decoding, layers, mla
 
-#: Cache positions one trip of the step's attention loop reads
-#: (``mla.Spec.decode_block``).  A trip reads ``slots x block`` latent
-#: rows of 1.1 KB and is a dozen operations, so a larger block trades
-#: positions read past the deepest row (half a block of every slot) against
-#: trips.  Chosen on one v5e chip at the served widths, 32 slots x 8192, 20
-#: rows live (my chip run, PR 31; PERF.md section 6): with the deepest row
-#: at 1500 / 4000 / 7900 the step takes 14.3 / 17.8 / 22.8 ms at 256 (what
-#: models/transformer.py chose at 8 x 2048), 14.0 / 16.9 / 21.2 at 512 and
-#: 14.7 / 15.8 / 20.3 at 1024: with prompts of thousands of tokens the
-#: deepest row stands past 7000 in nine steps of ten, where 1024 is best.
+#: Cache positions the step's attention reads at a time
+#: (``mla.Spec.decode_block``): an item of the kernel's grid
+#: (ops/latent_decode.py) brings in one block of ONE live slot, 1.1 KB a
+#: position, so a larger block trades positions read past a slot's own row
+#: (half a block of every live slot) against grid steps.  Chosen on one v5e
+#: chip at the served widths, 32 slots x 8192 (my chip run, PR 35; PERF.md
+#: section 6): with 20 rows live at 1500 / 4000 / 7900 the step takes 11.0 /
+#: 11.5 / 12.6 ms at 1024 and 10.5 / 11.3 / 12.8 at 512 (the loop this
+#: kernel replaced: 12.7 / 14.3 / 17.7 at 1024, same run), with 10 rows
+#: live at the cell's depths (mean 4,800) 10.35 at 1024 and 10.51 at 512
+#: (the loop: 15.98): level, and 1024 is kept.
 DECODE_BLOCK = 1024
 #: Cached positions a prefill chunk expands and attends over at a time
-#: (1024 reads 35.6 / 57.0 / 84.7 ms at those offsets: same run).
+#: (1024 reads 35.6 / 57.0 / 84.7 ms at offsets 1500 / 4000 / 7900: my chip
+#: run, PR 31).
 PREFILL_BLOCK = 512
 
 
@@ -306,7 +310,8 @@ def decode_step_batch(cfg: Config, params, cache, token, pos, live):
     and attends over its slot's rows ``<= pos``.  A row that is not live is
     inert the key/value way (what it writes is written again by the
     session's first real step, its logits mean nothing); ``live`` keeps it
-    out of the expert layer and out of the counters."""
+    out of the attention's read of the cache (it attends over nothing), out
+    of the expert layer and out of the counters."""
     spec = cfg.mla()
     counters = cache["counters"]
     new_cache = {}
@@ -316,7 +321,7 @@ def decode_step_batch(cfg: Config, params, cache, token, pos, live):
         written = {}
 
         def attn(j, pa, y):
-            o, written[f"attn_{j}"] = mla.decode(spec, pa, y, c[f"attn_{j}"], pos)
+            o, written[f"attn_{j}"] = mla.decode(spec, pa, y, c[f"attn_{j}"], pos, live)
             return o
 
         def moe(u):
@@ -373,7 +378,8 @@ def prefill_chunk(cfg: Config, params, cache, tokens, slot, offset, n_valid):
 def serve_decode_fns(cfg: Config):
     """``(init_cache_fn, step_fn, prefill_fn)`` for ``serve.
     ModelReplicaServer(decode_fns=...)``.  ``step_fn`` takes ``live`` (it
-    counts live rows) and says how far a step reads (``cache_rows_read``)."""
+    reads and counts live rows only) and says what a step reads of the cache
+    (``cache_rows_read``: ``mla.decode_rows_read`` at this model's block)."""
 
     def init_cache_fn(slots: int, max_len: int):
         return init_cache(cfg, slots, max_len)

@@ -25,10 +25,14 @@ Two forms of the same attention:
 - the one-token step ABSORBS ``kv_b`` into the query and the output
   (:func:`attend_absorbed`): ``q_nope . W_uk`` against ``c``, the weighted
   sum of ``c`` then through ``W_uv`` - ``H`` query heads over ONE latent row
-  a position, whose first ``kv_lora_rank`` columns are the values too.  The
-  cache is read a block of positions at a time as a running softmax, no
-  further than the deepest row (:func:`decode_rows_read`); the new row is
-  written in place (:func:`write_rows`).
+  a position, whose first ``kv_lora_rank`` columns are the values too.  On
+  a TPU the running softmax over the cache is ONE kernel a sub-layer
+  (ops/latent_decode.py): each LIVE slot is read a block of positions at a
+  time up to ITS OWN row, a slot that is not live not at all
+  (:func:`decode_rows_read` is the host's count of that).  On the CPU,
+  where the kernel would be interpreted, the same softmax is a loop over
+  blocks of every slot up to the deepest live row (:func:`_absorbed_loop`).
+  The new row is written in place (:func:`write_rows`).
 - the prefill chunk and the full forward EXPAND a block of cached latents
   at a time into keys and values (``kv_b . c``) and attend as published
   (:func:`attend_expanded`; :func:`chunk_write` puts a chunk's rows into
@@ -45,7 +49,10 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..ops import latent_decode
+from ..ops.flash_attention import interpret_mode
 from . import layers
 
 
@@ -66,9 +73,10 @@ class Spec:
     inv_freq: Any  # [rope // 2] float32: radians a position, by pair
     eps: float
     dtype: Any
-    #: Cache positions one trip of the step's loop reads (or the whole
-    #: cache, where that is shorter): a larger block trades positions read
-    #: past the deepest row (half a block of every slot) against trips.
+    #: Cache positions the step's attention reads at a time - an item of the
+    #: kernel's grid, a trip of the CPU's loop - or the whole cache, where
+    #: that is shorter: a larger block trades positions read past a slot's
+    #: row (half a block of every live slot) against grid steps.
     decode_block: int
     #: Cached positions a chunk expands and attends over at a time.
     prefill_block: int
@@ -176,14 +184,17 @@ def attend_expanded(spec: Spec, p, q_nope, q_rope, rows, q_pos, n_blocks, block)
     return jnp.moveaxis(acc / l, 0, 1).reshape(C, -1)
 
 
-def decode_rows_read(block: int, max_pos, max_len: int):
-    """Cache positions of EVERY slot that one decode step reads when its
-    deepest row stands at ``max_pos`` and its loop takes ``block`` positions
-    a trip: whole blocks up to the one that holds that position, at most the
-    cache.  The host's count of what :func:`attend_absorbed`'s loop does on
-    the device."""
+def decode_rows_read(block: int, pos, live, max_len: int) -> float:
+    """Cache positions one decode step reads A SLOT IN THE MEAN, from the
+    host's ``pos [S]`` and ``live [S]``: whole blocks of ``block`` positions
+    up to each live slot's own row, none of a slot that is not live, summed
+    and divided by the slots.  It counts what the KERNEL brings in
+    (ops/latent_decode.py: what the chip does); the CPU's loop reads every
+    slot to the deepest live row's block."""
     blk = min(block, max_len)
-    return min(max_len, (max_pos // blk + 1) * blk)
+    n = np.where(live, pos + 1, 0)
+    read = np.minimum(latent_decode.blocks_read(n, blk) * blk, max_len)
+    return float(read.sum()) / len(n)
 
 
 def write_rows(cache, new, pos):
@@ -197,49 +208,72 @@ def write_rows(cache, new, pos):
     return cache
 
 
-def attend_absorbed(spec: Spec, p, q_nope, q_rope, cache, pos):
-    """One query a slot against that slot's latent rows: ``q_nope [S, H,
-    nope]``, ``q_rope [S, H, rope]``, ``cache [S, T, latent]``, ``pos [S]``
-    -> ``[S, H x v_dim]`` float32; slot ``b`` attends over its positions
-    ``<= pos[b]``.  ``kv_b`` never touches the cache: its key half goes into
-    the query (``q_nope . W_uk``, then ONE product of ``[q_lat | q_rope]``
-    against the latent-wide row), its value half comes after the weighted
-    sum of the rows' first ``kv_lora_rank`` columns.  The loop is
-    models/transformer.py ``_decode_attention``'s: a block of positions a
-    trip, ``max(pos) // block + 1`` trips, a block wholly past a row's
-    position an exact no-op for that row."""
+def _absorbed_loop(q, cache, n, *, values: int, scale: float, block: int):
+    """ops/latent_decode.py ``latent_decode_attention`` in plain
+    ``jax.numpy``, the CPU's form and the kernel's reference: a block of
+    positions of EVERY slot a trip, up to the block that holds the deepest
+    row read (a block wholly past a slot's ``n`` is an exact no-op for that
+    slot), the slots that read nothing set to the kernel's zeros.  Block
+    ``i`` holds positions ``[i block, (i + 1) block)``; where ``T`` is no
+    multiple of the block the last one is read shifted back inside the
+    cache and what it shares with the block before is masked."""
     S, T, _ = cache.shape
-    R, H = spec.kv_lora_rank, spec.heads
-    blk = min(spec.decode_block, T)
+    H = q.shape[1]
+    blk = min(block, T)
     f32 = jnp.float32
-    w_uk, w_uv = _kv_b(spec, p)
-    q_lat = jnp.einsum("shd,rhd->shr", q_nope, w_uk, preferred_element_type=f32)
-    q = jnp.concatenate([q_lat.astype(spec.dtype), q_rope], axis=-1)  # [S, H, latent]
 
     def body(i, carry):
         m, l, acc = carry
         start = jnp.minimum(i * blk, T - blk)
         rows = jax.lax.dynamic_slice_in_dim(cache, start, blk, axis=1)
         s = jnp.einsum("shc,stc->sht", q, rows, preferred_element_type=f32)
-        s = s * spec.softmax_scale
+        s = s * scale
         t = start + jnp.arange(blk)
-        own = (t >= i * blk)[None, :] & (t[None, :] <= pos[:, None])
+        own = (t >= i * blk)[None, :] & (t[None, :] < n[:, None])
         s = jnp.where(own[:, None, :], s, -jnp.inf)
+        # Block 0 holds position 0, which every slot that reads anything
+        # sees: its maximum is finite from the first trip on.  A slot that
+        # reads nothing carries NaN to the end, where it is set to zeros.
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         w = jnp.exp(s - m_new)
         r = jnp.exp(m - m_new)
         l = l * r + w.sum(axis=-1, keepdims=True)
         acc = acc * r + jnp.einsum(
-            "sht,str->shr", w.astype(spec.dtype), rows[..., :R],
+            "sht,str->shr", w.astype(cache.dtype), rows[..., :values],
             preferred_element_type=f32)
         return m_new, l, acc
 
     stat = jnp.zeros((S, H, 1), f32)
     _, l, acc = jax.lax.fori_loop(
-        0, jnp.max(pos) // blk + 1, body,
-        (stat - jnp.inf, stat, jnp.zeros((S, H, R), f32)),
+        0, latent_decode.blocks_read(jnp.max(n), blk), body,
+        (stat - jnp.inf, stat, jnp.zeros((S, H, values), f32)),
     )
-    o = jnp.einsum("shr,rhd->shd", (acc / l).astype(spec.dtype), w_uv,
+    return jnp.where((n > 0)[:, None, None], acc / l, 0.0)
+
+
+def attend_absorbed(spec: Spec, p, q_nope, q_rope, cache, pos, live):
+    """One query a slot against that slot's latent rows: ``q_nope [S, H,
+    nope]``, ``q_rope [S, H, rope]``, ``cache [S, T, latent]``, ``pos [S]``,
+    ``live [S]`` bool -> ``[S, H x v_dim]`` float32; a live slot ``b``
+    attends over its positions ``<= pos[b]``, a slot that is not live reads
+    nothing and its result is what ``W_uv`` makes of zeros (finite; it means
+    nothing).  ``kv_b`` never touches the cache: its key half goes into the
+    query (``q_nope . W_uk``, beside ``q_rope`` ONE latent-wide query), its
+    value half comes after the weighted sum of the rows' first
+    ``kv_lora_rank`` columns.  Between the two, one form a platform, chosen
+    by the package's one platform test: the kernel on a TPU, the loop where
+    the kernel would be interpreted (the CPU, where a grid step of the
+    interpreter costs what a whole trip of the loop does)."""
+    S = cache.shape[0]
+    f32 = jnp.float32
+    w_uk, w_uv = _kv_b(spec, p)
+    q_lat = jnp.einsum("shd,rhd->shr", q_nope, w_uk, preferred_element_type=f32)
+    q = jnp.concatenate([q_lat.astype(spec.dtype), q_rope], axis=-1)  # [S, H, latent]
+    n = jnp.where(live, pos + 1, 0)
+    attend = _absorbed_loop if interpret_mode() else latent_decode.latent_decode_attention
+    c = attend(q, cache, n, values=spec.kv_lora_rank, scale=spec.softmax_scale,
+               block=spec.decode_block)
+    o = jnp.einsum("shr,rhd->shd", c.astype(spec.dtype), w_uv,
                    preferred_element_type=f32)
     return o.reshape(S, -1)
 
@@ -283,14 +317,15 @@ def forward(spec: Spec, p, h):
     return out_proj(spec, p, o)
 
 
-def decode(spec: Spec, p, h, cache, pos):
+def decode(spec: Spec, p, h, cache, pos, live):
     """The one-token step's sub-layer: the normed ``h [S, D]`` at per-row
-    positions ``pos [S]`` -> (``[S, D]`` float32, the cache with each row's
-    latent written at its position)."""
+    positions ``pos [S]``, ``live [S]`` bool -> (``[S, D]`` float32, the
+    cache with each row's latent written at its position).  A row that is
+    not live writes its latent like the others and attends over nothing."""
     q_nope, q_rope, row = query_and_latent(spec, p, h, pos)
     with jax.named_scope("mla/decode"):
         cache = write_rows(cache, row, pos)
-        o = attend_absorbed(spec, p, q_nope, q_rope, cache, pos)
+        o = attend_absorbed(spec, p, q_nope, q_rope, cache, pos, live)
     return out_proj(spec, p, o), cache
 
 

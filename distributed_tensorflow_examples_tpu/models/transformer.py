@@ -473,13 +473,16 @@ def _decode_constrain(mesh: Mesh | None, drop: tuple = ("seq",)):
 DECODE_BLOCK = 256
 
 
-def decode_rows_read(max_pos, max_len: int):
-    """Cache positions of EVERY slot that one decode step reads when its
-    deepest row stands at ``max_pos``: whole blocks of :data:`DECODE_BLOCK`
-    up to the one that holds that position, at most the cache.  The host's
-    count of what :func:`_decode_attention`'s loop does on the device."""
+def decode_rows_read(pos, live, max_len: int):
+    """Cache positions of EVERY slot that one decode step reads with its
+    rows at the host's ``pos [S]``: whole blocks of :data:`DECODE_BLOCK` up
+    to the one that holds the deepest position, at most the cache, whichever
+    rows are ``live`` (a row that is not stands at 0 or where its session
+    does).  The host's count of what :func:`_decode_attention`'s loop does
+    on the device."""
+    del live
     blk = min(DECODE_BLOCK, max_len)
-    return min(max_len, (max_pos // blk + 1) * blk)
+    return min(max_len, (int(pos.max()) // blk + 1) * blk)
 
 
 def _write_rows(cache, new, pos):

@@ -151,7 +151,7 @@ class _Flight(typing.NamedTuple):
     selection: object  # the step's ``[S]`` int32 on the device
     rows: list  # (ticket, row it emits from | None: emits nothing, done)
     held: int  # rows of sessions whose chunks were due
-    rows_read: int  # cache positions of each slot the step's attention read
+    rows_read: float  # cache positions the step's attention read, a slot
     params: object  # the served model's parameters it was launched with
 
 
@@ -215,9 +215,12 @@ class _DecodeEngine:
       attention mask confines each session to the positions it wrote
       itself.  A freed slot needs no cache reset.  Such a ``step_fn`` may
       say how far into the cache its step reads by an attribute
-      ``cache_rows_read(max_pos, max_len) -> int`` (the positions of every
-      slot read when the deepest row stands at ``max_pos``); without it a
-      step is taken to read all ``max_len`` (counter ``cache_rows_read``).
+      ``cache_rows_read(pos, live, max_len)`` (the positions read A SLOT IN
+      THE MEAN by the step launched with the host's ``pos [S]`` int32 and
+      ``live [S]`` bool, the engine's own arrays: not to be kept or
+      changed); without it a step is taken to read all ``max_len`` of
+      every slot (counter ``cache_rows_read``).  A model told which rows
+      are live (below) may say the same.
     - A model whose cache holds a STATE that every step overwrites (a
       state-space layer: models/jamba.py) cannot compute an inert row: it
       would advance the state by a token that is not there.  Such a model
@@ -286,7 +289,7 @@ class _DecodeEngine:
         self._wants_live = len(inspect.signature(step_fn).parameters) == 5
         # How far into the cache a step reads: all of it, unless told.
         self._rows_read = getattr(
-            step_fn, "cache_rows_read", lambda max_pos, max_len: max_len
+            step_fn, "cache_rows_read", lambda pos, live, max_len: max_len
         )
         # What the engine holds for its slots (state, keys and values).
         self.state_bytes = sum(
@@ -546,7 +549,7 @@ class _DecodeEngine:
             args = [jnp.asarray(tokens), jnp.asarray(from_host), jnp.asarray(pos)]
             if self._wants_live:
                 args.append(jnp.asarray(live))
-            rows_read = self._rows_read(int(pos.max()), self.max_len)
+            rows_read = self._rows_read(pos, live, self.max_len)
         with self._cache_donated(), _SPAN_DISPATCH:
             self._selection, self._cache = self._step_jit(
                 params, self._cache, self._selection, *args

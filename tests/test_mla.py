@@ -104,8 +104,11 @@ def test_chunks_then_absorbed_steps_are_the_expanded_full_forward(kind):
     np.testing.assert_array_equal(np.asarray(cache[1, 13:]), np.float32(0.37))
     for pos in range(13, 29):
         rows = jnp.stack([h[0, 3], h[0, pos]])
-        o, cache = decode(rows, cache, jnp.array([2, pos]))
+        # The other row is live in every second step: live or not, it is
+        # nothing to the session.
+        o, cache = decode(rows, cache, jnp.array([2, pos]), jnp.array([pos % 2 == 0, True]))
         got[pos] = o[1]
+        assert np.isfinite(np.asarray(o)).all()
     assert np.abs(got - full).max() < TOL
     # What each of the spec's numbers does is seen at this tolerance.
     for wrong in (dict(softmax_scale=spec.softmax_scale * 1.2),
@@ -124,23 +127,44 @@ def test_the_scaled_pairs_matter_past_the_original_context():
     assert np.abs(a - b).max() > 0.01
 
 
+def test_the_count_of_rows_read_is_each_live_slots_own_whole_blocks():
+    """``decode_rows_read``: the mean over the slots of the whole blocks up
+    to each live slot's own row, at most the cache, nothing of the others."""
+    one = lambda block, pos, max_len: mla.decode_rows_read(
+        block, np.array([pos]), np.array([True]), max_len)
+    assert one(8, 0, 32) == 8 and one(8, 7, 32) == 8
+    assert one(8, 8, 32) == 16 and one(8, 31, 32) == 32
+    assert one(1024, 5, 32) == 32  # a block longer than the cache
+    assert one(8, 29, 30) == 30  # a last block that the cache cuts short
+    pos, live = np.array([9, 4, 20, 0]), np.array([True, True, False, False])
+    assert mla.decode_rows_read(8, pos, live, 32) == (16 + 8) / 4
+    assert mla.decode_rows_read(8, pos, ~live, 32) == (24 + 8) / 4
+    assert mla.decode_rows_read(8, pos, live & False, 32) == 0
+
+
 def test_the_step_reads_whole_blocks_to_its_deepest_row_and_no_further():
-    assert mla.decode_rows_read(8, 0, 32) == 8 and mla.decode_rows_read(8, 7, 32) == 8
-    assert mla.decode_rows_read(8, 8, 32) == 16 and mla.decode_rows_read(8, 31, 32) == 32
-    assert mla.decode_rows_read(1024, 5, 32) == 32  # a block longer than the cache
+    """The CPU's form (the loop, which ``mla.decode`` runs here)."""
     # Rows past the deepest block are not read: garbage there changes nothing.
     spec = _spec("YARN")
     p = _params(spec)
     h = jax.random.normal(jax.random.key(3), (2, D))
     cache = jax.random.normal(jax.random.key(4), (2, 32, spec.latent))
-    pos = jnp.array([9, 4])
-    o, written = mla.decode(spec, p, h, cache, pos)
+    pos, live = jnp.array([9, 4]), jnp.array([True, True])
+    o, written = mla.decode(spec, p, h, cache, pos, live)
     spoiled = cache.at[:, 16:].set(jnp.nan)
-    o2, _ = mla.decode(spec, p, h, spoiled, pos)
+    o2, _ = mla.decode(spec, p, h, spoiled, pos, live)
     np.testing.assert_array_equal(np.asarray(o), np.asarray(o2))
     # The step wrote one row a slot, at its position, and nothing else.
     changed = np.asarray(written != cache).any(axis=-1)
     assert changed.sum() == 2 and changed[0, 9] and changed[1, 4]
+    # The deepest LIVE row bounds it: with row 0 not live, block 1 is not
+    # read either; row 0 still writes its latent and comes out finite, and
+    # row 1's result is what it was.
+    o3, written3 = mla.decode(
+        spec, p, h, cache.at[:, 8:].set(jnp.nan), pos, jnp.array([False, True]))
+    np.testing.assert_array_equal(np.asarray(o3[1]), np.asarray(o[1]))
+    assert np.isfinite(np.asarray(o3)).all()
+    np.testing.assert_array_equal(np.asarray(written3[0, 9]), np.asarray(written[0, 9]))
 
 
 def test_a_chunk_at_the_end_of_the_cache_is_written_where_it_belongs():

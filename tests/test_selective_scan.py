@@ -118,3 +118,34 @@ def test_grouped_ffn_compiles_for_a_v5e_at_the_served_widths(
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and f"%{gf.KERNEL_NAME}" in text
+
+
+@pytest.mark.parametrize("slots,heads,length,block", [(64, 128, 4096, 512), (32, 64, 8192, 1024)])
+def test_latent_decode_compiles_for_a_v5e_at_the_served_widths(
+    one_chip, monkeypatch, slots, heads, length, block,
+):
+    """Mosaic takes ops/latent_decode.py at the two served shapes (DeepSeek-V2
+    and LongCat: latent 576 = 512 values + 64 rotated, bfloat16): the grid of
+    a traced length, the five prefetched index arrays, the two products over
+    a latent that is no multiple of 128 lanes, and the kernel's name, which
+    the benchmark's readers look for.  And it takes the cache AS IT LIES: the
+    compiler puts the positions of a ``[S, T, 576]`` array last, the kernel
+    reads blocks of ``[576, block]``, and the compiled program holds no copy
+    of the cache (302 MB a call, were it otherwise) - its temporaries are the
+    work list and little else.  (Kept in this file: the one that loads the
+    TPU's library.)"""
+    from distributed_tensorflow_examples_tpu.ops import latent_decode as ld
+
+    monkeypatch.setattr(ld, "interpret_mode", lambda: False)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, c, n: ld.latent_decode_attention.__wrapped__(
+            q, c, n, values=512, scale=0.1, block=block)
+    ).lower(
+        s((slots, heads, 576), jnp.bfloat16), s((slots, length, 576), jnp.bfloat16),
+        s((slots,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{ld.KERNEL_NAME}" in text
+    cache_bytes = slots * length * 576 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 16

@@ -1606,6 +1606,65 @@ def test_cache_rows_read_counts_what_a_step_reads(monkeypatch, bounded):
     )
 
 
+def test_cache_rows_read_of_a_latent_cache_is_each_live_slots_own_blocks(monkeypatch):
+    """A model on models/mla.py says what its kernel reads: whole blocks up
+    to each LIVE slot's own row, nothing of the others, a slot in the mean.
+    Three slots, two sessions of different depth (a long prompt held while
+    its chunk is due, then decoding beside the short one) and a slot that
+    stays empty: the engine's count is the sum, over the steps it launched,
+    of that mean - reckoned here from the positions and live rows the
+    engine handed the model's hook."""
+    import jax
+
+    from distributed_tensorflow_examples_tpu import models
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    block, max_len, slots = 4, 30, 3
+    monkeypatch.setattr(models.deepseek, "DECODE_BLOCK", block)
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    cfg = models.deepseek.Config(
+        vocab_size=64, hidden_size=32, intermediate_size=32, moe_intermediate_size=16,
+        num_hidden_layers=2, num_attention_heads=2, kv_lora_rank=16, q_lora_rank=16,
+        qk_rope_head_dim=8, qk_nope_head_dim=8, v_head_dim=8, n_routed_experts=8,
+        n_shared_experts=1, n_group=2, topk_group=1, num_experts_per_tok=2,
+        rope_original_max_position_embeddings=16, experts_held=4, expert_first=4,
+        vocab_rows=64, param_dtype="float32",
+    )
+    params = models.deepseek.init(cfg, jax.random.key(0))
+    init_cache_fn, step_fn, prefill_fn = models.deepseek.serve_decode_fns(cfg)
+    said, launches = step_fn.cache_rows_read, []
+
+    def hook(pos, live, max_len):
+        launches.append((pos.copy(), live.copy()))
+        return said(pos, live, max_len)
+
+    step_fn.cache_rows_read = hook
+    eng = model_server._DecodeEngine(
+        lambda: (0, params), init_cache_fn, step_fn, prefill_fn,
+        slots=slots, max_len=max_len, max_sessions=4,
+    )
+    try:
+        _run_sessions(eng, [list(range(1, 12)), [5]], [14, 20], gap_s=0.05)
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    want = 0.0
+    for pos, live in launches[:stats["steps"]]:
+        own = [min(max_len, (p // block + 1) * block) for p, on in zip(pos, live) if on]
+        want += sum(own) / slots
+    assert stats["cache_rows_read"] == pytest.approx(want, rel=1e-9)
+    # Launched and not counted: at most the step in flight when the last
+    # session ended, which had no live row.
+    assert all(not live.any() for _pos, live in launches[stats["steps"]:])
+    both = [(pos, live) for pos, live in launches if live.sum() == 2]
+    assert both and any(abs(int(p[0]) - int(p[1])) >= block for p, _l in both)
+    assert all(not live[2] for _pos, live in launches)
+    # Far under what a read of every slot to the deepest row would count.
+    deepest = sum(min(max_len, (int(pos.max()) // block + 1) * block)
+                  for pos, _live in launches[:stats["steps"]])
+    assert stats["cache_rows_read"] < 0.7 * deepest
+
+
 # ----------------------------------------------------------------------------
 # A cache that holds a STATE: the step is told which rows are live (PR 27)
 # ----------------------------------------------------------------------------

@@ -393,8 +393,8 @@ def test_blocked_decode_matches_full_forward_at_every_depth(monkeypatch, T):
     ref = np.asarray(tf.apply(_BLOCKED, params, jnp.asarray(x)))  # [S, T, V]
     cache = _junk_cache(tf, _BLOCKED, S, T, 1)
     step = jax.jit(lambda c, t, p: tf.decode_step_batch(_BLOCKED, params, c, t, p))
-    assert tf.decode_rows_read(7, T) == 8 and tf.decode_rows_read(8, T) == 16
-    assert tf.decode_rows_read(T - 1, T) == T
+    rows_read = lambda *pos: tf.decode_rows_read(np.array(pos), np.ones(len(pos), bool), T)
+    assert rows_read(7, 0) == 8 and rows_read(3, 8) == 16 and rows_read(T - 1) == T
     for s in range(T + 3 * (S - 1)):
         pos = np.clip(s - 3 * np.arange(S), 0, T - 1).astype(np.int32)
         logits, cache = step(cache, jnp.asarray(x[np.arange(S), pos]), jnp.asarray(pos))
