@@ -340,16 +340,18 @@ class StreamTicket:
     produced its full budget) or an error (the step function raised — the
     whole active batch fails, like the row batcher's contract)."""
 
-    __slots__ = ("state", "opened_ns", "seated_ns", "_emits", "_done",
-                 "_error", "_cancelled", "_lock", "_event")
+    __slots__ = ("state", "opened_ns", "seated_ns", "emitted_ns", "_emits",
+                 "_done", "_error", "_cancelled", "_lock", "_event")
 
     def __init__(self, state):
         self.state = state
-        # ``time.perf_counter_ns()`` when the session was opened and when
-        # it took a slot (None while queued): what the batcher's seat-wait
-        # and first-token sums are taken from.
+        # ``time.perf_counter_ns()`` when the session was opened, when it
+        # took a slot (None while queued) and when it last emitted (None
+        # before its first item): what the batcher's sums and its three
+        # histograms are taken from.
         self.opened_ns = time.perf_counter_ns()
         self.seated_ns: int | None = None
+        self.emitted_ns: int | None = None
         self._emits: list = []
         self._done = False
         self._error: BaseException | None = None
@@ -436,6 +438,17 @@ class SlotBatcher:
     waiting with no active slot, ``<name>/emit`` handing results to the
     tickets; ``run_step`` adds its own between fill and emit.  None wraps
     another and none wraps the iteration.
+
+    What a session waits for, as three histograms of the process's registry
+    in milliseconds (``telemetry.Histogram``: a scraper differences their
+    ``/le/`` counts for a window's distribution): ``<name>/seat_wait_ms``
+    from ``open`` to the slot, one observation a session seated;
+    ``<name>/ttft_ms`` from ``open`` to the first item emitted, one a
+    session that emits; ``<name>/itl_ms`` from an item to the session's
+    next, one for every later item.  The step thread reads the clock ONCE a
+    step for them - every item of a step is emitted at that instant, so two
+    items of one step are 0 apart - and their counts are the counters'
+    ``seated``, ``first_tokens`` and ``emitted - first_tokens``.
     """
 
     def __init__(
@@ -471,6 +484,9 @@ class SlotBatcher:
         self._span_fill = telemetry.span(f"{name}/fill")
         self._span_park = telemetry.span(f"{name}/park")
         self._span_emit = telemetry.span(f"{name}/emit")
+        self._hist_seat_wait = telemetry.REGISTRY.histogram(f"{name}/seat_wait_ms")
+        self._hist_ttft = telemetry.REGISTRY.histogram(f"{name}/ttft_ms")
+        self._hist_itl = telemetry.REGISTRY.histogram(f"{name}/itl_ms")
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name=f"dtx-{name}-slots"
         )
@@ -527,6 +543,7 @@ class SlotBatcher:
     def _fill_slots(self) -> tuple[list, bool]:
         """Seat queued sessions in free slots, drop cancelled ones;
         returns ``(slots snapshot, any_active)``."""
+        waits = []
         with self._lock:
             for i in range(self.slots):
                 t = self._slots[i]
@@ -544,7 +561,10 @@ class SlotBatcher:
                 t.seated_ns = time.perf_counter_ns()
                 self.seated += 1
                 self.seat_wait_ns += t.seated_ns - t.opened_ns
+                waits.append((t.seated_ns - t.opened_ns) * 1e-6)
             snapshot = list(self._slots)
+        if waits:
+            self._hist_seat_wait.observe_many(waits)
         return snapshot, any(s is not None for s in snapshot)
 
     def _loop(self) -> None:
@@ -570,20 +590,30 @@ class SlotBatcher:
                 continue
             self.steps += 1
             with self._span_emit:
+                now = time.perf_counter_ns()  # the step's one emission stamp
+                firsts, gaps = [], []
                 for t, emits, done in results:
                     self._fresh.discard(t)
                     if emits:
-                        if not t._emits:  # only this thread appends
+                        if t.emitted_ns is None:  # only this thread stamps
                             self.first_tokens += 1
-                            self.first_token_ns += (
-                                time.perf_counter_ns() - t.seated_ns
-                            )
+                            self.first_token_ns += now - t.seated_ns
+                            firsts.append((now - t.opened_ns) * 1e-6)
+                        else:
+                            gaps.append((now - t.emitted_ns) * 1e-6)
+                        if len(emits) > 1:
+                            gaps.extend([0.0] * (len(emits) - 1))
+                        t.emitted_ns = now
                         self.emitted += len(emits)
                         t._emit(emits)
                     else:
                         self.fed += 1
                     if done:
                         t._finish()
+                if firsts:
+                    self._hist_ttft.observe_many(firsts)
+                if gaps:
+                    self._hist_itl.observe_many(gaps)
         # Drain: every active and queued session fails loudly instead of
         # hanging its poller.
         err = RuntimeError("slot batcher stopped")

@@ -99,13 +99,19 @@ NO_DECODER = wire.SRV_STATUS["NO_DECODER"]
 # slot batcher's family (its ``name``, "decode").  They follow the batcher's
 # ``decode/fill`` and precede its ``decode/emit``; none wraps another.
 #: The wait for the chunk dispatched a call earlier, at the top of the next
-#: call, once a chunk.  The engine adds to the span's ``/ns`` the host's
-#: launch of the chunk (``_prefill_one``) and the time the chunk had been
-#: on the device before the span was entered (``_await_chunk``), so the sum
-#: over ``/n`` stays what it was while one span held both: a chunk's launch
-#: and its time on the device.
+#: call, once a chunk.  The engine adds to the span's ``/ns`` (one ``inc``,
+#: ``_await_chunk``) the host's launch of the chunk and the time the chunk
+#: had been on the device before the span was entered, so the sum over
+#: ``/n`` stays what it was while one span held both: a chunk's launch and
+#: its time on the device.
 _SPAN_PREFILL = telemetry.span("decode/prefill")
 _PREFILL_NS = telemetry.REGISTRY.counter("decode/prefill/ns")
+#: The host's launch of a chunk (the runtime's ``PjitFunction(prefill_fn)``),
+#: under the step in flight.
+_SPAN_CHUNK_LAUNCH = telemetry.span("decode/chunk_launch")
+#: The read of what the model counts on the device: a wait for everything
+#: launched so far with nothing queued behind it, when ``stats()`` asked.
+_SPAN_COUNTERS = telemetry.span("decode/counters")
 #: Each row's token or its source, position and liveness, uploaded.
 _SPAN_PREPARE = telemetry.span("decode/prepare")
 #: Launching the jitted step (the runtime's ``PjitFunction(step_fn)``).
@@ -116,6 +122,12 @@ _SPAN_DISPATCH = telemetry.span("decode/dispatch")
 _SPAN_FETCH = telemetry.span("decode/fetch")
 #: Each stepped session's token, count and ``done`` from what was read.
 _SPAN_SELECT = telemetry.span("decode/select")
+#: What the engine's calls took on the wall LESS their two waits on the
+#: device (inside ``decode/fetch``, inside ``decode/prefill``): the engine's
+#: own work, its spans' and the Python between them.  With ``decode/fill``
+#: and ``decode/emit`` it is the host's loop, which has to fit under a step
+#: for the device to set the pace.
+_HOST_NS = telemetry.REGISTRY.counter("decode/host/ns")
 
 
 #: Prompt tokens one prefill chunk takes through the model.  The ONE size:
@@ -312,6 +324,7 @@ class _DecodeEngine:
         # it began there.
         self._chunk_echo = None
         self._chunk_began_ns = 0
+        self._chunk_launch_ns = 0
         # Slot-steps a seated session was not live: its chunks were due.
         self.held_rows = 0
         # Cache positions of each slot the steps' attention read, summed.
@@ -320,6 +333,10 @@ class _DecodeEngine:
         # slot-steps of sessions stepped past their last token.
         self.ahead_steps = 0
         self.idle_rows = 0
+        # Reads that found their step done: the host set that step's pace.
+        self.reads_ready = 0
+        # What the call under way has spent waiting on the device.
+        self._blocked_ns = 0
         # The last launch's selection, on the device, and that launch while
         # the host has not read it.
         self._selection = self._no_selection()
@@ -422,15 +439,18 @@ class _DecodeEngine:
         with the step queued behind it, so the wait idles nothing, and a
         chunk that failed on the device fails here.  The span holds the
         wait; the chunk had been running since ``_chunk_began_ns``, under
-        the host's emit and fill, and that stretch is added to its sum."""
+        the host's emit and fill, and that stretch and the chunk's launch
+        are added to its sum."""
         echo, self._chunk_echo = self._chunk_echo, None
         if echo is None:
             return
         import jax
 
-        _PREFILL_NS.inc(time.perf_counter_ns() - self._chunk_began_ns)
+        _PREFILL_NS.inc(
+            self._chunk_launch_ns + time.perf_counter_ns() - self._chunk_began_ns)
         with self._cache_donated(), _SPAN_PREFILL:
             jax.block_until_ready(echo)
+        self._blocked_ns += _SPAN_PREFILL.last_ns
 
     def _chunk_due(self, slots) -> int | None:
         """The slot whose chunk runs next: the longest-seated session's
@@ -458,25 +478,33 @@ class _DecodeEngine:
             st = slots[i].state
             done = st["cached"]
             n = min(self._chunk, st["prefill"] - done)
-            t0 = time.perf_counter_ns()
-            self._chunk_echo = self._prefill(
-                params, i, st["prompt"][done:done + n], done, n)
+            with _SPAN_CHUNK_LAUNCH:
+                self._chunk_echo = self._prefill(
+                    params, i, st["prompt"][done:done + n], done, n)
+            # Booked onto ``decode/prefill/ns`` when the chunk is waited for.
+            self._chunk_launch_ns = _SPAN_CHUNK_LAUNCH.last_ns
             # With nothing ahead of it the chunk begins now; behind a step
             # in flight, when that step's read returns (``_run_step``).
             self._chunk_began_ns = time.perf_counter_ns()
-            # The host's launch, under the step in flight (see _SPAN_PREFILL).
-            _PREFILL_NS.inc(self._chunk_began_ns - t0)
             st["cached"] = done + n
             self.prefill_chunks += 1
             self.prefill_tokens += n
 
     def _run_step(self, slots):
-        """One call of the batcher's loop: wait for the chunk the call
-        before dispatched, dispatch the chunk that is due, launch the step
-        for ``slots`` behind it, THEN read the step launched by the call
-        before and hand over its results - ``(ticket, emits, done)`` for
-        each session that step stepped - or None when there is no such step
-        yet."""
+        """One call of the batcher's loop (``_call``), timed: what it took
+        less what it waited on the device goes to ``decode/host/ns``."""
+        t0 = time.perf_counter_ns()
+        self._blocked_ns = 0
+        results = self._call(slots)
+        _HOST_NS.inc(time.perf_counter_ns() - t0 - self._blocked_ns)
+        return results
+
+    def _call(self, slots):
+        """Wait for the chunk the call before dispatched, dispatch the chunk
+        that is due, launch the step for ``slots`` behind it, THEN read the
+        step launched by the call before and hand over its results -
+        ``(ticket, emits, done)`` for each session that step stepped - or
+        None when there is no such step yet."""
         self._await_chunk()
         model = self._get_model()
         flight = self._flight
@@ -484,7 +512,7 @@ class _DecodeEngine:
             t is not None and t.state["pos"] < t.state["end"] for t in slots
         )
         if self._counts and (self._counters_asked.is_set() or not stepping):
-            with self._cache_donated():
+            with self._cache_donated(), _SPAN_COUNTERS:
                 self._read_counters()
         if flight is not None and (
             not stepping or model is None or model[1] is not flight.params
@@ -557,8 +585,12 @@ class _DecodeEngine:
         return _Flight(self._selection, rows, held, rows_read, params)
 
     def _collect(self, flight: _Flight) -> list:
-        with self._cache_donated(), _SPAN_FETCH:
-            selected = np.asarray(flight.selection)
+        with self._cache_donated():
+            if flight.selection.is_ready():
+                self.reads_ready += 1
+            with _SPAN_FETCH:
+                selected = np.asarray(flight.selection)
+        self._blocked_ns += _SPAN_FETCH.last_ns
         with _SPAN_SELECT:
             results = [
                 (t, [] if i is None else [int(selected[i])], done)
@@ -579,6 +611,7 @@ class _DecodeEngine:
         s["cache_rows_read"] = self.cache_rows_read
         s["ahead_steps"] = self.ahead_steps
         s["idle_rows"] = self.idle_rows
+        s["reads_ready"] = self.reads_ready
         s["state_bytes"] = self.state_bytes
         if self._counts:
             # Ask the step thread and give it a step and a chunk's time; a

@@ -7,6 +7,14 @@ engine hands a step, fails here and not in a chip run.
 
 The cases are the benchmark's own functions, imported: each parametrised
 case counts and none is written twice.
+
+Since PR 36 also ``benchmarks/tests/test_window_readers.py``'s cases: the
+readers of the engine's own record and the rehearsal of every serve cell that
+names their metrics - a program PR that renames ``decode/itl_ms``,
+``decode/host/ns`` or ``decode_reads_ready``, or moves a span the join reads,
+fails here too.  In THIS file because a rehearsal loads every core: pytest
+hands a file to one worker, so these run after the cases above and not beside
+them (the planted-weights cases below sample what finishes in three seconds).
 """
 
 import importlib.util
@@ -18,12 +26,17 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-_spec = importlib.util.spec_from_file_location(
-    "benchmarks_tests_test_families",
-    os.path.join(ROOT, "benchmarks", "tests", "test_families.py"),
-)
-_cases = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(_cases)
+def _cases_of(name: str):
+    spec = importlib.util.spec_from_file_location(
+        f"benchmarks_tests_{name}",
+        os.path.join(ROOT, "benchmarks", "tests", f"{name}.py"),
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+_cases = _cases_of("test_families")
 
 test_every_family_file_has_its_paths_whole_interface = (
     _cases.test_every_family_file_has_its_paths_whole_interface)
@@ -33,6 +46,11 @@ test_every_cell_rehearses_correct_through_its_family = (
     _cases.test_every_cell_rehearses_correct_through_its_family)
 test_a_new_family_is_files_and_entries_only = (
     _cases.test_a_new_family_is_files_and_entries_only)
+
+globals().update({
+    name: case for name, case in vars(_cases_of("test_window_readers")).items()
+    if name.startswith("test_")
+})
 
 
 def test_an_altered_selection_is_caught_by_the_serve_cells_comparison(monkeypatch):

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -138,8 +139,6 @@ def test_telemetry_imports_and_resolves_spans_without_jax():
     handle may not import it (only ENTERING a span reaches for the
     profiler's annotation)."""
     import subprocess
-    import sys
-
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     code = """
 import importlib, os, sys, types
@@ -203,6 +202,98 @@ def test_histogram_bounded_window_percentiles():
     # count is lifetime; the window retains only the last `capacity`.
     assert s["count"] == 100
     assert s["max"] == 99.0 and s["p50"] >= 90.0
+
+
+def _window_percentile(start: dict, end: dict, name: str, q: float):
+    """A window's percentile from two registry snapshots, as the benchmark
+    reads it (its reader is the one reduction of ``<name>/le/<edge>``)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.harness import manifest
+
+    return manifest.reader("counter_bucket_percentile")(
+        {"counters": {"start": start, "end": end}}, hist=name, q=q,
+        edges_per_octave=16)
+
+
+@pytest.mark.parametrize("shape", ["lognormal", "two_steps", "one_value", "heavy_tail"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_histogram_window_is_the_difference_of_two_snapshots(shape, seed):
+    """What a histogram observed between two snapshots, from the
+    ``<name>/le/<edge>`` counts alone: the window's own observations and no
+    earlier one - a stall from before the window is in no percentile of it,
+    though the ring's ``max`` still shows it - with p50 / p95 / p100 within
+    5 % of the exact percentiles of the same observations, for values that
+    crowd into one bucket as for values spread over many."""
+    rng = np.random.default_rng(seed)
+    n = 4000  # more than the ring of 512 keeps
+    window = {
+        "lognormal": lambda: rng.lognormal(np.log(9.0), 0.6, n),
+        # a step of 9.8 ms, a step + a chunk on a quarter of them
+        "two_steps": lambda: np.where(rng.random(n) < 0.25, 30.8, 9.8)
+        * rng.normal(1.0, 0.004, n),
+        "one_value": lambda: np.full(n, 18.4),
+        "heavy_tail": lambda: 5.0 * (1.0 + rng.pareto(1.5, n)),
+    }[shape]().tolist()
+    name = f"t_hist/window/{shape}/{seed}"
+    h = telemetry.REGISTRY.histogram(name)
+    before = [2500.0] * 3 + rng.lognormal(np.log(9.0), 0.6, 700).tolist()
+    for v in before:
+        h.observe(v)
+    start = telemetry.snapshot()
+    half = len(window) // 2
+    for v in window[:half]:
+        h.observe(v)
+    h.observe_many(window[half:])
+    end = telemetry.snapshot()
+    assert end[f"{name}_count"] - start[f"{name}_count"] == n
+    assert max(v for k, v in end.items() if k.startswith(f"{name}/le/")) == n + len(before)
+    for q in (50, 95, 100):
+        got = _window_percentile(start, end, name, q)
+        assert got == pytest.approx(np.percentile(window, q), rel=0.05), q
+    assert _window_percentile(start, end, name, 100) < 2500.0 * 0.9
+    # The same keys it had, for a person and for dtxtop, over the ring.
+    assert {f"{name}_{k}" for k in ("count", "p50", "p90", "p99", "max")} <= set(end)
+    # From the first snapshot of all, the window is everything observed.
+    everything = _window_percentile({f"{name}_count": 0}, end, name, 100)
+    assert everything == pytest.approx(2500.0, rel=0.05)
+
+
+def test_histogram_buckets_are_exact_under_threads_and_reset():
+    """``observe`` and ``observe_many`` from many threads at once lose no
+    observation of the ring's count or of the buckets; values at, under and
+    over the grid's ends land in its first and last buckets; ``reset``
+    empties the buckets with the ring."""
+    n_threads, per = 8, 500
+    h = telemetry.REGISTRY.histogram("t_hist/threads")
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def body(i):
+            for k in range(per):
+                h.observe(1.0 + i)
+                h.observe_many([0.0, 2.0**21, 1.0 + i])
+
+        threads = [threading.Thread(target=body, args=(i,)) for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    total = n_threads * per * 4
+    cum = h.cumulative()
+    assert h.count == total and cum["inf"] == total
+    assert cum["0.000976562"] == total // 4  # the zeros, at the lowest edge
+    assert total - max(v for k, v in cum.items() if k != "inf") == total // 4
+    # Each thread's value has a bucket of its own: 2 * per observations.
+    inner = sorted(v for k, v in cum.items() if k not in ("inf", "0.000976562"))
+    assert [b - a for a, b in zip([total // 4] + inner, inner)] == [2 * per] * n_threads
+    h._reset()  # what ``REGISTRY.reset()`` calls on every instrument
+    assert h.count == 0 and h.cumulative() == {}
+    assert not [k for k in telemetry.snapshot() if k.startswith("t_hist/threads/le/")]
 
 
 def test_latency_recorder_concurrent_hammer():

@@ -35,7 +35,7 @@ from distributed_tensorflow_examples_tpu.parallel import (
     wire,
 )
 from distributed_tensorflow_examples_tpu.serve import batcher as batcher_lib
-from distributed_tensorflow_examples_tpu.utils import metrics
+from distributed_tensorflow_examples_tpu.utils import metrics, telemetry
 
 D = 16
 
@@ -990,6 +990,81 @@ def test_slot_batcher_seat_wait_is_the_queued_sessions_own():
         b.stop()
 
 
+_WAIT_HISTS = ("decode/seat_wait_ms", "decode/ttft_ms", "decode/itl_ms")
+
+
+def _hist_counts() -> dict:
+    """Each of the batcher's three histograms: its lifetime count and what
+    its buckets hold (the last cumulative count), which have to agree."""
+    out = {}
+    for name in _WAIT_HISTS:
+        h = telemetry.REGISTRY.histogram(name)
+        out[name] = h.count
+        assert max(h.cumulative().values(), default=0) == out[name]
+    return out
+
+
+def _assert_hists_count_the_counters(before: dict, stats: dict) -> None:
+    """The three histograms against the sums the batcher kept before them:
+    one seat wait a session seated, one first token a session that emitted,
+    one gap for every later item."""
+    got = {k: v - before[k] for k, v in _hist_counts().items()}
+    assert got == {
+        "decode/seat_wait_ms": stats["seated"],
+        "decode/ttft_ms": stats["first_tokens"],
+        "decode/itl_ms": stats["emitted"] - stats["first_tokens"],
+    }
+
+
+@pytest.mark.parametrize("items", [1, 3])
+def test_slot_batcher_observes_every_wait_once(items):
+    """``decode/seat_wait_ms``, ``decode/ttft_ms`` and ``decode/itl_ms``
+    count what ``seated``, ``first_tokens`` and ``emitted - first_tokens``
+    count, whether a step hands a session one item or several (the items of
+    one step are emitted at one instant: gaps of 0); a session that is fed
+    and never emits observes a seat wait and nothing else."""
+
+    def run_step(slots):
+        out = []
+        for t in slots:
+            if t is None:
+                continue
+            st = t.state
+            st["seen"] = st.get("seen", 0) + 1
+            if st["seen"] <= st["feed"]:
+                out.append((t, [], False))
+            else:
+                k = st["seen"] - st["feed"]
+                out.append((t, [k] * items, k >= st["n"]))
+        return out
+
+    before = _hist_counts()
+    zeros0 = telemetry.snapshot().get("decode/itl_ms/le/0.000976562", 0)  # the lowest edge
+    b = batcher_lib.SlotBatcher(run_step, slots=2)
+    try:
+        ts = [b.open({"feed": f, "n": n}) for f, n in ((2, 4), (0, 1), (1, 3))]
+        _wait_done(ts)
+        mute = b.open({"feed": 10**9, "n": 1})
+        deadline = time.monotonic() + 10
+        while b.stats()["seated"] < 4:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        mute.cancel()
+        stats = b.stats()
+    finally:
+        b.stop()
+    assert stats["seated"] == 4 and stats["first_tokens"] == 3
+    assert stats["emitted"] == (4 + 1 + 3) * items
+    _assert_hists_count_the_counters(before, stats)
+    # Every item of a step after its first is 0 after the one before it.
+    zeros = telemetry.snapshot().get("decode/itl_ms/le/0.000976562", 0) - zeros0
+    assert zeros == (4 + 1 + 3) * (items - 1)
+    # A session's stamps are its own: opened, seated, last emitted, in order.
+    for t in ts:
+        assert t.opened_ns <= t.seated_ns <= t.emitted_ns
+    assert mute.emitted_ns is None
+
+
 # ----------------------------------------------------------------------------
 # Decode sessions over the wire (r19)
 # ----------------------------------------------------------------------------
@@ -1196,9 +1271,10 @@ def test_decode_spans_reach_the_profiler_trace_as_leaves(tmp_path, prefill):
     """A ``jax.profiler`` trace of a live replica holds all seven span
     names on the step thread's ``python`` line — where the benchmark's
     ``trace.load`` collects host events — and no two of them overlap: the
-    spans are leaves that follow each other.  ``decode/prefill`` is an
-    eighth only where a chunk ran: an adapter of two functions feeds its
-    prompts through the step and never enters it."""
+    spans are leaves that follow each other.  ``decode/prefill`` (the wait
+    for a chunk) and ``decode/chunk_launch`` (its dispatch) are there only
+    where a chunk ran: an adapter of two functions feeds its prompts
+    through the step and never enters them."""
     import glob
 
     import jax
@@ -1246,7 +1322,8 @@ def test_decode_spans_reach_the_profiler_trace_as_leaves(tmp_path, prefill):
     assert len(lines) == 1, "one step thread, one line"
     (evs,) = lines
     assert {name for _s, _e, name in evs} == {
-        *_STEP_SPANS, "decode/park", *(["decode/prefill"] if prefill else []),
+        *_STEP_SPANS, "decode/park",
+        *(["decode/prefill", "decode/chunk_launch"] if prefill else []),
     }
     for (_s0, e0, n0), (s1, _e1, n1) in zip(evs, evs[1:]):
         assert e0 <= s1, f"{n0} overlaps {n1}"
@@ -1261,6 +1338,10 @@ def test_decode_spans_reach_the_profiler_trace_as_leaves(tmp_path, prefill):
     )
     assert chunks == (1 if prefill else 0)
     assert after["decode_prefill_chunks"] - before["decode_prefill_chunks"] == chunks
+    assert (
+        after["registry"]["decode/chunk_launch/n"]
+        - before["registry"]["decode/chunk_launch/n"]
+    ) == chunks
     for name in _STEP_SPANS[1:]:
         assert (
             after["registry"][f"{name}/n"] - before["registry"][f"{name}/n"]
@@ -2085,6 +2166,151 @@ def test_a_failed_launch_fails_the_active_sessions_and_leaves_nothing_in_flight(
         eng.stop()
 
 
+@pytest.mark.parametrize("family", ["prefilled", "teacher_forced", "state"])
+def test_the_engine_observes_every_session_wait_once(monkeypatch, family):
+    """Through the engine, with prompts longer than a chunk, sessions that
+    queue for a slot and steps that emit for one row and not for another:
+    the three histograms' counts are the counters' ``seated``,
+    ``first_tokens`` and ``emitted - first_tokens``."""
+    fns = {
+        "prefilled": _toy_cached_decode_fns, "state": _toy_state_decode_fns,
+        "teacher_forced": lambda: _toy_cached_decode_fns()[:2],
+    }[family]()
+    before = _hist_counts()
+    eng = _ahead_engine(monkeypatch, fns)
+    try:
+        _run_sessions(eng, _AHEAD_PROMPTS, _AHEAD_BUDGETS)
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    assert stats["seated"] == stats["first_tokens"] == len(_AHEAD_PROMPTS)
+    assert stats["emitted"] == sum(_AHEAD_BUDGETS)
+    _assert_hists_count_the_counters(before, stats)
+
+
+class _TickingClock:
+    """``time`` with a ``perf_counter_ns`` that advances one tick a read,
+    whichever thread reads: every interval is a count of reads."""
+
+    TICK = 1000
+
+    def __init__(self):
+        self._now = 10**12
+        self._lock = threading.Lock()
+
+    def perf_counter_ns(self) -> int:
+        with self._lock:
+            self._now += self.TICK
+            return self._now
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_the_host_share_of_a_call_is_its_wall_time_less_its_two_waits(monkeypatch):
+    """``decode/host/ns`` + the time inside ``decode/fetch`` + the time
+    inside the ``decode/prefill`` wait is the wall time of the engine's
+    calls, on a clock that ticks once a read: nothing else is taken off a
+    call (not the chunk's launch, not the upload, not the select), and
+    nothing is taken off twice.  ``decode/prefill/ns`` still holds each
+    chunk's launch and its stretch on the device beside the wait."""
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    clock = _TickingClock()
+    for mod in (model_server, batcher_lib, telemetry):
+        monkeypatch.setattr(mod, "time", clock)
+    # The wait alone: ``decode/prefill/ns`` also takes the hand-booked part.
+    wait = telemetry.span("t_host_share/prefill_wait")
+    monkeypatch.setattr(model_server, "_SPAN_PREFILL", wait)
+    names = ("decode/host/ns", "decode/fetch/ns", "decode/prefill/ns",
+             "decode/chunk_launch/ns", "decode/chunk_launch/n",
+             "t_host_share/prefill_wait/ns", "t_host_share/prefill_wait/n")
+
+    def read():
+        return {k: telemetry.REGISTRY.counter(k).value for k in names}
+
+    before = read()
+    gate = threading.Event()
+    eng = _ahead_engine(monkeypatch, _toy_cached_decode_fns(), gate)
+    run, walls = eng.batcher._run, []
+
+    def timed(slots):
+        t0 = clock.perf_counter_ns()
+        try:
+            return run(slots)
+        finally:
+            # Two reads of the clock lie between this pair and the call's own.
+            walls.append(clock.perf_counter_ns() - t0 - 2 * clock.TICK)
+
+    eng.batcher._run = timed
+    try:
+        # Opened while the first call stands at the gate: from there on the
+        # step thread alone reads the clock.
+        tickets = [
+            eng.open(np.asarray(p, np.int32), n)
+            for p, n in zip(_AHEAD_PROMPTS, _AHEAD_BUDGETS)
+        ]
+        gate.set()
+        _wait_done(tickets, 60)
+        deadline = time.monotonic() + 10
+        while eng.stats()["slots_active"]:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    d = {k: v - before[k] for k, v in read().items()}
+    assert stats["step_errors"] == 0 and stats["prefill_chunks"] == 5
+    assert d["decode/host/ns"] > 0
+    assert (
+        d["decode/host/ns"] + d["decode/fetch/ns"]
+        + d["t_host_share/prefill_wait/ns"]
+    ) == sum(walls)
+    # Each chunk was launched in a span of its own and waited for once; the
+    # launch is booked onto ``decode/prefill/ns`` with the chunk's stretch
+    # (at least the one read that ends it).
+    assert d["decode/chunk_launch/n"] == d["t_host_share/prefill_wait/n"] == 5
+    assert d["decode/chunk_launch/ns"] >= 5 * clock.TICK
+    assert d["decode/prefill/ns"] >= d["decode/chunk_launch/ns"] + 5 * clock.TICK
+
+
+class _Selection:
+    """What a launch leaves the host to read, by a script."""
+
+    def __init__(self, ready: bool, slots: int):
+        self._ready, self._slots = ready, slots
+
+    def is_ready(self) -> bool:
+        return self._ready
+
+    def __array__(self, dtype=None, copy=None):
+        return np.zeros(self._slots, np.int32)
+
+
+@pytest.mark.parametrize(
+    "script", [(False,) * 4, (True, False, True, True, False), (True,) * 3])
+def test_reads_ready_counts_the_reads_that_found_their_step_done(monkeypatch, script):
+    """``reads_ready`` rises by one for each read whose selection says
+    ``is_ready()`` before the host waits for it - the step was done first,
+    so the host set its pace - and by none for the others."""
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    eng = _ahead_engine(monkeypatch, _toy_cached_decode_fns())
+    try:
+        assert eng.stats()["reads_ready"] == 0
+        for ready in script:  # the step thread is parked: nothing else reads
+            flight = model_server._Flight(
+                _Selection(ready, eng.slots), [], 0, 0.0, None)
+            assert eng._collect(flight) == []
+        assert eng.stats()["reads_ready"] == sum(script)
+        # A live engine on the CPU reads each step once, ready or not.
+        _run_sessions(eng, [[1, 2, 3]], [6])
+        stats = eng.stats()
+        assert sum(script) <= stats["reads_ready"] <= sum(script) + stats["steps"]
+    finally:
+        eng.stop()
+
+
 def _watch_chunks(eng) -> list:
     """Record, for every chunk dispatched (not the chunk of no token that
     compiles the program), its slot, offset and count and the step that was
@@ -2453,6 +2679,7 @@ def test_counters_are_read_on_the_step_thread_and_survive_a_lost_cache(monkeypat
         lambda: (0, params), *fns, slots=2, max_len=40, max_sessions=8)
     readers = []
     read = eng._read_counters
+    spans0 = telemetry.REGISTRY.counter("decode/counters/n").value
     monkeypatch.setattr(
         eng, "_read_counters",
         lambda: (readers.append(threading.current_thread().name), read())[1])
@@ -2466,6 +2693,8 @@ def test_counters_are_read_on_the_step_thread_and_survive_a_lost_cache(monkeypat
         assert first == cfg.moe_topk * (4 * (cfg.num_layers - 1) + 30 * cfg.num_layers)
         assert seen == sorted(seen) and seen[-1] <= first
         assert readers and set(readers) == {"dtx-decode-slots"}
+        # Each of those reads lies in a span of its own, ``decode/counters``.
+        assert telemetry.REGISTRY.counter("decode/counters/n").value - spans0 == len(readers)
         # Lose the cache: the next launch raises.
         step = eng._step_jit
         monkeypatch.setattr(eng, "_step_jit", lambda *a: (_ for _ in ()).throw(RuntimeError("x")))
