@@ -86,13 +86,21 @@ from . import decoding, layers, mla
 #: 18.46 / 18.48 at 512 and 18.59 / 18.61 at 1024 (the loop: 22.25 /
 #: 23.30): level where the cell stands, 512 reads less past a slot's row.
 DECODE_BLOCK = 512
-#: Cached positions a prefill chunk expands and attends over at a time: at
-#: offsets 0 / 1536 / 3072 a chunk takes 29.5 / 44.9 / 60.3 ms at 64, 29.6 /
-#: 44.2 / 59.0 at 128, 29.8 / 45.1 / 60.5 at 256 (twice, to 0.02 ms), 31.3 /
-#: 52.0 / 73.0 at 512 (models/longcat.py's choice) and 39.5 / 64.3 / 84.1 at
-#: 1024; ABSORBED, with products twice as wide again at 128 heads, 37.6 /
-#: 61.4 / 85.4 at 512 and 44.3 / 59.3 / 89.6 at 1024 (same two runs).
-PREFILL_BLOCK = 128
+#: Cached positions a prefill chunk expands and attends over at a time
+#: (``mla.Spec.prefill_block``): an item of the kernel's grid
+#: (ops/latent_prefill.py) takes one block for a group of heads, the CPU's
+#: loop one a trip.  Chosen on one v5e chip at the served widths, 128 heads,
+#: 64 slots x 4096 (my chip runs, PR 38; PERF.md section 6): a whole chunk
+#: at offsets 0 / 512 / 1536 / 3072 takes 30.3 / 30.4 / 34.2 / 41.4 ms at
+#: 1024 and 29.7 / 32.3 / 37.1 / 44.1 at 512 (the loop this kernel replaced,
+#: at its best block of 128, where wider scores spilled: 31.0 / 36.1 / 45.9 /
+#: 60.6); one sub-layer's attention at 1536 / 3072 takes 1.26 / 2.28 ms at
+#: 1024 and 1.61 / 2.63 at 512 (the loop: 3.35 / 5.61).  A block of 1024
+#: under a chunk at 0 or 1024 expands 512 positions no query sees, and still
+#: the cell's prompts (256-2048: a chunk at 0 / 512 / 1024 / 1536 in 100 /
+#: 85 / 57 / 29 of a hundred) come out 1.7 % shorter at 1024.  ABSORBED, as
+#: loops, 37.6 / 61.4 / 85.4 ms at 0 / 1536 / 3072 (my chip run, PR 33).
+PREFILL_BLOCK = 1024
 
 
 @dataclasses.dataclass(frozen=True)
@@ -407,7 +415,8 @@ def serve_decode_fns(cfg: Config):
     """``(init_cache_fn, step_fn, prefill_fn)`` for ``serve.
     ModelReplicaServer(decode_fns=...)``.  ``step_fn`` takes ``live`` (it
     reads and counts live rows only) and says what a step reads of the cache
-    (``cache_rows_read``: ``mla.decode_rows_read`` at this model's block)."""
+    (``cache_rows_read``: ``mla.decode_rows_read`` at this model's block), as
+    ``prefill_fn`` says what a chunk reads (``mla.prefill_rows_read``)."""
 
     def init_cache_fn(slots: int, max_len: int):
         return init_cache(cfg, slots, max_len)
@@ -419,6 +428,8 @@ def serve_decode_fns(cfg: Config):
 
     def prefill_fn(params, cache, tokens, slot, offset, n_valid):
         return prefill_chunk(cfg, params, cache, tokens, slot, offset, n_valid)
+
+    prefill_fn.cache_rows_read = functools.partial(mla.prefill_rows_read, PREFILL_BLOCK)
 
     return init_cache_fn, step_fn, prefill_fn
 

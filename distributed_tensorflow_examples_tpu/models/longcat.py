@@ -23,9 +23,10 @@ a position leaves behind is 576 values where expanded keys and values are
 ``param_dtype``.  The chunk EXPANDS because by the counts of a 512-token
 chunk that is the cheaper form at every depth - absorbing trades the
 expansion (``T x 16.8`` MFLOP) for products three and four times as wide
-(``T x 67`` MFLOP more) - and so the chip reads it: at offsets 0 / 3584 /
-7168 a chunk takes 30.4 / 45.8 / 60.1 ms expanded and 33.0 / 49.9 / 66.7
-absorbed (my chip run, PR 31).
+(``T x 67`` MFLOP more) - and so the chip read it while both were loops:
+at offsets 0 / 3584 / 7168 a chunk took 30.4 / 45.8 / 60.1 ms expanded and
+33.0 / 49.9 / 66.7 absorbed (my chip run, PR 31).  On a TPU the expanded
+form is one kernel a sub-layer since PR 38 (:data:`PREFILL_BLOCK`).
 
 MoE (ops/moe.py ``apply_share``): softmax router in float32 over
 ``n_routed_experts + zero_expert_num``; ``moe_topk`` of ``s + bias``;
@@ -83,9 +84,20 @@ from . import decoding, layers, mla
 #: (the loop: 15.98): level, and 1024 is kept.
 DECODE_BLOCK = 1024
 #: Cached positions a prefill chunk expands and attends over at a time
-#: (1024 reads 35.6 / 57.0 / 84.7 ms at offsets 1500 / 4000 / 7900: my chip
-#: run, PR 31).
-PREFILL_BLOCK = 512
+#: (``mla.Spec.prefill_block``): an item of the kernel's grid
+#: (ops/latent_prefill.py) takes one block for a group of heads, the CPU's
+#: loop one a trip.  Chosen on one v5e chip at the served widths, 64 heads,
+#: 32 slots x 8192 (my chip runs, PR 38; PERF.md section 6): one sub-layer's
+#: attention of a 512-token chunk at offsets 1536 / 3584 / 7168 takes 0.67 /
+#: 1.18 / 2.21 ms at 1024, 0.84 / 1.52 / 2.71 at 512 and 1.25 / 2.30 / 4.13
+#: at 256 (the loop this kernel replaced, at its best block of 512: 1.57 /
+#: 2.91 / 5.24, same runs) - what a head pays a block whatever its width
+#: (the reductions of the running maximum and sum, the accumulator's
+#: rescale: about 1.1 us) is paid half as often - and a whole chunk at 0 /
+#: 1536 / 3584 / 7168 takes 29.7 / 31.4 / 35.5 / 42.6 ms (the loop: 30.8 /
+#: 36.9 / 45.5 / 59.6).  Heads a group: 4, 8 and 16 read level (2.21 / 2.21 /
+#: 2.19 at 7168); ops/latent_prefill.py takes the most its VMEM plan holds.
+PREFILL_BLOCK = 1024
 
 
 @dataclasses.dataclass(frozen=True)
@@ -379,7 +391,8 @@ def serve_decode_fns(cfg: Config):
     """``(init_cache_fn, step_fn, prefill_fn)`` for ``serve.
     ModelReplicaServer(decode_fns=...)``.  ``step_fn`` takes ``live`` (it
     reads and counts live rows only) and says what a step reads of the cache
-    (``cache_rows_read``: ``mla.decode_rows_read`` at this model's block)."""
+    (``cache_rows_read``: ``mla.decode_rows_read`` at this model's block), as
+    ``prefill_fn`` says what a chunk reads (``mla.prefill_rows_read``)."""
 
     def init_cache_fn(slots: int, max_len: int):
         return init_cache(cfg, slots, max_len)
@@ -391,6 +404,8 @@ def serve_decode_fns(cfg: Config):
 
     def prefill_fn(params, cache, tokens, slot, offset, n_valid):
         return prefill_chunk(cfg, params, cache, tokens, slot, offset, n_valid)
+
+    prefill_fn.cache_rows_read = functools.partial(mla.prefill_rows_read, PREFILL_BLOCK)
 
     return init_cache_fn, step_fn, prefill_fn
 

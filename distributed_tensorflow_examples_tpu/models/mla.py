@@ -35,8 +35,17 @@ Two forms of the same attention:
   The new row is written in place (:func:`write_rows`).
 - the prefill chunk and the full forward EXPAND a block of cached latents
   at a time into keys and values (``kv_b . c``) and attend as published
-  (:func:`attend_expanded`; :func:`chunk_write` puts a chunk's rows into
-  one slot).
+  (:func:`chunk_write` puts a chunk's rows into one slot first).  On a TPU
+  the chunk's running softmax over the slot's blocks is ONE kernel a
+  sub-layer (ops/latent_prefill.py): it reads the slot's rows where they
+  lie in the cache, up to the block that holds the chunk's last query, and
+  a block's scores and weights never leave VMEM
+  (:func:`prefill_rows_read` is the host's count of the rows).  On the
+  CPU, where the kernel would be interpreted, the chunk runs the same
+  softmax as a loop over a copy of the slot's rows
+  (:func:`attend_expanded`), which is also the kernel's reference and what
+  the full forward runs on every platform (under ``vmap``: parity tests and
+  ``generate``'s reference).
 
 Precision: parameters in ``dtype``; products in it with float32
 accumulation; norms, rotary and softmax in float32.
@@ -51,7 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import latent_decode
+from ..ops import latent_decode, latent_prefill
 from ..ops.flash_attention import interpret_mode
 from . import layers
 
@@ -279,12 +288,12 @@ def attend_absorbed(spec: Spec, p, q_nope, q_rope, cache, pos, live):
 
 
 def chunk_write(cache, new, slot, offset, n_valid):
-    """Write ``new [C, latent]`` rows ``[0, n_valid)`` into ``cache [S, T,
-    latent]`` at ``[slot, offset:offset + n_valid]`` and touch nothing else;
-    returns the cache and the slot's rows ``[T, latent]``.  The window
-    starts at ``min(offset, T - C)`` (``dynamic_update_slice`` clamps a start
-    that overruns and would overwrite earlier rows), the chunk rolled inside
-    it; as models/transformer.py ``_block_prefill``."""
+    """``cache [S, T, latent]`` with ``new [C, latent]`` rows ``[0,
+    n_valid)`` written at ``[slot, offset:offset + n_valid]`` and nothing
+    else touched.  The window starts at ``min(offset, T - C)``
+    (``dynamic_update_slice`` clamps a start that overruns and would
+    overwrite earlier rows), the chunk rolled inside it; as
+    models/transformer.py ``_block_prefill``."""
     C, W = new.shape
     T = cache.shape[1]
     start = jnp.clip(offset, 0, T - C)
@@ -294,8 +303,16 @@ def chunk_write(cache, new, slot, offset, n_valid):
     at = (slot, start, 0)
     old = jax.lax.dynamic_slice(cache, at, (1, C, W))
     win = jnp.where(own, jnp.roll(new[None], shift, axis=1), old)
-    cache = jax.lax.dynamic_update_slice(cache, win, at)
-    return cache, jax.lax.dynamic_slice_in_dim(cache, slot, 1, axis=0)[0]
+    return jax.lax.dynamic_update_slice(cache, win, at)
+
+
+def prefill_rows_read(block: int, offset: int, chunk: int, max_len: int) -> int:
+    """Cache positions the attention of one chunk of ``chunk`` queries at
+    ``offset`` reads: whole blocks of ``block`` positions up to the chunk's
+    last query, at most the cache - the grid of ops/latent_prefill.py and
+    the trips of :func:`attend_expanded` alike."""
+    blk = min(block, max_len)
+    return min(latent_prefill.blocks_read(offset, chunk, blk, max_len) * blk, max_len)
 
 
 # ----------------------------------------------------------------------------
@@ -333,13 +350,23 @@ def prefill(spec: Spec, p, h, cache, slot, offset, n_valid):
     """The prefill chunk's sub-layer: the normed ``h [C, D]`` of ONE slot's
     positions ``offset .. offset + C - 1``, the first ``n_valid`` real ->
     (``[C, D]`` float32, the cache with the valid rows written); reads no
-    further than ``offset + C``."""
+    further than ``offset + C``.  The attention is one form a platform, as
+    :func:`attend_absorbed`'s: the kernel on a TPU (it reads the slot's rows
+    where they lie), the loop over a copy of them where the kernel would be
+    interpreted."""
     C, T = h.shape[0], cache.shape[1]
-    block = min(spec.prefill_block, T)
     q_pos = offset + jnp.arange(C)
-    n_blocks = jnp.minimum(offset + C - 1, T - 1) // block + 1
     q_nope, q_rope, new = query_and_latent(spec, p, h, q_pos)
     with jax.named_scope("mla/prefill"):
-        cache, rows = chunk_write(cache, new, slot, offset, n_valid)
-        o = attend_expanded(spec, p, q_nope, q_rope, rows, q_pos, n_blocks, block)
+        cache = chunk_write(cache, new, slot, offset, n_valid)
+        if interpret_mode():
+            block = min(spec.prefill_block, T)
+            rows = jax.lax.dynamic_index_in_dim(cache, slot, keepdims=False)
+            o = attend_expanded(
+                spec, p, q_nope, q_rope, rows, q_pos,
+                latent_prefill.blocks_read(offset, C, block, T), block)
+        else:
+            o = latent_prefill.latent_prefill_attention(
+                q_nope, q_rope, p["kv_b"]["kernel"], cache, slot, offset,
+                nope=spec.nope, scale=spec.softmax_scale, block=spec.prefill_block)
     return out_proj(spec, p, o), cache

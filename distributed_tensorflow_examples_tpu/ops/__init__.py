@@ -11,3 +11,4 @@ from . import flash_attention  # noqa: F401
 from . import selective_scan  # noqa: F401
 from . import grouped_ffn  # noqa: F401
 from . import latent_decode  # noqa: F401
+from . import latent_prefill  # noqa: F401

@@ -257,6 +257,10 @@ class _DecodeEngine:
     prompt token are cached it is an ordinary decode row at ``pos = P - 1``
     and the next step emits its first token.  Without ``prefill_fn`` the
     prompt is teacher-forced through the decode step, a token a step.
+    A ``prefill_fn`` may say how far into the slot's cache a chunk's
+    attention reads by an attribute ``cache_rows_read(offset, chunk,
+    max_len)`` (positions, from the chunk's offset and width); without it a
+    chunk is taken to read all ``max_len`` (counter ``prefill_rows_read``).
 
     What a model counts on the device.  A model whose cache tree has an
     entry ``counters`` - a dict of small int32 arrays that its step and its
@@ -314,6 +318,10 @@ class _DecodeEngine:
             if prefill_fn else None
         )
         self._chunk = min(PREFILL_CHUNK, self.max_len)
+        # How far into the slot's cache a chunk reads: all of it, unless told.
+        self._chunk_rows_read = getattr(
+            prefill_fn, "cache_rows_read", lambda offset, chunk, max_len: max_len
+        )
         self._prefill_warm = False
         self.prefill_chunks = 0
         self.prefill_tokens = 0  # valid tokens; padding is not counted
@@ -329,6 +337,8 @@ class _DecodeEngine:
         self.held_rows = 0
         # Cache positions of each slot the steps' attention read, summed.
         self.cache_rows_read = 0
+        # ... and of the chunk's slot the chunks' attention read, summed.
+        self.prefill_rows_read = 0
         # Steps launched while the step before them had not been read, and
         # slot-steps of sessions stepped past their last token.
         self.ahead_steps = 0
@@ -489,6 +499,8 @@ class _DecodeEngine:
             st["cached"] = done + n
             self.prefill_chunks += 1
             self.prefill_tokens += n
+            self.prefill_rows_read += self._chunk_rows_read(
+                done, self._chunk, self.max_len)
 
     def _run_step(self, slots):
         """One call of the batcher's loop (``_call``), timed: what it took
@@ -609,6 +621,7 @@ class _DecodeEngine:
         s["queued_chunks"] = self.queued_chunks
         s["held_rows"] = self.held_rows
         s["cache_rows_read"] = self.cache_rows_read
+        s["prefill_rows_read"] = self.prefill_rows_read
         s["ahead_steps"] = self.ahead_steps
         s["idle_rows"] = self.idle_rows
         s["reads_ready"] = self.reads_ready

@@ -47,10 +47,21 @@ test_every_cell_rehearses_correct_through_its_family = (
 test_a_new_family_is_files_and_entries_only = (
     _cases.test_a_new_family_is_files_and_entries_only)
 
+_window = _cases_of("test_window_readers")
 globals().update({
-    name: case for name, case in vars(_cases_of("test_window_readers")).items()
-    if name.startswith("test_")
+    name: case for name, case in vars(_window).items() if name.startswith("test_")
 })
+
+
+def test_the_fourteen_metrics_are_entries_and_files_of_their_families(monkeypatch):
+    """PR 36's case, which looks for its fourteen entries at the very END of
+    ``per_layer``, on the manifest cut off after the last of them: a later
+    PR appends its own there (PR 38: two), as ``BENCHMARK.json`` asks, and
+    ``benchmarks/tests`` is not a program PR's to edit."""
+    entries = _window.M["per_layer"]
+    last = max(i for i, p in enumerate(entries) if p["name"] in _window.NEW)
+    monkeypatch.setitem(_window.M, "per_layer", entries[:last + 1])
+    _window.test_the_fourteen_metrics_are_entries_and_files_of_their_families()
 
 
 def test_an_altered_selection_is_caught_by_the_serve_cells_comparison(monkeypatch):

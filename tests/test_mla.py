@@ -80,12 +80,18 @@ def test_shapes_of_a_sub_layer_and_the_latent_row():
     assert q_nope.shape == (5, 3, 8) and q_rope.shape == (5, 3, 8) and row.shape == (5, 24)
 
 
+@pytest.mark.parametrize("chunks", ["loop", "kernel"])
 @pytest.mark.parametrize("kind", ["RANK_SCALED", "YARN"])
-def test_chunks_then_absorbed_steps_are_the_expanded_full_forward(kind):
+def test_chunks_then_absorbed_steps_are_the_expanded_full_forward(kind, chunks, monkeypatch):
     """One sequence of 29 positions (past the YARN spec's original 16): the
     full forward in the expanded form; the same through a cache - two chunks
     into slot 1 of a used cache, the second padded, then absorbed steps
-    beside a row that is not the session's."""
+    beside a row that is not the session's.  ``kernel``: the chunks and the
+    steps as a TPU runs them - ``mla`` told it is not interpreted, so it
+    calls ops/latent_prefill.py and ops/latent_decode.py, which here still
+    are."""
+    if chunks == "kernel":
+        monkeypatch.setattr(mla, "interpret_mode", lambda: False)
     spec = _spec(kind)
     p = _params(spec)
     h = jax.random.normal(jax.random.key(2), (1, 29, D))
@@ -171,8 +177,7 @@ def test_a_chunk_at_the_end_of_the_cache_is_written_where_it_belongs():
     """A chunk whose window would overrun the cache is rolled inside it."""
     new = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4) + 1
     cache = jnp.zeros((2, 12, 4))
-    out, rows = mla.chunk_write(cache, new, 1, 8, 3)  # rows 8, 9, 10 of slot 1
+    out = mla.chunk_write(cache, new, 1, 8, 3)  # rows 8, 9, 10 of slot 1
     np.testing.assert_array_equal(np.asarray(out[1, 8:11]), np.asarray(new[:3]))
     assert not np.asarray(out[1, :8]).any() and not np.asarray(out[1, 11:]).any()
     assert not np.asarray(out[0]).any()
-    np.testing.assert_array_equal(np.asarray(rows), np.asarray(out[1]))

@@ -120,32 +120,57 @@ def test_grouped_ffn_compiles_for_a_v5e_at_the_served_widths(
     assert "tpu_custom_call" in text and f"%{gf.KERNEL_NAME}" in text
 
 
-@pytest.mark.parametrize("slots,heads,length,block", [(64, 128, 4096, 512), (32, 64, 8192, 1024)])
-def test_latent_decode_compiles_for_a_v5e_at_the_served_widths(
-    one_chip, monkeypatch, slots, heads, length, block,
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("model,slots,heads,length", [("deepseek", 64, 128, 4096), ("longcat", 32, 64, 8192)])
+def test_latent_attention_compiles_for_a_v5e_at_the_served_widths(
+    one_chip, monkeypatch, kernel, model, slots, heads, length,
 ):
-    """Mosaic takes ops/latent_decode.py at the two served shapes (DeepSeek-V2
-    and LongCat: latent 576 = 512 values + 64 rotated, bfloat16): the grid of
-    a traced length, the five prefetched index arrays, the two products over
-    a latent that is no multiple of 128 lanes, and the kernel's name, which
-    the benchmark's readers look for.  And it takes the cache AS IT LIES: the
-    compiler puts the positions of a ``[S, T, 576]`` array last, the kernel
-    reads blocks of ``[576, block]``, and the compiled program holds no copy
-    of the cache (302 MB a call, were it otherwise) - its temporaries are the
-    work list and little else.  (Kept in this file: the one that loads the
-    TPU's library.)"""
+    """Mosaic takes ops/latent_decode.py and ops/latent_prefill.py at the two
+    served shapes (DeepSeek-V2 and LongCat: latent 576 = 512 values + 64
+    rotated, bfloat16, each model's own blocks; the chunk 512 queries): the
+    grids of a traced extent, the prefetched index arrays, the products over
+    a latent that is no multiple of 128 lanes, the prefill kernel's VMEM
+    allowance, and the kernels' names, which the benchmark's readers look
+    for.  And both take the cache AS IT LIES: the compiler puts the positions
+    of a ``[S, T, 576]`` array last, the kernels read blocks of ``[576,
+    block]``, and the compiled program holds no copy of the cache (302 MB a
+    call, were it otherwise) nor - the chunk, which writes its rows into the
+    cache it was donated and then attends over them - of one slot's rows
+    (4.7 and 9.4 MB): the transpose is a bitcast.  (Kept in this file: the
+    one that loads the TPU's library.)"""
+    from distributed_tensorflow_examples_tpu import models
+    from distributed_tensorflow_examples_tpu.models import mla
     from distributed_tensorflow_examples_tpu.ops import latent_decode as ld
+    from distributed_tensorflow_examples_tpu.ops import latent_prefill as lp
 
     monkeypatch.setattr(ld, "interpret_mode", lambda: False)
+    monkeypatch.setattr(lp, "interpret_mode", lambda: False)
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    compiled = jax.jit(
-        lambda q, c, n: ld.latent_decode_attention.__wrapped__(
-            q, c, n, values=512, scale=0.1, block=block)
-    ).lower(
-        s((slots, heads, 576), jnp.bfloat16), s((slots, length, 576), jnp.bfloat16),
-        s((slots,), jnp.int32),
-    ).compile()
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    cache = s((slots, length, 576), bf16)
+    if kernel == "decode":
+        name = ld.KERNEL_NAME
+        compiled = jax.jit(
+            lambda q, c, n: ld.latent_decode_attention.__wrapped__(
+                q, c, n, values=512, scale=0.1, block=getattr(models, model).DECODE_BLOCK)
+        ).lower(s((slots, heads, 576), bf16), cache, s((slots,), i32)).compile()
+    else:
+        name = lp.KERNEL_NAME
+
+        def chunk(q_nope, q_rope, kv_b, new, c, slot, offset, n_valid):
+            c = mla.chunk_write(c, new, slot, offset, n_valid)
+            return c, lp.latent_prefill_attention.__wrapped__(
+                q_nope, q_rope, kv_b, c, slot, offset, nope=128, scale=0.1,
+                block=getattr(models, model).PREFILL_BLOCK)
+
+        compiled = jax.jit(chunk, donate_argnums=4).lower(
+            s((512, heads, 128), bf16), s((512, heads, 64), bf16),
+            s((512, heads * 256), bf16), s((512, 576), bf16), cache,
+            s((), i32), s((), i32), s((), i32),
+        ).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and f"%{ld.KERNEL_NAME}" in text
-    cache_bytes = slots * length * 576 * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 16
+    assert "tpu_custom_call" in text and f"%{name}" in text
+    # The step's temporaries are the work list and the absorbed query; the
+    # chunk's nothing to speak of (a slot's rows are 4.7 MB and more).
+    limit = slots * length * 576 * 2 / 16 if kernel == "decode" else 3 << 20
+    assert compiled.memory_analysis().temp_size_in_bytes < limit

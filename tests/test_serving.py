@@ -1695,24 +1695,13 @@ def test_cache_rows_read_of_a_latent_cache_is_each_live_slots_own_blocks(monkeyp
     stays empty: the engine's count is the sum, over the steps it launched,
     of that mean - reckoned here from the positions and live rows the
     engine handed the model's hook."""
-    import jax
-
     from distributed_tensorflow_examples_tpu import models
     from distributed_tensorflow_examples_tpu.serve import model_server
 
     block, max_len, slots = 4, 30, 3
     monkeypatch.setattr(models.deepseek, "DECODE_BLOCK", block)
     monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
-    cfg = models.deepseek.Config(
-        vocab_size=64, hidden_size=32, intermediate_size=32, moe_intermediate_size=16,
-        num_hidden_layers=2, num_attention_heads=2, kv_lora_rank=16, q_lora_rank=16,
-        qk_rope_head_dim=8, qk_nope_head_dim=8, v_head_dim=8, n_routed_experts=8,
-        n_shared_experts=1, n_group=2, topk_group=1, num_experts_per_tok=2,
-        rope_original_max_position_embeddings=16, experts_held=4, expert_first=4,
-        vocab_rows=64, param_dtype="float32",
-    )
-    params = models.deepseek.init(cfg, jax.random.key(0))
-    init_cache_fn, step_fn, prefill_fn = models.deepseek.serve_decode_fns(cfg)
+    params, (init_cache_fn, step_fn, prefill_fn) = _tiny_latent_fns("deepseek")
     said, launches = step_fn.cache_rows_read, []
 
     def hook(pos, live, max_len):
@@ -1744,6 +1733,71 @@ def test_cache_rows_read_of_a_latent_cache_is_each_live_slots_own_blocks(monkeyp
     deepest = sum(min(max_len, (int(pos.max()) // block + 1) * block)
                   for pos, _live in launches[:stats["steps"]])
     assert stats["cache_rows_read"] < 0.7 * deepest
+
+
+def _tiny_latent_fns(model: str):
+    """``(params, serve_decode_fns)`` of one of the two models on
+    models/mla.py, cut small."""
+    import jax
+
+    from distributed_tensorflow_examples_tpu import models
+
+    if model == "deepseek":
+        mod, cfg = models.deepseek, models.deepseek.Config(
+            vocab_size=64, hidden_size=32, intermediate_size=32, moe_intermediate_size=16,
+            num_hidden_layers=2, num_attention_heads=2, kv_lora_rank=16, q_lora_rank=16,
+            qk_rope_head_dim=8, qk_nope_head_dim=8, v_head_dim=8, n_routed_experts=8,
+            n_shared_experts=1, n_group=2, topk_group=1, num_experts_per_tok=2,
+            rope_original_max_position_embeddings=16, experts_held=4, expert_first=4,
+            vocab_rows=64, param_dtype="float32",
+        )
+    else:
+        mod, cfg = models.longcat, models.longcat.Config(
+            vocab_size=64, hidden_size=32, ffn_hidden_size=32, expert_ffn_hidden_size=16,
+            num_layers=2, num_attention_heads=2, kv_lora_rank=16, q_lora_rank=16,
+            qk_rope_head_dim=8, qk_nope_head_dim=8, v_head_dim=8, n_routed_experts=8,
+            zero_expert_num=4, moe_topk=2, experts_held=4, expert_first=4,
+            vocab_rows=64, param_dtype="float32",
+        )
+    return mod.init(cfg, jax.random.key(0)), mod.serve_decode_fns(cfg)
+
+
+@pytest.mark.parametrize("model", ["deepseek", "longcat", "transformer"])
+def test_prefill_rows_read_counts_what_a_chunk_reads(monkeypatch, model):
+    """``prefill_rows_read`` grows a chunk by the positions of its slot the
+    chunk's attention read.  The two models on models/mla.py say what the
+    grid of ops/latent_prefill.py runs (the trips of the loop that is its
+    form here): whole blocks up to the chunk's last query, whatever the
+    chunk holds of real tokens; a model whose ``prefill_fn`` does not say is
+    taken to read all ``max_len`` a chunk."""
+    from distributed_tensorflow_examples_tpu import models
+    from distributed_tensorflow_examples_tpu.ops import latent_prefill
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    chunk, block, max_len = 8, 4, 30
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", chunk)
+    if model == "transformer":
+        eng, _params = _tiny_transformer_engine(max_len=max_len)
+    else:
+        monkeypatch.setattr(getattr(models, model), "PREFILL_BLOCK", block)
+        params, fns = _tiny_latent_fns(model)
+        eng = model_server._DecodeEngine(
+            lambda: (0, params), *fns, slots=2, max_len=max_len, max_sessions=4)
+    try:
+        # 21 and 12 cached positions (a prompt's last token goes through the
+        # step): chunks at 0, 8, 16 (5 real tokens) and at 0, 8 (4 real).
+        _run_sessions(eng, [list(range(1, 23)), list(range(1, 14))], [2, 2])
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    assert stats["prefill_chunks"] == 5 and stats["prefill_tokens"] == 21 + 12
+    if model == "transformer":
+        assert stats["prefill_rows_read"] == 5 * max_len
+    else:
+        grid = lambda offset: block * int(
+            latent_prefill.blocks_read(np.int32(offset), chunk, block, max_len))
+        assert [grid(o) for o in (0, 8, 16)] == [8, 16, 24]
+        assert stats["prefill_rows_read"] == 2 * (8 + 16) + 24
 
 
 # ----------------------------------------------------------------------------
