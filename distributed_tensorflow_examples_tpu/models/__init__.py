@@ -18,10 +18,13 @@ traced function XLA can fuse end-to-end.
   layer with zero-compute experts (Meituan LongCat-Flash), served
 - ``deepseek`` — latent attention with scaled rotary positions, a choice of
   experts limited to groups, shared experts (DeepSeek-V2), served
+- ``afmoe``    — gated grouped-query attention behind QK-norm, a ring cache
+  in the sliding-window layers beside full rows in the global ones, whole
+  expert layers under a sigmoid router (arcee-ai Trinity), served
 - ``mla``      — the latent-attention sub-layer ``longcat`` and ``deepseek``
   share
 - ``decoding`` — the generate loop of a model served by chunks and steps,
-  which the same two call
+  which those two and ``afmoe`` call
 """
 
 from . import layers  # noqa: F401
@@ -36,3 +39,4 @@ from . import mla  # noqa: F401
 from . import decoding  # noqa: F401
 from . import longcat  # noqa: F401
 from . import deepseek  # noqa: F401
+from . import afmoe  # noqa: F401

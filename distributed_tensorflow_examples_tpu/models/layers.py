@@ -106,7 +106,9 @@ def rmsnorm_init(d: int, dtype=jnp.float32):
 def rmsnorm(params, x, eps: float = 1e-6):
     """``x / rms(x) * scale`` over the last axis, in float32 whatever comes
     in (the mean of squares is what a low precision loses first); returns
-    float32."""
+    float32.  Over ``[.., heads, head_dim]`` with a scale of ``head_dim`` it
+    is QK-norm: each head's query or key over its own width
+    (models/afmoe.py)."""
     x = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x * lax.rsqrt(ms + eps) * params["scale"].astype(jnp.float32)

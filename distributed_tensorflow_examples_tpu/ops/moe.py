@@ -247,7 +247,7 @@ class ShareConfig:
     n_experts: int  # routed experts of the MODEL (ids 0 .. n_experts - 1)
     n_zero: int  # zero-compute experts after them: E(u) = u
     top_k: int
-    scale: float  # every chosen score is multiplied by it; no renormalising
+    scale: float  # every chosen weight is multiplied by it
     first: int  # the routed experts held here are first .. first + held - 1
     held: int
     #: The choice limited to groups (:func:`share_choice`): expert ``e`` is
@@ -256,8 +256,16 @@ class ShareConfig:
     #: ``top_groups`` 0: the free choice over everything.
     n_group: int = 0
     top_groups: int = 0
+    #: What turns the router's logits into scores: ``"softmax"`` over all
+    #: the experts, or ``"sigmoid"``, each expert's own.
+    scoring: str = "softmax"
+    #: The chosen scores are divided by their sum (plus ``NORMALISE_EPS``)
+    #: before ``scale``: a token's weights then add up to ``scale``.
+    normalise: bool = False
 
     def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring {self.scoring!r}")
         if not self.top_groups:
             return
         if self.n_zero or self.n_group <= 0 or self.n_experts % self.n_group:
@@ -275,6 +283,10 @@ class ShareConfig:
                 f"the held experts {self.first} .. {self.first + self.held - 1} are "
                 f"not whole groups of {per}: the share would be no device's")
 
+
+#: What keeps a normalised token's weights finite where every chosen score
+#: underflows (the published modelling code's constant).
+NORMALISE_EPS = 1e-20
 
 #: What :func:`apply_share` counts, each an int32 scalar.
 SHARE_COUNTS = ("choices", "choices_held", "choices_zero", "experts_touched",
@@ -319,14 +331,16 @@ def apply_share(p, u, share: ShareConfig, live=None, *, dtype):
     zero-compute experts give.
 
     Router in float32 throughout (the product at the highest precision): ``s
-    = softmax(u . router)`` over all ``n_experts + n_zero``; the choice is
-    :func:`share_choice`'s (free over ``s + bias``, or limited to groups);
-    weights ``scale * s`` of the chosen, not renormalised.  A choice on a
-    held expert becomes a row of that expert's group (sorted by expert, each
-    group on a block boundary: ops/grouped_ffn.py); a choice on a
-    zero-compute expert adds ``w u`` where the token lives; a choice on an
-    expert that lives on another chip adds NOTHING - no capacity, no dropped
-    token, no stand-in for the other chips' part.  ``p``: ``router/kernel
+    = softmax(u . router)`` over all ``n_experts + n_zero``, or the sigmoid
+    of each (``share.scoring``); the choice is :func:`share_choice`'s (free
+    over ``s + bias``, or limited to groups); weights ``scale * s`` of the
+    chosen, or where ``share.normalise`` ``scale * s / (sum of the chosen s +
+    NORMALISE_EPS)``.  A choice on a held expert becomes a row of that
+    expert's group (sorted by expert, each group on a block boundary:
+    ops/grouped_ffn.py); a choice on a zero-compute expert adds ``w u`` where
+    the token lives; a choice on an expert that lives on another chip adds
+    NOTHING - no capacity, no dropped token, no stand-in for the other chips'
+    part.  ``p``: ``router/kernel
     [D, n_experts + n_zero]``, ``router/bias`` (or none: the choice is on
     ``s`` alone), ``gate, up [held, D, F]``, ``down [held, F, D]``.  ``live
     [T]`` bool: a row that is not live (an empty slot, padding) gets no
@@ -346,10 +360,15 @@ def apply_share(p, u, share: ShareConfig, live=None, *, dtype):
             u.astype(f32), p["router"]["kernel"].astype(f32),
             precision=jax.lax.Precision.HIGHEST,
         )
-        s = jax.nn.softmax(logits, axis=-1)
+        if share.scoring == "softmax":
+            s = jax.nn.softmax(logits, axis=-1)
+        else:
+            s = jax.nn.sigmoid(logits)
         bias = p["router"].get("bias")
         choice, chosen = share_choice(  # [T, k]
             s, share, None if bias is None else bias.astype(f32))
+        if share.normalise:
+            chosen = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + NORMALISE_EPS)
         w = share.scale * chosen
         local = choice - share.first
         on_held = (local >= 0) & (local < held) & live[:, None]

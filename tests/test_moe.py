@@ -697,3 +697,155 @@ def test_a_counted_call_adds_to_the_entries_a_model_keeps_and_to_no_other():
         "moe_tokens_reaching": 2 * int(counts["tokens_reaching"]),
         "moe_chunk_choices_held": int(counts["choices_held"])}
     assert int(counts["choices_held"]) > 0
+
+
+# ----------------------------------------------------------------------------
+# Sigmoid scores, normalised weights, a bias in the choice (AFMoE's router)
+# ----------------------------------------------------------------------------
+
+from benchmarks.reference import afmoe_ref  # noqa: E402
+
+#: 32 experts, 4 a token, one shared expert; the reference's layer 2 (the
+#: first expert layer of a model with two leading dense ones).
+C_SIGMOID = dict(
+    hidden_size=64, intermediate_size=128, moe_intermediate_size=32, num_hidden_layers=4,
+    num_dense_layers=2, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    layer_types=("sliding_attention",) * 4, sliding_window=16, num_experts=32,
+    num_experts_per_tok=4, num_shared_experts=1, route_scale=2.826, vocab_size=100,
+    rms_norm_eps=1e-5, rope_theta=1e4, init_std=0.125, router_std_factor=0.25,
+    expert_bias_std=0.05,
+)
+
+
+def _sigmoid(first=0, held=32, **over):
+    return moe_ops.ShareConfig(**dict(dict(
+        n_experts=32, n_zero=0, top_k=4, scale=2.826, first=first, held=held,
+        scoring="sigmoid", normalise=True), **over))
+
+
+def _sigmoid_layer(first, held, seed=17):
+    """Layer 2's ``moe`` leaves for ``held`` experts from ``first`` on and
+    its ``shared`` leaves, as the reference seeds them, in float32."""
+    key = ref_weights.base_key(seed)
+    p = afmoe_ref.build(afmoe_ref.layer_spec(C_SIGMOID, False), key, layer=2)
+    p["moe"].update(jax.vmap(lambda e: afmoe_ref.expert(C_SIGMOID, key, 2, e))(
+        first + jnp.arange(held)))
+    return jax.tree.map(lambda a: a.astype(jnp.float32), p)
+
+
+def _expert_of(moe):
+    """``expert_fn`` of the reference's ``routed`` over stacked leaves."""
+    return lambda e: {k: moe[k][e] for k in ("gate", "up", "down")}
+
+
+def _sigmoid_by_hand(p, u, share, leak=False):
+    """The layer in NumPy, a token and a choice at a time: sigmoid scores,
+    the ``top_k`` largest of ``s + bias``, weights ``scale x s / (sum of the
+    chosen s + 1e-20)``.  ``leak``: the fault, the bias in the weights."""
+    u = np.asarray(u, np.float64)
+    s = 1.0 / (1.0 + np.exp(-(u @ np.asarray(p["router"]["kernel"], np.float64))))
+    bias = np.asarray(p["router"]["bias"], np.float64)
+    silu = lambda x: x / (1 + np.exp(-x))
+    out, chosen, weights = np.zeros_like(u), [], []
+    for t in range(u.shape[0]):
+        pick = np.argsort(-(s[t] + bias), kind="stable")[:share.top_k]
+        score = s[t, pick] + (bias[pick] if leak else 0.0)
+        w = share.scale * score / (score.sum() + 1e-20)
+        chosen.append(pick.tolist())
+        weights.append(w)
+        for e, w_e in zip(pick, w):
+            if share.first <= e < share.first + share.held:
+                g, up, down = (np.asarray(p[k][e - share.first], np.float64)
+                               for k in ("gate", "up", "down"))
+                out[t] += w_e * ((silu(u[t] @ g) * (u[t] @ up)) @ down)
+    return out, chosen, np.asarray(weights)
+
+
+def test_sigmoid_normalised_biased_choice_is_the_layer_written_out():
+    """The new fields against the sum by hand AND against the plain
+    reference's routed part; a token's weights add up to ``scale``; and THE
+    BIAS CHANGES THE CHOICE BUT NOT A WEIGHT - with the bias in the weights
+    (the fault) the sum by hand is another layer, which the program is not."""
+    u = jax.random.normal(jax.random.key(5), (24, 64))
+    p, share = _sigmoid_layer(0, 32), _sigmoid()
+    want, chosen, w = _sigmoid_by_hand(p["moe"], u, share)
+    np.testing.assert_allclose(w.sum(axis=1), share.scale, rtol=1e-12)
+    m, counts = moe_ops.apply_share(p["moe"], u, share, dtype=jnp.float32)
+    assert np.abs(want).max() > 0.5
+    assert np.abs(np.asarray(m) - want).max() < SHARE_TOL
+    assert int(counts["choices_held"]) == int(counts["choices"]) == 24 * 4
+    ref = afmoe_ref.routed(C_SIGMOID, p["moe"], _expert_of(p["moe"]), u, "float32")
+    assert np.abs(np.asarray(m) - np.asarray(ref)).max() < SHARE_TOL
+    # The seeded bias moves some token's choice ...
+    no_bias = dict(p["moe"], router=dict(p["moe"]["router"], bias=jnp.zeros((32,))))
+    _, chosen_plain, _ = _sigmoid_by_hand(no_bias, u, share)
+    assert any(sorted(a) != sorted(b) for a, b in zip(chosen, chosen_plain))
+    # ... and a bias that outweighs every score picks the experts and still
+    # weighs each by its own score: the four weights are the four scores'.
+    favoured = [3, 9, 20, 31]
+    steered = dict(p["moe"], router=dict(
+        p["moe"]["router"], bias=jnp.zeros((32,)).at[jnp.asarray(favoured)].set(10.0)))
+    want_steered, chosen, _ = _sigmoid_by_hand(steered, u, share)
+    assert all(sorted(c) == favoured for c in chosen)
+    m, _ = moe_ops.apply_share(steered, u, share, dtype=jnp.float32)
+    assert np.abs(np.asarray(m) - want_steered).max() < SHARE_TOL
+    leaked, _, _ = _sigmoid_by_hand(p["moe"], u, share, leak=True)
+    assert np.abs(leaked - want).max() > 100 * SHARE_TOL
+
+
+def test_the_sigmoid_shares_of_four_ranks_and_the_shared_expert_once_are_the_uncut_layer():
+    """THE SHARE TIES TO THE MODEL under the new scoring too: with ``held``
+    < ``n_experts`` the routed parts of ranks 0-3, 8 of the 32 experts each
+    from one seed, plus the shared expert counted once, are the reference's
+    whole expert layer - the weights are normalised over a token's CHOSEN
+    experts wherever they live, not over those a rank holds."""
+    u = jax.random.normal(jax.random.key(3), (40, 64))
+    whole = _sigmoid_layer(0, 32)
+    want = np.asarray(
+        afmoe_ref.routed(C_SIGMOID, whole["moe"], _expert_of(whole["moe"]), u, "float32")
+        + afmoe_ref._gated(u, afmoe_ref._kernels(whole["shared"]), "float32"))
+    total = np.array(layers.gated_mlp(whole["shared"], u, dtype=jnp.float32))
+    held = 0
+    for rank in range(4):
+        p = _sigmoid_layer(8 * rank, 8)
+        m, c = moe_ops.apply_share(p["moe"], u, _sigmoid(8 * rank, 8), dtype=jnp.float32)
+        assert int(c["choices"]) == 40 * 4 and 0 < int(c["experts_touched"]) <= 8
+        total += np.asarray(m)
+        held += int(c["choices_held"])
+    assert held == 40 * 4  # every choice is on some rank's expert
+    assert np.abs(want).max() > 0.5
+    assert np.abs(total - want).max() < 4 * SHARE_TOL
+
+
+@pytest.mark.parametrize("model", ["longcat", "deepseek"])
+def test_the_softmax_defaults_give_the_two_expert_models_their_layers_bit_for_bit(model):
+    """The two fields default to what ``apply_share`` was before it had
+    them: a share that states ``softmax`` and no normalising IS the models'
+    own share, its result and its lowered program are theirs to the bit
+    (the models' whole steps and chunks were compared as lowered text
+    before and after the change; PERF.md section 6, PR 39), and an unknown
+    scoring is refused."""
+    from distributed_tensorflow_examples_tpu.models import deepseek, longcat
+
+    if model == "longcat":
+        share, p = longcat.Config(**{
+            k: v for k, v in C_SHARE.items() if k != "init_std"},
+            experts_held=8, expert_first=8).share, _share_params(8, 8)
+    else:
+        share = deepseek.Config(**{
+            k: v for k, v in C_GROUPS.items() if k != "init_std"},
+            experts_held=8, expert_first=8).share
+        p = _grouped_layer(8, 8)["moe"]
+    assert (share.scoring, share.normalise) == ("softmax", False)
+    stated = dataclasses.replace(share, scoring="softmax", normalise=False)
+    assert stated == share
+    u = jax.random.normal(jax.random.key(9), (20, 64))
+    run = lambda s: jax.jit(lambda p, u: moe_ops.apply_share(p, u, s, dtype=jnp.float32)[0])
+    assert run(share).lower(p, u).as_text() == run(stated).lower(p, u).as_text()
+    assert np.array_equal(np.asarray(run(share)(p, u)), np.asarray(run(stated)(p, u)))
+    # The other scoring is another program and another layer.
+    other = dataclasses.replace(share, scoring="sigmoid")
+    assert run(other).lower(p, u).as_text() != run(share).lower(p, u).as_text()
+    assert np.abs(np.asarray(run(other)(p, u)) - np.asarray(run(share)(p, u))).max() > 1e-3
+    with pytest.raises(ValueError, match="scoring"):
+        dataclasses.replace(share, scoring="tanh")

@@ -94,20 +94,23 @@ def test_kernel_compiles_for_a_v5e_at_the_served_widths(one_chip, monkeypatch):
     assert "tpu_custom_call" in text and f"%{ss.KERNEL_NAME}" in text
 
 
-@pytest.mark.parametrize("rows,block", [(896, 32), (8192, 128)])
+@pytest.mark.parametrize("D,F,E,rows,block", [
+    (6144, 2048, 16, 896, 32), (6144, 2048, 16, 8192, 128),
+    (2048, 1024, 128, 4352, 32), (2048, 1024, 128, 20480, 128)])
 def test_grouped_ffn_compiles_for_a_v5e_at_the_served_widths(
-    one_chip, monkeypatch, rows, block,
+    one_chip, monkeypatch, D, F, E, rows, block,
 ):
     """Mosaic takes ops/grouped_ffn.py at ``D`` 6144, ``F`` 2048, 16 experts
-    held, with the row buffers of a decode step (32 slots x 12 choices, in
-    blocks of 32) and of a prefill chunk (512 x 12, in blocks of 128): its
-    39 MB of VMEM, the scalar-prefetched index maps and the kernel's name,
-    which the benchmark's readers look for.  (Kept in this file: the one
-    that loads the TPU's library.)"""
+    held (LongCat), with the row buffers of a decode step (32 slots x 12
+    choices, in blocks of 32) and of a prefill chunk (512 x 12, in blocks of
+    128), and at ``D`` 2048, ``F`` 1024 with ALL 128 experts of a layer held
+    (Trinity-Mini, models/afmoe.py: 32 x 8 and 512 x 8 choices, a block more
+    for each of 128 groups): its VMEM, the scalar-prefetched index maps and
+    the kernel's name, which the benchmark's readers look for.  (Kept in
+    this file: the one that loads the TPU's library.)"""
     from distributed_tensorflow_examples_tpu.ops import grouped_ffn as gf
 
     monkeypatch.setattr(gf, "interpret_mode", lambda: False)
-    D, F, E = 6144, 2048, 16
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     bf16 = jnp.bfloat16
     compiled = jax.jit(

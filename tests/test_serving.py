@@ -2861,3 +2861,108 @@ def test_deepseek_at_64_slots_through_the_engine_and_its_counters_add_up(monkeyp
         finally:
             eng.stop()
         assert together[i] == alone, i
+
+
+# ----------------------------------------------------------------------------
+# A cache of two kinds of key/value row: rings beside full rows (PR 39)
+# ----------------------------------------------------------------------------
+
+
+def test_afmoe_sessions_over_wrapping_rings_through_the_engine_and_its_counters_add_up(
+        monkeypatch):
+    """models/afmoe.py behind ``_DecodeEngine``: a window of 8 positions,
+    rings of 8 + 8 rows (the engine's chunk is 8) in three layers of four and
+    full rows in the fourth; seven sessions on two slots, so slots are
+    reseated over rings another session filled - prompts of no, one and
+    several chunks, SHORTER than the window, LONGER than a ring (30) and
+    sessions that end past two rings (a one-token prompt stepped 40 times).
+    Each session gets the tokens it gets alone and the tokens ``generate``
+    picks; ``model_attn_*`` say what the step's attention read and needed by
+    kind of layer - what was READ is, summed over slots and layers, what the
+    model's ``cache_rows_read`` told the engine a slot in the mean layer -
+    and ``model_moe_*`` what the expert layers did: every choice held."""
+    import jax
+
+    from distributed_tensorflow_examples_tpu.models import afmoe
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    window, slots, max_len = 8, 2, 48
+    cfg = afmoe.Config(
+        vocab_size=97, hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
+        num_hidden_layers=8, num_dense_layers=2, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, sliding_window=window, num_experts=8,
+        num_experts_per_tok=2, layer_types=(afmoe.SLIDING,) * 3 + (afmoe.FULL,)
+        + (afmoe.SLIDING,) * 3 + (afmoe.FULL,), held_layers=(1, 5, 6, 7),
+        ring_slack=8, attn_block=4, param_dtype="float32")
+    params = afmoe.init(cfg, jax.random.key(5))
+    # A larger head than the initialisation's: logits far enough apart that
+    # a token is no matter of rounding (every other write passes a norm).
+    params["head"] = jax.tree.map(lambda a: a * 6, params["head"])
+    fns = afmoe.serve_decode_fns(cfg)
+    said, launches = fns[1].cache_rows_read, []
+
+    def hook(pos, live, max_len):
+        launches.append((pos.copy(), live.copy()))
+        return said(pos, live, max_len)
+
+    fns[1].cache_rows_read = hook
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 97, size=n) for n in (5, 30, 3, 1, 19, 9, 8)]
+    budgets = [20, 6, 4, 40, 12, 3, 30]
+
+    def engine():
+        return model_server._DecodeEngine(
+            lambda: (0, params), *fns, slots=slots, max_len=max_len, max_sessions=8)
+
+    eng = engine()
+    try:
+        assert eng._wants_live and eng._counts
+        # Keys and values of 2 heads of 8, float32: three rings and a full
+        # layer a slot, a spare slot beside the two, and the counters.
+        assert eng.state_bytes == (slots + 1) * 2 * 2 * 8 * 4 * (3 * 16 + max_len) + 4 * (
+            8 + 5 * slots)
+        together = _run_sessions(eng, prompts, budgets, gap_s=0.05)
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    n_layers, n_sliding, n_moe = 4, 3, 3
+    chunked = sum(len(p) - 1 for p in prompts)
+    stepped = sum(budgets)
+    assert stats["prefill_tokens"] == chunked and stats["emitted"] == stepped
+    # A chunk's last layer is an expert layer and is skipped.
+    token_layers = chunked * (n_moe - 1) + stepped * n_moe
+    assert stats["model_moe_choices"] == stats["model_moe_choices_held"] == 2 * token_layers
+    assert stats["model_moe_calls"] == (
+        (stats["prefill_chunks"] + 1) * (n_moe - 1) + stats["steps"] * n_moe)
+    # What the live rows needed: a sliding layer min(pos + 1, window) rows,
+    # the full layer pos + 1, each session's steps at positions P-1 .. P+n-2.
+    at = [range(len(p) - 1, len(p) - 1 + n) for p, n in zip(prompts, budgets)]
+    assert stats["model_attn_global_rows_needed"] == sum(t + 1 for r in at for t in r)
+    assert stats["model_attn_window_rows_needed"] == n_sliding * sum(
+        min(t + 1, window) for r in at for t in r)
+    # What was read: every slot to the deepest live row's block, a ring at
+    # most whole - as the hook said, launch by launch.
+    counted = launches[:stats["steps"]]
+    assert all(not live.any() for _pos, live in launches[stats["steps"]:])
+    deepest = [int(np.where(live, pos + 1, 0).max()) for pos, live in counted]
+    blocks = lambda rows: -(-rows // 4) * 4
+    assert stats["model_attn_global_rows_read"] == slots * sum(blocks(d) for d in deepest)
+    assert stats["model_attn_window_rows_read"] == slots * n_sliding * sum(
+        min(blocks(d), 16) for d in deepest)
+    assert stats["model_attn_rows_read"] == (
+        stats["model_attn_window_rows_read"] + stats["model_attn_global_rows_read"])
+    assert stats["cache_rows_read"] == pytest.approx(
+        stats["model_attn_rows_read"] / (slots * n_layers), rel=1e-9)
+    assert max(deepest) > 2 * 16  # past two rings
+    assert stats["model_attn_window_rows_needed"] < stats["model_attn_window_rows_read"]
+    for i, (p, n, got) in enumerate(zip(prompts, budgets, together)):
+        eng = engine()
+        try:
+            alone = _run_sessions(eng, [p], [n])[0]
+        finally:
+            eng.stop()
+        assert got == alone, i
+        if len(p) > 1:
+            picked = afmoe.generate(cfg, params, p[None], max_new_tokens=n)
+            assert got == np.asarray(picked)[0, len(p):].tolist(), i
