@@ -130,18 +130,47 @@ _SPAN_SELECT = telemetry.span("decode/select")
 _HOST_NS = telemetry.REGISTRY.counter("decode/host/ns")
 
 
-#: Prompt tokens one prefill chunk takes through the model.  The ONE size:
-#: the engine has two programs, the decode step and this chunk, whatever
-#: the prompt lengths (a cache shorter than this makes it the cache's
-#: length).  Sessions that decode wait one chunk longer for their next
-#: token whenever one runs, so the size trades the first token of a prompt
-#: longer than a chunk against how many token gaps carry a chunk.  Chosen
-#: on one v5e chip with Cerebras-GPT-1.3B, 8 slots x 2048 (my chip runs,
-#: PR 25; PERF.md section 6): a chunk reads the weights once whatever its
-#: size and takes 11.0 / 12.6 / 15.6 ms at 128 / 256 / 512 beside a decode
-#: step of 23.4 ms; the chat replay (prompts 16-768) needs 78 / 49 / 40
-#: chunks a window, i.e. 6 / 3.8 / 3.1 % of its steps carry one.
+#: The most prompt tokens one prefill chunk takes through the model (a
+#: cache shorter than this makes it the cache's length).  Sessions that
+#: decode wait one chunk longer for their next token whenever one runs, so
+#: the size trades the first token of a prompt longer than a chunk against
+#: how many token gaps carry a chunk.  Chosen on one v5e chip with
+#: Cerebras-GPT-1.3B, 8 slots x 2048 (my chip runs, PR 25; PERF.md section
+#: 6): a chunk reads the weights once whatever its size and takes 11.0 /
+#: 12.6 / 15.6 ms at 128 / 256 / 512 beside a decode step of 23.4 ms; the
+#: chat replay (prompts 16-768) needs 78 / 49 / 40 chunks a window, i.e. 6 /
+#: 3.8 / 3.1 % of its steps carry one.
 PREFILL_CHUNK = 512
+
+#: The narrowest chunk the engine dispatches.  A chunk is as wide as the
+#: tokens it carries: the engine compiles the chunk program at
+#: ``PREFILL_CHUNK`` and at its halvings down to this floor
+#: (:func:`chunk_widths`: 256 / 512) and dispatches the narrowest that
+#: holds what its slot still owes, because a chunk bound by its products
+#: and not by its weights takes as long as it is wide (Jamba2-3B 11.5 /
+#: 14.1 / 23.8 ms at 128 / 256 / 512: PR 37's builder's chip runs) and every
+#: decoding row waits it out.  Why 256 and not the lane tile, 128: every
+#: width is one more program that each replica traces, lowers and loads
+#: before its first answer, with the compile cache warm too - 3.0-6.4 s a
+#: width for Jamba2-3B, whose start with 128 / 256 / 512 read 100-105 s
+#: where one width reads 91-97 and two 94-99 (my chip runs, PR 40; PERF.md
+#: section 6) - and the second halving buys a third of the first: 1.5-4.4
+#: ms a chunk over the four families on record against 3.2-13.6 (PR 37's
+#: builder's), and nothing at the 95th percentile of the token gaps, which
+#: is a step and a 256-wide chunk with either.  Derived, not tuned to a
+#: mix, and no setting: a ``PREFILL_CHUNK`` under twice the floor is the
+#: one width.
+PREFILL_FLOOR = 256
+
+
+def chunk_widths(chunk: int) -> tuple[int, ...]:
+    """The widths a chunk of at most ``chunk`` tokens is dispatched at,
+    narrowest first: ``chunk`` and its halvings down to
+    :data:`PREFILL_FLOOR` (an odd width is not halved)."""
+    widths = [chunk]
+    while widths[0] % 2 == 0 and widths[0] // 2 >= PREFILL_FLOOR:
+        widths.insert(0, widths[0] // 2)
+    return tuple(widths)
 
 
 def flat_param_spec(init_fn):
@@ -249,18 +278,26 @@ class _DecodeEngine:
     slot, offset, n_valid) -> cache`` (it enters ONE slot's positions
     ``[offset, offset + n_valid)`` into that slot's cache and touches no
     other slot) has its prompts PREFILLED: before the decode step a call
-    runs at most one chunk of ``PREFILL_CHUNK`` tokens, for the
+    runs at most one chunk of at most ``PREFILL_CHUNK`` tokens, for the
     longest-seated session whose prompt is not yet cached, so every other
     session waits at most one step plus one chunk for its next token,
-    whatever the prompt lengths or the burst.  A session being prefilled
+    whatever the prompt lengths or the burst.  The chunk is as wide as the
+    tokens it carries: ``tokens[C]`` is the narrowest of
+    :func:`chunk_widths` that holds them, padded with token 0 past
+    ``n_valid``, so ``prefill_fn`` is traced once a width - one ``jax.jit``,
+    a compiled program a width, each run once on a chunk of no valid token
+    before the first session is answered - and must take every one of them
+    (counters ``prefill_width``, the tokens dispatched with the padding in,
+    beside ``prefill_tokens``, the valid ones).  A session being prefilled
     holds its slot with a row that is not live; once all but its last
     prompt token are cached it is an ordinary decode row at ``pos = P - 1``
     and the next step emits its first token.  Without ``prefill_fn`` the
     prompt is teacher-forced through the decode step, a token a step.
     A ``prefill_fn`` may say how far into the slot's cache a chunk's
     attention reads by an attribute ``cache_rows_read(offset, chunk,
-    max_len)`` (positions, from the chunk's offset and width); without it a
-    chunk is taken to read all ``max_len`` (counter ``prefill_rows_read``).
+    max_len)`` (positions, from the chunk's offset and the width it was
+    dispatched at); without it a chunk is taken to read all ``max_len``
+    (counter ``prefill_rows_read``).
 
     What a model counts on the device.  A model whose cache tree has an
     entry ``counters`` - a dict of small int32 arrays that its step and its
@@ -318,6 +355,7 @@ class _DecodeEngine:
             if prefill_fn else None
         )
         self._chunk = min(PREFILL_CHUNK, self.max_len)
+        self._widths = chunk_widths(self._chunk)
         # How far into the slot's cache a chunk reads: all of it, unless told.
         self._chunk_rows_read = getattr(
             prefill_fn, "cache_rows_read", lambda offset, chunk, max_len: max_len
@@ -325,6 +363,7 @@ class _DecodeEngine:
         self._prefill_warm = False
         self.prefill_chunks = 0
         self.prefill_tokens = 0  # valid tokens; padding is not counted
+        self.prefill_width = 0  # tokens dispatched: the chunks' widths
         # Chunks dispatched while a step launched earlier had not been read.
         self.queued_chunks = 0
         # The chunk on the device that the host has not waited for: what its
@@ -431,11 +470,12 @@ class _DecodeEngine:
             self._selection = self._no_selection()
             raise
 
-    def _prefill(self, params, slot: int, tokens, offset: int, n_valid: int):
-        """Dispatch one chunk behind whatever is on the device and hand its
-        cache on; returns what the program echoes, for the host to wait on.
-        Inside ``_cache_donated()``."""
-        buf = np.zeros((self._chunk,), np.int32)
+    def _prefill(self, params, slot: int, tokens, offset: int, n_valid: int,
+                 width: int):
+        """Dispatch one chunk of ``width`` tokens behind whatever is on the
+        device and hand its cache on; returns what the program echoes, for
+        the host to wait on.  Inside ``_cache_donated()``."""
+        buf = np.zeros((width,), np.int32)
         buf[:n_valid] = tokens
         self._cache, echo = self._prefill_jit(
             params, self._cache, buf, np.int32(slot), np.int32(offset),
@@ -478,19 +518,23 @@ class _DecodeEngine:
 
         with self._cache_donated():
             if not self._prefill_warm:
-                # Both programs exist before the first session is answered,
-                # whatever its prompt's length: a chunk of no valid token
-                # compiles the chunk program and writes nothing.
-                jax.block_until_ready(self._prefill(params, 0, (), 0, 0))
+                # Every program exists before the first session is
+                # answered, whatever its prompt's length: a chunk of no valid
+                # token compiles the chunk program of its width and writes
+                # nothing.
+                for width in self._widths:
+                    jax.block_until_ready(
+                        self._prefill(params, 0, (), 0, 0, width))
                 self._prefill_warm = True
             if i is None:
                 return
             st = slots[i].state
             done = st["cached"]
             n = min(self._chunk, st["prefill"] - done)
+            width = next(w for w in self._widths if w >= n)
             with _SPAN_CHUNK_LAUNCH:
                 self._chunk_echo = self._prefill(
-                    params, i, st["prompt"][done:done + n], done, n)
+                    params, i, st["prompt"][done:done + n], done, n, width)
             # Booked onto ``decode/prefill/ns`` when the chunk is waited for.
             self._chunk_launch_ns = _SPAN_CHUNK_LAUNCH.last_ns
             # With nothing ahead of it the chunk begins now; behind a step
@@ -499,8 +543,9 @@ class _DecodeEngine:
             st["cached"] = done + n
             self.prefill_chunks += 1
             self.prefill_tokens += n
+            self.prefill_width += width
             self.prefill_rows_read += self._chunk_rows_read(
-                done, self._chunk, self.max_len)
+                done, width, self.max_len)
 
     def _run_step(self, slots):
         """One call of the batcher's loop (``_call``), timed: what it took
@@ -618,6 +663,7 @@ class _DecodeEngine:
         s["max_len"] = self.max_len
         s["prefill_chunks"] = self.prefill_chunks
         s["prefill_tokens"] = self.prefill_tokens
+        s["prefill_width"] = self.prefill_width
         s["queued_chunks"] = self.queued_chunks
         s["held_rows"] = self.held_rows
         s["cache_rows_read"] = self.cache_rows_read

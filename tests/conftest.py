@@ -59,6 +59,29 @@ def _np_seed():
     np.random.seed(0)
 
 
+@pytest.fixture()
+def engine_chunks(monkeypatch):
+    """``plan(owed, chunk, floor) -> [(offset, n_valid, width), ...]``: the
+    chunks the serve engine dispatches for a prompt that owes its cache
+    ``owed`` tokens, with ``PREFILL_CHUNK`` at ``chunk`` and the floor of
+    its widths at ``floor`` (both far under the served sizes: a rehearsal) -
+    whole chunks of ``chunk``, then the narrowest of
+    ``model_server.chunk_widths`` that holds the rest.  A model's chunk is
+    held to its reference through the widths the engine would hand it."""
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    def plan(owed: int, chunk: int, floor: int) -> list:
+        monkeypatch.setattr(model_server, "PREFILL_FLOOR", floor)
+        widths = model_server.chunk_widths(chunk)
+        chunks = []
+        for offset in range(0, owed, chunk):
+            n = min(chunk, owed - offset)
+            chunks.append((offset, n, next(w for w in widths if w >= n)))
+        return chunks
+
+    return plan
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long multi-process / fault-injection tests"

@@ -93,13 +93,18 @@ def programs():
 
 def _prefill(chunk, params, cache, prompt, slot, width):
     """All but the prompt's last token through chunks of ``width``, the last
-    one padded - what the serve engine does."""
+    one padded - what the serve engine did with its one width.  A list: the
+    chunks themselves, ``(valid, width)``."""
     n = len(prompt) - 1
-    for offset in range(0, n, width):
-        valid = min(width, n - offset)
-        buf = np.zeros(width, np.int32)
+    plan = width if isinstance(width, list) else [
+        (min(width, n - offset), width) for offset in range(0, n, width)]
+    assert sum(valid for valid, _w in plan) == n
+    offset = 0
+    for valid, w in plan:
+        buf = np.zeros(w, np.int32)
         buf[:valid] = prompt[offset:offset + valid]
         cache = chunk(params, cache, buf, slot, offset, valid)
+        offset += valid
     return cache
 
 
@@ -205,23 +210,41 @@ def test_a_part_of_the_block_left_out_is_seen_at_the_tolerance(
 # ----------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("width", [8, 5])
-@pytest.mark.parametrize("prompt_len", [9, WINDOW, WINDOW + 1, 37, 59])
+#: 36 tokens as chunks of 8, 8, 6 in 8, then FOUR IN A CHUNK OF 4 at rows 22,
+#: 23, 0, 1 of a ring of 24 - a narrow chunk across the ring's seam - 2 in 2, 8.
+SEAM = [(8, 8), (8, 8), (6, 8), (4, 4), (2, 2), (8, 8)]
+
+
+@pytest.mark.parametrize("prompt_len,width", [
+    (n, w) for w in (8, 5) for n in (9, WINDOW, WINDOW + 1, 37, 59)] + [
+    # As the serve engine cuts them at this size, widths 2 / 4 / 8.
+    (11, "widths"),  # 10 = 8 + 2 in a chunk of 2
+    (WINDOW + 4, "widths"),  # 19 = 8 + 8 + 3 in a chunk of 4, the window just left
+    (37, "widths"),  # 36 = four chunks of 8 + 4 in a chunk of 4, on the second lap
+    (59, "widths"),  # 58 = seven chunks of 8 + 2 in a chunk of 2, on the third
+    (37, SEAM),
+], ids=lambda v: "seam" if v is SEAM else str(v))
 def test_chunks_then_steps_through_the_ring_are_the_full_forward(
-        programs, params32, tokens, reference, prompt_len, width):
+        programs, params32, tokens, reference, engine_chunks, prompt_len, width):
     """Prompts SHORTER than the window (9), EQUAL to it (16, 17: the first
     step is the first query that loses a position) and several times LONGER
     (37, 59: past the ring's 24 rows once and twice), by chunks of 8 (three
-    to a ring: a boundary ON the ring's end) and of 5 (a chunk ACROSS it),
-    then steps to position 71 - with a second slot stepping at another depth
-    in the same launches, and a third that is not live."""
+    to a ring: a boundary ON the ring's end) and of 5 (a chunk ACROSS it) -
+    and as the engine cuts them, the last chunk 2 or 4 wide where that holds
+    what is left, and with a chunk of 4 across the ring's end - then steps
+    to position 71 - with a second slot stepping at another depth in the
+    same launches, and a third that is not live."""
     chunk, step = programs
     cache = afmoe.init_cache(CFG32, 3, L + 8)
     assert cache["layer_4"]["k"].shape == (3 + 1, 2, WINDOW + SLACK, 16)
     assert cache["layer_7"]["v"].shape == (3 + 1, 2, L + 8, 16)
     other_len = 5
+    if width == "widths":
+        width = [(n, w) for _o, n, w in engine_chunks(prompt_len - 1, 8, 2)]
+        assert min(w for _n, w in width) < 8
     cache = _prefill(chunk, params32, cache, tokens[0, :prompt_len], 2, width)
-    cache = _prefill(chunk, params32, cache, tokens[1, :other_len], 0, width)
+    cache = _prefill(chunk, params32, cache, tokens[1, :other_len], 0,
+                     8 if isinstance(width, list) else width)
     pos = np.array([other_len - 1, 0, prompt_len - 1], np.int32)
     live = np.array([True, False, True])
     worst = 0.0

@@ -129,18 +129,26 @@ def test_apply_in_bfloat16_stays_within_its_bound(params16, tokens, reference, f
 
 
 @pytest.mark.parametrize(
-    "prompt_len,chunk",
+    "prompt_len,chunk,floor",
     [
-        (21, 8),   # three chunks, the last padded (20 = 8 + 8 + 4)
-        (17, 16),  # one whole chunk, none padded
-        (10, 16),  # one padded chunk
-        (1, 8),    # a one-token prompt: no chunk at all
+        # One width: the floor is over half the chunk.
+        (21, 8, 128),   # three chunks, the last padded (20 = 8 + 8 + 4)
+        (17, 16, 128),  # one whole chunk, none padded
+        (10, 16, 128),  # one padded chunk
+        (1, 8, 128),    # a one-token prompt: no chunk at all
+        # The engine's widths, ``floor`` .. ``chunk``.
+        (21, 16, 4),    # 20 = 16 + 4 in a chunk of 4: none padded
+        (23, 16, 4),    # 22 = 16 + 6 in a chunk of 8
+        (4, 16, 4),     # 3 in a chunk of 4, the narrowest
+        (27, 8, 2),     # 26 = 8 + 8 + 8 + 2 in a chunk of 2
     ],
 )
 def test_prefill_by_chunks_then_absorbed_decode_against_the_full_forward(
-    params32, tokens, reference, prompt_len, chunk,
+    params32, tokens, reference, engine_chunks, prompt_len, chunk, floor,
 ):
-    """A prompt enters slot 1 of a USED cache by chunks, then the tokens
+    """A prompt enters slot 1 of a USED cache by chunks (as the engine cuts
+    it: whole chunks, then the narrowest of its widths that holds the
+    rest), then the tokens
     that follow are decoded through the latent cache one by one beside two
     rows that are not live, to position 39 (``original_max`` is 16); every
     step's logits are the full forward's at that position, and the counters
@@ -152,9 +160,8 @@ def test_prefill_by_chunks_then_absorbed_decode_against_the_full_forward(
              if k != "counters" else v for k, v in cache.items()}
     row = tokens[0]
     chunks = 0
-    for off in range(0, prompt_len - 1, chunk):
-        n = min(chunk, prompt_len - 1 - off)
-        buf = np.zeros(chunk, np.int32)
+    for off, n, width in engine_chunks(prompt_len - 1, chunk, floor):
+        buf = np.zeros(width, np.int32)
         buf[:n] = row[off:off + n]
         cache = pre(params32, cache, buf, 1, off, n)
         chunks += 1

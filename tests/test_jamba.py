@@ -85,31 +85,52 @@ def test_apply_against_the_references_full_forward(params, tokens, reference):
 
 
 @pytest.mark.parametrize(
-    "prompt_len,chunk",
+    "prompt_len,chunk,floor",
     [
-        (21, 8),   # three chunks, the last padded (20 = 8 + 8 + 4)
-        (17, 16),  # one whole chunk, none padded
-        (10, 16),  # one padded chunk
-        (1, 8),    # no chunk: the step at pos == 0 starts the state
+        # One width: the floor is over half the chunk.
+        (21, 8, 128),   # three chunks, the last padded (20 = 8 + 8 + 4)
+        (17, 16, 128),  # one whole chunk, none padded
+        (10, 16, 128),  # one padded chunk
+        (1, 8, 128),    # no chunk: the step at pos == 0 starts the state
+        # The engine's widths, ``floor`` .. ``chunk``.
+        (21, 16, 4),    # 20 = 16 + 4 in a chunk of 4: none padded
+        (23, 16, 4),    # 22 = 16 + 6 in a chunk of 8
+        (4, 16, 4),     # 3 in a chunk of 4, the narrowest: the state starts there
+        (27, 8, 2),     # 26 = 8 + 8 + 8 + 2 in a chunk of 2
     ],
 )
 def test_prefill_by_chunks_then_decode_against_the_full_forward(
-    params, tokens, reference, prompt_len, chunk,
+    params, tokens, reference, engine_chunks, prompt_len, chunk, floor,
 ):
-    """A prompt enters slot 1 of a USED cache by chunks, then the tokens
-    that follow are decoded through it one by one beside two rows that are
-    not live; every step's logits are the full forward's at that position."""
+    """A prompt enters slot 1 of a USED cache by chunks - as the engine cuts
+    it: whole chunks, then the narrowest of its widths that holds the rest -
+    then the tokens that follow are decoded through it one by one beside two
+    rows that are not live; every step's logits are the full forward's at
+    that position.  Conv tail and state after the valid tokens are what the
+    chunks of ONE width leave: the padding a narrower chunk no longer
+    computes never reached them."""
     pre = jax.jit(lambda p, c, t, s, o, n: jamba.prefill_chunk(CFG, p, c, t, s, o, n))
     step = jax.jit(lambda p, c, t, pos, live: jamba.decode_step_batch(CFG, p, c, t, pos, live))
     # Whatever a session before left in the slots: a state that is not zero.
     cache = jax.tree.map(
         lambda a: jnp.full(a.shape, 0.37, a.dtype), jamba.init_cache(CFG, 3, 64))
     row = tokens[0]
-    for off in range(0, prompt_len - 1, chunk):
-        n = min(chunk, prompt_len - 1 - off)
-        buf = np.zeros(chunk, np.int32)
+
+    def fill(cache, off, n, width):
+        buf = np.zeros(width, np.int32)
         buf[:n] = row[off:off + n]
-        cache = pre(params, cache, buf, 1, off, n)
+        return pre(params, cache, buf, 1, off, n)
+
+    used = cache
+    for off, n, width in engine_chunks(prompt_len - 1, chunk, floor):
+        # Only a prompt's last chunk is narrower: that one at the ONE width too.
+        cache, used = fill(cache, off, n, width), fill(cache, off, n, chunk)
+    for name, layer in cache.items():
+        for kind, a in layer.items():  # conv, ssm | k, v: what slot 1 holds
+            rows = (slice(None), slice(0, prompt_len - 1)) if kind in "kv" else ()
+            np.testing.assert_allclose(
+                np.asarray(a[1])[rows], np.asarray(used[name][kind][1])[rows],
+                rtol=1e-5, atol=1e-6, err_msg=f"{name}/{kind}")
     others = jax.tree.map(lambda a: np.asarray(a[::2]), cache)
     worst = 0.0
     for pos in range(prompt_len - 1, 40):

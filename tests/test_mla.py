@@ -80,16 +80,21 @@ def test_shapes_of_a_sub_layer_and_the_latent_row():
     assert q_nope.shape == (5, 3, 8) and q_rope.shape == (5, 3, 8) and row.shape == (5, 24)
 
 
+@pytest.mark.parametrize("plan", [
+    [(8, 8), (5, 8)],  # chunks of one width, the second padded
+    [(8, 8), (4, 4), (1, 2)],  # the engine's widths 2 / 4 / 8: narrower as less is left
+], ids=["one_width", "widths"])
 @pytest.mark.parametrize("chunks", ["loop", "kernel"])
 @pytest.mark.parametrize("kind", ["RANK_SCALED", "YARN"])
-def test_chunks_then_absorbed_steps_are_the_expanded_full_forward(kind, chunks, monkeypatch):
+def test_chunks_then_absorbed_steps_are_the_expanded_full_forward(
+        kind, chunks, plan, monkeypatch):
     """One sequence of 29 positions (past the YARN spec's original 16): the
-    full forward in the expanded form; the same through a cache - two chunks
-    into slot 1 of a used cache, the second padded, then absorbed steps
-    beside a row that is not the session's.  ``kernel``: the chunks and the
-    steps as a TPU runs them - ``mla`` told it is not interpreted, so it
-    calls ops/latent_prefill.py and ops/latent_decode.py, which here still
-    are."""
+    full forward in the expanded form; the same through a cache - the chunks
+    of ``plan`` (valid tokens, width) into slot 1 of a used cache, the last
+    padded, then absorbed steps beside a row that is not the session's.
+    ``kernel``: the chunks and the steps as a TPU runs them - ``mla`` told it
+    is not interpreted, so it calls ops/latent_prefill.py and
+    ops/latent_decode.py, which here still are."""
     if chunks == "kernel":
         monkeypatch.setattr(mla, "interpret_mode", lambda: False)
     spec = _spec(kind)
@@ -101,11 +106,13 @@ def test_chunks_then_absorbed_steps_are_the_expanded_full_forward(kind, chunks, 
     got = np.zeros_like(full)
     prefill = jax.jit(lambda *a: mla.prefill(spec, p, *a))
     decode = jax.jit(lambda *a: mla.decode(spec, p, *a))
-    o, cache = prefill(h[0, :8], cache, 1, 0, 8)
-    got[:8] = o
-    padded = jnp.concatenate([h[0, 8:13], jnp.zeros((3, D))])
-    o, cache = prefill(padded, cache, 1, 8, 5)
-    got[8:13] = o[:5]
+    at = 0
+    for n, width in plan:
+        padded = jnp.concatenate([h[0, at:at + n], jnp.zeros((width - n, D))])
+        o, cache = prefill(padded, cache, 1, at, n)
+        got[at:at + n] = o[:n]
+        at += n
+    assert at == 13
     np.testing.assert_array_equal(np.asarray(cache[0]), np.float32(0.37))  # slot 0 untouched
     np.testing.assert_array_equal(np.asarray(cache[1, 13:]), np.float32(0.37))
     for pos in range(13, 29):

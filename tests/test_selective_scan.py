@@ -79,12 +79,26 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_kernel_compiles_for_a_v5e_at_the_served_widths(one_chip, monkeypatch):
-    """Mosaic takes the kernel at ``C`` 512, ``d_inner`` 5120, ``N`` 16 (what
-    interpret mode cannot show: tiling, VMEM and SMEM), and the operation
-    carries the kernel's name, which the benchmark's readers look for."""
+#: The widths each chunk kernel compiles at: those the serve engine
+#: dispatches a chunk at (PREFILL_CHUNK and its halvings down to
+#: PREFILL_FLOOR), and one halving further, for the day the floor moves.
+CHUNK_WIDTHS = (128, 256, 512)
+
+
+def test_the_chunk_widths_here_hold_the_serve_engines():
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    assert model_server.chunk_widths(model_server.PREFILL_CHUNK) == CHUNK_WIDTHS[1:]
+
+
+@pytest.mark.parametrize("C", CHUNK_WIDTHS)
+def test_kernel_compiles_for_a_v5e_at_the_served_widths(one_chip, monkeypatch, C):
+    """Mosaic takes the kernel at ``C`` 256 / 512 (the engine's chunk widths)
+    and 128, ``d_inner`` 5120, ``N`` 16 (what interpret mode cannot show:
+    tiling, VMEM and SMEM), and the operation carries the kernel's name,
+    which the benchmark's readers look for."""
     monkeypatch.setattr(ss, "interpret_mode", lambda: False)
-    C, D, N = 512, 5120, 16
+    D, N = 5120, 16
     s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     n_valid = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     compiled = jax.jit(ss.selective_scan.__wrapped__).lower(
@@ -96,7 +110,11 @@ def test_kernel_compiles_for_a_v5e_at_the_served_widths(one_chip, monkeypatch):
 
 @pytest.mark.parametrize("D,F,E,rows,block", [
     (6144, 2048, 16, 896, 32), (6144, 2048, 16, 8192, 128),
-    (2048, 1024, 128, 4352, 32), (2048, 1024, 128, 20480, 128)])
+    (2048, 1024, 128, 4352, 32), (2048, 1024, 128, 20480, 128),
+    # The narrower chunks' row buffers (128 and 256 tokens' choices in blocks
+    # of 128, a block more for each group held).
+    (6144, 2048, 16, 3584, 128), (6144, 2048, 16, 5120, 128),
+    (2048, 1024, 128, 17408, 128), (2048, 1024, 128, 18432, 128)])
 def test_grouped_ffn_compiles_for_a_v5e_at_the_served_widths(
     one_chip, monkeypatch, D, F, E, rows, block,
 ):
@@ -123,14 +141,15 @@ def test_grouped_ffn_compiles_for_a_v5e_at_the_served_widths(
     assert "tpu_custom_call" in text and f"%{gf.KERNEL_NAME}" in text
 
 
-@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("kernel", ["decode", "prefill", "prefill_128", "prefill_256"])
 @pytest.mark.parametrize("model,slots,heads,length", [("deepseek", 64, 128, 4096), ("longcat", 32, 64, 8192)])
 def test_latent_attention_compiles_for_a_v5e_at_the_served_widths(
     one_chip, monkeypatch, kernel, model, slots, heads, length,
 ):
     """Mosaic takes ops/latent_decode.py and ops/latent_prefill.py at the two
     served shapes (DeepSeek-V2 and LongCat: latent 576 = 512 values + 64
-    rotated, bfloat16, each model's own blocks; the chunk 512 queries): the
+    rotated, bfloat16, each model's own blocks; the chunk 512 queries, the
+    256 of the engine's narrower chunk, and 128): the
     grids of a traced extent, the prefetched index arrays, the products over
     a latent that is no multiple of 128 lanes, the prefill kernel's VMEM
     allowance, and the kernels' names, which the benchmark's readers look
@@ -166,9 +185,11 @@ def test_latent_attention_compiles_for_a_v5e_at_the_served_widths(
                 q_nope, q_rope, kv_b, c, slot, offset, nope=128, scale=0.1,
                 block=getattr(models, model).PREFILL_BLOCK)
 
+        C = int(kernel.partition("_")[2] or 512)
+        assert C in CHUNK_WIDTHS
         compiled = jax.jit(chunk, donate_argnums=4).lower(
-            s((512, heads, 128), bf16), s((512, heads, 64), bf16),
-            s((512, heads * 256), bf16), s((512, 576), bf16), cache,
+            s((C, heads, 128), bf16), s((C, heads, 64), bf16),
+            s((512, heads * 256), bf16), s((C, 576), bf16), cache,
             s((), i32), s((), i32), s((), i32),
         ).compile()
     text = compiled.as_text()

@@ -1394,16 +1394,31 @@ def test_served_decode_program_is_named_step_fn():
 # ----------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("P,chunks", [(1, 0), (2, 1), (5, 1), (6, 2), (14, 4)])
+@pytest.mark.parametrize(
+    "P,chunks,floor,width",
+    [
+        # One width (the floor is over half the chunk): every chunk is 4 wide.
+        (1, 0, 128, 0), (2, 1, 128, 4), (5, 1, 128, 4), (6, 2, 128, 8),
+        (14, 4, 128, 16),
+        # Widths 1 / 2 / 4: the tokens owed sit on, one under and one over
+        # each edge; whole chunks of 4, then the narrowest that holds the rest.
+        (2, 1, 1, 1), (3, 1, 1, 2), (4, 1, 1, 4), (5, 1, 1, 4), (6, 2, 1, 4 + 1),
+        (7, 2, 1, 4 + 2), (8, 2, 1, 4 + 4), (14, 4, 1, 4 + 4 + 4 + 1),
+    ],
+)
 def test_prefilled_prompt_costs_its_chunks_then_one_step(
-    tmp_path, monkeypatch, P, chunks,
+    tmp_path, monkeypatch, P, chunks, floor, width,
 ):
     """A ``P``-token prompt costs ``ceil((P - 1) / C)`` chunks — one an
     iteration, each followed by a decode step in which the slot's row is
-    inert — and the step after the last chunk emits its first token."""
+    inert — and the step after the last chunk emits its first token.  Each
+    chunk is dispatched at the narrowest width that holds its tokens
+    (``decode_prefill_width``), every width was compiled before the first
+    answer, and the tokens are the one-width engine's."""
     from distributed_tensorflow_examples_tpu.serve import model_server
 
     monkeypatch.setattr(model_server, "PREFILL_CHUNK", 4)
+    monkeypatch.setattr(model_server, "PREFILL_FLOOR", floor)
     srv = _pinned_decode_server(
         tmp_path, "pf0", decode_fns=_toy_cached_decode_fns()
     )
@@ -1421,6 +1436,8 @@ def test_prefilled_prompt_costs_its_chunks_then_one_step(
     d = lambda k: after[k] - before[k]
     assert d("decode_prefill_chunks") == chunks
     assert d("decode_prefill_tokens") == P - 1
+    assert d("decode_prefill_width") == width >= P - 1
+    assert after["decode_prefill_width"] >= after["decode_prefill_tokens"]
     # All but the last chunk's iteration leave the row inert; the three
     # tokens then take three steps.
     assert d("decode_fed") == max(chunks - 1, 0)
@@ -1429,6 +1446,8 @@ def test_prefilled_prompt_costs_its_chunks_then_one_step(
     r = lambda k: after["registry"][k] - before["registry"][k]
     assert r("decode/prefill/n") == chunks
     assert r("decode/dispatch/n") == d("decode_steps")
+    # Whatever the prompt's length, its programs were there.
+    assert r("jax/compiles") == 0
 
 
 def test_prefill_is_one_chunk_a_step_and_leaves_decoding_sessions_alone(
@@ -1798,6 +1817,173 @@ def test_prefill_rows_read_counts_what_a_chunk_reads(monkeypatch, model):
             latent_prefill.blocks_read(np.int32(offset), chunk, block, max_len))
         assert [grid(o) for o in (0, 8, 16)] == [8, 16, 24]
         assert stats["prefill_rows_read"] == 2 * (8 + 16) + 24
+
+
+# ----------------------------------------------------------------------------
+# A chunk is as wide as the tokens it carries (PR 40)
+# ----------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("chunk,floor,max_len,widths", [
+    (512, 256, 2048, (256, 512)),  # the served sizes
+    (512, 128, 2048, (128, 256, 512)),  # a floor a halving lower
+    (512, 128, 300, (150, 300)),  # a cache shorter than a chunk
+    (8, 128, 48, (8,)), (4, 128, 32, (4,)),  # what the tests above patch in
+    (128, 128, 2048, (128,)), (200, 128, 2048, (200,)),  # half is under the floor
+    (8, 2, 48, (2, 4, 8)), (12, 2, 48, (3, 6, 12)),  # an odd width is not halved
+])
+def test_the_widths_are_the_chunk_and_its_halvings_down_to_the_floor(
+    monkeypatch, chunk, floor, max_len, widths,
+):
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    if (chunk, floor) == (512, 256):  # as the module has them
+        assert (model_server.PREFILL_CHUNK, model_server.PREFILL_FLOOR) == (chunk, floor)
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", chunk)
+    monkeypatch.setattr(model_server, "PREFILL_FLOOR", floor)
+    eng = model_server._DecodeEngine(
+        lambda: (0, None), *_toy_cached_decode_fns(), slots=1, max_len=max_len,
+        max_sessions=1)
+    try:
+        assert eng._widths == widths == model_server.chunk_widths(eng._chunk)
+        # No argument of the engine or of the replica chooses a width.
+        assert "width" not in str(inspect.signature(model_server._DecodeEngine))
+        assert "chunk" not in str(inspect.signature(model_server._DecodeEngine))
+    finally:
+        eng.stop()
+
+
+#: Tokens a prompt owes its cache - all but its last - on, one under and one
+#: over each edge of the widths 2 / 4 / 8, and past one and two whole chunks.
+_OWED = (1, 2, 3, 4, 5, 7, 8, 9, 15, 16, 17)
+
+
+@pytest.mark.parametrize("family", ["toy", "toy_state", "transformer"])
+def test_a_chunk_is_as_wide_as_the_narrowest_width_that_holds_its_tokens(
+    monkeypatch, engine_chunks, family,
+):
+    """Widths 2 / 4 / 8.  Eleven prompts on two slots (sessions seated into
+    slots others left, chunks queued behind steps in flight): every session
+    gets the tokens the ONE-WIDTH engine gives it and those of ``generate``
+    (the plain stream, for a toy), to the token; each real chunk went out at
+    the narrowest width that holds its tokens and the hook was told that
+    width; ``prefill_width`` is their sum, never under ``prefill_tokens``;
+    and every width ran once on no valid token before the first real
+    chunk."""
+    import jax
+
+    from distributed_tensorflow_examples_tpu import models
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    max_len, budget = 24, 3
+    params = None
+    if family == "transformer":
+        cfg = models.transformer.Config(
+            vocab_size=61, dim=32, n_layers=2, n_heads=4, max_seq_len=max_len,
+            compute_dtype="float32",
+        )
+        params = models.transformer.init(cfg, jax.random.key(3))
+        fns = models.transformer.serve_decode_fns(cfg)
+        vocab = cfg.vocab_size
+    else:
+        fns = (_toy_cached_decode_fns if family == "toy" else _toy_state_decode_fns)()
+        vocab = 11
+    rng = np.random.default_rng(40)
+    prompts = [rng.integers(1, vocab, size=owed + 1).astype(np.int32) for owed in _OWED]
+
+    def serve_all(floor):
+        monkeypatch.setattr(model_server, "PREFILL_FLOOR", floor)
+        said = getattr(fns[2], "cache_rows_read", lambda o, c, m: m)
+        told, sent = [], []
+
+        def hook(offset, chunk, max_len):
+            told.append(chunk)
+            return said(offset, chunk, max_len)
+
+        monkeypatch.setattr(fns[2], "cache_rows_read", hook, raising=False)
+        eng = model_server._DecodeEngine(
+            lambda: (0, params), *fns, slots=2, max_len=max_len, max_sessions=16)
+        prefill_jit = eng._prefill_jit
+
+        def logged(params, cache, tokens, slot, offset, n_valid):
+            assert not tokens[int(n_valid):].any()  # padded with token 0
+            sent.append((int(n_valid), len(tokens)))
+            return prefill_jit(params, cache, tokens, slot, offset, n_valid)
+
+        eng._prefill_jit = logged
+        try:
+            outs = _run_sessions(eng, prompts, [budget] * len(prompts))
+            return outs, eng.stats(), sent, told, eng._widths
+        finally:
+            eng.stop()
+
+    due = sorted((n, w) for owed in _OWED for _o, n, w in engine_chunks(owed, 8, 2))
+    outs, stats, sent, told, widths = serve_all(floor=2)
+    assert widths == (2, 4, 8)
+    # Before the first real chunk, each width once on no valid token.
+    assert sent[:3] == [(0, 2), (0, 4), (0, 8)]
+    assert sorted(sent[3:]) == due
+    assert told == [w for _n, w in sent[3:]]
+    assert stats["prefill_tokens"] == sum(_OWED)
+    assert stats["prefill_width"] == sum(w for _n, w in sent[3:]) == 96
+    assert stats["prefill_chunks"] == len(sent) - 3 == 16
+    # The engine of one width: the same chunks, each 8 wide; the same tokens.
+    outs_one, stats_one, sent_one, told_one, widths_one = serve_all(floor=128)
+    assert widths_one == (8,) and sent_one[0] == (0, 8) and set(told_one) == {8}
+    assert [n for n, _w in sent_one[1:]] == [n for n, _w in sent[3:]]
+    assert stats_one["prefill_width"] == 8 * stats_one["prefill_chunks"] == 8 * 16
+    assert outs == outs_one
+    for i, (p, o) in enumerate(zip(prompts, outs)):
+        if family == "toy":
+            assert o == _toy_cached_stream(p, budget)
+        elif family == "toy_state":
+            assert o == _toy_state_stream(p, budget)
+        elif _OWED[i] in (3, 9, 17):  # a program a length: three of them
+            ref = np.asarray(models.transformer.generate(
+                cfg, params, p[None], max_new_tokens=budget))
+            assert o == ref[0, len(p):].tolist(), _OWED[i]
+
+
+@pytest.mark.parametrize(
+    "metric", ["prefill_valid_token_share", "batch_prefill_valid_token_share"])
+def test_the_valid_token_metrics_name_a_reader_and_counters_that_exist(
+    tmp_path, monkeypatch, metric,
+):
+    """The metric files the counter ``prefill_width`` came with: the reader
+    each names is there, ``server.stats()`` carries the counters it divides,
+    the share is valid over dispatched tokens - and nothing, without
+    raising, on the parent's replica, which has no such counter."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.harness import manifest
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 16)
+    monkeypatch.setattr(model_server, "PREFILL_FLOOR", 4)
+    spec = manifest.layer_metric(metric)
+    read = manifest.reader(spec["reader"])
+    assert spec["args"] == {
+        "num": "decode_prefill_tokens", "den": "decode_prefill_width"}
+    srv = _pinned_decode_server(tmp_path, "vt0", decode_fns=_toy_cached_decode_fns())
+    try:
+        c = serve.ServeClient("127.0.0.1", srv.port, role="vt_sv")
+        start = c.stats()
+        c.generate(np.arange(1, 24, dtype=np.int32) % 11, 2)  # owes 22: 16 + 6 in 8
+        end = c.stats()
+        c.close()
+    finally:
+        srv.stop()
+    assert end["decode_prefill_width"] - start["decode_prefill_width"] == 24
+    share = read({"counters": {"start": start, "end": end}}, **spec["args"])
+    assert share == pytest.approx(100 * 22 / 24)
+    for stats in (start, end):
+        del stats["decode_prefill_width"]  # the parent's replica
+    assert read({"counters": {"start": start, "end": end}}, **spec["args"]) is None
 
 
 # ----------------------------------------------------------------------------

@@ -443,18 +443,27 @@ def test_blocked_decode_on_a_mesh_matches_one_device(monkeypatch, mesh_4x2):
 
 
 @pytest.mark.parametrize(
-    "C,T,n,slot",
+    "C,T,n,slot,floor",
     [
-        (8, 40, 21, 2),   # several chunks, a ragged last one, a slot other than 0
-        (8, 29, 28, 1),   # max_len no multiple of C; the prompt ends at max_len - 1
-        (8, 16, 8, 0),    # exactly one full chunk
-        (8, 32, 3, 3),    # one chunk, mostly padding
-        (16, 16, 15, 2),  # the chunk as long as the cache
-        (8, 12, 11, 0),   # every chunk but the first shifted back inside its window
+        # One width, ``C``: the floor is over half of it.
+        (8, 40, 21, 2, 128),   # several chunks, a ragged last one, a slot other than 0
+        (8, 29, 28, 1, 128),   # max_len no multiple of C; the prompt ends at max_len - 1
+        (8, 16, 8, 0, 128),    # exactly one full chunk
+        (8, 32, 3, 3, 128),    # one chunk, mostly padding
+        (16, 16, 15, 2, 128),  # the chunk as long as the cache
+        (8, 12, 11, 0, 128),   # every chunk but the first shifted back inside its window
+        # Widths ``floor`` .. ``C``: the last chunk as wide as what is left needs.
+        (16, 40, 21, 2, 4),    # 16, then 5 in a chunk of 8
+        (16, 40, 20, 1, 4),    # 16, then 4 in a chunk of 4: none padded
+        (16, 40, 3, 3, 4),     # 3 in a chunk of 4
+        (8, 29, 28, 1, 2),     # 8, 8, 8, then 4 up to the cache's last row but one
+        (8, 12, 10, 0, 2),     # 8, then a chunk of 2
+        (8, 10, 9, 0, 4),      # 8, then 1 in a chunk of 4 shifted back inside the cache
     ],
 )
-def test_prefill_chunks_match_token_by_token_decode(C, T, n, slot):
-    """``n`` prompt tokens prefilled ``C`` at a time leave the slot's cache
+def test_prefill_chunks_match_token_by_token_decode(engine_chunks, C, T, n, slot, floor):
+    """``n`` prompt tokens prefilled ``C`` at a time - the last chunk at the
+    narrowest of the engine's widths that holds it - leave the slot's cache
     rows ``[0, n)`` as ``n`` calls of ``decode_step_batch`` leave them and
     the following decode steps' logits equal (summation order apart),
     and no other row of the cache is touched at all."""
@@ -477,9 +486,8 @@ def test_prefill_chunks_match_token_by_token_decode(C, T, n, slot):
     for p in range(n):
         _logits, ref = step(ref, row(toks[p]), row(p))
     got = junk
-    for o in range(0, n, C):
-        nv = min(C, n - o)
-        buf = np.zeros((C,), np.int32)
+    for o, nv, width in engine_chunks(n, C, floor):
+        buf = np.zeros((width,), np.int32)
         buf[:nv] = toks[o:o + nv]
         got = chunk(got, buf, o, nv)
     for name in got:
@@ -588,13 +596,15 @@ def test_transformer_served_decode_byte_identical_to_reference(tmp_path):
         srv.stop()
 
 
+@pytest.mark.parametrize("floor,width", [(128, 9 * 8), (2, 28 + 12 + 20)])
 def test_transformer_served_with_chunked_prefill_matches_generate(
-    tmp_path, monkeypatch,
+    tmp_path, monkeypatch, floor, width,
 ):
     """Prompts longer than a chunk, seated together on a live replica: the
     engine's chunks (several a prompt, one an iteration, while the other
-    rows decode) leave every session the tokens ``generate`` gives it —
-    and ``generate`` those of the token-by-token feed."""
+    rows decode; all 8 wide, or a prompt's last one 2 or 4 wide) leave
+    every session the tokens ``generate`` gives it — and ``generate`` those
+    of the token-by-token feed."""
     import threading
 
     from distributed_tensorflow_examples_tpu import serve
@@ -607,6 +617,7 @@ def test_transformer_served_with_chunked_prefill_matches_generate(
     )
 
     monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    monkeypatch.setattr(model_server, "PREFILL_FLOOR", floor)
     cfg = models.transformer.Config(
         vocab_size=211, dim=32, n_layers=2, n_heads=4, max_seq_len=48,
         compute_dtype="float32",
@@ -647,6 +658,8 @@ def test_transformer_served_with_chunked_prefill_matches_generate(
         srv.stop()
     assert st["decode_prefill_tokens"] == sum(len(p) - 1 for p in prompts)
     assert st["decode_prefill_chunks"] == 4 + 0 + 2 + 3
+    # 28 = 8 + 8 + 8 + 4, 11 = 8 + 3 in 4, 19 = 8 + 8 + 3 in 4.
+    assert st["decode_prefill_width"] == width
     step = jax.jit(lambda c, t, p: tf.decode_step(cfg, params, c, t, p))
     for p, o in zip(prompts, outs):
         ref = np.asarray(tf.generate(cfg, params, p[None], max_new_tokens=12))
