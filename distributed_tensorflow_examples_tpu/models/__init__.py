@@ -21,10 +21,15 @@ traced function XLA can fuse end-to-end.
 - ``afmoe``    — gated grouped-query attention behind QK-norm, a ring cache
   in the sliding-window layers beside full rows in the global ones, whole
   expert layers under a sigmoid router (arcee-ai Trinity), served
+- ``smallthinker`` — a router that reads its layer's input before attention,
+  whole layers of ReLU-gated experts, a NoPE global layer to three rotary
+  window layers (PowerInfer SmallThinker), served
+- ``ring_cache`` — the slot cache with rings in the window layers and the two
+  blocked attentions over it, which ``afmoe`` and ``smallthinker`` share
 - ``mla``      — the latent-attention sub-layer ``longcat`` and ``deepseek``
   share
 - ``decoding`` — the generate loop of a model served by chunks and steps,
-  which those two and ``afmoe`` call
+  which those two, ``afmoe`` and ``smallthinker`` call
 """
 
 from . import layers  # noqa: F401
@@ -39,4 +44,6 @@ from . import mla  # noqa: F401
 from . import decoding  # noqa: F401
 from . import longcat  # noqa: F401
 from . import deepseek  # noqa: F401
+from . import ring_cache  # noqa: F401
 from . import afmoe  # noqa: F401
+from . import smallthinker  # noqa: F401

@@ -47,44 +47,15 @@ whole on its chip.  Parameters and cache entries are keyed by the published
 index (``layer_4``), and a layer's kind and feed-forward are those of its
 published index.
 
-THE CACHE, per layer BY KIND, arrays ``k`` and ``v`` a layer in
-``param_dtype`` (a sliding layer's keys are kept rotated):
-
-- a full layer ``[slots + 1, kv_heads, max_len, head_dim]`` each, a
-  position's row its own, written in place;
-- a sliding layer a RING ``[slots + 1, kv_heads, R, head_dim]`` each of ``R =
-  sliding_window + ring_slack`` rows (or ``max_len`` where that is fewer: no
-  position then wraps), a position's row ``pos % R``.  The ring is WRITTEN
-  BEFORE IT IS ATTENDED, as the full layer is: the slack is what lets a
-  chunk of up to ``ring_slack`` tokens be written whole and its FIRST query
-  still find its ``sliding_window - 1`` predecessors (the rows a chunk at
-  ``offset`` overwrites held positions below ``offset + C - R <= offset -
-  sliding_window``).  A ring of exactly ``sliding_window`` rows would have
-  to be attended before it is overwritten - the chunk against the old ring
-  and against itself, in two pieces; 512 rows more a window layer (1 MB a
-  slot) buy one code path for both kinds.  A chunk may lie anywhere on the
-  ring, across its end too (:func:`_chunk_write`).
-
-What a row of either kind holds is told BY POSITION ARITHMETIC, never by
-clearing: with ``last`` the latest position its session has written, row
-``r`` holds position ``last - (last - r) mod R``; below 0 it holds nothing
-of this session (whatever the slot's previous session left there), above a
-query's own position or ``sliding_window`` or more behind it the query does
-not see it.
-
-The step reads the cache a block of ``attn_block`` rows of EVERY slot at a
-time, up to the block that holds the deepest live slot's row (a ring: at
-most ``R``), in plain ``jax.numpy`` - the loop of models/mla.py
-``_absorbed_loop``, for grouped heads and rings; the chunk reads its own
-slot's blocks up to its last query's row.  The step is told which rows are
-LIVE: a row that is not leaves everything its slot owns unchanged (on a ring
-its write would land on a row that a chunk of the session being prefilled
-there still reads), reads nothing and is counted nowhere.  Its key and value
-go to a SPARE slot, the last of each layer's array, which no session is
-seated in and nothing reads: to leave a row as it was the step would have to
-read it first, and with a row read out of it the compiler lays the whole
-cache out position-major - a copy of all 1.7 GB in and another out, every
-step (the compiled step for a v5e, PR 39; 54 MB of spare slot instead).
+THE CACHE is models/ring_cache.py's, per layer BY KIND, arrays ``k`` and
+``v`` a layer in ``param_dtype`` (a sliding layer's keys are kept rotated): a
+full layer ``max_len`` rows a slot, a sliding layer a RING of
+``sliding_window + ring_slack`` rows written before it is attended, a SPARE
+slot beside the seated ones for the step's rows that are not live; what a
+row holds is position arithmetic.  The step's and the chunk's blocked
+attention are that module's too (``attend_step``, ``attend_chunk``).  The
+step is told which rows are LIVE: one that is not leaves everything its slot
+owns unchanged, reads nothing and is counted nowhere.
 
 What the model counts on the device (the cache tree's ``counters``): the
 ``moe_*`` sums of models/deepseek.py (:data:`COUNTS`, the chunk's part of
@@ -107,15 +78,13 @@ Serving only: no loss (``load_balance_coeff`` is training's), no mesh.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..ops import moe as moe_ops
-from . import decoding, layers
+from . import decoding, layers, ring_cache
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 #: The source's pattern: ``global_attn_every_n_layers`` 4.
@@ -264,8 +233,7 @@ def init(cfg: Config, rng: jax.Array):
 COUNTS = ("choices", "choices_held", "experts_touched", "calls", "tokens_reaching")
 CHUNK_COUNTS = ("choices_held", "experts_touched", "calls")
 #: The step's attention by kind of layer (module docstring), ``[slots]`` each.
-ATTN_COUNTS = ("attn_window_rows_read", "attn_window_rows_needed",
-               "attn_global_rows_read", "attn_global_rows_needed", "attn_rows_read")
+ATTN_COUNTS = ring_cache.ATTN_COUNTS
 
 
 def init_cache(cfg: Config, slots: int, max_len: int):
@@ -324,32 +292,6 @@ def _gated_out(cfg: Config, p, u, o):
 
 def _scope(window) -> str:
     return "afmoe/attn_global" if window is None else "afmoe/attn_window"
-
-
-def _held_position(last, r, rows: int):
-    """The position that cache row ``r`` of ``rows`` holds when ``last`` is
-    the latest position its session has written (below 0: none of it)."""
-    return last - jnp.mod(last - r, rows)
-
-
-#: Where a running softmax's maximum starts (:func:`_softmax_fold`).
-_FLOOR = -1e30
-
-
-def _softmax_fold(carry, s, v, dtype, spec: str):
-    """One block folded into a running softmax: ``carry`` = (maximum, sum,
-    weighted values) in float32, ``s`` the block's masked scores (``-inf``
-    where unseen).  The maximum starts FINITE (:data:`_FLOOR`): a block may
-    hold nothing a query sees - a ring's rows in any order - and ``exp(-inf
-    - -inf)`` would poison the sums."""
-    m, l, acc = carry
-    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-    w = jnp.exp(s - m_new)
-    r = jnp.exp(m - m_new)
-    l = l * r + w.sum(axis=-1, keepdims=True)
-    acc = acc * r + jnp.einsum(
-        spec, w.astype(dtype), v, preferred_element_type=jnp.float32)
-    return m_new, l, acc
 
 
 def _layer(cfg: Config, p, i: int, x, attn, routed):
@@ -423,67 +365,6 @@ def apply(cfg: Config, params, tokens):
 # ----------------------------------------------------------------------------
 
 
-def _blocks_read(deepest, rows: int, block: int):
-    """Blocks of ``min(block, rows)`` cache rows that hold everything up to
-    the ``deepest``-th row written (a ring: at most all of it); arrays of
-    numpy or of the traced program alike."""
-    blk = min(block, rows)
-    return (jnp if isinstance(deepest, jax.Array) else np).minimum(
-        -(-deepest // blk), -(-rows // blk))
-
-
-def _write_rows(cache, new, pos, live):
-    """``cache [S + 1, KV, R, hd]`` with ``new[b] [KV, hd]`` written at row
-    ``pos[b] % R`` of every LIVE slot ``b`` and no slot's rows changed else:
-    one ``dynamic_update_slice`` a slot, each in place in a donated cache
-    (models/transformer.py ``_write_rows`` has the chip reading that chose
-    this over a scatter), a row that is not live writing into the SPARE
-    slot ``S`` (:func:`init_cache`)."""
-    S, R = new.shape[0], cache.shape[2]
-    for b in range(S):
-        cache = jax.lax.dynamic_update_slice(
-            cache, new[b][None, :, None], (jnp.where(live[b], b, S), 0, pos[b] % R, 0))
-    return cache
-
-
-def _attend_step(cfg: Config, q, ck, cv, pos, live, window):
-    """One query a slot against that slot's rows: ``q [S, KV, G, hd]``,
-    ``ck, cv [S + 1, KV, R, hd]`` (the slot's row at ``pos`` already
-    written) -> ``([S, KV, G, hd]`` float32, rows read a slot``)``; a live
-    slot ``b`` attends over its positions ``<= pos[b]`` (and, with
-    ``window``, fewer than ``window`` behind it), one that is not over
-    nothing (zeros)."""
-    S, (_, KV, R, hd) = q.shape[0], ck.shape
-    blk = min(cfg.attn_block, R)
-    n = jnp.where(live, pos + 1, 0)
-    n_blocks = _blocks_read(jnp.max(n), R, cfg.attn_block)
-    scale = 1.0 / math.sqrt(hd)
-
-    def body(i, carry):
-        # Where R is no multiple of the block the last one is read shifted
-        # back inside the cache and what it shares with the block before
-        # is masked.
-        start = jnp.minimum(i * blk, R - blk)
-        k, v = (jax.lax.dynamic_slice(c, (0, 0, start, 0), (S, KV, blk, hd))
-                for c in (ck, cv))
-        s = jnp.einsum("skgd,sktd->skgt", q, k,
-                       preferred_element_type=jnp.float32) * scale
-        r = start + jnp.arange(blk)
-        held = _held_position(pos[:, None], r[None, :], R)  # [S, blk]
-        seen = (r >= i * blk)[None, :] & (held >= 0) & live[:, None]
-        if window is not None:
-            seen &= pos[:, None] - held < window
-        s = jnp.where(seen[:, None, None, :], s, -jnp.inf)
-        return _softmax_fold(carry, s, v, cfg.dtype, "skgt,sktd->skgd")
-
-    stat = jnp.zeros(q.shape[:3] + (1,), jnp.float32)
-    with jax.named_scope(_scope(window)):
-        _, l, acc = jax.lax.fori_loop(
-            0, n_blocks, body, (stat + _FLOOR, stat, jnp.zeros(q.shape, jnp.float32)))
-        o = acc / jnp.where(l == 0, 1.0, l)  # a slot that read nothing: zeros
-    return o, jnp.minimum(n_blocks * blk, R)
-
-
 def decode_step_batch(cfg: Config, params, cache, token, pos, live):
     """token ``[S]`` int32, pos ``[S]`` int32 (per-row positions), live
     ``[S]`` bool -> (logits ``[S, vocab]``, new cache): every LIVE row
@@ -498,21 +379,12 @@ def decode_step_batch(cfg: Config, params, cache, token, pos, live):
     for i in cfg.layers:
         p = params[f"layer_{i}"]
         window = cfg.window(i)
-        written = {}
 
         def attn(pa, u):
             q, new = _qkv(cfg, pa, u, pos, window is not None)
-            old = cache[f"layer_{i}"]
-            ck = written["k"] = _write_rows(old["k"], new[:, 0], pos, live)
-            cv = written["v"] = _write_rows(old["v"], new[:, 1], pos, live)
-            o, read = _attend_step(cfg, q, ck, cv, pos, live, window)
-            need = jnp.where(live, pos + 1, 0)
-            kind = "global" if window is None else "window"
-            if window is not None:
-                need = jnp.minimum(need, window)
-            counters[f"attn_{kind}_rows_read"] += read
-            counters[f"attn_{kind}_rows_needed"] += need
-            counters["attn_rows_read"] += read
+            o, new_cache[f"layer_{i}"] = ring_cache.step_attention(
+                q, new, cache[f"layer_{i}"], pos, live, window, counters,
+                attn_block=cfg.attn_block, dtype=cfg.dtype, scope=_scope(window))
             return _gated_out(cfg, pa, u, o)
 
         def routed(u):
@@ -522,69 +394,8 @@ def decode_step_batch(cfg: Config, params, cache, token, pos, live):
             return m
 
         h = _layer(cfg, p, i, h, attn, routed)
-        new_cache[f"layer_{i}"] = written
     new_cache["counters"] = counters
     return _logits(cfg, params, h), new_cache
-
-
-def _chunk_write(cache, new, slot, offset, n_valid):
-    """``cache [S + 1, KV, R, hd]`` with ``new [KV, C, hd]`` rows ``[0,
-    n_valid)`` written at rows ``(offset + i) % R`` of ``slot`` and nothing
-    else changed; returns the cache and the slot's rows ``[KV, R, hd]``.
-    THE SLOT'S ROWS ARE READ, CHANGED AND WRITTEN BACK WHOLE (2.6 MB a
-    ring, 16.8 MB a full layer of 16,384 rows): the chunk may lie anywhere,
-    across a ring's end too, and nothing is cut out of the cache at a traced
-    ROW - read a window of rows at one, change it and write it back, and the
-    compiler lays the whole cache out position-major, a copy of every slot
-    in and another out each chunk (the compiled chunk for a v5e, PR 39)."""
-    _, KV, R, hd = cache.shape
-    C = new.shape[1]
-    first = offset % R
-    old = jax.lax.dynamic_slice(cache, (slot, 0, 0, 0), (1, KV, R, hd))[0]
-    # Row r is token (r - first) mod R's: the chunk laid out from row 0,
-    # then turned to where it starts.
-    at_home = jnp.roll(jnp.pad(new, ((0, 0), (0, R - C), (0, 0))), first, axis=1)
-    own = (jnp.mod(jnp.arange(R) - first, R) < n_valid)[None, :, None]
-    rows = jnp.where(own, at_home, old)
-    return jax.lax.dynamic_update_slice(cache, rows[None], (slot, 0, 0, 0)), rows
-
-
-def _attend_chunk(cfg: Config, q, k_rows, v_rows, offset, n_valid, window):
-    """``q [C, KV, G, hd]`` - the queries at positions ``offset .. offset +
-    C - 1`` of a slot, the first ``n_valid`` real - against that slot's rows
-    ``k_rows, v_rows [KV, R, hd]`` (the valid ones' own already written) ->
-    ``[C, KV, G, hd]`` float32; a block of rows a trip, no further than the
-    last query's row.  A padding query sees what the last valid one sees
-    (zeros where there is none) and nothing keeps its result."""
-    C = q.shape[0]
-    KV, R, hd = k_rows.shape
-    blk = min(cfg.attn_block, R)
-    t = offset + jnp.arange(C)
-    last = offset + n_valid - 1
-    scale = 1.0 / math.sqrt(hd)
-
-    def body(i, carry):
-        start = jnp.minimum(i * blk, R - blk)
-        k, v = (jax.lax.dynamic_slice_in_dim(rows, start, blk, axis=1)
-                for rows in (k_rows, v_rows))
-        s = jnp.einsum("ckgd,ktd->kgct", q, k,
-                       preferred_element_type=jnp.float32) * scale
-        r = start + jnp.arange(blk)
-        held = _held_position(last, r, R)  # [blk]
-        behind = t[:, None] - held[None, :]  # [C, blk]
-        seen = ((r >= i * blk) & (held >= 0))[None, :] & (behind >= 0)
-        if window is not None:
-            seen &= behind < window
-        s = jnp.where(seen[None, None], s, -jnp.inf)
-        return _softmax_fold(carry, s, v, cfg.dtype, "kgct,ktd->kgcd")
-
-    G = q.shape[2]
-    stat = jnp.zeros((KV, G, C, 1), jnp.float32)
-    with jax.named_scope(_scope(window)):
-        _, l, acc = jax.lax.fori_loop(
-            0, _blocks_read(offset + C, R, cfg.attn_block), body,
-            (stat + _FLOOR, stat, jnp.zeros((KV, G, C, hd), jnp.float32)))
-    return jnp.moveaxis(acc / jnp.where(l == 0, 1.0, l), 2, 0)
 
 
 def prefill_chunk(cfg: Config, params, cache, tokens, slot, offset, n_valid):
@@ -608,22 +419,14 @@ def prefill_chunk(cfg: Config, params, cache, tokens, slot, offset, n_valid):
     for i in cfg.layers:
         p = params[f"layer_{i}"]
         window = cfg.window(i)
-        written = {}
 
         def attn(pa, u):
-            old = cache[f"layer_{i}"]
-            wraps = window is not None and old["k"].shape[2] == window + cfg.ring_slack
-            if wraps and C > cfg.ring_slack:
-                raise ValueError(
-                    f"a chunk of {C} tokens would overwrite rows of a ring of "
-                    f"{old['k'].shape[2]} that its first query still reads: "
-                    f"ring_slack is {cfg.ring_slack}")
             q, new = _qkv(cfg, pa, u, pos, window is not None)
-            new = jnp.moveaxis(new, 0, 2)  # [2, KV, C, hd]
-            written["k"], k_rows = _chunk_write(old["k"], new[0], slot, offset, n_valid)
-            written["v"], v_rows = _chunk_write(old["v"], new[1], slot, offset, n_valid)
-            return _gated_out(cfg, pa, u, _attend_chunk(
-                cfg, q, k_rows, v_rows, offset, n_valid, window))
+            o, new_cache[f"layer_{i}"] = ring_cache.chunk_attention(
+                q, new, cache[f"layer_{i}"], slot, offset, n_valid, window,
+                slack=cfg.ring_slack, attn_block=cfg.attn_block, dtype=cfg.dtype,
+                scope=_scope(window))
+            return _gated_out(cfg, pa, u, o)
 
         def routed(u):
             nonlocal counters
@@ -633,53 +436,19 @@ def prefill_chunk(cfg: Config, params, cache, tokens, slot, offset, n_valid):
             return m
 
         h = _layer(cfg, p, i, h, attn, routed if i != cfg.layers[-1] else None)
-        new_cache[f"layer_{i}"] = written
     new_cache["counters"] = counters
     return new_cache
 
 
-def decode_rows_read(cfg: Config, pos, live, max_len: int) -> float:
-    """Cache positions one decode step reads A SLOT IN THE MEAN LAYER, from
-    the host's ``pos [S]`` and ``live [S]``: in every layer whole blocks of
-    every slot up to the deepest live slot's row, in a ring at most the
-    ring (:func:`_attend_step`)."""
-    deepest = int(np.where(live, pos + 1, 0).max())
-    return float(np.mean([_rows_read(cfg, i, deepest, max_len) for i in cfg.layers]))
-
-
-def prefill_rows_read(cfg: Config, offset: int, chunk: int, max_len: int) -> float:
-    """Cache positions the attention of one chunk of ``chunk`` queries at
-    ``offset`` reads in the mean layer (:func:`_attend_chunk`)."""
-    return float(np.mean(
-        [_rows_read(cfg, i, offset + chunk, max_len) for i in cfg.layers]))
-
-
-def _rows_read(cfg: Config, i: int, deepest: int, max_len: int) -> int:
-    rows = cfg.cache_rows(i, max_len)
-    return min(int(_blocks_read(deepest, rows, cfg.attn_block)) * min(cfg.attn_block, rows), rows)
+decode_rows_read = ring_cache.decode_rows_read
+prefill_rows_read = ring_cache.prefill_rows_read
 
 
 def serve_decode_fns(cfg: Config):
     """``(init_cache_fn, step_fn, prefill_fn)`` for ``serve.
-    ModelReplicaServer(decode_fns=...)``.  ``step_fn`` takes ``live`` (a row
-    that is not live must leave its ring alone) and says what a step reads
-    of the cache (``cache_rows_read``: :func:`decode_rows_read`), as
-    ``prefill_fn`` says what a chunk reads (:func:`prefill_rows_read`)."""
-
-    def init_cache_fn(slots: int, max_len: int):
-        return init_cache(cfg, slots, max_len)
-
-    def step_fn(params, cache, tokens, pos, live):
-        return decode_step_batch(cfg, params, cache, tokens, pos, live)
-
-    step_fn.cache_rows_read = functools.partial(decode_rows_read, cfg)
-
-    def prefill_fn(params, cache, tokens, slot, offset, n_valid):
-        return prefill_chunk(cfg, params, cache, tokens, slot, offset, n_valid)
-
-    prefill_fn.cache_rows_read = functools.partial(prefill_rows_read, cfg)
-
-    return init_cache_fn, step_fn, prefill_fn
+    ModelReplicaServer(decode_fns=...)``, with what a step and a chunk read
+    of the cache (models/ring_cache.py ``serve_decode_fns``)."""
+    return ring_cache.serve_decode_fns(cfg, init_cache, decode_step_batch, prefill_chunk)
 
 
 # ----------------------------------------------------------------------------

@@ -1,7 +1,9 @@
-"""Gated-SiLU feed-forward over rows grouped by expert: the experts a chip
+"""Gated feed-forward over rows grouped by expert: the experts a chip
 holds, each applied to the rows routed to it.
 
-    y[r] = down[e] . (silu(gate[e] . x[r]) * (up[e] . x[r]))      r in group e
+    y[r] = down[e] . (act(gate[e] . x[r]) * (up[e] . x[r]))       r in group e
+
+``act`` is SiLU or, where the caller says so (``activation="relu"``), ReLU.
 
 ``rows [M, D]`` (bfloat16) hold group after group in expert order, each
 group STARTING ON A BLOCK BOUNDARY (:func:`group_starts`): group ``e`` is
@@ -30,7 +32,7 @@ steps that accumulate ``x . gate`` and ``x . up`` in float32 scratch
 (``[block_rows, F]`` each), then ``F / block_f`` steps that add ``h[:,
 f-block] . down[f-block]`` into the resident output block.  Blocks of the
 matrices are whole rows of them (contiguous in HBM).  Products are bfloat16
-x bfloat16 accumulated in float32; SiLU and the gate's product in float32,
+x bfloat16 accumulated in float32; ``act`` and the gate's product in float32,
 ``h`` rounded once to bfloat16 - the arithmetic of ``models/layers.py
 gated_mlp``.
 
@@ -38,7 +40,10 @@ VMEM at the served widths (D 6144, F 2048) with ``block_rows`` 128,
 ``BLOCK_K`` 1024, ``BLOCK_F`` 512, double buffers counted: gate and up
 blocks 16.8 MB, down 12.6 MB, output 6.3 MB, rows 0.5 MB, scratch 2.6 MB -
 39 MB of the chip's 128 MiB, above the compiler's default allowance, so
-the call states its own (:data:`VMEM_LIMIT`).
+the call states its own (:data:`VMEM_LIMIT`).  At D 2560, F 768 neither
+block divides its dimension and :func:`_block` takes 640 and 384 (four steps
+and two): gate and up blocks 3.9 MB, down 3.9 MB, output 2.6 MB, rows 0.3
+MB, scratch 1.0 MB - 12 MB.
 """
 
 from __future__ import annotations
@@ -53,12 +58,15 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import compiler_params, interpret_mode
 
 #: Rows of ``gate`` / ``up`` (of D) and of ``down`` (of F) one grid step
-#: brings in; a dimension shorter than its block is taken whole.
+#: brings in; a dimension shorter than its block is taken whole, one that
+#: its block does not divide in narrower blocks (:func:`_block`).
 BLOCK_K = 1024
 BLOCK_F = 512
 VMEM_LIMIT = 64 * 1024 * 1024
 #: The kernel's name, which its operations carry in a device trace.
 KERNEL_NAME = "moe_grouped_ffn"
+#: What ``activation`` may name.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def group_starts(group_sizes, block_rows: int):
@@ -71,7 +79,7 @@ def group_starts(group_sizes, block_rows: int):
 
 
 def _kernel(tile_expert, n_real, x_ref, gate_ref, up_ref, down_ref, out_ref,
-            g_acc, u_acc, h_ref, *, nk: int, nf: int):
+            g_acc, u_acc, h_ref, *, nk: int, nf: int, act):
     del tile_expert  # the index maps read it
     t, s = pl.program_id(0), pl.program_id(1)
     real = t < n_real[0]
@@ -94,7 +102,7 @@ def _kernel(tile_expert, n_real, x_ref, gate_ref, up_ref, down_ref, out_ref,
 
     @pl.when(real & (s == nk - 1))
     def _():
-        h = (jax.nn.silu(g_acc[...]) * u_acc[...]).astype(h_ref.dtype)
+        h = (act(g_acc[...]) * u_acc[...]).astype(h_ref.dtype)
         bf = h.shape[1] // nf
         for j in range(nf):
             h_ref[j] = h[:, j * bf:(j + 1) * bf]
@@ -114,18 +122,24 @@ def _kernel(tile_expert, n_real, x_ref, gate_ref, up_ref, down_ref, out_ref,
 
 
 def _block(n: int, want: int) -> int:
+    """``want`` where it divides ``n`` (or ``n`` is shorter); otherwise the
+    largest multiple of 128 under ``want`` that does (2560 under 1024: 640;
+    768 under 512: 384)."""
     if n <= want:
         return n
-    if n % want:
-        raise ValueError(f"a dimension of {n} is no multiple of its block {want}")
-    return want
+    for block in (want, *range(want - want % 128, 0, -128)):
+        if n % block == 0:
+            return block
+    raise ValueError(f"a dimension of {n} is no multiple of its block {want}, "
+                     "nor of a multiple of 128 under it")
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows",))
-def grouped_ffn(rows, group_sizes, gate, up, down, *, block_rows: int = 128):
+@functools.partial(jax.jit, static_argnames=("block_rows", "activation"))
+def grouped_ffn(rows, group_sizes, gate, up, down, *, block_rows: int = 128,
+                activation: str = "silu"):
     """See the module docstring.  Any ``M`` (padded here to whole blocks);
-    ``block_rows`` a multiple of 16.  Compiles through Mosaic on a TPU,
-    interpreted on the CPU."""
+    ``block_rows`` a multiple of 16; ``activation`` of :data:`ACTIVATIONS`.
+    Compiles through Mosaic on a TPU, interpreted on the CPU."""
     M, D = rows.shape
     E, _, F = gate.shape
     bm = block_rows
@@ -157,7 +171,7 @@ def grouped_ffn(rows, group_sizes, gate, up, down, *, block_rows: int = 128):
     w_in = pl.BlockSpec(
         (None, bk, F), lambda t, s, te, n: (te[tile(t, n)], k_step(t, s, n), 0))
     out = pl.pallas_call(
-        functools.partial(_kernel, nk=nk, nf=nf),
+        functools.partial(_kernel, nk=nk, nf=nf, act=ACTIVATIONS[activation]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(tiles, nk + nf),
@@ -185,7 +199,8 @@ def grouped_ffn(rows, group_sizes, gate, up, down, *, block_rows: int = 128):
     return out[:M]
 
 
-def grouped_ffn_reference(rows, group_sizes, gate, up, down, *, block_rows: int = 128):
+def grouped_ffn_reference(rows, group_sizes, gate, up, down, *, block_rows: int = 128,
+                          activation: str = "silu"):
     """The same rows through a loop over the experts in plain ``jax.numpy``
     (every expert applied to every row, masked): what the kernel is tested
     against.  Rows in no group come out 0."""
@@ -195,7 +210,7 @@ def grouped_ffn_reference(rows, group_sizes, gate, up, down, *, block_rows: int 
     for e in range(gate.shape[0]):
         g = jnp.dot(rows, gate[e], preferred_element_type=jnp.float32)
         u = jnp.dot(rows, up[e], preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(g) * u).astype(rows.dtype)
+        h = (ACTIVATIONS[activation](g) * u).astype(rows.dtype)
         y = jnp.dot(h, down[e], preferred_element_type=jnp.float32)
         mine = (r >= starts[e]) & (r < starts[e] + group_sizes[e])
         out = jnp.where(mine[:, None], y, out)

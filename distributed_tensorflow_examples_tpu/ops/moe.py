@@ -25,7 +25,10 @@ A second layer beside it, :func:`apply_share`, is ONE CHIP'S SHARE of a
 wide expert-parallel deployment, dropless: it is told which of the model's
 experts it holds, routes over all of them, and computes the part of the
 result that its own experts (and the zero-compute ones, which need no
-exchange) give - ops/grouped_ffn.py does the products.  The two share
+exchange) give - ops/grouped_ffn.py does the products.  It is two halves, a
+plan from what the router reads (:func:`share_plan`) and the products on
+what the experts read (:func:`apply_share_plan`), which a model whose router
+reads another tensor than its experts calls apart.  The two layers share
 nothing but this file; a model calls the one it is.
 """
 
@@ -33,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import typing
 import warnings
 
 import jax
@@ -262,10 +266,15 @@ class ShareConfig:
     #: The chosen scores are divided by their sum (plus ``NORMALISE_EPS``)
     #: before ``scale``: a token's weights then add up to ``scale``.
     normalise: bool = False
+    #: What an expert applies to its gate's half (ops/grouped_ffn.py
+    #: ``ACTIVATIONS``).
+    activation: str = "silu"
 
     def __post_init__(self):
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown scoring {self.scoring!r}")
+        if self.activation not in grouped_ffn_lib.ACTIVATIONS:
+            raise ValueError(f"unknown activation {self.activation!r}")
         if not self.top_groups:
             return
         if self.n_zero or self.n_group <= 0 or self.n_experts % self.n_group:
@@ -288,7 +297,7 @@ class ShareConfig:
 #: underflows (the published modelling code's constant).
 NORMALISE_EPS = 1e-20
 
-#: What :func:`apply_share` counts, each an int32 scalar.
+#: What :func:`share_counts` counts, each an int32 scalar.
 SHARE_COUNTS = ("choices", "choices_held", "choices_zero", "experts_touched",
                 "calls", "tokens_reaching")
 
@@ -325,46 +334,50 @@ def share_rows_block(tokens: int) -> int:
     return 128 if tokens >= 128 else 32
 
 
-def apply_share(p, u, share: ShareConfig, live=None, *, dtype):
-    """u ``[T, D]`` float32 (normed) -> ``(m [T, D] float32, counts)``: the
-    part of ``sum_i w_i E_i(u)`` that this chip's experts and the
-    zero-compute experts give.
+class SharePlan(typing.NamedTuple):
+    """Where a call's choices go (:func:`share_plan`): everything of a call
+    that depends on the router's input alone."""
+
+    w: jax.Array  # [T, k] float32: the weight of each choice
+    on_held: jax.Array  # [T, k] bool: a live row's choice on an expert held here
+    on_zero: jax.Array  # [T, k] bool: ... on a zero-compute expert
+    sizes: jax.Array  # [held] int32: rows of each held expert's group
+    dest: jax.Array  # [T k] int32: a held choice's row of the buffer (else past it)
+    token_of_row: jax.Array  # [rows] int32: the token whose row each one is
+    live: jax.Array  # [T] bool
+
+
+def share_plan(p_router, r_in, share: ShareConfig, live=None) -> SharePlan:
+    """The plan of one call from the router's input ``r_in [T, D]`` float32
+    - which need not be what the experts read (a router that reads its
+    layer's input plans before the attention whose result the experts get).
 
     Router in float32 throughout (the product at the highest precision): ``s
-    = softmax(u . router)`` over all ``n_experts + n_zero``, or the sigmoid
-    of each (``share.scoring``); the choice is :func:`share_choice`'s (free
-    over ``s + bias``, or limited to groups); weights ``scale * s`` of the
-    chosen, or where ``share.normalise`` ``scale * s / (sum of the chosen s +
-    NORMALISE_EPS)``.  A choice on a held expert becomes a row of that
+    = softmax(r_in . router)`` over all ``n_experts + n_zero``, or the
+    sigmoid of each (``share.scoring``); the choice is :func:`share_choice`'s
+    (free over ``s + bias``, or limited to groups); weights ``scale * s`` of
+    the chosen, or where ``share.normalise`` ``scale * s / (sum of the chosen
+    s + NORMALISE_EPS)``.  A choice on a held expert becomes a row of that
     expert's group (sorted by expert, each group on a block boundary:
-    ops/grouped_ffn.py); a choice on a zero-compute expert adds ``w u`` where
-    the token lives; a choice on an expert that lives on another chip adds
-    NOTHING - no capacity, no dropped token, no stand-in for the other chips'
-    part.  ``p``: ``router/kernel
-    [D, n_experts + n_zero]``, ``router/bias`` (or none: the choice is on
-    ``s`` alone), ``gate, up [held, D, F]``, ``down [held, F, D]``.  ``live
-    [T]`` bool: a row that is not live (an empty slot, padding) gets no
-    expert row, a zero result and no count.  The row buffer is static and
-    holds the worst case, ``top_k x T`` choices all held.
-
-    ``counts`` (:data:`SHARE_COUNTS`): the live rows' choices, those on
-    held and on zero-compute experts, the held experts with at least one
-    row, 1 for the call, and the live rows with at least one held choice
-    (``tokens_reaching``: what the deployment's exchange would send here)."""
-    T, D = u.shape
+    ops/grouped_ffn.py).  ``p_router``: ``kernel [D, n_experts + n_zero]``,
+    ``bias`` (or none: the choice is on ``s`` alone).  ``live [T]`` bool: a
+    row that is not live (an empty slot, padding) gets no expert row.  The
+    row buffer is static and holds the worst case, ``top_k x T`` choices all
+    held."""
+    T = r_in.shape[0]
     k, E, held = share.top_k, share.n_experts, share.held
     f32 = jnp.float32
     live = jnp.ones((T,), bool) if live is None else live
     with jax.named_scope("moe/route"):
         logits = jnp.dot(
-            u.astype(f32), p["router"]["kernel"].astype(f32),
+            r_in.astype(f32), p_router["kernel"].astype(f32),
             precision=jax.lax.Precision.HIGHEST,
         )
         if share.scoring == "softmax":
             s = jax.nn.softmax(logits, axis=-1)
         else:
             s = jax.nn.sigmoid(logits)
-        bias = p["router"].get("bias")
+        bias = p_router.get("bias")
         choice, chosen = share_choice(  # [T, k]
             s, share, None if bias is None else bias.astype(f32))
         if share.normalise:
@@ -376,7 +389,7 @@ def apply_share(p, u, share: ShareConfig, live=None, *, dtype):
         # Each held choice's row: its group's start plus how many choices on
         # the same expert come before it.
         block = share_rows_block(T)
-        rows = (-(-T * k // block) + held) * block
+        rows = _share_rows(T, share)
         flat = jnp.where(on_held, local, held).reshape(-1)  # [T k]
         hit = flat[:, None] == jnp.arange(held)[None, :]  # [T k, held]
         sizes = jnp.sum(hit, axis=0, dtype=jnp.int32)
@@ -386,24 +399,66 @@ def apply_share(p, u, share: ShareConfig, live=None, *, dtype):
         dest = jnp.where(flat < held, dest, rows)  # past the end: dropped
         token_of_row = jnp.full((rows,), T - 1, jnp.int32).at[dest].set(
             jnp.arange(T * k, dtype=jnp.int32) // k, mode="drop")
+    return SharePlan(w, on_held, on_zero, sizes, dest, token_of_row, live)
+
+
+def _share_rows(tokens: int, share: ShareConfig) -> int:
+    """Rows of the grouped product's buffer for a call of ``tokens``."""
+    block = share_rows_block(tokens)
+    return (-(-tokens * share.top_k // block) + share.held) * block
+
+
+def apply_share_plan(p, u, plan: SharePlan, share: ShareConfig, *, dtype):
+    """u ``[T, D]`` float32 (normed) -> ``m [T, D]`` float32: the part of
+    ``sum_i w_i E_i(u)`` that this chip's experts and the zero-compute
+    experts give, the choices and weights ``plan``'s.  A choice on a held
+    expert is a row of the grouped product; a choice on a zero-compute
+    expert adds ``w u`` where the token lives; a choice on an expert that
+    lives on another chip adds NOTHING - no capacity, no dropped token, no
+    stand-in for the other chips' part; a row that is not live gets a zero
+    result.  ``p``: ``gate, up [held, D, F]``, ``down [held, F, D]``."""
+    T, D = u.shape
+    rows = _share_rows(T, share)
     with jax.named_scope("moe/experts"):
         y = grouped_ffn_lib.grouped_ffn(
-            jnp.take(u.astype(dtype), token_of_row, axis=0), sizes,
-            p["gate"], p["up"], p["down"], block_rows=block,
+            jnp.take(u.astype(dtype), plan.token_of_row, axis=0), plan.sizes,
+            p["gate"], p["up"], p["down"], block_rows=share_rows_block(T),
+            activation=share.activation,
         )
         # A row outside the groups may hold anything: selected away, never
         # multiplied by a zero.
-        mine = jnp.take(y, jnp.minimum(dest, rows - 1), axis=0).reshape(T, k, D)
-        m = jnp.sum(jnp.where(on_held[..., None], w[..., None] * mine, 0.0), axis=1)
+        mine = jnp.take(y, jnp.minimum(plan.dest, rows - 1), axis=0).reshape(
+            T, share.top_k, D)
+        m = jnp.sum(
+            jnp.where(plan.on_held[..., None], plan.w[..., None] * mine, 0.0), axis=1)
     with jax.named_scope("moe/zero"):
-        m = m + jnp.sum(jnp.where(on_zero, w, 0.0), axis=1, keepdims=True) * u
+        m = m + jnp.sum(jnp.where(plan.on_zero, plan.w, 0.0), axis=1, keepdims=True) * u
+    return m
+
+
+def share_counts(plan: SharePlan, share: ShareConfig) -> dict:
+    """:data:`SHARE_COUNTS` of the call ``plan`` is of, from the plan alone:
+    the live rows' choices, those on held and on zero-compute experts, the
+    held experts with at least one row, 1 for the call, and the live rows
+    with at least one held choice (``tokens_reaching``: what the
+    deployment's exchange would send here)."""
     count = lambda x: jnp.sum(x, dtype=jnp.int32)
-    counts = {
-        "choices": k * count(live), "choices_held": count(on_held),
-        "choices_zero": count(on_zero), "experts_touched": count(sizes > 0),
-        "calls": jnp.int32(1), "tokens_reaching": count(jnp.any(on_held, axis=1)),
+    return {
+        "choices": share.top_k * count(plan.live), "choices_held": count(plan.on_held),
+        "choices_zero": count(plan.on_zero), "experts_touched": count(plan.sizes > 0),
+        "calls": jnp.int32(1), "tokens_reaching": count(jnp.any(plan.on_held, axis=1)),
     }
-    return m, counts
+
+
+def apply_share(p, u, share: ShareConfig, live=None, *, dtype, plan=None):
+    """u ``[T, D]`` float32 (normed) -> ``(m [T, D] float32, counts)``:
+    :func:`apply_share_plan` under ``plan`` - None: :func:`share_plan`'s
+    from ``u`` itself, with ``p["router"]`` and ``live`` - and the call's
+    :func:`share_counts`.  A plan handed in was made with its own ``live``."""
+    if plan is None:
+        plan = share_plan(p["router"], u, share, live)
+    m = apply_share_plan(p, u, plan, share, dtype=dtype)
+    return m, share_counts(plan, share)
 
 
 def share_counters(counts, chunk_counts=()) -> dict:
@@ -416,11 +471,11 @@ def share_counters(counts, chunk_counts=()) -> dict:
 
 
 def apply_share_counted(p, u, share: ShareConfig, live, counters, *,
-                        chunk_counts=(), dtype):
+                        chunk_counts=(), dtype, plan=None):
     """:func:`apply_share` and ``counters`` (:func:`share_counters`) with
     this call's counts added to the entries it has - a prefill chunk names
     the ``chunk_counts`` it keeps a second time."""
-    m, counts = apply_share(p, u, share, live, dtype=dtype)
+    m, counts = apply_share(p, u, share, live, dtype=dtype, plan=plan)
     added = {f"moe_{k}": v for k, v in counts.items()}
     added.update({f"moe_chunk_{k}": counts[k] for k in chunk_counts})
     return m, {k: v + added.get(k, 0) for k, v in counters.items()}

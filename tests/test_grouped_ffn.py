@@ -89,3 +89,40 @@ def test_bfloat16_operands_give_the_gated_mlps_arithmetic():
     p = {"gate": {"kernel": gate[1]}, "up": {"kernel": up[1]}, "down": {"kernel": down[1]}}
     want = np.asarray(layers.gated_mlp(p, rows[:20], dtype=jnp.bfloat16))
     np.testing.assert_allclose(got[:20], want, atol=1e-6)
+
+
+def test_relu_and_widths_the_old_blocks_refused_against_the_loop():
+    """SmallThinker's experts, 2560 -> 768 -> 2560 with ``relu``: neither
+    width is a multiple of its block, and the kernel takes the largest
+    multiple of 128 under it that divides the width (four steps of 640, two
+    of 384); a width the blocks divide keeps them.  A few rows, two experts
+    of three touched."""
+    D, F = 2560, 768
+    assert (gf._block(D, gf.BLOCK_K), gf._block(F, gf.BLOCK_F)) == (640, 384)
+    assert (gf._block(6144, gf.BLOCK_K), gf._block(2048, gf.BLOCK_F)) == (1024, 512)
+    rows, gate, up, down = _inputs(40, D=D, F=F, E=3)
+    gate, up, down = gate / 6, up / 6, down / 4
+    n = jnp.asarray([5, 0, 20], jnp.int32)
+    got = np.asarray(gf.grouped_ffn(rows, n, gate, up, down, block_rows=16, activation="relu"))
+    want = np.asarray(gf.grouped_ffn_reference(
+        rows, n, gate, up, down, block_rows=16, activation="relu"))
+    mine = _group_rows([5, 0, 20], 16, 40)
+    assert np.abs(want[mine]).max() > 0.1
+    assert np.abs(got[mine] - want[mine]).max() < 20 * TOL  # sums of 2560 and 768 terms
+    silu = np.asarray(gf.grouped_ffn_reference(rows, n, gate, up, down, block_rows=16))
+    assert np.abs(silu[mine] - want[mine]).max() > 0.01
+
+
+def test_silu_is_what_the_kernel_computed_before_it_took_an_activation():
+    """The default is the old kernel's program to the letter: ``silu`` named
+    or not lowers to the same text, and ``relu`` to another."""
+    rows, gate, up, down = _inputs(48)
+    n = jnp.asarray([3, 0, 17, 5], jnp.int32)
+    text = lambda **kw: gf.grouped_ffn.lower(
+        rows, n, gate, up, down, block_rows=16, **kw).as_text()
+    assert text() == text(activation="silu") != text(activation="relu")
+    assert np.array_equal(
+        np.asarray(gf.grouped_ffn(rows, n, gate, up, down, block_rows=16)),
+        np.asarray(gf.grouped_ffn(rows, n, gate, up, down, block_rows=16, activation="silu")))
+    with pytest.raises(KeyError):
+        gf.grouped_ffn(rows, n, gate, up, down, block_rows=16, activation="gelu")

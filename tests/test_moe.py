@@ -849,3 +849,94 @@ def test_the_softmax_defaults_give_the_two_expert_models_their_layers_bit_for_bi
     assert np.abs(np.asarray(run(other)(p, u)) - np.asarray(run(share)(p, u))).max() > 1e-3
     with pytest.raises(ValueError, match="scoring"):
         dataclasses.replace(share, scoring="tanh")
+
+
+# ----------------------------------------------------------------------------
+# The plan apart from the product (a router that reads another tensor)
+# ----------------------------------------------------------------------------
+
+from benchmarks.reference import smallthinker_ref  # noqa: E402
+
+#: 32 ReLU-gated experts, 4 a token, the softmax over the chosen.
+C_AHEAD = dict(
+    hidden_size=64, moe_ffn_hidden_size=32, num_hidden_layers=2, num_attention_heads=14,
+    num_key_value_heads=2, head_dim=16, sliding_window_layout=(0, 1), rope_layout=(0, 1),
+    sliding_window_size=16, moe_num_primary_experts=32, moe_num_active_primary_experts=4,
+    vocab_size=100, rms_norm_eps=1e-6, rope_theta=1e4, init_std=0.125,
+    router_spread=0.125, out_std_factor=1.0,
+)
+
+
+def _ahead(first=0, held=32):
+    return moe_ops.ShareConfig(
+        n_experts=32, n_zero=0, top_k=4, scale=1.0, first=first, held=held,
+        scoring="softmax", normalise=True, activation="relu")
+
+
+def _ahead_layer(first, held, seed=19):
+    key = ref_weights.base_key(seed)
+    p = smallthinker_ref.build(smallthinker_ref.layer_spec(C_AHEAD, 1), key, layer=1)["moe"]
+    p.update(jax.vmap(lambda e: smallthinker_ref.expert(C_AHEAD, key, 1, e))(
+        first + jnp.arange(held)))
+    return jax.tree.map(lambda a: a.astype(jnp.float32), p)
+
+
+@pytest.mark.parametrize("model", ["longcat", "deepseek", "trinity"])
+def test_the_plan_and_the_product_apart_are_apply_share_bit_for_bit(model):
+    """``share_plan`` on ``u`` then ``apply_share_plan`` on the same ``u``
+    and ``share_counts`` of the plan ARE ``apply_share`` - result, counts and
+    lowered program - on the settings of the three models that call it whole
+    (zero-compute experts and a scale; a choice limited to groups; sigmoid
+    scores normalised under a bias), with some rows not live."""
+    share, p = {
+        "longcat": lambda: (_share(8, 8), _share_params(8, 8)),
+        "deepseek": lambda: (_grouped(8, 8), _grouped_layer(8, 8)["moe"]),
+        "trinity": lambda: (_sigmoid(), _sigmoid_layer(0, 32)["moe"]),
+    }[model]()
+    assert share.activation == "silu"
+    u = jax.random.normal(jax.random.key(21), (20, 64))
+    live = jnp.arange(20) % 5 != 3
+
+    def apart(p, u, live):
+        plan = moe_ops.share_plan(p["router"], u, share, live)
+        m = moe_ops.apply_share_plan(p, u, plan, share, dtype=jnp.float32)
+        return m, moe_ops.share_counts(plan, share)
+
+    whole = jax.jit(lambda p, u, live: moe_ops.apply_share(p, u, share, live, dtype=jnp.float32))
+    assert jax.jit(apart).lower(p, u, live).as_text().replace("apart", "_lambda") == \
+        whole.lower(p, u, live).as_text().replace("<lambda>", "_lambda")
+    (m, counts), (m_whole, counts_whole) = jax.jit(apart)(p, u, live), whole(p, u, live)
+    assert np.abs(np.asarray(m)).max() > 0.1
+    assert np.array_equal(np.asarray(m), np.asarray(m_whole))
+    assert {k: int(v) for k, v in counts.items()} == {
+        k: int(v) for k, v in counts_whole.items()}
+
+
+def test_the_shares_of_four_ranks_under_a_plan_from_another_tensor_are_the_uncut_layer():
+    """THE SHARE TIES TO THE MODEL for the split too: the router reads ``x``
+    and the experts ``u``; with ``held`` < ``n_experts`` the parts of ranks
+    0-3, 8 of the 32 ReLU-gated experts each from one seed, every rank
+    planning from the same ``x``, add up to the reference's whole layer (the
+    weights are the softmax over a token's CHOSEN experts wherever they
+    live) - and a plan from ``u`` itself is another layer."""
+    x = jax.random.normal(jax.random.key(3), (40, 64))
+    u = jax.random.normal(jax.random.key(4), (40, 64))
+    whole = _ahead_layer(0, 32)
+    choice, w = smallthinker_ref.route(C_AHEAD, whole, x)
+    want = np.asarray(smallthinker_ref.routed(
+        C_AHEAD, choice, w, _expert_of(whole), u, "float32"))
+    total, held = np.zeros_like(want), 0
+    for rank in range(4):
+        p, share = _ahead_layer(8 * rank, 8), _ahead(8 * rank, 8)
+        plan = moe_ops.share_plan(p["router"], x, share)
+        total += np.asarray(moe_ops.apply_share_plan(p, u, plan, share, dtype=jnp.float32))
+        counts = moe_ops.share_counts(plan, share)
+        assert int(counts["choices"]) == 40 * 4 and 0 < int(counts["experts_touched"]) <= 8
+        held += int(counts["choices_held"])
+    assert held == 40 * 4  # every choice is on some rank's expert
+    assert np.abs(want).max() > 0.5
+    assert np.abs(total - want).max() < 4 * SHARE_TOL
+    late, _ = moe_ops.apply_share(whole, u, _ahead(), dtype=jnp.float32)
+    assert np.abs(np.asarray(late) - want).max() > 0.1
+    with pytest.raises(ValueError, match="activation"):
+        dataclasses.replace(_ahead(), activation="gelu")

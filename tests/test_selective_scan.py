@@ -126,19 +126,35 @@ def test_grouped_ffn_compiles_for_a_v5e_at_the_served_widths(
     for each of 128 groups): its VMEM, the scalar-prefetched index maps and
     the kernel's name, which the benchmark's readers look for.  (Kept in
     this file: the one that loads the TPU's library.)"""
+    _compile_grouped_ffn(one_chip, monkeypatch, D, F, E, rows, block)
+
+
+def _compile_grouped_ffn(one_chip, monkeypatch, D, F, E, rows, block, **kw):
     from distributed_tensorflow_examples_tpu.ops import grouped_ffn as gf
 
     monkeypatch.setattr(gf, "interpret_mode", lambda: False)
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     bf16 = jnp.bfloat16
     compiled = jax.jit(
-        lambda r, n, g, u, d: gf.grouped_ffn.__wrapped__(r, n, g, u, d, block_rows=block)
+        lambda r, n, g, u, d: gf.grouped_ffn.__wrapped__(r, n, g, u, d, block_rows=block, **kw)
     ).lower(
         s((rows, D), bf16), s((E,), jnp.int32), s((E, D, F), bf16),
         s((E, D, F), bf16), s((E, F, D), bf16),
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and f"%{gf.KERNEL_NAME}" in text
+
+
+@pytest.mark.parametrize("rows,block", [(2240, 32), (9728, 128), (11264, 128)])
+def test_grouped_ffn_compiles_for_a_v5e_with_relu_at_widths_its_blocks_do_not_divide(
+    one_chip, monkeypatch, rows, block,
+):
+    """... and at ``D`` 2560, ``F`` 768 with ``relu`` and ALL 64 experts of a
+    layer held (SmallThinker, models/smallthinker.py: 32 x 6 choices in blocks
+    of 32, 256 x 6 and 512 x 6 in blocks of 128, a block more for each of 64
+    groups): neither width is a multiple of its block, and Mosaic takes the
+    640 and 384 that ``_block`` finds (lanes of 128, sublanes of 16)."""
+    _compile_grouped_ffn(one_chip, monkeypatch, 2560, 768, 64, rows, block, activation="relu")
 
 
 @pytest.mark.parametrize("kernel", ["decode", "prefill", "prefill_128", "prefill_256"])
