@@ -293,6 +293,35 @@ def test_batchnorm_one_pass_stats_match_two_pass():
     )
 
 
+@pytest.mark.parametrize("ids_case", ["8x1", "1x512", "out_of_range"])
+@pytest.mark.parametrize("dtype", [None, "bfloat16", "float32"])
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16"])
+def test_embedding_lookup_equals_take_of_the_cast_table_to_the_bit(
+    table_dtype, dtype, ids_case
+):
+    """``layers.embedding_lookup`` gathers and then casts; a convert is
+    element-wise, so that is ``jnp.take(table.astype(dtype), ids)`` bit for
+    bit - the rows an id out of range reads (NaN) included."""
+    from distributed_tensorflow_examples_tpu.models import layers
+
+    vocab, dim = 211, 24
+    table = (
+        jax.random.normal(jax.random.key(7), (vocab, dim), jnp.float32) * 3.0
+    ).astype(table_dtype)
+    rng = np.random.default_rng(11)
+    shape = {"8x1": (8, 1), "1x512": (1, 512), "out_of_range": (8, 1)}[ids_case]
+    ids = rng.integers(0, vocab, size=shape).astype(np.int32)
+    if ids_case == "out_of_range":
+        ids[3, 0] = vocab + 5
+    got = layers.embedding_lookup({"table": table}, jnp.asarray(ids), dtype=dtype)
+    want = jnp.take(table if dtype is None else table.astype(dtype), ids, axis=0)
+    assert got.dtype == want.dtype and got.shape == want.shape == shape + (dim,)
+    # The raw bit patterns, so that NaN equals NaN.
+    assert np.array_equal(np.asarray(got).view(np.uint8), np.asarray(want).view(np.uint8))
+    if ids_case == "out_of_range":
+        assert np.isnan(np.asarray(got, np.float32)[3]).all()
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("relu", [False, True])
 def test_batchnorm_sharded_matches_unsharded(mesh8, relu, dtype):
