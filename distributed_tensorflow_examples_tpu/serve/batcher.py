@@ -338,10 +338,19 @@ class StreamTicket:
     from ``cursor`` on), so a replayed poll after a reconnect re-reads
     instead of double-draining.  Terminal states: ``done`` (the session
     produced its full budget) or an error (the step function raised — the
-    whole active batch fails, like the row batcher's contract)."""
+    whole active batch fails, like the row batcher's contract).
+
+    A consumer that found nothing at its cursor need not come back to look:
+    ``when_ready(cursor, fn)`` leaves ONE waiter with the ticket, called once
+    when the stream holds an emission at or past ``cursor`` or has ended
+    (done, failed or cancelled).  It is called by the thread that emits or
+    ends the session - the step thread, as a rule - so it must only hand
+    over (``serve.model_server`` puts the held poll on its notifier's
+    queue); ``forget`` takes a waiter back uncalled and ``release`` calls it
+    whatever the stream holds."""
 
     __slots__ = ("state", "opened_ns", "seated_ns", "emitted_ns", "_emits",
-                 "_done", "_error", "_cancelled", "_lock", "_event")
+                 "_done", "_error", "_cancelled", "_lock", "_event", "_waiter")
 
     def __init__(self, state):
         self.state = state
@@ -358,12 +367,26 @@ class StreamTicket:
         self._cancelled = False
         self._lock = threading.Lock()
         self._event = threading.Event()
+        self._waiter: tuple | None = None  # (cursor, fn)
+
+    def _take_waiter(self, force: bool = False):
+        """The waiter, taken off the ticket, if the stream holds what it
+        waits for (or ``force``); else None.  The caller holds the lock and
+        calls what it is given once it has let the lock go."""
+        w = self._waiter
+        if w is None or not (force or self._done or len(self._emits) > w[0]):
+            return None
+        self._waiter = None
+        return w[1]
 
     # -- step-thread side --
     def _emit(self, items) -> None:
         with self._lock:
             self._emits.extend(items)
+            fn = self._take_waiter()
         self._event.set()
+        if fn is not None:
+            fn()
 
     def _finish(self, error: BaseException | None = None) -> None:
         with self._lock:
@@ -371,7 +394,10 @@ class StreamTicket:
                 return
             self._done = True
             self._error = error
+            fn = self._take_waiter()
         self._event.set()
+        if fn is not None:
+            fn()
 
     # -- consumer side --
     def cancel(self) -> None:
@@ -403,6 +429,38 @@ class StreamTicket:
         ok = self._event.wait(timeout_s)
         self._event.clear()
         return ok
+
+    def when_ready(self, cursor: int, fn) -> None:
+        """Call ``fn()`` once, when ``snapshot(cursor)`` has an emission to
+        give or the session has ended - at once, from this thread, if that
+        holds already: the check is made under the lock that ``_emit`` and
+        ``_finish`` take, so an emission that races the registration is
+        never missed.  A ticket keeps one waiter: the one this replaces is
+        called now, so that whoever left it is answered."""
+        with self._lock:
+            replaced = self._take_waiter(force=True)
+            self._waiter = (max(0, int(cursor)), fn)
+            due = self._take_waiter()
+        for f in (replaced, due):
+            if f is not None:
+                f()
+
+    def forget(self, fn) -> bool:
+        """Take the waiter ``fn`` back: True if the ticket still held it,
+        and will now never call it; False if it was called or replaced."""
+        with self._lock:
+            if self._waiter is None or self._waiter[1] is not fn:
+                return False
+            self._waiter = None
+            return True
+
+    def release(self) -> None:
+        """Call the waiter now, if there is one, whatever the stream holds
+        (the replica is stopping and answers what it holds)."""
+        with self._lock:
+            fn = self._take_waiter(force=True)
+        if fn is not None:
+            fn()
 
 
 class SlotBatcher:

@@ -383,7 +383,13 @@ class ServeClient:
         """Poll a session's token stream from ``cursor`` (tokens already
         received): ``(tokens, done, model_step)``.  Cursor-addressed, so
         replaying the poll after a reconnect re-reads instead of
-        double-draining."""
+        double-draining.  Tokens, the end or a failure come back at once;
+        where the stream holds nothing at ``cursor`` the replica keeps the
+        poll until the session emits or ends and answers then - or, after
+        ``model_server.DECODE_HOLD_S`` (0.1 s, far under any
+        ``op_timeout_s``), with no tokens and ``done`` false, as an empty
+        poll always was.  A replica from before the held poll answers empty
+        at once; the caller's loop is the same for both."""
         status, out = self.call(
             SRV_DECODE_NEXT, a=int(session), b=int(cursor), batch=True,
         )
@@ -405,7 +411,12 @@ class ServeClient:
     ) -> np.ndarray:
         """Convenience client for the whole stream: open, poll the token
         stream to completion, close; returns the generated int32 tokens
-        (the continuation only — the prompt is not echoed)."""
+        (the continuation only — the prompt is not echoed).  A poll goes
+        out ``poll_s`` after the last one's answer; one that finds nothing
+        waits AT THE REPLICA for the next token (``decode_next``), so a
+        token arrives when it is emitted, whatever ``poll_s`` is, and
+        ``deadline_s`` is looked at after every answer - at least every
+        tenth of a second."""
         sid = self.decode_open(prompt, max_new_tokens)
         tokens: list[int] = []
         try:

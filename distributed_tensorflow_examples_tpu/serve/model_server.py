@@ -46,9 +46,11 @@ import inspect
 import json
 import logging
 import os
+import queue
 import threading
 import time
 import typing
+from collections import deque
 
 import numpy as np
 
@@ -171,6 +173,39 @@ def chunk_widths(chunk: int) -> tuple[int, ...]:
     while widths[0] % 2 == 0 and widths[0] // 2 >= PREFILL_FLOOR:
         widths.insert(0, widths[0] // 2)
     return tuple(widths)
+
+
+#: How long a ``DECODE_NEXT`` that finds nothing at its cursor is held for
+#: an emission before it is answered empty and not done - what every empty
+#: poll was answered at once before the replica held any.  A client looks at
+#: its own deadline (``ServeClient.generate``'s ``deadline_s``) between two
+#: polls, so this is how often it gets to; a tenth of a second is far under
+#: the smallest ``op_timeout_s`` a client of this wire has (10 s) and under
+#: ``session_idle_s`` (60 s), and long enough that a session still queued or
+#: prefilling sends ten empty polls a second and not the hundred or two its
+#: ``poll_s`` would.  No setting: nothing a deployment knows should move it.
+DECODE_HOLD_S = 0.1
+
+
+class _HeldPoll:
+    """A ``DECODE_NEXT`` that found nothing, waiting with its session's
+    ticket (``StreamTicket.when_ready``).  The ticket calls it from the
+    thread that emits or ends the session - the step thread - and all it
+    does there is put itself on the notifier's queue: the snapshot, the
+    encoding and the reply are the notifier thread's."""
+
+    __slots__ = ("conn", "sid", "ticket", "cursor", "due", "_hand_over")
+
+    def __init__(self, hand_over, conn, sid: int, ticket, cursor: int):
+        self._hand_over = hand_over
+        self.conn = conn  # the request's reply handle
+        self.sid = sid
+        self.ticket = ticket  # None once answered (the notifier's mark)
+        self.cursor = cursor
+        self.due = time.monotonic() + DECODE_HOLD_S
+
+    def __call__(self) -> None:
+        self._hand_over(self)
 
 
 def flat_param_spec(init_fn):
@@ -931,6 +966,23 @@ class ModelReplicaServer:
         self._sessions: dict[int, list] = {}  # sid -> [ticket, last_poll]
         self._next_sid = 1
         self._decode_opens = 0
+        # The held poll: a DECODE_NEXT that finds nothing waits with its
+        # ticket (at most ``DECODE_HOLD_S``) instead of being answered empty.
+        # ``_fired`` takes the polls whose tickets called; ``_held`` keeps
+        # every held poll in the order its hold runs out.  One thread, the
+        # notifier, answers both, so a poll is answered once and the step
+        # thread only ever puts on a queue.
+        self._decode_polls = 0
+        self._decode_polls_held = 0
+        self._decode_polls_expired = 0
+        self._fired: queue.SimpleQueue = queue.SimpleQueue()
+        self._held: deque = deque()
+        self._notifier = None
+        if self._engine is not None:
+            self._notifier = threading.Thread(
+                target=self._notify_loop, daemon=True, name="msrv-notify"
+            )
+            self._notifier.start()
         self._stop = threading.Event()
         self.shutdown_requested = threading.Event()
         # The shared server runtime (r17): selector-driven I/O, bounded
@@ -1040,10 +1092,20 @@ class ModelReplicaServer:
             self._heartbeat.close()
             self._heartbeat = None
         self._stop.set()
+        # Held polls are in flight to the core: answer them with what their
+        # streams hold now (no later poll is held, ``_stop`` is set), or the
+        # drain below waits out their holds.
+        with self._lock:
+            tickets = [entry[0] for entry in self._sessions.values()]
+        for t in tickets:
+            t.release()
         # The core drains first (in-flight predicts resolve and their
         # buffered responses flush) and releases the port before
         # returning — the zero-dropped-requests half of a scale-down.
         self._core.stop()
+        if self._notifier is not None:
+            self._fired.put(None)
+            self._notifier.join(timeout=5.0)
         self._refresher.join(timeout=5.0)
         self._batcher.stop()
         if self._engine is not None:
@@ -1295,6 +1357,12 @@ class ModelReplicaServer:
                 "reshards_followed": self._reshards,
                 "decode_sessions_open": len(self._sessions),
                 "decode_opens": self._decode_opens,
+                # Every DECODE_NEXT handled; those that found nothing and
+                # were held for an emission; those of them answered empty
+                # when the hold (DECODE_HOLD_S) ran out.
+                "decode_polls": self._decode_polls,
+                "decode_polls_held": self._decode_polls_held,
+                "decode_polls_expired": self._decode_polls_expired,
                 "leased": bool(
                     self._heartbeat is not None and self._heartbeat.enabled
                 ),
@@ -1329,7 +1397,7 @@ class ModelReplicaServer:
         if op == SRV_DECODE_OPEN:
             return self._handle_decode_open(a, payload)
         if op == SRV_DECODE_NEXT:
-            return self._handle_decode_next(a, b)
+            return self._handle_decode_next(conn, a, b)
         if op == SRV_DECODE_CLOSE:
             return self._handle_decode_close(a)
         if op == SRV_STATS:
@@ -1375,14 +1443,10 @@ class ModelReplicaServer:
             self._decode_opens += 1
         return sid, None
 
-    def _handle_decode_next(self, sid: int, cursor: int):
-        with self._lock:
-            entry = self._sessions.get(sid)
-            if entry is not None:
-                entry[1] = time.monotonic()
-        if entry is None:
-            return BAD_SESSION, None
-        ticket = entry[0]
+    def _decode_answer(self, sid: int, ticket, cursor: int, hold: bool = False):
+        """A poll's answer from what the stream holds now, ``(status,
+        bufs)`` - or, with ``hold``, None where it holds neither a token at
+        ``cursor`` nor an end to tell: the poll that is held instead."""
         try:
             tokens, done = ticket.snapshot(cursor)
         except _NoModel:
@@ -1393,11 +1457,82 @@ class ModelReplicaServer:
             with self._lock:
                 self._sessions.pop(sid, None)
             return ERR, None
+        if hold and not (tokens or done):
+            return None
         out = self._stamp({
             "tokens": np.asarray(tokens, np.int32),
             "done": np.asarray([1 if done else 0], np.uint8),
         })
         return self.model_step, wire.encode_batch(out)
+
+    def _handle_decode_next(self, conn, sid: int, cursor: int):
+        """Tokens, an end or a failure are answered at once.  A poll that
+        finds none of them is HELD: it waits with the session's ticket and
+        is answered, by the notifier thread and with the frame built here,
+        when the session emits or ends, when another poll for the session
+        arrives, when the replica stops - or empty and not done, as before,
+        once ``DECODE_HOLD_S`` has passed.  It returns ``ASYNC`` meanwhile,
+        so it holds a reply slot of its connection and no pool worker."""
+        with self._lock:
+            self._decode_polls += 1
+            entry = self._sessions.get(sid)
+            if entry is not None:
+                entry[1] = time.monotonic()
+        if entry is None:
+            return BAD_SESSION, None
+        ticket = entry[0]
+        answer = self._decode_answer(
+            sid, ticket, cursor, hold=not self._stop.is_set())
+        if answer is not None:
+            return answer
+        held = _HeldPoll(self._fired.put, conn, sid, ticket, cursor)
+        with self._lock:
+            self._decode_polls_held += 1
+        self._held.append(held)
+        ticket.when_ready(cursor, held)
+        if self._stop.is_set():
+            ticket.release()  # ``stop`` went through the sessions before it
+        return server_core.ASYNC
+
+    def _answer_held(self, held: _HeldPoll, expired: bool = False) -> None:
+        """Notifier thread: the held poll's reply, and the session's stamp
+        of activity (an answered poll is one, like one that arrives)."""
+        ticket, held.ticket = held.ticket, None
+        try:
+            status, bufs = self._decode_answer(held.sid, ticket, held.cursor)
+            with self._lock:
+                self._decode_polls_expired += expired
+                entry = self._sessions.get(held.sid)
+                if entry is not None:
+                    entry[1] = time.monotonic()
+            held.conn.reply(status, bufs)
+        except Exception:  # noqa: BLE001 — the notifier outlives any one poll
+            log.error("held decode poll of session %d failed", held.sid,
+                      exc_info=True)
+            held.conn.reply(ERR, None)
+
+    def _notify_loop(self) -> None:
+        """Answer held polls off the step thread: those their tickets hand
+        over (``_fired``) as they come, and those nothing came for when
+        their hold runs out.  Holds run out in the order they began, so the
+        oldest unanswered one says how long to wait for the queue."""
+        held_polls = self._held
+        while True:
+            now = time.monotonic()
+            while held_polls and (
+                held_polls[0].ticket is None or held_polls[0].due <= now
+            ):
+                held = held_polls.popleft()
+                if held.ticket is not None and held.ticket.forget(held):
+                    self._answer_held(held, expired=True)
+            wait = held_polls[0].due - now if held_polls else DECODE_HOLD_S
+            try:
+                held = self._fired.get(timeout=wait)
+            except queue.Empty:
+                continue
+            if held is None:
+                return
+            self._answer_held(held)
 
     def _handle_decode_close(self, sid: int):
         with self._lock:

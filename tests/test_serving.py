@@ -1202,6 +1202,348 @@ def test_decode_concurrent_sessions_byte_identical_to_solo(tmp_path):
         srv.stop()
 
 
+# ----------------------------------------------------------------------------
+# The held poll (PR 44): a DECODE_NEXT that finds nothing waits for a token
+# ----------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("how", [
+    "held_already", "emitted", "below_cursor", "finished", "failed",
+    "cancelled", "replaced", "forgotten", "released",
+])
+def test_a_tickets_waiter_is_called_once_when_its_cursor_has_something(how):
+    t = batcher_lib.StreamTicket(None)
+    calls: list = []
+    first = lambda: calls.append("first")  # noqa: E731
+    if how == "held_already":
+        t._emit([5])
+        t.when_ready(0, first)
+        assert calls == ["first"]  # at once, from the registering thread
+    else:
+        t.when_ready(1 if how == "below_cursor" else 0, first)
+        assert calls == []
+    if how in ("emitted", "below_cursor"):
+        t._emit([5])
+        # An emission BELOW the waiter's cursor is not what it waits for.
+        assert calls == ([] if how == "below_cursor" else ["first"])
+        t._emit([6])
+        assert calls == ["first"]
+    elif how == "finished":
+        t._finish()
+    elif how == "failed":
+        t._finish(error=RuntimeError("boom"))
+    elif how == "cancelled":
+        t.cancel()
+    elif how == "replaced":
+        # One waiter a ticket: the second answers the first, then waits.
+        t.when_ready(0, lambda: calls.append("second"))
+        assert calls == ["first"]
+        t._emit([5])
+        assert calls == ["first", "second"]
+    elif how == "forgotten":
+        assert t.forget(first) and not t.forget(first)
+        t._emit([5])
+        assert calls == []
+    elif how == "released":
+        t.release()
+    if how != "forgotten":
+        assert calls[0] == "first" and not t.forget(first)
+    n = len(calls)
+    t._emit([7])
+    t._finish()
+    t.release()
+    assert len(calls) == n  # a waiter is called once
+
+
+def _gated_decode_server(tmp_path, role, monkeypatch, hold_s=30.0, **kw):
+    """A decode replica whose step thread makes a call into the engine only
+    while ``gate`` is set (and fails the step while ``boom`` is): tokens
+    exist when the test says so.  The step is compiled before the gate is
+    there, and the hold is long unless the test is about its end."""
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "DECODE_HOLD_S", hold_s)
+    srv = _pinned_decode_server(tmp_path, role, **kw)
+    warm = serve.ServeClient("127.0.0.1", srv.port, role=f"{role}_warm")
+    assert warm.generate(np.array([1], np.int32), 2).tolist() == [2, 3]
+    warm.close()
+    gate, boom = threading.Event(), threading.Event()
+    run = srv._engine.batcher._run
+
+    def gated(slots):
+        gate.wait(60)
+        if boom.is_set():
+            raise RuntimeError("boom")
+        return run(slots)
+
+    srv._engine.batcher._run = gated
+    return srv, gate, boom
+
+
+def _poll_in_thread(port: int, role: str, sid: int, cursor: int = 0):
+    """``decode_next`` from a thread of its own, on a connection of its own:
+    ``(thread, client, out)``, ``out`` filled when the answer has come."""
+    c = serve.ServeClient("127.0.0.1", port, role=role)
+    out: dict = {}
+
+    def body():
+        try:
+            out["answer"] = c.decode_next(sid, cursor=cursor)
+        except Exception as e:  # noqa: BLE001 — the test looks at it
+            out["error"] = e
+        out["t"] = time.monotonic()
+
+    th = threading.Thread(target=body, daemon=True)
+    th.start()
+    return th, c, out
+
+
+def _polls_since(client, base: dict) -> tuple:
+    """``(polls, held, expired)`` the replica has counted since ``base``."""
+    st = client.stats()
+    return tuple(
+        st[k] - base[k]
+        for k in ("decode_polls", "decode_polls_held", "decode_polls_expired"))
+
+
+def _wait_held(client, base: dict, want: int, timeout_s: float = 20.0) -> None:
+    t_end = time.monotonic() + timeout_s
+    while _polls_since(client, base)[1] < want:
+        assert time.monotonic() < t_end, (_polls_since(client, base), want)
+        time.sleep(0.01)
+
+
+def test_a_poll_sent_before_the_token_exists_is_answered_when_it_is_emitted(
+    tmp_path, monkeypatch,
+):
+    srv, gate, _ = _gated_decode_server(tmp_path, "hp0", monkeypatch)
+    try:
+        c = serve.ServeClient("127.0.0.1", srv.port, role="hp0_sv")
+        base = c.stats()
+        sid = c.decode_open(np.array([3], np.int32), 4)
+        th, pc, out = _poll_in_thread(srv.port, "hp0_poll", sid)
+        _wait_held(c, base, 1)
+        time.sleep(0.05)
+        assert th.is_alive() and not out  # held: no empty answer came
+        t_open = time.monotonic()
+        gate.set()
+        th.join(timeout=20)
+        toks, done, step = out["answer"]
+        # The token, with the frame a poll always got, and long before the
+        # hold (30 s here) would have run out.
+        assert toks.tolist()[0] == 4 and step == 7 and pc.last_model_version == 1
+        assert out["t"] - t_open < 10.0
+        assert _polls_since(c, base) == (1, 1, 0)
+        # The rest of the stream, by cursor, is what it always was.
+        rest = toks.tolist()
+        while not done:
+            got, done, _ = pc.decode_next(sid, cursor=len(rest))
+            rest += got.tolist()
+        assert rest == [4, 5, 6, 7]
+        pc.close()
+        c.close()
+    finally:
+        gate.set()
+        srv.stop()
+
+
+def test_a_held_poll_past_the_limit_is_answered_empty_and_not_done(
+    tmp_path, monkeypatch,
+):
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    # The limit as shipped: a tenth of a second, far under every client's
+    # op timeout and under the idle sweep.
+    assert model_server.DECODE_HOLD_S == 0.1
+    srv, gate, _ = _gated_decode_server(tmp_path, "hp1", monkeypatch, hold_s=0.1)
+    try:
+        c = serve.ServeClient("127.0.0.1", srv.port, role="hp1_sv")
+        base = c.stats()
+        sid = c.decode_open(np.array([3], np.int32), 4)
+        t0 = time.monotonic()
+        toks, done, step = c.decode_next(sid)
+        took = time.monotonic() - t0
+        assert toks.tolist() == [] and not done and step == 7
+        assert 0.09 <= took < 5.0
+        assert _polls_since(c, base) == (1, 1, 1)
+        # The session lives on: the next poll is held in its turn, and
+        # answered with the token when there is one.
+        th, pc, out = _poll_in_thread(srv.port, "hp1_poll", sid)
+        gate.set()
+        th.join(timeout=20)
+        assert out["answer"][0].tolist()[0] == 4
+        pc.close()
+        c.close()
+    finally:
+        gate.set()
+        srv.stop()
+
+
+@pytest.mark.parametrize(
+    "cause", ["decode_close", "idle_sweep", "failed_step", "stop", "second_poll"])
+def test_a_held_poll_is_answered_once_by_whatever_ends_its_wait(
+    tmp_path, monkeypatch, cause,
+):
+    kw = {"session_idle_s": 0.4} if cause == "idle_sweep" else {}
+    srv, gate, boom = _gated_decode_server(tmp_path, "hp2", monkeypatch, **kw)
+    stopped = False
+    try:
+        c = serve.ServeClient("127.0.0.1", srv.port, role="hp2_sv")
+        base = c.stats()
+        sid = c.decode_open(np.array([3], np.int32), 4)
+        th, pc, out = _poll_in_thread(srv.port, "hp2_poll", sid)
+        _wait_held(c, base, 1)
+        assert th.is_alive()
+        if cause == "decode_close":
+            c.decode_close(sid)
+        elif cause == "failed_step":
+            boom.set()
+            gate.set()
+        elif cause == "stop":
+            c.close()
+            # The step thread may go on once the poll is answered: ``stop``
+            # joins it, and the test's gate is not what is being timed.
+            threading.Thread(
+                target=lambda: (th.join(20), gate.set()), daemon=True).start()
+            t0 = time.monotonic()
+            srv.stop()
+            stopped = True
+            # Neither the hold (30 s) nor the core's drain (5 s) was waited out.
+            assert time.monotonic() - t0 < 4.0
+        elif cause == "second_poll":
+            th2, pc2, out2 = _poll_in_thread(srv.port, "hp2_poll2", sid)
+        th.join(timeout=20)
+        assert not th.is_alive()
+        if cause in ("decode_close", "idle_sweep"):
+            toks, done, _ = out["answer"]
+            assert toks.tolist() == [] and done  # a cancelled session's end
+        elif cause == "failed_step":
+            assert isinstance(out["error"], serve.ServeRejectedError)
+            with pytest.raises(serve.ServeSessionError):
+                pc.decode_next(sid)  # the session went with its step
+        else:
+            toks, done, step = out["answer"]
+            assert toks.tolist() == [] and not done and step == 7
+        if cause == "second_poll":
+            # The second poll holds on; the token answers it.
+            _wait_held(c, base, 2)
+            assert th2.is_alive()
+            gate.set()
+            th2.join(timeout=20)
+            assert out2["answer"][0].tolist()[0] == 4
+            pc2.close()
+        if not stopped:
+            # Once: a second frame for the poll would be read as the answer
+            # to the connection's next request.
+            assert _polls_since(pc, base)[2] == 0
+            c.close()
+        pc.close()
+    finally:
+        gate.set()
+        if not stopped:
+            srv.stop()
+
+
+def test_more_polls_are_held_than_the_core_has_workers_and_it_still_answers(
+    tmp_path, monkeypatch,
+):
+    """Twelve sessions' first polls wait on a core of eight workers: none
+    holds a worker, so STATS (the control worker's) and a thirteenth
+    session's open, poll and close (the pool's) are answered at once, and
+    every ``generate`` returns its stream as it always did."""
+    srv, gate, _ = _gated_decode_server(tmp_path, "hp3", monkeypatch)
+    try:
+        base = srv.stats()
+        assert base["core"]["worker_threads"] == 8
+        prompts = [np.array([i % 11], np.int32) for i in range(12)]
+        outs: list = [None] * 12
+
+        def body(i):
+            ci = serve.ServeClient("127.0.0.1", srv.port, role=f"hp3_{i}")
+            outs[i] = ci.generate(prompts[i], 3)
+            ci.close()
+
+        ts = [threading.Thread(target=body, args=(i,), daemon=True)
+              for i in range(12)]
+        for t in ts:
+            t.start()
+        c = serve.ServeClient("127.0.0.1", srv.port, role="hp3_sv")
+        _wait_held(c, base, 12)
+        t0 = time.monotonic()
+        st = c.stats()
+        sid = c.decode_open(np.array([1], np.int32), 1)
+        c.decode_close(sid)
+        with pytest.raises(serve.ServeSessionError):
+            c.decode_next(sid)
+        assert time.monotonic() - t0 < 2.0
+        assert st["decode_polls_held"] - base["decode_polls_held"] == 12
+        assert st["decode_polls_expired"] == base["decode_polls_expired"]
+        assert st["core"]["dispatch_depth"] == 0
+        gate.set()
+        for t in ts:
+            t.join(timeout=30)
+        for i, o in enumerate(outs):
+            assert o.tolist() == [(i + k) % 11 for k in (1, 2, 3)]
+        st = c.stats()
+        assert 0 <= st["decode_polls_expired"] <= st["decode_polls_held"] \
+            <= st["decode_polls"]
+        c.close()
+    finally:
+        gate.set()
+        srv.stop()
+
+
+def test_held_poll_share_names_a_reader_and_counters_that_exist(
+    tmp_path, monkeypatch,
+):
+    """The metric file this PR adds: its reader is there, ``server.stats()``
+    carries the two counters it divides, ``generate`` over polls that wait
+    gives the stream a plain loop gives - and the reader finds nothing,
+    without raising, on the parent's replica, which counts no polls."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.harness import manifest
+
+    spec = manifest.layer_metric("held_poll_share")
+    read = manifest.reader(spec["reader"])
+    assert spec["args"] == {"num": "decode_polls_held", "den": "decode_polls"}
+    entry = {p["name"]: p for p in manifest.benchmark()["per_layer"]}[
+        "held_poll_share"]
+    itl = {e["name"]: e for e in manifest.benchmark()["end_to_end"]}["itl_p95_ms"]
+    assert entry["workloads"] == itl["workloads"]
+    srv = _pinned_decode_server(
+        tmp_path, "hp4", decode_fns=_toy_cached_decode_fns())
+    run = srv._engine.batcher._run
+
+    def slow(slots):  # tokens come slower than ``generate`` polls
+        time.sleep(0.02)
+        return run(slots)
+
+    srv._engine.batcher._run = slow
+    try:
+        c = serve.ServeClient("127.0.0.1", srv.port, role="hp4_sv")
+        start = c.stats()
+        prompt = np.array([2, 9, 4], np.int32)
+        assert c.generate(prompt, 6).tolist() == _toy_cached_stream(prompt, 6)
+        end = c.stats()
+        c.close()
+    finally:
+        srv.stop()
+    polls = end["decode_polls"] - start["decode_polls"]
+    held = end["decode_polls_held"] - start["decode_polls_held"]
+    assert 1 <= held <= polls
+    assert end["decode_polls_expired"] <= end["decode_polls_held"]
+    share = read({"counters": {"start": start, "end": end}}, **spec["args"])
+    assert share == pytest.approx(100 * held / polls)
+    for stats in (start, end):
+        del stats["decode_polls_held"]  # the parent's replica
+    assert read({"counters": {"start": start, "end": end}}, **spec["args"]) is None
+
+
 def test_predict_only_replica_answers_no_decoder(tmp_path):
     from distributed_tensorflow_examples_tpu.serve.registry import (
         ModelRegistry,
