@@ -28,8 +28,9 @@ traced function XLA can fuse end-to-end.
   blocked attentions over it, which ``afmoe`` and ``smallthinker`` share
 - ``mla``      — the latent-attention sub-layer ``longcat`` and ``deepseek``
   share
-- ``decoding`` — the generate loop of a model served by chunks and steps,
-  which those two, ``afmoe`` and ``smallthinker`` call
+- ``decoding`` — what the served families share: the contract each hands
+  the decode engine (``DecodeFns``) and the generate loop of a model served
+  by chunks and steps
 """
 
 from . import layers  # noqa: F401

@@ -78,6 +78,7 @@ Serving only: no loss (``load_balance_coeff`` is training's), no mesh.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -445,10 +446,14 @@ prefill_rows_read = ring_cache.prefill_rows_read
 
 
 def serve_decode_fns(cfg: Config):
-    """``(init_cache_fn, step_fn, prefill_fn)`` for ``serve.
-    ModelReplicaServer(decode_fns=...)``, with what a step and a chunk read
-    of the cache (models/ring_cache.py ``serve_decode_fns``)."""
-    return ring_cache.serve_decode_fns(cfg, init_cache, decode_step_batch, prefill_chunk)
+    """What ``serve.ModelReplicaServer(decode_fns=...)`` is told of this
+    model (``decoding.DecodeFns``): its step takes ``live`` (a row that is
+    not live must leave its ring alone), and a step and a chunk read the
+    cache as far as :func:`decode_rows_read` / :func:`prefill_rows_read` say."""
+    return decoding.serve_fns(
+        cfg, init_cache, decode_step_batch, prefill_chunk, wants_live=True,
+        step_rows_read=functools.partial(decode_rows_read, cfg),
+        chunk_rows_read=functools.partial(prefill_rows_read, cfg))
 
 
 # ----------------------------------------------------------------------------

@@ -1,18 +1,76 @@
-"""Generation for a model served by chunks and steps: the loop a replica
-runs, as one compiled program.
+"""What the served families share: the contract a model hands the decode
+engine, and generation by the loop a replica runs, as one compiled program.
 
 A model that has ``init_cache(cfg, slots, max_len)``, ``prefill_chunk(cfg,
 params, cache, tokens, slot, offset, n_valid)`` and ``decode_step_batch(cfg,
-params, cache, tokens, pos, live)`` (models/longcat.py, models/deepseek.py)
-generates with :func:`generate`; it names no model.
+params, cache, tokens, pos[, live])`` is served through :func:`serve_fns`
+(a :class:`DecodeFns`) and, where its step takes ``live``, generates with
+:func:`generate`; neither names a model, and nothing here imports ``serve/``.
 """
 
 from __future__ import annotations
 
 import functools
+import typing
 
 import jax
 import jax.numpy as jnp
+
+
+class DecodeFns(typing.NamedTuple):
+    """What ``serve.ModelReplicaServer(decode_fns=...)`` is told of a served
+    model.  Still a tuple whose first fields are the old pair and triple:
+    ``DecodeFns(*pair)`` says nothing more than the pair did.
+
+    ``init_cache(slots, max_len)``  the per-slot cache pytree.
+    ``step(params, cache, tokens[S], pos[S][, live[S]])``
+                      ``-> (logits [S, V], cache)``: every row one position
+                      on; the logits float32 or the model's compute type
+                      (the engine selects over them as they are).
+    ``prefill(params, cache, tokens[C], slot, offset, n_valid) -> cache``
+                      one slot's positions ``[offset, offset + n_valid)``
+                      entered in one pass, at every width of the engine's
+                      ``chunk_widths``; ``None``: the engine feeds a prompt
+                      through ``step``, a token a step.
+    ``wants_live``    ``step`` takes ``live [S]`` bool, and a row that is not
+                      live leaves everything its slot owns unchanged (a
+                      state, a ring: ``_DecodeEngine``'s docstring).
+    ``step_rows_read(pos, live, max_len)``
+                      cache positions a step reads A SLOT IN THE MEAN, from
+                      the host's ``pos [S]`` int32 and ``live [S]`` bool (the
+                      engine's own arrays: not to be kept or changed).
+    ``chunk_rows_read(offset, chunk, max_len)``
+                      positions of its slot a chunk's attention reads, from
+                      the chunk's offset and the width it was dispatched at.
+                      ``None`` for either: all ``max_len``.
+    """
+
+    init_cache: typing.Callable
+    step: typing.Callable
+    prefill: typing.Callable | None = None
+    wants_live: bool = False
+    step_rows_read: typing.Callable | None = None
+    chunk_rows_read: typing.Callable | None = None
+
+
+def serve_fns(cfg, init_cache, decode_step_batch, prefill_chunk, *,
+              wants_live: bool, step_rows_read=None, chunk_rows_read=None) -> DecodeFns:
+    """The :class:`DecodeFns` of a model from its own three functions, each
+    closed over ``cfg``; ``prefill_chunk`` may be ``None``.  One definition,
+    so the served decode path and the model cannot drift."""
+
+    def init_cache_fn(slots: int, max_len: int):
+        return init_cache(cfg, slots, max_len)
+
+    def step_fn(params, cache, tokens, pos, *live):
+        return decode_step_batch(cfg, params, cache, tokens, pos, *live)
+
+    def prefill_fn(params, cache, tokens, slot, offset, n_valid):
+        return prefill_chunk(cfg, params, cache, tokens, slot, offset, n_valid)
+
+    return DecodeFns(
+        init_cache_fn, step_fn, prefill_fn if prefill_chunk else None,
+        wants_live, step_rows_read, chunk_rows_read)
 
 
 def generate(cfg, params, prompt, *, init_cache, prefill_chunk, decode_step_batch,

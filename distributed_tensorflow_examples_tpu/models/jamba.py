@@ -47,14 +47,13 @@ Serving only: no loss, no mesh (one chip holds it whole).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.selective_scan import selective_scan
-from . import layers
+from . import decoding, layers
 
 ATTENTION, MAMBA = "attention", "mamba"
 
@@ -437,21 +436,12 @@ def prefill_chunk(cfg: Config, params, cache, tokens, slot, offset, n_valid):
 
 
 def serve_decode_fns(cfg: Config):
-    """``(init_cache_fn, step_fn, prefill_fn)`` for ``serve.
-    ModelReplicaServer(decode_fns=...)``.  ``step_fn`` takes the FIFTH
-    argument ``live``: that is how a model asks the engine for the rows
-    that may change what their slots own (``_DecodeEngine``)."""
-
-    def init_cache_fn(slots: int, max_len: int):
-        return init_cache(cfg, slots, max_len)
-
-    def step_fn(params, cache, tokens, pos, live):
-        return decode_step_batch(cfg, params, cache, tokens, pos, live)
-
-    def prefill_fn(params, cache, tokens, slot, offset, n_valid):
-        return prefill_chunk(cfg, params, cache, tokens, slot, offset, n_valid)
-
-    return init_cache_fn, step_fn, prefill_fn
+    """What ``serve.ModelReplicaServer(decode_fns=...)`` is told of this
+    model (``decoding.DecodeFns``): its step takes ``live``, the rows that
+    may change what their slots own (``_DecodeEngine``); a step and a chunk
+    are taken to read all of the cache's rows."""
+    return decoding.serve_fns(
+        cfg, init_cache, decode_step_batch, prefill_chunk, wants_live=True)
 
 
 # ----------------------------------------------------------------------------
@@ -461,45 +451,10 @@ def serve_decode_fns(cfg: Config):
 
 def generate(cfg: Config, params, prompt, *, max_new_tokens: int,
              temperature: float = 0.0, rng: jax.Array | None = None):
-    """prompt ``[B, Tp]`` -> ``[B, Tp + max_new_tokens]``: each row's prompt
-    but its last token goes through :func:`prefill_chunk` (one chunk a row),
-    then a ``lax.scan`` of :func:`decode_step_batch` decodes greedily
-    (temperature 0) or by temperature sampling - the path a replica takes."""
-    prompt = jnp.asarray(prompt, jnp.int32)
-    B, Tp = prompt.shape
-    rng = jax.random.key(0) if rng is None else rng
-    run = _generate_loop(cfg, Tp, Tp + max_new_tokens, float(temperature))
-    cache = init_cache(cfg, B, Tp + max_new_tokens)
-    return jnp.concatenate([prompt, run(params, cache, prompt, rng).T], axis=1)
-
-
-@functools.lru_cache(maxsize=32)
-def _generate_loop(cfg: Config, Tp: int, total: int, temperature: float):
-    def step(params, carry, pos):
-        cache, tok, rng = carry
-        B = tok.shape[0]
-        logits, cache = decode_step_batch(
-            cfg, params, cache, tok, jnp.full((B,), pos), jnp.ones((B,), bool))
-        rng, sub = jax.random.split(rng)
-        if temperature > 0:
-            nxt = jax.random.categorical(sub, logits / temperature)
-        else:
-            nxt = jnp.argmax(logits, axis=-1)
-        nxt = nxt.astype(jnp.int32)
-        return (cache, nxt, rng), nxt
-
-    def run(params, cache, prompt, rng):
-        if Tp > 1:
-            cache = jax.lax.fori_loop(
-                0, prompt.shape[0],
-                lambda b, c: prefill_chunk(
-                    cfg, params, c, prompt[b, :Tp - 1], b, 0, Tp - 1),
-                cache,
-            )
-        _, toks = jax.lax.scan(
-            lambda c, p: step(params, c, p),
-            (cache, prompt[:, Tp - 1], rng), jnp.arange(Tp - 1, total - 1),
-        )
-        return toks
-
-    return jax.jit(run)
+    """prompt ``[B, Tp]`` -> ``[B, Tp + max_new_tokens]`` by
+    :func:`prefill_chunk` and :func:`decode_step_batch`, the path a replica
+    takes (models/decoding.py)."""
+    return decoding.generate(
+        cfg, params, prompt, init_cache=init_cache, prefill_chunk=prefill_chunk,
+        decode_step_batch=decode_step_batch, max_new_tokens=max_new_tokens,
+        temperature=temperature, rng=rng)

@@ -388,26 +388,15 @@ def prefill_chunk(cfg: Config, params, cache, tokens, slot, offset, n_valid):
 
 
 def serve_decode_fns(cfg: Config):
-    """``(init_cache_fn, step_fn, prefill_fn)`` for ``serve.
-    ModelReplicaServer(decode_fns=...)``.  ``step_fn`` takes ``live`` (it
-    reads and counts live rows only) and says what a step reads of the cache
-    (``cache_rows_read``: ``mla.decode_rows_read`` at this model's block), as
-    ``prefill_fn`` says what a chunk reads (``mla.prefill_rows_read``)."""
-
-    def init_cache_fn(slots: int, max_len: int):
-        return init_cache(cfg, slots, max_len)
-
-    def step_fn(params, cache, tokens, pos, live):
-        return decode_step_batch(cfg, params, cache, tokens, pos, live)
-
-    step_fn.cache_rows_read = functools.partial(mla.decode_rows_read, DECODE_BLOCK)
-
-    def prefill_fn(params, cache, tokens, slot, offset, n_valid):
-        return prefill_chunk(cfg, params, cache, tokens, slot, offset, n_valid)
-
-    prefill_fn.cache_rows_read = functools.partial(mla.prefill_rows_read, PREFILL_BLOCK)
-
-    return init_cache_fn, step_fn, prefill_fn
+    """What ``serve.ModelReplicaServer(decode_fns=...)`` is told of this
+    model (``decoding.DecodeFns``): its step takes ``live`` (it reads and
+    counts live rows only), and a step and a chunk read the cache as far as
+    ``mla.decode_rows_read`` / ``mla.prefill_rows_read`` say at this model's
+    blocks."""
+    return decoding.serve_fns(
+        cfg, init_cache, decode_step_batch, prefill_chunk, wants_live=True,
+        step_rows_read=functools.partial(mla.decode_rows_read, DECODE_BLOCK),
+        chunk_rows_read=functools.partial(mla.prefill_rows_read, PREFILL_BLOCK))
 
 
 # ----------------------------------------------------------------------------

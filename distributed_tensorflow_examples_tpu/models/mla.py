@@ -61,7 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import latent_decode, latent_prefill
-from ..ops.flash_attention import interpret_mode
+from ..ops.common import interpret_mode
 from . import layers
 
 

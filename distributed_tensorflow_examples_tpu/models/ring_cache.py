@@ -2,8 +2,9 @@
 attentions that read it: what models/afmoe.py and models/smallthinker.py
 share.  It names no model: a caller hands in its rows per block
 (``attn_block``), the type it multiplies in and the name of its scope; what
-a model tells the serve engine of its reads (:func:`serve_decode_fns`)
-takes the model's ``Config``, which lays the cache out by kind.
+a model tells the serve engine of its reads (:func:`decode_rows_read`,
+:func:`prefill_rows_read`) takes the model's ``Config``, which lays the
+cache out by kind.
 
 THE CACHE, per layer BY KIND, arrays ``k`` and ``v`` a layer (a window
 layer's keys are kept as the model attends them, rotated where it rotates):
@@ -46,7 +47,6 @@ v5e, PR 39).
 
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -279,27 +279,3 @@ def _mean_rows_read(cfg, deepest: int, max_len: int) -> float:
     return float(np.mean([
         min(int(blocks_read(deepest, rows, blk)) * min(blk, rows), rows)
         for rows in (cfg.cache_rows(i, max_len) for i in cfg.layers)]))
-
-
-def serve_decode_fns(cfg, init_cache, decode_step_batch, prefill_chunk):
-    """``(init_cache_fn, step_fn, prefill_fn)`` for ``serve.
-    ModelReplicaServer(decode_fns=...)`` from a model's three functions.
-    ``step_fn`` takes ``live`` (a row that is not live must leave its ring
-    alone) and says what a step reads of the cache (``cache_rows_read``:
-    :func:`decode_rows_read`), as ``prefill_fn`` says what a chunk reads
-    (:func:`prefill_rows_read`)."""
-
-    def init_cache_fn(slots: int, max_len: int):
-        return init_cache(cfg, slots, max_len)
-
-    def step_fn(params, cache, tokens, pos, live):
-        return decode_step_batch(cfg, params, cache, tokens, pos, live)
-
-    step_fn.cache_rows_read = functools.partial(decode_rows_read, cfg)
-
-    def prefill_fn(params, cache, tokens, slot, offset, n_valid):
-        return prefill_chunk(cfg, params, cache, tokens, slot, offset, n_valid)
-
-    prefill_fn.cache_rows_read = functools.partial(prefill_rows_read, cfg)
-
-    return init_cache_fn, step_fn, prefill_fn

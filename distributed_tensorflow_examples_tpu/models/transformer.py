@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import attention as attn_ops
-from . import layers
+from . import decoding, layers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -745,33 +745,21 @@ def prefill_chunk(
 
 
 def serve_decode_fns(cfg: Config, *, mesh: Mesh | None = None):
-    """The ``(init_cache_fn, step_fn[, prefill_fn])`` tuple a serving
-    replica's decode engine needs (``serve.ModelReplicaServer(decode_fns=
-    ...)``): slot-shaped KV cache, the per-row-position batched step and,
-    for dense blocks, :func:`prefill_chunk` as ``prefill_fn(params, cache,
-    tokens[C], slot, offset, n_valid) -> cache``, with which the engine
-    puts a seated prompt into the cache a chunk per forward pass.  An MoE
-    model gets the pair: the engine then feeds the prompt a token a step
-    (see :func:`prefill_chunk`).  One definition, so the served decode
-    path and the model cannot drift."""
-
-    def init_cache_fn(slots: int, max_len: int):
-        return init_cache(cfg, slots, max_len, mesh=mesh)
-
-    def step_fn(params, cache, tokens, pos):
-        return decode_step_batch(cfg, params, cache, tokens, pos, mesh=mesh)
-
-    # How far into the cache a step reads: the engine counts it.
-    step_fn.cache_rows_read = decode_rows_read
-
-    def prefill_fn(params, cache, tokens, slot, offset, n_valid):
-        return prefill_chunk(
-            cfg, params, cache, tokens, slot, offset, n_valid, mesh=mesh
-        )
-
-    if cfg.moe_experts > 0:
-        return init_cache_fn, step_fn
-    return init_cache_fn, step_fn, prefill_fn
+    """What a serving replica's decode engine is told of this model
+    (``serve.ModelReplicaServer(decode_fns=...)``, ``decoding.DecodeFns``):
+    slot-shaped KV cache, the per-row-position batched step, which is not
+    told which rows are live (a row that is not computes an inert row), how
+    far that step reads (:func:`decode_rows_read`) and, for dense blocks,
+    :func:`prefill_chunk`, with which the engine puts a seated prompt into
+    the cache a chunk per forward pass.  An MoE model hands no ``prefill``:
+    the engine then feeds the prompt a token a step (see
+    :func:`prefill_chunk`)."""
+    init, step, chunk = (
+        functools.partial(f, mesh=mesh)
+        for f in (init_cache, decode_step_batch, prefill_chunk))
+    return decoding.serve_fns(
+        cfg, init, step, None if cfg.moe_experts > 0 else chunk,
+        wants_live=False, step_rows_read=decode_rows_read)
 
 
 def generate(

@@ -21,8 +21,9 @@ Design (per /opt/skills/guides/pallas_guide.md):
   inner over k) and dk/dv (grid over k blocks, inner over q) — using the
   saved LSE and the FA2 recurrence: p = exp(s - lse); ds = p*(do.v^T - D);
   D = rowsum(do * o).
-- ``interpret=True`` on the CPU platform only (``interpret_mode``), so CPU
-  tests run the same kernels; on TPU they compile through Mosaic or raise.
+- ``interpret=True`` on the CPU platform only (``common.interpret_mode``),
+  so CPU tests run the same kernels; on TPU they compile through Mosaic or
+  raise.
 
 Composes with ring attention (ops.attention): the ring rotates k/v shards
 between chips; this kernel is the per-chip block compute.
@@ -37,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .common import compiler_params, interpret_mode
 
 NEG_INF = -1e30
 
@@ -73,34 +76,6 @@ def _dot_tn(a, b):
     """a.T @ b via dot_general (no explicit transpose of the score tile)."""
     return jax.lax.dot_general(
         a.astype(b.dtype), b, _TRANS_A, preferred_element_type=jnp.float32
-    )
-
-
-def interpret_mode() -> bool:
-    """Whether the Pallas kernels run interpreted: True on the ``cpu``
-    platform only, where a caller reaches a kernel by asking for it
-    (``attention="flash"``, ``impl="flash"``, a direct call — the auto
-    dispatch never picks it there, see ``flash_viable``).  On ``tpu`` the
-    kernels compile through Mosaic or the call raises; any other platform
-    is refused rather than interpreted under a kernel's name.  The platform
-    is the one jit places this computation's arrays on
-    (``jax.default_backend()``).  The ONE platform test every Pallas kernel
-    in the package uses."""
-    platform = jax.default_backend()
-    if platform == "tpu":
-        return False
-    if platform == "cpu":
-        return True
-    raise NotImplementedError(
-        f"Pallas TPU kernels compile on 'tpu' and interpret on 'cpu'; "
-        f"platform {platform!r} is neither"
-    )
-
-
-def compiler_params(semantics: tuple[str, ...], vmem_limit_bytes: int | None = None):
-    """The ONE spelling every TPU kernel in the package uses."""
-    return pltpu.CompilerParams(
-        dimension_semantics=semantics, vmem_limit_bytes=vmem_limit_bytes
     )
 
 

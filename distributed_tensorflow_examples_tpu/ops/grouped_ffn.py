@@ -55,7 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import compiler_params, interpret_mode
+from .common import compiler_params, interpret_mode
 
 #: Rows of ``gate`` / ``up`` (of D) and of ``down`` (of F) one grid step
 #: brings in; a dimension shorter than its block is taken whole, one that

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import compiler_params, interpret_mode
+from .common import compiler_params, interpret_mode
 
 #: The kernel's name, which its operations carry in a device trace.
 KERNEL_NAME = "mla_prefill_attention"

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import compiler_params, interpret_mode
+from .common import compiler_params, interpret_mode
 
 LANES = 128
 #: Channels of one block: 8 sublanes x LANES, one vector register a state row.

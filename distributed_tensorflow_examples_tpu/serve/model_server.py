@@ -42,7 +42,6 @@ misinterpreted; the HELLO service identity makes even the refusal loud.
 from __future__ import annotations
 
 import contextlib
-import inspect
 import json
 import logging
 import os
@@ -235,18 +234,22 @@ class _DecodeEngine:
     """Stepped decode over a per-slot cache behind the sequence-slot
     batcher (r19).
 
-    Model-agnostic: the model supplies ``init_cache_fn(slots, max_len)``
-    (a per-slot cache pytree) and ``step_fn(params, cache, tokens[S],
-    pos[S]) -> (logits [S, V], cache)`` — one jitted apply advances EVERY
-    active session one position.  The engine owns the host-side session
-    state (each session's position and counts), how a prompt reaches the
-    cache and what is in flight, so batched decode is byte-identical to a
-    session running alone: the slot array shape is FIXED, every row's math
-    depends only on its own slot, and a session reads only what it wrote
-    itself.
+    Model-agnostic: the model is what ``fns``, a ``models.decoding.
+    DecodeFns``, says of it - ``init_cache(slots, max_len)`` (a per-slot
+    cache pytree), ``step(params, cache, tokens[S], pos[S][, live[S]]) ->
+    (logits [S, V], cache)``, one jitted apply that advances EVERY active
+    session one position, ``prefill`` or ``None``, ``wants_live``, and
+    ``step_rows_read`` / ``chunk_rows_read`` or ``None`` (a step, a chunk,
+    is then taken to read all ``max_len`` rows of a slot: counters
+    ``cache_rows_read``, ``prefill_rows_read``).  The engine owns the
+    host-side session state (each session's position and counts), how a
+    prompt reaches the cache and what is in flight, so batched decode is
+    byte-identical to a session running alone: the slot array shape is
+    FIXED, every row's math depends only on its own slot, and a session
+    reads only what it wrote itself.
 
     Who picks the token.  The compiled step does: the engine jits ONE
-    function, still named ``step_fn``, that hands the model's ``step_fn``
+    function, named ``step_fn``, that hands the model's ``step``
     ``where(from_host, tokens, prev)`` and returns ``(argmax(logits, -1)
     as int32 [S], cache)`` - greedy selection, the lowest index among
     equals as NumPy's has it, over the model's own float32 logits, which
@@ -284,24 +287,16 @@ class _DecodeEngine:
     prompt chunks are still due and the row of a session stepped past its
     end are not.  Two kinds of model:
 
-    - A model whose cache holds keys and values (the four-argument
-      ``step_fn`` above) is not told: its rows that are not live compute
-      inert rows, like the row batcher's pad rows - what such a row writes
-      at its position the session's first real step writes again, and the
-      attention mask confines each session to the positions it wrote
-      itself.  A freed slot needs no cache reset.  Such a ``step_fn`` may
-      say how far into the cache its step reads by an attribute
-      ``cache_rows_read(pos, live, max_len)`` (the positions read A SLOT IN
-      THE MEAN by the step launched with the host's ``pos [S]`` int32 and
-      ``live [S]`` bool, the engine's own arrays: not to be kept or
-      changed); without it a step is taken to read all ``max_len`` of
-      every slot (counter ``cache_rows_read``).  A model told which rows
-      are live (below) may say the same.
+    - A model whose cache holds keys and values (``wants_live`` false) is
+      not told: its rows that are not live compute inert rows, like the row
+      batcher's pad rows - what such a row writes at its position the
+      session's first real step writes again, and the attention mask
+      confines each session to the positions it wrote itself.  A freed slot
+      needs no cache reset.
     - A model whose cache holds a STATE that every step overwrites (a
       state-space layer: models/jamba.py) cannot compute an inert row: it
       would advance the state by a token that is not there.  Such a model
-      asks for the live rows by giving its ``step_fn`` a FIFTH argument,
-      ``live[S]`` bool, and promises: (a) a row that is not live leaves
+      says ``wants_live`` and promises: (a) a row that is not live leaves
       everything its slot owns unchanged; (b) a session starts from the
       zero state - the step at ``pos == 0`` and the chunk at ``offset ==
       0`` start there whatever the slot held, so a freed slot still needs
@@ -309,8 +304,8 @@ class _DecodeEngine:
       left in the slot.  The engine uploads ``live`` beside tokens and
       positions for such a model and for no other.
 
-    A model that also supplies ``prefill_fn(params, cache, tokens[C],
-    slot, offset, n_valid) -> cache`` (it enters ONE slot's positions
+    A model that supplies ``prefill(params, cache, tokens[C], slot,
+    offset, n_valid) -> cache`` (it enters ONE slot's positions
     ``[offset, offset + n_valid)`` into that slot's cache and touches no
     other slot) has its prompts PREFILLED: before the decode step a call
     runs at most one chunk of at most ``PREFILL_CHUNK`` tokens, for the
@@ -319,20 +314,15 @@ class _DecodeEngine:
     whatever the prompt lengths or the burst.  The chunk is as wide as the
     tokens it carries: ``tokens[C]`` is the narrowest of
     :func:`chunk_widths` that holds them, padded with token 0 past
-    ``n_valid``, so ``prefill_fn`` is traced once a width - one ``jax.jit``,
+    ``n_valid``, so ``prefill`` is traced once a width - one ``jax.jit``,
     a compiled program a width, each run once on a chunk of no valid token
     before the first session is answered - and must take every one of them
     (counters ``prefill_width``, the tokens dispatched with the padding in,
     beside ``prefill_tokens``, the valid ones).  A session being prefilled
     holds its slot with a row that is not live; once all but its last
     prompt token are cached it is an ordinary decode row at ``pos = P - 1``
-    and the next step emits its first token.  Without ``prefill_fn`` the
+    and the next step emits its first token.  Without ``prefill`` the
     prompt is teacher-forced through the decode step, a token a step.
-    A ``prefill_fn`` may say how far into the slot's cache a chunk's
-    attention reads by an attribute ``cache_rows_read(offset, chunk,
-    max_len)`` (positions, from the chunk's offset and the width it was
-    dispatched at); without it a chunk is taken to read all ``max_len``
-    (counter ``prefill_rows_read``).
 
     What a model counts on the device.  A model whose cache tree has an
     entry ``counters`` - a dict of small int32 arrays that its step and its
@@ -364,21 +354,17 @@ class _DecodeEngine:
     """
 
     def __init__(
-        self, model_getter, init_cache_fn, step_fn, prefill_fn=None, *,
-        slots: int, max_len: int, max_sessions: int,
+        self, model_getter, fns, *, slots: int, max_len: int, max_sessions: int,
     ):
         import jax
 
         self._get_model = model_getter  # () -> (step, params) | None
-        self._init_cache = init_cache_fn
-        self._cache = init_cache_fn(slots, max_len)
-        self._step_jit = jax.jit(_selecting(step_fn), donate_argnums=1)
-        # A fifth parameter is the model asking for the live rows.
-        self._wants_live = len(inspect.signature(step_fn).parameters) == 5
+        self._init_cache = fns.init_cache
+        self._cache = fns.init_cache(slots, max_len)
+        self._step_jit = jax.jit(_selecting(fns.step), donate_argnums=1)
+        self._wants_live = fns.wants_live
         # How far into the cache a step reads: all of it, unless told.
-        self._rows_read = getattr(
-            step_fn, "cache_rows_read", lambda pos, live, max_len: max_len
-        )
+        self._rows_read = fns.step_rows_read or (lambda pos, live, max_len: max_len)
         # What the engine holds for its slots (state, keys and values).
         self.state_bytes = sum(
             int(a.nbytes) for a in jax.tree.leaves(self._cache)
@@ -386,14 +372,14 @@ class _DecodeEngine:
         self.slots = int(slots)
         self.max_len = int(max_len)
         self._prefill_jit = (
-            jax.jit(_echoing(prefill_fn), donate_argnums=1)
-            if prefill_fn else None
+            jax.jit(_echoing(fns.prefill), donate_argnums=1)
+            if fns.prefill else None
         )
         self._chunk = min(PREFILL_CHUNK, self.max_len)
         self._widths = chunk_widths(self._chunk)
         # How far into the slot's cache a chunk reads: all of it, unless told.
-        self._chunk_rows_read = getattr(
-            prefill_fn, "cache_rows_read", lambda offset, chunk, max_len: max_len
+        self._chunk_rows_read = fns.chunk_rows_read or (
+            lambda offset, chunk, max_len: max_len
         )
         self._prefill_warm = False
         self.prefill_chunks = 0
@@ -800,16 +786,17 @@ class ModelReplicaServer:
     stamps the HELLO answer, every predict/decode response and STATS, so
     pools can route and account per version (canary vs stable).
 
-    Decode serving (r19): ``decode_fns=(init_cache_fn, step_fn)`` adds
-    the stepped KV-cache decode path — stateful sessions behind the
+    Decode serving (r19): ``decode_fns``, a ``models.decoding.DecodeFns``
+    (what every served model's ``serve_decode_fns(cfg)`` gives) or the plain
+    ``(init_cache_fn, step_fn[, prefill_fn])`` that is its first fields,
+    adds the stepped KV-cache decode path — stateful sessions behind the
     sequence-slot batcher, streamed token responses over the
     DECODE_OPEN/NEXT/CLOSE wire (``serve.ServeClient.generate`` is the
-    client side).  A third function, ``prefill_fn`` (what
-    ``models.transformer.serve_decode_fns`` gives for a dense model), puts
-    a seated prompt into the cache a chunk per forward pass instead of a
-    token per decode step; a ``step_fn`` with a fifth argument ``live``
-    (``models.jamba.serve_decode_fns``) is told which rows may change what
-    their slots own (:class:`_DecodeEngine`).
+    client side).  Its fields: ``init_cache``, ``step``, ``prefill`` (puts a
+    seated prompt into the cache a chunk per forward pass instead of a token
+    per decode step), ``wants_live`` (the step is told which rows may change
+    what their slots own), ``step_rows_read`` and ``chunk_rows_read``
+    (:class:`_DecodeEngine`).
     """
 
     def __init__(
@@ -954,14 +941,15 @@ class ModelReplicaServer:
         # batcher.  Session ids are handed to clients as the DECODE_OPEN
         # status; the table maps them to stream tickets, and the refresher
         # sweeps sessions nobody polled for ``session_idle_s``.
-        self._engine = (
-            _DecodeEngine(
-                lambda: self._model, *decode_fns, slots=decode_slots,
+        self._engine = None
+        if decode_fns is not None:
+            # Here, so a replica without a decoder loads no model.
+            from ..models.decoding import DecodeFns
+
+            self._engine = _DecodeEngine(
+                lambda: self._model, DecodeFns(*decode_fns), slots=decode_slots,
                 max_len=decode_max_len, max_sessions=decode_max_sessions,
             )
-            if decode_fns is not None
-            else None
-        )
         self._session_idle_s = float(session_idle_s)
         self._sessions: dict[int, list] = {}  # sid -> [ticket, last_poll]
         self._next_sid = 1

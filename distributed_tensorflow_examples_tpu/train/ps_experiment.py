@@ -665,8 +665,8 @@ def run_ps_cluster_task(
             ",".join(f"{h}:{p}" for h, p in shard_addrs),
             "hosted in-process" if chief_hosts_service else "external PS tasks",
         )
-        # Scrapable platform record: tools/ps_tpu_smoke.py asserts the chief
-        # genuinely ran on the chip (not a silent CPU fallback).
+        # Scrapable platform record: whether the chief genuinely ran on the
+        # chip (not a silent CPU fallback).
         print(f"CHIEF_PLATFORM={jax.devices()[0].platform}", flush=True)
         trainer = async_ps.RemotePSChief(
             acfg, loss_fn, optimizer, params,

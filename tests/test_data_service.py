@@ -448,57 +448,6 @@ def test_bad_spec_and_missing_eval():
 
 
 # ----------------------------------------------------------------------------
-# Satellite: perf-gate rules for the data-service bench
-# ----------------------------------------------------------------------------
-
-
-def _gate_result(remote_mbs, *, raw_mb=1.5):
-    return {
-        "metric": "data_service_stream_mbs",
-        "detail": {
-            "raw_batch_mb": raw_mb,
-            "memcpy_mbs": 10000.0,
-            "local": {"stream_mbs": 100.0, "stream_mbs_frac_memcpy": 0.01},
-            "remote": {
-                "stream_mbs": remote_mbs,
-                "stream_mbs_frac_memcpy": remote_mbs / 10000.0,
-            },
-        },
-    }
-
-
-def test_perf_gate_data_service_rules():
-    import importlib
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "tools"))
-    try:
-        perf_gate = importlib.import_module("perf_gate")
-    finally:
-        sys.path.pop(0)
-    baseline = _gate_result(80.0)
-    kw = dict(tolerance=0.25, if_newer_ratio=20.0, remote_local_ratio=0.5)
-    # Within 2x of local at 1 MB+ batches: pass.
-    assert perf_gate.gate(_gate_result(60.0), baseline, **kw) == []
-    # Below the acceptance bound: flagged, from the result alone.
-    fails = perf_gate.gate(_gate_result(40.0), baseline, **kw)
-    assert any("disaggregation acceptance bound" in f for f in fails), fails
-    # The bound applies only in the 1 MB+ regime (--quick runs are exempt;
-    # the normalized-throughput floor vs baseline still applies there).
-    assert perf_gate.gate(
-        _gate_result(40.0, raw_mb=0.5), baseline, **kw
-    ) == []
-    # A structural collapse still trips the memcpy-fraction floor.
-    fails = perf_gate.gate(_gate_result(1.0, raw_mb=0.5), baseline, **kw)
-    assert any("frac_memcpy" in f for f in fails), fails
-    # Baseline auto-select covers both bench metrics.
-    assert perf_gate.BASELINES["data_service_stream_mbs"] == "data_service_baseline.json"
-    assert perf_gate.BASELINES["ps_transport_set_get_mbs"] == "ps_transport_baseline.json"
-
-
-# ----------------------------------------------------------------------------
 # Satellite: MetricsWriter context manager
 # ----------------------------------------------------------------------------
 

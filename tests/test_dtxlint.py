@@ -1774,38 +1774,12 @@ def test_cli_changed_mode_lints_only_the_diff(tmp_path, capsys):
     # contract; exercised against the real repo in the CLI tests above).
 
 
-def test_perf_gate_enforces_dtxlint_wall_time_budget():
-    """The lint runs inside tier-1 on every PR: a silently slower pass
-    must fail the perf gate, and the checked-in baseline must
-    stay auto-selectable from the step's metric field."""
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    try:
-        import perf_gate
-    finally:
-        sys.path.pop(0)
-    with open(os.path.join(ROOT, "tools", "dtxlint_time_baseline.json")) as f:
-        baseline = json.load(f)
-    assert perf_gate.BASELINES["dtxlint"] == "dtxlint_time_baseline.json"
-    ok = {"metric": "dtxlint", "ok": True,
-          "seconds": baseline["budget_s"] / 2}
-    assert perf_gate.gate(ok, baseline, tolerance=0.25,
-                          if_newer_ratio=20.0) == []
-    slow = {"metric": "dtxlint", "ok": True,
-            "seconds": baseline["budget_s"] + 1}
-    assert any("budget" in f for f in perf_gate.gate(
-        slow, baseline, tolerance=0.25, if_newer_ratio=20.0))
-    dirty = {"metric": "dtxlint", "ok": False, "seconds": 1.0}
-    assert any("not clean" in f for f in perf_gate.gate(
-        dirty, baseline, tolerance=0.25, if_newer_ratio=20.0))
-    # A result that lost its timing cannot silently pass the budget.
-    untimed = {"metric": "dtxlint", "ok": True}
-    assert any("seconds" in f for f in perf_gate.gate(
-        untimed, baseline, tolerance=0.25, if_newer_ratio=20.0))
-
-
 def test_dtxlint_step_emits_gated_metric():
-    """The shim's single JSON line carries the metric + seconds
-    perf_gate keys off, on top of the full --json document shape."""
+    """The shim's single JSON line carries the metric + seconds, on top of
+    the full --json document shape.  The lint runs inside tier-1 on every
+    PR, so a pass whose cost quietly explodes (an accidentally quadratic AST
+    walk) taxes every run: all 7 passes took 2.5 s on the dev box at r16, and
+    ten times a 30 s budget for slow hosts is where this fails."""
     import subprocess
 
     proc = subprocess.run(
@@ -1816,7 +1790,5 @@ def test_dtxlint_step_emits_gated_metric():
     doc = json.loads(proc.stdout.strip().splitlines()[-1])
     assert doc["metric"] == "dtxlint"
     assert doc["ok"] is True
-    assert 0 < doc["seconds"] < 10 * json.load(
-        open(os.path.join(ROOT, "tools", "dtxlint_time_baseline.json"))
-    )["budget_s"]
+    assert 0 < doc["seconds"] < 10 * 30.0
     assert doc["schema_version"] == dtxlint.JSON_SCHEMA_VERSION

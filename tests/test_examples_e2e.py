@@ -31,11 +31,11 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(example: str, *args: str, timeout: int = 900):
+def _run(example: str, *args: str, timeout: int = 900, devices: int = 8):
     """Run examples/<example> in a subprocess on the fake CPU mesh."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"  # a CLI test never takes the chip
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     cmd = [sys.executable, os.path.join(ROOT, "examples", example), *args]
     proc = subprocess.run(
         cmd, capture_output=True, text=True, timeout=timeout, env=env, cwd=ROOT
@@ -184,26 +184,32 @@ def test_ptb_lstm(tmp_path):
 def test_resnet50_tiny(tmp_path):
     """W3 at toy resolution: the full ResNet-50 v1.5 graph end-to-end —
     WITH a learning signal (r2 verdict: step-count-only was the weakest
-    e2e in the suite): 30 steps on learnable synthetic blobs must drive
-    the logged loss down, not just execute."""
+    e2e in the suite): 12 steps over the whole set of learnable synthetic
+    blobs must drive the logged loss down, not just execute.  The batch IS
+    the set (64) and the device is ONE: batches of 16 over eight replicas
+    gave batch norm one or two images a replica, so the loss swung between
+    3 and 14 and 60 steps were there to outlast it, each 1.7 s of eight
+    replicas' updates of 25M parameters on eight cores; the mesh is the
+    other CLIs' to show.  What is left is the graph's compile."""
     out = _run(
         "resnet50.py",
         "--image_size=32",
         "--num_classes=10",
-        "--batch_size=16",
-        "--train_steps=60",
-        "--log_every_steps=5",
+        "--batch_size=64",
+        "--train_steps=12",
+        "--log_every_steps=2",
         "--synthetic_examples=64",
         "--grad_accum=2",  # accumulation path through the CLI
         f"--log_dir={tmp_path}",
+        devices=1,
     )
     f = _final(out)
-    assert f["step"] == 60
+    assert f["step"] == 12
     assert "test_accuracy" in f
     # Learning signal on the CE term ("loss" includes the L2 penalty, ~20
-    # at init for 25M params — it swamps the ~2.3 CE scale); batch 16 on a
-    # 50-layer BN net is noisy, so compare min-of-late to the early value
-    # and require train accuracy to clear chance (0.1) decisively.
+    # at init for 25M params — it swamps the ~2.3 CE scale); a 50-layer BN
+    # net is noisy, so compare min-of-late to the early value and require
+    # train accuracy to clear chance (0.1) decisively.
     ms = [m for m in _metrics_jsonl(str(tmp_path)) if "ce" in m]
     assert len(ms) >= 6, ms
     early = ms[0]["ce"]

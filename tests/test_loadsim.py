@@ -1,7 +1,7 @@
 """loadsim (r14 tentpole): verdict logic units + the chaos smoke e2e.
 
 The unit tests pin the SLO verdict computation (step-progress analysis,
-chaos plan composition, perf-gate integration) deterministically; the
+chaos plan composition) deterministically; the
 smoke e2e drives the REAL ``tools/loadsim.py`` — a multi-process
 train-and-serve cluster off the product CLI with a full kill/join/leave
 cycle under closed-loop predict load — and asserts the gates the
@@ -22,7 +22,6 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from tools import loadsim  # noqa: E402
-from tools import perf_gate  # noqa: E402
 
 
 def test_build_plan_scripts_one_full_cycle():
@@ -95,46 +94,6 @@ def test_analyze_steps_verdicts():
     assert v["step_first"] == 5 and v["step_monotone"]
 
 
-def test_perf_gate_loadsim_rules():
-    base = {
-        "metric": "loadsim_slo", "slo_pass": True, "p99_ms": 20.0,
-        "gates": {"zero_failed_predicts": True, "join_lease_seen": True},
-    }
-    ok = {
-        "metric": "loadsim_slo", "slo_pass": True, "p99_ms": 35.0,
-        "gates": {"zero_failed_predicts": True, "join_lease_seen": True},
-    }
-    assert perf_gate.gate(
-        ok, base, tolerance=0.25, if_newer_ratio=20.0
-    ) == []
-    # slo_pass False names the failing gates.
-    bad = dict(ok, slo_pass=False,
-               gates={"zero_failed_predicts": False,
-                      "join_lease_seen": True})
-    (f,) = perf_gate.gate(bad, base, tolerance=0.25, if_newer_ratio=20.0)
-    assert "zero_failed_predicts" in f
-    # A gate present in the baseline cannot silently vanish.
-    shrunk = dict(ok, gates={"zero_failed_predicts": True})
-    fails = perf_gate.gate(shrunk, base, tolerance=0.25, if_newer_ratio=20.0)
-    assert any("join_lease_seen" in f for f in fails)
-    # The loose cross-host p99 tripwire.
-    slow = dict(ok, p99_ms=20.0 * 50)
-    fails = perf_gate.gate(slow, base, tolerance=0.25, if_newer_ratio=20.0)
-    assert any("p99_ms" in f for f in fails)
-
-
-def test_checked_in_loadsim_baseline_is_a_passing_verdict():
-    with open(os.path.join(ROOT, "tools", "loadsim_baseline.json")) as f:
-        base = json.load(f)
-    assert base["metric"] == "loadsim_slo"
-    assert base["slo_pass"] is True and base["predict_failed"] == 0
-    assert perf_gate.BASELINES["loadsim_slo"] == "loadsim_baseline.json"
-    # The baseline gates itself (the identity compare must pass).
-    assert perf_gate.gate(
-        base, base, tolerance=0.25, if_newer_ratio=20.0
-    ) == []
-
-
 @pytest.mark.slow
 def test_loadsim_chaos_smoke_e2e(tmp_path):
     """THE acceptance smoke: a short real-cluster run with the full
@@ -175,35 +134,3 @@ def test_canary_scenario_surface_and_phases():
         p["publish_v2"] < p["canary_up"] < p["kill_serve"]
         < p["promote_start"] < p["retire_old"] < 1.0
     )
-
-
-def test_perf_gate_canary_rules_and_checked_in_baseline():
-    base = {
-        "metric": "loadsim_canary_slo", "slo_pass": True, "p99_ms": 30.0,
-        "gates": {"zero_failed_predicts": True, "canary_weight_honored": True,
-                  "flip_completed": True},
-    }
-    ok = dict(base, p99_ms=40.0)
-    assert perf_gate.gate(ok, base, tolerance=0.25, if_newer_ratio=20.0) == []
-    bad = dict(ok, slo_pass=False, gates=dict(
-        base["gates"], canary_weight_honored=False
-    ))
-    (f,) = perf_gate.gate(bad, base, tolerance=0.25, if_newer_ratio=20.0)
-    assert "canary_weight_honored" in f
-    # Gate-set shrink detection holds for the canary verdict too.
-    shrunk = dict(ok, gates={"zero_failed_predicts": True})
-    fails = perf_gate.gate(shrunk, base, tolerance=0.25, if_newer_ratio=20.0)
-    assert any("flip_completed" in f for f in fails)
-    # The checked-in baseline is a PASSING verdict and gates itself.
-    assert perf_gate.BASELINES["loadsim_canary_slo"] == (
-        "loadsim_canary_baseline.json"
-    )
-    with open(os.path.join(ROOT, "tools", "loadsim_canary_baseline.json")) as f:
-        checked = json.load(f)
-    assert checked["metric"] == "loadsim_canary_slo"
-    assert checked["slo_pass"] is True and checked["predict_failed"] == 0
-    assert checked["gates"]["canary_weight_honored"]
-    assert checked["gates"]["flip_completed"]
-    assert perf_gate.gate(
-        checked, checked, tolerance=0.25, if_newer_ratio=20.0
-    ) == []

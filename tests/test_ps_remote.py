@@ -498,22 +498,3 @@ def test_payload_scale_cnn_sized_gradients():
         c.close()
     finally:
         ps_service.stop_server()
-
-
-def test_ps_smoke_final_parser():
-    import sys
-
-    tools = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"
-    )
-    sys.path.insert(0, tools)
-    try:
-        import ps_tpu_smoke
-    finally:
-        sys.path.pop(0)
-
-    out = "noise\nFINAL step=40 steps_per_sec=11.7 examples_per_sec_per_chip=748 mode=sync_replicas_cluster\n"
-    f = ps_tpu_smoke._final(out)
-    assert f["step"] == 40 and f["mode"] == "sync_replicas_cluster"
-    with pytest.raises(AssertionError):
-        ps_tpu_smoke._final("no final here")

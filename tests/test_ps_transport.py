@@ -5,29 +5,19 @@ round trips for every payload-carrying op x {f32, bf16} x {empty, small,
 multi-MB} payloads, HELLO version negotiation (a mismatched peer fails the
 CONNECT loudly instead of misparsing frames mid-stream), ``get_if_newer``
 semantics (fresh step -> payload, same step -> status-only) including
-across a server restart, and the perf-gate tripwire that keeps future PRs
-from re-introducing the copy-per-send framing.
+across a server restart.
 """
 
 from __future__ import annotations
 
-import os
 import socket
 import struct
-import sys
 import threading
-from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from distributed_tensorflow_examples_tpu.parallel import ps_service
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TOOLS = os.path.join(ROOT, "tools")
-for p in (ROOT, TOOLS):
-    if p not in sys.path:
-        sys.path.insert(0, p)
 
 
 def _bf16_exact(n: int) -> np.ndarray:
@@ -265,94 +255,3 @@ def test_param_cache_across_server_restart(server_port):
     s, v3 = ps.get()
     assert s == 5 and v3[0] == 5.0
     c.close()
-
-
-def test_transport_bench_quick_and_perf_gate(tmp_path):
-    """Tier-1 tripwire: the quick in-process transport bench must pass the
-    checked-in perf gate — a re-introduced copy-per-send (or an O(params)
-    if-newer pull) trips it before a PR lands."""
-    import json
-
-    import perf_gate
-    import ps_transport_bench as ptb
-
-    # 16 MB payload: big enough that a full pull takes milliseconds even on
-    # a fast loopback, so the O(header)-vs-O(params) ratio check has margin
-    # (at 4 MB a healthy full pull is only ~6x an if-newer RTT).
-    args = SimpleNamespace(
-        large_mb=16.0, small_kb=4.0, clients=2, reps_large=3, reps_small=30,
-        dtypes=["f32", "bf16"],
-    )
-    detail = ptb.run(args)
-    assert detail["f32"]["set_get_mbs_large"] > 0
-    with open(os.path.join(TOOLS, "ps_transport_baseline.json")) as f:
-        baseline = json.load(f)
-    failures = perf_gate.gate(
-        {"detail": detail}, baseline, tolerance=0.1, if_newer_ratio=10.0
-    )
-    assert not failures, failures
-
-
-def test_perf_gate_flags_structural_regressions():
-    """Gate mechanics on synthetic records: a halved normalized throughput
-    and an O(params) if-newer pull must both be flagged; a healthy result
-    must pass."""
-    import perf_gate
-
-    base = {"detail": {"large_mb": 64.0, "f32": {
-        "set_get_mbs_large_frac_memcpy": 0.2,
-        "get_mbs_large": 1000.0,
-        "if_newer_rtt_us": 150.0,
-    }}}
-    healthy = {"detail": {"large_mb": 64.0, "f32": {
-        "set_get_mbs_large_frac_memcpy": 0.18,
-        "get_mbs_large": 900.0,
-        "if_newer_rtt_us": 200.0,
-    }}}
-    assert perf_gate.gate(healthy, base, tolerance=0.25, if_newer_ratio=20.0) == []
-    slow = {"detail": {"large_mb": 64.0, "f32": {
-        "set_get_mbs_large_frac_memcpy": 0.01,  # copy-per-send came back
-        "get_mbs_large": 900.0,
-        "if_newer_rtt_us": 200.0,
-    }}}
-    fails = perf_gate.gate(slow, base, tolerance=0.25, if_newer_ratio=20.0)
-    assert any("set_get_mbs_large_frac_memcpy" in f for f in fails), fails
-    fat_pull = {"detail": {"large_mb": 64.0, "f32": {
-        "set_get_mbs_large_frac_memcpy": 0.18,
-        "get_mbs_large": 900.0,
-        "if_newer_rtt_us": 50_000.0,  # unchanged pull moving O(params)
-    }}}
-    fails = perf_gate.gate(fat_pull, base, tolerance=0.25, if_newer_ratio=20.0)
-    assert any("if_newer" in f for f in fails), fails
-    missing = {"detail": {"large_mb": 64.0}}
-    assert perf_gate.gate(missing, base, tolerance=0.25, if_newer_ratio=20.0)
-
-
-def test_perf_gate_bounds_replicated_push_overhead():
-    """r12 gate mechanics: a replicated-push overhead past the bound (the
-    dedup mirror started moving payloads?) and a replicated-set collapse
-    are both flagged; a healthy replication row passes; a result that
-    DROPPED the rows against a baseline that has them is flagged too."""
-    import perf_gate
-
-    def rec(push_ov, set_ov):
-        return {"detail": {"large_mb": 64.0, "replicas": {
-            "1": {"set_mbs": 1000.0, "push_pop_mbs": 700.0},
-            "2": {"set_mbs": 1000.0 / set_ov, "push_pop_mbs": 700.0 / push_ov,
-                  "replicated_push_overhead": push_ov,
-                  "replicated_set_overhead": set_ov},
-        }}}
-
-    base = rec(1.1, 1.9)
-    kw = dict(tolerance=0.25, if_newer_ratio=20.0)
-    assert perf_gate.gate(rec(1.1, 1.9), base, **kw) == []
-    fails = perf_gate.gate(rec(2.4, 1.9), base, **kw)
-    assert any("replicated_push_overhead" in f for f in fails), fails
-    fails = perf_gate.gate(rec(1.1, 4.0), base, **kw)
-    assert any("replicated_set_overhead" in f for f in fails), fails
-    assert perf_gate.gate({"detail": {"large_mb": 64.0}}, base, **kw)
-    # Small-payload results (--quick) skip the bound — loopback RTTs
-    # dominate tiny payloads and the acceptance size is 64 MB.
-    quick = rec(2.4, 4.0)
-    quick["detail"]["large_mb"] = 8.0
-    assert perf_gate.gate(quick, base, **kw) == []

@@ -549,14 +549,14 @@ def test_serve_decode_fns_gives_prefill_for_dense_blocks_only():
     chunk function, an MoE model (capacity is per call) does not."""
     tf = models.transformer
     fns = tf.serve_decode_fns(CFG)
-    assert [f.__name__ for f in fns] == ["init_cache_fn", "step_fn", "prefill_fn"]
+    assert [f.__name__ for f in fns[:3]] == ["init_cache_fn", "step_fn", "prefill_fn"]
     moe = tf.Config(
         vocab_size=128, dim=32, n_layers=1, n_heads=4, max_seq_len=64,
         moe_experts=4,
     )
-    assert [f.__name__ for f in tf.serve_decode_fns(moe)] == [
-        "init_cache_fn", "step_fn",
-    ]
+    fns = tf.serve_decode_fns(moe)
+    assert [f.__name__ for f in fns[:2]] == ["init_cache_fn", "step_fn"]
+    assert fns.prefill is None and not fns.wants_live
     with pytest.raises(NotImplementedError):
         tf.prefill_chunk(moe, None, None, np.zeros(4, np.int32), 0, 0, 4)
 

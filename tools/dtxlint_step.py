@@ -3,10 +3,9 @@
 
 It runs the passes through the library, emits the ``--json --compact``
 document EXTENDED with ``metric: "dtxlint"`` and the run's ``seconds`` as
-its single output line, and exits with the CLI's code.
-``tools/perf_gate.py`` gates ``seconds`` against the checked-in budget
-(``tools/dtxlint_time_baseline.json``), so a new pass that silently blows
-up lint wall-time — and with it tier-1's repo-gate — fails loudly instead.
+its single output line, and exits with the CLI's code.  The test bounds
+``seconds``, so a new pass that silently blows up lint wall-time — and with
+it tier-1's repo-gate — fails loudly instead.
 """
 
 from __future__ import annotations

@@ -34,8 +34,7 @@ The run ends in a machine-readable SLO VERDICT (last stdout line, and
 - the joined worker's lease was observed by the mid-run dtxtop scrape.
 
 Exit code 0 iff every gate holds — the standing acceptance rig ROADMAP
-items 1–4 gate on, runnable on any CPU dev box (baseline gated by
-``tools/perf_gate.py``).
+items 1–4 gate on, runnable on any CPU dev box.
 
 Usage::
 
@@ -481,7 +480,7 @@ def run_reshard(args) -> int:
 
     verdict: dict = {
         "schema_version": VERDICT_SCHEMA_VERSION,
-        "metric": "loadsim_reshard_slo",  # perf_gate baseline auto-select
+        "metric": "loadsim_reshard_slo",
         "qps_target": args.qps,
         "duration_s": args.duration_s,
         "p99_bound_ms": args.p99_bound_ms,
@@ -751,7 +750,7 @@ def run_overload(args) -> int:
 
     verdict: dict = {
         "schema_version": VERDICT_SCHEMA_VERSION,
-        "metric": "loadsim_overload_slo",  # perf_gate baseline auto-select
+        "metric": "loadsim_overload_slo",
         "qps_target": args.qps,
         "gen_threads": args.gen_threads,
         "burst_threads": args.burst_threads,
@@ -1054,7 +1053,7 @@ def run_multitenant(args) -> int:
 
     verdict: dict = {
         "schema_version": VERDICT_SCHEMA_VERSION,
-        "metric": "loadsim_multitenant_slo",  # perf_gate baseline auto-select
+        "metric": "loadsim_multitenant_slo",
         "qps_target": args.qps,
         "gen_threads": args.gen_threads,
         "duration_s": args.duration_s,
@@ -1345,7 +1344,7 @@ def run_canary(args) -> int:
 
     verdict: dict = {
         "schema_version": VERDICT_SCHEMA_VERSION,
-        "metric": "loadsim_canary_slo",  # perf_gate baseline auto-select
+        "metric": "loadsim_canary_slo",
         "qps_target": args.qps,
         "duration_s": args.duration_s,
         "p99_bound_ms": args.p99_bound_ms,
@@ -1863,7 +1862,7 @@ def main(argv=None) -> int:
 
     verdict: dict = {
         "schema_version": VERDICT_SCHEMA_VERSION,
-        "metric": "loadsim_slo",  # perf_gate baseline auto-select key
+        "metric": "loadsim_slo",
         "qps_target": args.qps,
         "gen_threads": args.gen_threads,
         "duration_s": args.duration_s,
