@@ -24,8 +24,13 @@ traced function XLA can fuse end-to-end.
 - ``smallthinker`` — a router that reads its layer's input before attention,
   whole layers of ReLU-gated experts, a NoPE global layer to three rotary
   window layers (PowerInfer SmallThinker), served
+- ``nemotron_h`` — layers of one sub-layer each: Mamba-2 mixers whose state
+  is a matrix a head, NoPE grouped-query attention, LatentMoE feed-forwards
+  of ungated squared-ReLU experts in a narrower latent (NVIDIA Nemotron-H),
+  served
 - ``ring_cache`` — the slot cache with rings in the window layers and the two
-  blocked attentions over it, which ``afmoe`` and ``smallthinker`` share
+  blocked attentions over it, which ``afmoe``, ``smallthinker`` and
+  ``nemotron_h`` share
 - ``mla``      — the latent-attention sub-layer ``longcat`` and ``deepseek``
   share
 - ``decoding`` — what the served families share: the contract each hands
@@ -48,3 +53,4 @@ from . import deepseek  # noqa: F401
 from . import ring_cache  # noqa: F401
 from . import afmoe  # noqa: F401
 from . import smallthinker  # noqa: F401
+from . import nemotron_h  # noqa: F401

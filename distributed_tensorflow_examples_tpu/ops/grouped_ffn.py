@@ -1,15 +1,19 @@
-"""Gated feed-forward over rows grouped by expert: the experts a chip
-holds, each applied to the rows routed to it.
+"""Feed-forward over rows grouped by expert: the experts a chip holds, each
+applied to the rows routed to it, gated or - where the caller hands in no
+``gate`` - not.
 
     y[r] = down[e] . (act(gate[e] . x[r]) * (up[e] . x[r]))       r in group e
+    y[r] = down[e] . act(up[e] . x[r])                            gate=None
 
-``act`` is SiLU or, where the caller says so (``activation="relu"``), ReLU.
+``act`` is SiLU or what the caller names of :data:`ACTIVATIONS`: ReLU, or
+the squared ReLU (``"relu2"``) of an ungated expert.
 
 ``rows [M, D]`` (bfloat16) hold group after group in expert order, each
 group STARTING ON A BLOCK BOUNDARY (:func:`group_starts`): group ``e`` is
 rows ``[start_e, start_e + size_e)`` with ``start_e`` the sum of the sizes
 before it, each rounded up to ``block_rows``.  ``group_sizes [E]`` int32,
-``gate, up [E, D, F]``, ``down [E, F, D]`` -> ``[M, D]`` float32.  A row
+``gate, up [E, D, F]`` (``gate`` may be ``None``), ``down [E, F, D]`` ->
+``[M, D]`` float32.  A row
 that lies in no group (the filling of a group's last block, everything past
 the last group) has an UNDEFINED result - it may never have been written -
 and its input may be anything finite; the caller reads the groups' rows.
@@ -29,7 +33,8 @@ a grid step.  An expert with no row is never named, so never read.
 
 One row block runs two phases over the grid's second axis: ``D / block_k``
 steps that accumulate ``x . gate`` and ``x . up`` in float32 scratch
-(``[block_rows, F]`` each), then ``F / block_f`` steps that add ``h[:,
+(``[block_rows, F]`` each; ungated: ``x . up`` alone, one accumulator and no
+read of a second matrix), then ``F / block_f`` steps that add ``h[:,
 f-block] . down[f-block]`` into the resident output block.  Blocks of the
 matrices are whole rows of them (contiguous in HBM).  Products are bfloat16
 x bfloat16 accumulated in float32; ``act`` and the gate's product in float32,
@@ -43,7 +48,10 @@ blocks 16.8 MB, down 12.6 MB, output 6.3 MB, rows 0.5 MB, scratch 2.6 MB -
 the call states its own (:data:`VMEM_LIMIT`).  At D 2560, F 768 neither
 block divides its dimension and :func:`_block` takes 640 and 384 (four steps
 and two): gate and up blocks 3.9 MB, down 3.9 MB, output 2.6 MB, rows 0.3
-MB, scratch 1.0 MB - 12 MB.
+MB, scratch 1.0 MB - 12 MB.  Ungated at D 1024, F 2688 ``D`` is one block
+and :func:`_block` takes 384 of ``F`` (one step and seven): the up block -
+an expert's whole matrix - 11.0 MB, down 1.6 MB, output 1.0 MB, rows 0.5 MB,
+scratch 2.1 MB - 16 MB.
 """
 
 from __future__ import annotations
@@ -66,7 +74,8 @@ VMEM_LIMIT = 64 * 1024 * 1024
 #: The kernel's name, which its operations carry in a device trace.
 KERNEL_NAME = "moe_grouped_ffn"
 #: What ``activation`` may name.
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+               "relu2": lambda v: jnp.square(jax.nn.relu(v))}
 
 
 def group_starts(group_sizes, block_rows: int):
@@ -76,6 +85,37 @@ def group_starts(group_sizes, block_rows: int):
     padded = -(-group_sizes // block_rows) * block_rows
     ends = jnp.cumsum(padded)
     return ends - padded, ends[-1]
+
+
+def _accumulate(s, acc, value):
+    """``acc`` set at the phase's first step, added to after."""
+
+    @pl.when(s == 0)
+    def _():
+        acc[...] = value
+
+    @pl.when(s > 0)
+    def _():
+        acc[...] += value
+
+
+def _down_phase(real, s, h, h_ref, down_ref, out_ref, *, nk: int, nf: int):
+    """What both forms end on: at the first phase's last step ``h()`` is
+    rounded and laid out by ``F`` block, then ``F / block_f`` steps add ``h[:,
+    f-block] . down[f-block]`` into the resident output block."""
+
+    @pl.when(real & (s == nk - 1))
+    def _():
+        rounded = h().astype(h_ref.dtype)
+        bf = rounded.shape[1] // nf
+        for j in range(nf):
+            h_ref[j] = rounded[:, j * bf:(j + 1) * bf]
+
+    @pl.when(real & (s >= nk))
+    def _():
+        j = s - nk
+        _accumulate(j, out_ref, jnp.dot(
+            h_ref[j], down_ref[...], preferred_element_type=jnp.float32))
 
 
 def _kernel(tile_expert, n_real, x_ref, gate_ref, up_ref, down_ref, out_ref,
@@ -100,25 +140,24 @@ def _kernel(tile_expert, n_real, x_ref, gate_ref, up_ref, down_ref, out_ref,
             g_acc[...] += g
             u_acc[...] += u
 
-    @pl.when(real & (s == nk - 1))
+    _down_phase(real, s, lambda: act(g_acc[...]) * u_acc[...], h_ref, down_ref, out_ref,
+                nk=nk, nf=nf)
+
+
+def _kernel_ungated(tile_expert, n_real, x_ref, up_ref, down_ref, out_ref,
+                    u_acc, h_ref, *, nk: int, nf: int, act):
+    """:func:`_kernel` with no gate: one accumulation over ``up``, ``h =
+    act(x . up)``."""
+    del tile_expert
+    t, s = pl.program_id(0), pl.program_id(1)
+    real = t < n_real[0]
+
+    @pl.when(real & (s < nk))
     def _():
-        h = (act(g_acc[...]) * u_acc[...]).astype(h_ref.dtype)
-        bf = h.shape[1] // nf
-        for j in range(nf):
-            h_ref[j] = h[:, j * bf:(j + 1) * bf]
+        _accumulate(s, u_acc, jnp.dot(
+            x_ref[...], up_ref[...], preferred_element_type=jnp.float32))
 
-    @pl.when(real & (s >= nk))
-    def _():
-        j = s - nk
-        y = jnp.dot(h_ref[j], down_ref[...], preferred_element_type=jnp.float32)
-
-        @pl.when(j == 0)
-        def _():
-            out_ref[...] = y
-
-        @pl.when(j > 0)
-        def _():
-            out_ref[...] += y
+    _down_phase(real, s, lambda: act(u_acc[...]), h_ref, down_ref, out_ref, nk=nk, nf=nf)
 
 
 def _block(n: int, want: int) -> int:
@@ -138,10 +177,11 @@ def _block(n: int, want: int) -> int:
 def grouped_ffn(rows, group_sizes, gate, up, down, *, block_rows: int = 128,
                 activation: str = "silu"):
     """See the module docstring.  Any ``M`` (padded here to whole blocks);
-    ``block_rows`` a multiple of 16; ``activation`` of :data:`ACTIVATIONS`.
-    Compiles through Mosaic on a TPU, interpreted on the CPU."""
+    ``block_rows`` a multiple of 16; ``activation`` of :data:`ACTIVATIONS`;
+    ``gate`` None: the ungated form.  Compiles through Mosaic on a TPU,
+    interpreted on the CPU."""
     M, D = rows.shape
-    E, _, F = gate.shape
+    E, _, F = up.shape
     bm = block_rows
     bk, bf = _block(D, BLOCK_K), _block(F, BLOCK_F)
     nk, nf = D // bk, F // bf
@@ -170,22 +210,24 @@ def grouped_ffn(rows, group_sizes, gate, up, down, *, block_rows: int = 128,
 
     w_in = pl.BlockSpec(
         (None, bk, F), lambda t, s, te, n: (te[tile(t, n)], k_step(t, s, n), 0))
+    gated = gate is not None
+    acc = pltpu.VMEM((bm, F), jnp.float32)
     out = pl.pallas_call(
-        functools.partial(_kernel, nk=nk, nf=nf, act=ACTIVATIONS[activation]),
+        functools.partial(_kernel if gated else _kernel_ungated, nk=nk, nf=nf,
+                          act=ACTIVATIONS[activation]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(tiles, nk + nf),
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda t, s, te, n: (tile(t, n), k_step(t, s, n))),
-                w_in, w_in,
+                *([w_in, w_in] if gated else [w_in]),
                 pl.BlockSpec(
                     (None, bf, D),
                     lambda t, s, te, n: (te[tile(t, n)], f_step(t, s, n), 0)),
             ],
             out_specs=pl.BlockSpec((bm, D), lambda t, s, te, n: (tile(t, n), 0)),
             scratch_shapes=[
-                pltpu.VMEM((bm, F), jnp.float32),
-                pltpu.VMEM((bm, F), jnp.float32),
+                *([acc, acc] if gated else [acc]),
                 pltpu.VMEM((nf, bm, bf), rows.dtype),
             ],
         ),
@@ -195,7 +237,7 @@ def grouped_ffn(rows, group_sizes, gate, up, down, *, block_rows: int = 128,
         compiler_params=compiler_params(("arbitrary", "arbitrary"), VMEM_LIMIT),
         interpret=interpret_mode(),
         name=KERNEL_NAME,
-    )(tile_expert, n_real.reshape(1), rows, gate, up, down)
+    )(tile_expert, n_real.reshape(1), rows, *([gate, up] if gated else [up]), down)
     return out[:M]
 
 
@@ -207,10 +249,14 @@ def grouped_ffn_reference(rows, group_sizes, gate, up, down, *, block_rows: int 
     starts, _ = group_starts(group_sizes.astype(jnp.int32), block_rows)
     r = jnp.arange(rows.shape[0])
     out = jnp.zeros(rows.shape, jnp.float32)
-    for e in range(gate.shape[0]):
-        g = jnp.dot(rows, gate[e], preferred_element_type=jnp.float32)
+    act = ACTIVATIONS[activation]
+    for e in range(up.shape[0]):
         u = jnp.dot(rows, up[e], preferred_element_type=jnp.float32)
-        h = (ACTIVATIONS[activation](g) * u).astype(rows.dtype)
+        if gate is None:
+            h = act(u).astype(rows.dtype)
+        else:
+            g = jnp.dot(rows, gate[e], preferred_element_type=jnp.float32)
+            h = (act(g) * u).astype(rows.dtype)
         y = jnp.dot(h, down[e], preferred_element_type=jnp.float32)
         mine = (r >= starts[e]) & (r < starts[e] + group_sizes[e])
         out = jnp.where(mine[:, None], y, out)

@@ -266,8 +266,9 @@ class ShareConfig:
     #: The chosen scores are divided by their sum (plus ``NORMALISE_EPS``)
     #: before ``scale``: a token's weights then add up to ``scale``.
     normalise: bool = False
-    #: What an expert applies to its gate's half (ops/grouped_ffn.py
-    #: ``ACTIVATIONS``).
+    #: What an expert applies (ops/grouped_ffn.py ``ACTIVATIONS``): to its
+    #: gate's half where its parameters hold a ``gate``, ``down . (act(gate .
+    #: x) * (up . x))``, else to its one product, ``down . act(up . x)``.
     activation: str = "silu"
 
     def __post_init__(self):
@@ -416,13 +417,15 @@ def apply_share_plan(p, u, plan: SharePlan, share: ShareConfig, *, dtype):
     expert adds ``w u`` where the token lives; a choice on an expert that
     lives on another chip adds NOTHING - no capacity, no dropped token, no
     stand-in for the other chips' part; a row that is not live gets a zero
-    result.  ``p``: ``gate, up [held, D, F]``, ``down [held, F, D]``."""
+    result.  ``p``: ``gate, up [held, D, F]``, ``down [held, F, D]``; with
+    no ``gate`` among them the experts are ungated.  ``D`` is ``u``'s width,
+    which need not be the width the plan's router read."""
     T, D = u.shape
     rows = _share_rows(T, share)
     with jax.named_scope("moe/experts"):
         y = grouped_ffn_lib.grouped_ffn(
             jnp.take(u.astype(dtype), plan.token_of_row, axis=0), plan.sizes,
-            p["gate"], p["up"], p["down"], block_rows=share_rows_block(T),
+            p.get("gate"), p["up"], p["down"], block_rows=share_rows_block(T),
             activation=share.activation,
         )
         # A row outside the groups may hold anything: selected away, never

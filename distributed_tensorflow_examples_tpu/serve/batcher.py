@@ -485,6 +485,13 @@ class SlotBatcher:
                       (the same explicit-shed contract as ``submit``).
     ``idle_wait_s``   how long the step thread parks when no slot is
                       active.
+    ``on_park``       called on the step thread, with no argument, each
+                      time it finds no slot active, before it parks: a
+                      session a client CLOSED frees its slot without one
+                      more call of ``run_step``, so what a step function
+                      owes once its last row has gone it does here
+                      (:meth:`wake` brings a parked thread round to it).
+                      An exception out of it counts as a step error.
 
     An exception out of ``run_step`` fails every ACTIVE session (each
     waiter sees it) and frees their slots — queued sessions then take
@@ -511,11 +518,12 @@ class SlotBatcher:
 
     def __init__(
         self, run_step, *, slots: int = 4, max_sessions: int = 64,
-        idle_wait_s: float = 0.2, name: str = "decode",
+        idle_wait_s: float = 0.2, name: str = "decode", on_park=None,
     ):
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
         self._run = run_step
+        self._on_park = on_park
         self.slots = int(slots)
         self.max_sessions = max(self.slots, int(max_sessions))
         self._idle_wait_s = float(idle_wait_s)
@@ -590,6 +598,12 @@ class SlotBatcher:
                 "first_token_ns": self.first_token_ns,
             }
 
+    def wake(self) -> bool:
+        """Bring the step thread round its loop now, parked or not (a parked
+        one calls ``on_park`` again); False where it has been stopped."""
+        self._work.set()
+        return not self._stopped
+
     def stop(self) -> None:
         with self._lock:
             self._stopped = True
@@ -632,6 +646,11 @@ class SlotBatcher:
             with self._span_fill:
                 slots, active = self._fill_slots()
             if not active:
+                if self._on_park is not None:
+                    try:
+                        self._on_park()
+                    except BaseException:  # noqa: BLE001 — no session is left to tell
+                        self.step_errors += 1
                 with self._span_park:
                     self._work.wait(self._idle_wait_s)
                     self._work.clear()

@@ -332,10 +332,11 @@ class _DecodeEngine:
     an array as the sum of its elements), summed over everything launched
     since the engine started.  ``stats()``
     only ASKS: the read is made on the step thread, at the top of its next
-    call or when it has no row left to step (so a parked engine's numbers
-    are already there), where that thread holds the cache and no program
-    has been handed it - never from a handler's thread against a buffer
-    that a launch may have donated meanwhile.  No step fetches anything
+    call, when it has no row left to step, or as it parks (``_parked``:
+    rows their clients closed leave without a call, and ``stats()`` wakes
+    a thread that is parked already), where that thread holds the cache and
+    no program has been handed it - never from a handler's thread against
+    a buffer that a launch may have donated meanwhile.  No step fetches anything
     for it; the asked-for read waits for the step in flight like any read
     of the cache would.  The device's int32 sums may wrap: the host keeps
     the totals and adds each read's difference modulo 2**32.  A cache
@@ -422,6 +423,7 @@ class _DecodeEngine:
             self._read_counters()
         self.batcher = batcher_lib.SlotBatcher(
             self._run_step, slots=self.slots, max_sessions=max_sessions,
+            on_park=self._parked,
         )
 
     def _no_selection(self):
@@ -568,6 +570,15 @@ class _DecodeEngine:
             self.prefill_rows_read += self._chunk_rows_read(
                 done, width, self.max_len)
 
+    def _parked(self) -> None:
+        """The step thread, about to park: a ``stats()`` that asked for the
+        counters is answered here too.  Sessions their clients closed leave
+        without one more ``_call``, so the ask a call would have answered
+        would else wait out its 2 s and read what an earlier ask left."""
+        if self._counts and self._counters_asked.is_set():
+            with self._cache_donated(), _SPAN_COUNTERS:
+                self._read_counters()
+
     def _run_step(self, slots):
         """One call of the batcher's loop (``_call``), timed: what it took
         less what it waited on the device goes to ``decode/host/ns``."""
@@ -694,11 +705,12 @@ class _DecodeEngine:
         s["reads_ready"] = self.reads_ready
         s["state_bytes"] = self.state_bytes
         if self._counts:
-            # Ask the step thread and give it a step and a chunk's time; a
-            # parked engine read its counters as its last row finished.
+            # Ask the step thread and give it a step and a chunk's time: it
+            # answers from its next call, or from ``_parked`` where its last
+            # rows were closed under it (woken, if it is parked already).
             self._counters_fresh.clear()
             self._counters_asked.set()
-            if s["slots_active"]:
+            if self.batcher.wake():
                 self._counters_fresh.wait(2.0)
             s.update({f"model_{k}": v for k, v in self.model_counters.items()})
         return s

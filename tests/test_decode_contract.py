@@ -66,6 +66,13 @@ FORMS = {
         layer_types=(models.afmoe.SLIDING,) * 3 + (models.afmoe.FULL,)), 97, jnp.float32),
     "smallthinker": lambda: (
         models.smallthinker, models.smallthinker.Config(**_SMALLTHINKER), 97, jnp.float32),
+    "nemotron_h": lambda: (models.nemotron_h, models.nemotron_h.Config(
+        vocab_size=97, hidden_size=32, num_hidden_layers=4, hybrid_override_pattern="ME*E",
+        mamba_num_heads=4, mamba_head_dim=8, ssm_state_size=16, n_groups=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8, n_routed_experts=8,
+        num_experts_per_tok=2, moe_latent_size=16, moe_intermediate_size=24,
+        moe_shared_expert_intermediate_size=40, experts_held=4, expert_first=4,
+        vocab_rows=64), 64, jnp.float32),
 }
 
 

@@ -126,3 +126,51 @@ def test_silu_is_what_the_kernel_computed_before_it_took_an_activation():
         np.asarray(gf.grouped_ffn(rows, n, gate, up, down, block_rows=16, activation="silu")))
     with pytest.raises(KeyError):
         gf.grouped_ffn(rows, n, gate, up, down, block_rows=16, activation="gelu")
+
+
+@pytest.mark.parametrize("sizes,M,D,F", [
+    ([3, 0, 17, 5], 96, 64, 32),    # an empty group among groups of uneven size
+    ([0, 0, 0, 0], 64, 64, 32),     # no row at all
+    ([0, 50, 0, 0], 64, 64, 32),    # one group has every row, four blocks of it
+    ([5, 0, 20, 0], 40, 1024, 2688),  # Nemotron's latent and expert widths
+])
+def test_the_ungated_relu2_kernel_against_the_loop(sizes, M, D, F):
+    """``gate=None``: ``down . relu(up . x)^2``, one accumulation and no
+    second matrix - at a tiny width and at 1024 -> 2688 -> 1024, where ``D``
+    is one block and ``F`` seven of 384 - against the loop's ungated form;
+    and it is neither the gated kernel with ``up`` for a gate nor ``relu``."""
+    if D == 1024:
+        assert (gf._block(D, gf.BLOCK_K), gf._block(F, gf.BLOCK_F)) == (1024, 384)
+    rows, gate, up, down = _inputs(M, D=D, F=F)
+    scale = (64 / D) ** 0.5
+    up, down = up * scale, down * (32 / F) ** 0.5
+    n = jnp.asarray(sizes, jnp.int32)
+    got = np.asarray(gf.grouped_ffn(
+        rows, n, None, up, down, block_rows=16, activation="relu2"))
+    want = np.asarray(gf.grouped_ffn_reference(
+        rows, n, None, up, down, block_rows=16, activation="relu2"))
+    mine = _group_rows(sizes, 16, M)
+    assert got.shape == (M, D) and got.dtype == np.float32
+    if not mine.any():
+        return
+    assert np.abs(want[mine]).max() > 0.1
+    assert np.abs(got[mine] - want[mine]).max() < 20 * TOL
+    by_hand = np.square(np.maximum(np.asarray(rows)[mine] @ np.asarray(up)[
+        np.flatnonzero(sizes)[0]], 0)) @ np.asarray(down)[np.flatnonzero(sizes)[0]]
+    first = slice(0, sizes[np.flatnonzero(sizes)[0]])
+    assert np.abs(got[mine][first] - by_hand[first]).max() < 20 * TOL
+    for other in (dict(gate=up * scale, activation="relu2"), dict(gate=None, activation="relu")):
+        wrong = np.asarray(gf.grouped_ffn_reference(
+            rows, n, other["gate"], up, down, block_rows=16, activation=other["activation"]))
+        assert np.abs(wrong[mine] - want[mine]).max() > 0.01
+
+
+def test_the_form_is_chosen_by_the_gate_alone():
+    """The gated call takes two ``[E, D, F]`` operands and the ungated one,
+    which lowers to another text: no switch but ``gate`` itself."""
+    rows, gate, up, down = _inputs(48)
+    n = jnp.asarray([3, 0, 17, 5], jnp.int32)
+    head = lambda *a, **kw: gf.grouped_ffn.lower(*a, block_rows=16, **kw).as_text().split(
+        "\n")[1]
+    gated, ungated = head(rows, n, gate, up, down), head(rows, n, None, up, down)
+    assert (gated.count("tensor<4x64x32xf32>"), ungated.count("tensor<4x64x32xf32>")) == (2, 1)

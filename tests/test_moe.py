@@ -940,3 +940,59 @@ def test_the_shares_of_four_ranks_under_a_plan_from_another_tensor_are_the_uncut
     assert np.abs(np.asarray(late) - want).max() > 0.1
     with pytest.raises(ValueError, match="activation"):
         dataclasses.replace(_ahead(), activation="gelu")
+
+
+# -- ungated experts read in another WIDTH than the router (Nemotron-H) ---------
+
+from benchmarks.reference import nemotron_h_ref  # noqa: E402
+
+#: 32 ungated squared-ReLU experts in a latent of 32 under a hidden of 64,
+#: 6 a token under a sigmoid router with a bias in the choice.
+C_LATENT = dict(
+    hidden_size=64, n_routed_experts=32, num_experts_per_tok=6, moe_latent_size=32,
+    moe_intermediate_size=48, moe_shared_expert_intermediate_size=96,
+    routed_scaling_factor=5.0, hybrid_override_pattern="E", expert_bias_std=0.05,
+    expert_down_factor=0.5)
+
+
+def _latent(first=0, held=32, **changes):
+    return moe_ops.ShareConfig(**{**dict(
+        n_experts=32, n_zero=0, top_k=6, scale=5.0, first=first, held=held,
+        scoring="sigmoid", normalise=True, activation="relu2"), **changes})
+
+
+def _latent_layer(first, held):
+    key = ref_weights.base_key(17)
+    p = nemotron_h_ref.build(nemotron_h_ref.layer_spec(C_LATENT, 0), key, layer=0)["moe"]
+    p.update(jax.vmap(lambda e: nemotron_h_ref.expert(C_LATENT, key, 0, e))(
+        first + jnp.arange(held)))
+    return jax.tree.map(lambda a: a.astype(jnp.float32), p)
+
+
+@pytest.mark.parametrize("ranks", [1, 4])
+def test_the_ungated_shares_under_a_plan_of_another_width_are_the_uncut_routed_sum(ranks):
+    """THE SHARE TIES TO THE MODEL where the router reads 64 values a token
+    and the experts 32: every rank plans from the same hidden ``u`` with the
+    whole router and multiplies the LATENT ``l``; the parts of ``ranks``
+    ranks, 32 / ``ranks`` ungated experts each and NO ``gate`` leaf among
+    them (which is all that says so), add up to the reference's routed
+    latent sum."""
+    u = jax.random.normal(jax.random.key(3), (40, 64))
+    whole = _latent_layer(0, 32)
+    latent = u @ whole["latent_in"]["kernel"]
+    choice, w = nemotron_h_ref.route(C_LATENT, whole, u)
+    want = np.zeros((40, 32), np.float32)
+    for e in range(32):
+        w_e = np.where(np.asarray(choice) == e, np.asarray(w), 0.0).sum(-1, keepdims=True)
+        want += w_e * np.asarray(
+            jnp.square(jax.nn.relu(latent @ whole["up"][e])) @ whole["down"][e])
+    per = 32 // ranks
+    total, held = np.zeros_like(want), 0
+    for rank in range(ranks):
+        p, share = _latent_layer(per * rank, per), _latent(per * rank, per)
+        assert "gate" not in p
+        plan = moe_ops.share_plan(p["router"], u, share)
+        total += np.asarray(moe_ops.apply_share_plan(p, latent, plan, share, dtype=jnp.float32))
+        held += int(moe_ops.share_counts(plan, share)["choices_held"])
+    assert held == 40 * 6 and np.abs(want).max() > 0.5
+    assert np.abs(total - want).max() < 4 * SHARE_TOL

@@ -157,6 +157,78 @@ def test_grouped_ffn_compiles_for_a_v5e_with_relu_at_widths_its_blocks_do_not_di
     _compile_grouped_ffn(one_chip, monkeypatch, 2560, 768, 64, rows, block, activation="relu")
 
 
+@pytest.mark.parametrize("rows,block", [(4800, 32), (22016, 128), (27648, 128)])
+def test_the_ungated_grouped_ffn_compiles_for_a_v5e_at_nemotrons_widths(
+    one_chip, monkeypatch, rows, block,
+):
+    """... and UNGATED (``gate=None``) with ``relu2`` at ``D`` 1024, ``F`` 2688
+    and 128 experts held (Nemotron-H's LatentMoE, models/nemotron_h.py: 32 x
+    22 choices in blocks of 32, 256 x 22 and 512 x 22 in blocks of 128, a
+    block more for each of 128 groups): ``D`` one block, ``F`` seven of 384,
+    an expert's whole ``up`` a block."""
+    from distributed_tensorflow_examples_tpu.ops import grouped_ffn as gf
+
+    monkeypatch.setattr(gf, "interpret_mode", lambda: False)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(
+        lambda r, n, u, d: gf.grouped_ffn.__wrapped__(
+            r, n, None, u, d, block_rows=block, activation="relu2")
+    ).lower(
+        s((rows, 1024), bf16), s((128,), jnp.int32), s((128, 1024, 2688), bf16),
+        s((128, 2688, 1024), bf16),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{gf.KERNEL_NAME}" in text
+
+
+@pytest.mark.parametrize("C", CHUNK_WIDTHS)
+def test_the_chunked_recurrence_compiles_for_a_v5e_at_the_served_widths(
+    one_chip, monkeypatch, C,
+):
+    """Mosaic takes ops/ssd.py's chunk at ``C`` 128 / 256 / 512 positions of
+    128 heads x 64 channels in 8 groups with a state of 128 columns, blocks
+    of 128 positions, bfloat16 operands (Nemotron-H): time on the lanes, the
+    decays' rows and columns, the three products a head, and the kernel's
+    name, which the benchmark's readers look for."""
+    from distributed_tensorflow_examples_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "interpret_mode", lambda: False)
+    H, P, G, N = 128, 64, 8, 128
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    n_valid = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda *a: ssd.ssd_chunk.__wrapped__(
+        *a, chunk_size=128, dtype=jnp.bfloat16)).lower(
+        s(C, H, P), s(C, H), s(H), s(C, G, N), s(C, G, N), s(H), s(H, P, N), n_valid
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{ssd.CHUNK_KERNEL_NAME}" in text
+
+
+def test_the_state_step_compiles_for_a_v5e_in_place_in_the_donated_cache(
+    one_chip, monkeypatch,
+):
+    """Mosaic takes ops/ssd.py's step at 32 slots of 128 x 64 x 128 float32
+    (Nemotron-H: 4.19 MB a slot, 134 MB a layer) - a slot's whole state a
+    block, the live slots' numbers prefetched - and WITH THE STATES DONATED
+    the compiled program holds no copy of them: the kernel's output is the
+    cache's own array, a slot that is not live is neither read nor written."""
+    from distributed_tensorflow_examples_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "interpret_mode", lambda: False)
+    S, H, P, G, N = 32, 128, 64, 8, 128
+    s = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(ssd.state_step.__wrapped__, donate_argnums=0).lower(
+        s((S, H, P, N)), s((S, H, P)), s((S, H)), s((H,)), s((S, G, N)), s((S, G, N)),
+        s((S,), jnp.bool_), s((S,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{ssd.STEP_KERNEL_NAME}" in text
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and f"f32[{S},{H},{P},{N}]" in line]
+    assert compiled.memory_analysis().temp_size_in_bytes < S * H * P * N * 4 / 16
+
+
 @pytest.mark.parametrize("kernel", ["decode", "prefill", "prefill_128", "prefill_256"])
 @pytest.mark.parametrize("model,slots,heads,length", [("deepseek", 64, 128, 4096), ("longcat", 32, 64, 8192)])
 def test_latent_attention_compiles_for_a_v5e_at_the_served_widths(

@@ -3197,6 +3197,44 @@ def test_counters_are_read_on_the_step_thread_and_survive_a_lost_cache(monkeypat
         eng.stop()
 
 
+@pytest.mark.parametrize("parked", [False, True])
+def test_counters_asked_for_are_read_though_the_last_session_was_closed(monkeypatch, parked):
+    """A session its client closes leaves its slot without one more call of
+    the step function - a load generator closes every open session at its
+    window's end, the instant the window's closing ``stats()`` is made.  The
+    ask is answered all the same, as the step thread parks (``_parked``) or,
+    woken, after it has: ``stats()`` then gives what the device counted, not
+    what the ask before it left."""
+    import jax
+
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    cfg, params, fns = _tiny_longcat()
+    eng = model_server._DecodeEngine(
+        lambda: (0, params), fns, slots=2, max_len=40, max_sessions=8)
+    try:
+        ticket = eng.open(np.arange(1, 6, dtype=np.int32), 30)
+        start = eng.stats()["model_moe_choices"]
+        while len(ticket.snapshot(0)[0]) < 8:
+            ticket.wait(0.05)
+        ticket.cancel()
+        if parked:
+            while eng.batcher.stats()["slots_active"]:
+                time.sleep(0.01)
+        end = eng.stats()["model_moe_choices"]
+        while eng.batcher.stats()["slots_active"]:
+            time.sleep(0.01)
+        on_device = int(np.asarray(jax.device_get(eng._cache["counters"]["moe_choices"])).sum())
+        # Not yet parked, the thread may answer from a call and launch
+        # once more before it sees the row gone.
+        assert start < end <= on_device and (end == on_device or not parked)
+        assert eng.stats()["model_moe_choices"] == end
+    finally:
+        eng.stop()
+    assert eng.stats()["model_moe_choices"] == end  # stopped: asks nobody, waits for nobody
+
+
 @pytest.mark.parametrize("family", ["transformer", "toy", "toy_state"])
 def test_a_model_without_counters_reports_none(family):
     """No ``counters`` entry in the cache tree: no ``model_*`` key, no read,
