@@ -385,7 +385,7 @@ def decode_step_batch(cfg: Config, params, cache, token, pos, live):
             q, new = _qkv(cfg, pa, u, pos, window is not None)
             o, new_cache[f"layer_{i}"] = ring_cache.step_attention(
                 q, new, cache[f"layer_{i}"], pos, live, window, counters,
-                attn_block=cfg.attn_block, dtype=cfg.dtype, scope=_scope(window))
+                attn_block=cfg.attn_block, scope=_scope(window))
             return _gated_out(cfg, pa, u, o)
 
         def routed(u):

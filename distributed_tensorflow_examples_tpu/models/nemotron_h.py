@@ -500,7 +500,7 @@ def decode_step_batch(cfg: Config, params, cache, token, pos, live):
             q, new = _qkv(cfg, p["attn"], u)
             o, new_cache[f"layer_{i}"] = ring_cache.step_attention(
                 q, new, cache[f"layer_{i}"], pos, live, None, counters,
-                attn_block=cfg.attn_block, dtype=cfg.dtype, scope=_ATTN_SCOPE)
+                attn_block=cfg.attn_block, scope=_ATTN_SCOPE)
             out = _attn_out(cfg, p["attn"], o)
         else:
             out, counters = _experts(cfg, p["moe"], u, live, counters)

@@ -1,7 +1,7 @@
 """A slot cache whose window layers are RINGS, and the two blocked
-attentions that read it: what models/afmoe.py and models/smallthinker.py
-share.  It names no model: a caller hands in its rows per block
-(``attn_block``), the type it multiplies in and the name of its scope; what
+attentions that read it: what models/afmoe.py, models/smallthinker.py and
+models/nemotron_h.py share.  It names no model: a caller hands in its rows
+per block (``attn_block``), the type it multiplies in and the name of its scope; what
 a model tells the serve engine of its reads (:func:`decode_rows_read`,
 :func:`prefill_rows_read`) takes the model's ``Config``, which lays the
 cache out by kind.
@@ -30,11 +30,17 @@ below 0 it holds nothing of this session (whatever the slot's previous
 session left there), above a query's own position or ``window`` or more
 behind it the query does not see it.
 
-The step reads the cache a block of ``attn_block`` rows of EVERY slot at a
-time, up to the block that holds the deepest live slot's row (a ring: at
-most ``R``), in plain ``jax.numpy`` - the loop of models/mla.py
-``_absorbed_loop``, for grouped heads and rings; the chunk reads its own
-slot's blocks up to its last query's row.  A row of the step that is not
+ON A TPU the step reads of each LIVE slot the blocks of ``attn_block`` rows up
+to that slot's own row (a ring: at most ``R``) and no other row:
+ops/slot_decode.py's kernel over the (slot, block) pairs that exist (PR 47).
+Wherever ``ops/common.py`` ``interpret_mode()`` is true it is the loop the
+kernel is held to bit for bit (:func:`step_loop`: a block of EVERY slot a
+trip, up to the deepest live slot's row) - models/mla.py's precedent, and for
+its reason: interpreted, the kernel makes a CPU step two to three times as
+long, and the benchmark's rehearsals end on a clock.  What the step counts
+and what it tells the engine it read are those of the form that ran.  The
+chunk reads its own slot's blocks up to its last query's row, in plain
+``jax.numpy``.  A row of the step that is not
 LIVE leaves everything its slot owns unchanged (on a ring its write would
 land on a row that a chunk of the session being prefilled there still
 reads) and reads nothing.  Its key and value go to a SPARE slot, the last of
@@ -53,9 +59,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.common import interpret_mode
+from ..ops.slot_decode import FLOOR, slot_decode_attention
+
 #: What the step's attention counts BY KIND of layer, ``[slots]`` int32 each
-#: (:func:`step_attention`): the rows the loop read a slot, and the rows the
-#: slot's live session needed (a window layer: at most the window).
+#: (:func:`step_attention`): the rows read of a slot (the kernel: whole blocks
+#: to the slot's own row; the loop: to the deepest live slot's), and the rows
+#: the slot's live session needed (a window layer: at most the window).
 ATTN_COUNTS = ("attn_window_rows_read", "attn_window_rows_needed",
                "attn_global_rows_read", "attn_global_rows_needed", "attn_rows_read")
 
@@ -66,16 +76,12 @@ def held_position(last, r, rows: int):
     return last - jnp.mod(last - r, rows)
 
 
-#: Where a running softmax's maximum starts (:func:`softmax_fold`).
-_FLOOR = -1e30
-
-
 def softmax_fold(carry, s, v, dtype, spec: str):
     """One block folded into a running softmax: ``carry`` = (maximum, sum,
     weighted values) in float32, ``s`` the block's masked scores (``-inf``
-    where unseen).  The maximum starts FINITE (:data:`_FLOOR`): a block may
-    hold nothing a query sees - a ring's rows in any order - and ``exp(-inf
-    - -inf)`` would poison the sums."""
+    where unseen).  The maximum starts FINITE (ops/slot_decode.py
+    ``FLOOR``): a block may hold nothing a query sees - a ring's rows in any
+    order - and ``exp(-inf - -inf)`` would poison the sums."""
     m, l, acc = carry
     m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
     w = jnp.exp(s - m_new)
@@ -109,13 +115,12 @@ def write_rows(cache, new, pos, live):
     return cache
 
 
-def attend_step(q, ck, cv, pos, live, window, *, attn_block: int, dtype, scope: str):
-    """One query a slot against that slot's rows: ``q [S, KV, G, hd]``,
-    ``ck, cv [S + 1, KV, R, hd]`` (the slot's row at ``pos`` already
-    written) -> ``([S, KV, G, hd]`` float32, rows read a slot``)``; a live
-    slot ``b`` attends over its positions ``<= pos[b]`` (and, with
-    ``window``, fewer than ``window`` behind it), one that is not over
-    nothing (zeros)."""
+def step_loop(q, ck, cv, pos, live, window, *, attn_block: int):
+    """:func:`attend_step` in plain ``jax.numpy``, the CPU's form and the
+    reference ops/slot_decode.py's kernel is held to: a block of
+    ``attn_block`` rows of EVERY slot a trip, up to the block that holds
+    the deepest live slot's row (a ring: at most ``R``) - the loop of
+    models/mla.py ``_absorbed_loop``, for grouped heads and rings."""
     S, (_, KV, R, hd) = q.shape[0], ck.shape
     blk = min(attn_block, R)
     n = jnp.where(live, pos + 1, 0)
@@ -137,18 +142,32 @@ def attend_step(q, ck, cv, pos, live, window, *, attn_block: int, dtype, scope: 
         if window is not None:
             seen &= pos[:, None] - held < window
         s = jnp.where(seen[:, None, None, :], s, -jnp.inf)
-        return softmax_fold(carry, s, v, dtype, "skgt,sktd->skgd")
+        return softmax_fold(carry, s, v, cv.dtype, "skgt,sktd->skgd")
 
     stat = jnp.zeros(q.shape[:3] + (1,), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_blocks, body, (stat + FLOOR, stat, jnp.zeros(q.shape, jnp.float32)))
+    o = acc / jnp.where(l == 0, 1.0, l)  # a slot that read nothing: zeros
+    return o, jnp.broadcast_to(jnp.minimum(n_blocks * blk, R), (S,))
+
+
+def attend_step(q, ck, cv, pos, live, window, *, attn_block: int, scope: str):
+    """One query a slot against that slot's rows: ``q [S, KV, G, hd]``,
+    ``ck, cv [S + 1, KV, R, hd]`` (the slot's row at ``pos`` already
+    written) -> ``([S, KV, G, hd]`` float32, rows read a slot ``[S]``)``; a
+    live slot ``b`` attends over its positions ``<= pos[b]`` (and, with
+    ``window``, fewer than ``window`` behind it), one that is not over
+    nothing (zeros).  On a TPU ops/slot_decode.py's kernel, which reads each
+    live slot to its own row; wherever ``interpret_mode()`` is true
+    :func:`step_loop`, which reads every slot to the deepest."""
     with jax.named_scope(scope):
-        _, l, acc = jax.lax.fori_loop(
-            0, n_blocks, body, (stat + _FLOOR, stat, jnp.zeros(q.shape, jnp.float32)))
-        o = acc / jnp.where(l == 0, 1.0, l)  # a slot that read nothing: zeros
-    return o, jnp.minimum(n_blocks * blk, R)
+        if interpret_mode():
+            return step_loop(q, ck, cv, pos, live, window, attn_block=attn_block)
+        return slot_decode_attention(q, ck, cv, pos, live, window, block=attn_block)
 
 
 def step_attention(q, new, layer, pos, live, window, counters, *, attn_block: int,
-                   dtype, scope: str):
+                   scope: str):
     """One layer of the step: the rows' keys and values ``new [S, 2, KV, hd]``
     written into ``layer`` (``{"k", "v"}``, :func:`write_rows`), the queries
     ``q [S, KV, G, hd]`` attended over them (:func:`attend_step`), and what
@@ -157,7 +176,7 @@ def step_attention(q, new, layer, pos, live, window, counters, *, attn_block: in
     ck = write_rows(layer["k"], new[:, 0], pos, live)
     cv = write_rows(layer["v"], new[:, 1], pos, live)
     o, read = attend_step(q, ck, cv, pos, live, window, attn_block=attn_block,
-                          dtype=dtype, scope=scope)
+                          scope=scope)
     need = jnp.where(live, pos + 1, 0)
     kind = "global" if window is None else "window"
     if window is not None:
@@ -225,7 +244,7 @@ def attend_chunk(q, k_rows, v_rows, offset, n_valid, window, *, attn_block: int,
     with jax.named_scope(scope):
         _, l, acc = jax.lax.fori_loop(
             0, blocks_read(offset + C, R, attn_block), body,
-            (stat + _FLOOR, stat, jnp.zeros((KV, G, C, hd), jnp.float32)))
+            (stat + FLOOR, stat, jnp.zeros((KV, G, C, hd), jnp.float32)))
     return jnp.moveaxis(acc / jnp.where(l == 0, 1.0, l), 2, 0)
 
 
@@ -259,10 +278,12 @@ def chunk_attention(q, new, layer, slot, offset, n_valid, window, *, slack: int,
 
 def decode_rows_read(cfg, pos, live, max_len: int) -> float:
     """Cache positions one decode step reads A SLOT IN THE MEAN LAYER, from
-    the host's ``pos [S]`` and ``live [S]``: in every layer whole blocks of
-    every slot up to the deepest live slot's row, in a ring at most the
-    ring (:func:`attend_step`)."""
-    return _mean_rows_read(cfg, int(np.where(live, pos + 1, 0).max()), max_len)
+    the host's ``pos [S]`` and ``live [S]``, as :func:`attend_step`'s form
+    that runs here reads them: the kernel each live slot's own whole blocks
+    up to its row and nothing of a slot that is not live, the loop every
+    slot's up to the deepest live slot's row; in a ring at most the ring."""
+    n = np.where(live, pos + 1, 0)
+    return _mean_rows_read(cfg, n.max() if interpret_mode() else n, max_len)
 
 
 def prefill_rows_read(cfg, offset: int, chunk: int, max_len: int) -> float:
@@ -271,11 +292,11 @@ def prefill_rows_read(cfg, offset: int, chunk: int, max_len: int) -> float:
     return _mean_rows_read(cfg, offset + chunk, max_len)
 
 
-def _mean_rows_read(cfg, deepest: int, max_len: int) -> float:
-    """Rows of a slot that either attention reads IN THE MEAN LAYER when the
-    deepest row it needs is the ``deepest``-th written: whole blocks, in a
-    ring at most the ring."""
+def _mean_rows_read(cfg, deepest, max_len: int) -> float:
+    """Rows that either attention reads A SLOT IN THE MEAN LAYER when the
+    deepest row a slot needs is its ``deepest``-th written (a number, or one
+    a slot): whole blocks, in a ring at most the ring."""
     blk = cfg.attn_block
     return float(np.mean([
-        min(int(blocks_read(deepest, rows, blk)) * min(blk, rows), rows)
+        np.minimum(blocks_read(deepest, rows, blk) * min(blk, rows), rows)
         for rows in (cfg.cache_rows(i, max_len) for i in cfg.layers)]))

@@ -310,7 +310,7 @@ def decode_step_batch(cfg: Config, params, cache, token, pos, live):
             q, new = _qkv(cfg, pa, u, pos, bool(cfg.rope_layout[i]))
             o, new_cache[f"layer_{i}"] = ring_cache.step_attention(
                 q, new, cache[f"layer_{i}"], pos, live, window, counters,
-                attn_block=cfg.attn_block, dtype=cfg.dtype, scope=_scope(window))
+                attn_block=cfg.attn_block, scope=_scope(window))
             return _out(cfg, pa, o)
 
         h, counters = _layer(cfg, params[f"layer_{i}"], h, live, attn, counters)

@@ -39,7 +39,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmarks.reference import afmoe_ref, weights  # noqa: E402
-from distributed_tensorflow_examples_tpu.models import afmoe  # noqa: E402
+from distributed_tensorflow_examples_tpu.models import afmoe, ring_cache  # noqa: E402
 from distributed_tensorflow_examples_tpu.ops import moe as moe_ops  # noqa: E402
 
 S, F = afmoe.SLIDING, afmoe.FULL
@@ -319,26 +319,42 @@ def test_a_chunk_wider_than_the_rings_slack_is_refused(params32):
 # ----------------------------------------------------------------------------
 
 
-def test_the_steps_rows_read_and_needed_are_counted_by_kind_of_layer(programs, params32, tokens):
-    """Two live slots at depths 5 and 40 and one that is not: every slot's
-    blocks are read to the deepest live row (a ring: at most the ring), each
-    live slot NEEDS ``min(pos + 1, window)`` rows of a sliding layer and
-    ``pos + 1`` of the full one.  Each counter is a ``[slots]`` array."""
+@pytest.mark.parametrize("form", ["loop", "kernel"])
+def test_the_steps_rows_read_and_needed_are_counted_by_kind_of_layer(
+        programs, params32, tokens, form, monkeypatch):
+    """Two live slots at depths 5 and 40 and one that is not, in both forms
+    of the step's attention - "loop" is what ``ring_cache.attend_step`` runs
+    on the CPU and "kernel" what it runs on a TPU (ops/slot_decode.py,
+    interpreted here): the loop reads every slot's blocks to the deepest live
+    row, the kernel each live slot's to its OWN row and nothing of the one
+    that is not (a ring: at most the ring); each live slot NEEDS ``min(pos +
+    1, window)`` rows of a sliding layer and ``pos + 1`` of the full one.
+    Each counter is a ``[slots]`` array, and the two forms' logits are equal
+    to the bit."""
     chunk, step = programs
+    if form == "kernel":
+        monkeypatch.setattr(ring_cache, "interpret_mode", lambda: False)
+        step = jax.jit(lambda p, c, t, pos, live: afmoe.decode_step_batch(
+            CFG32, p, c, t, pos, live))
+    read = lambda deep, shallow: [deep] * 3 if form == "loop" else [deep, 0, shallow]
     cache = afmoe.init_cache(CFG32, 3, L)
     cache = _prefill(chunk, params32, cache, tokens[0, :41], 0, 8)
     cache = _prefill(chunk, params32, cache, tokens[1, :6], 2, 8)
     pos, live = np.array([40, 9, 5], np.int32), np.array([True, False, True])
-    _, cache = step(params32, cache, np.array([1, 2, 3], np.int32), pos, live)
+    want, _ = programs[1](params32, cache, np.array([1, 2, 3], np.int32), pos, live)  # the loop's
+    logits, cache = step(params32, cache, np.array([1, 2, 3], np.int32), pos, live)
+    np.testing.assert_array_equal(np.asarray(logits)[live], np.asarray(want)[live])
     c = {k: np.asarray(v).tolist() for k, v in cache["counters"].items()}
-    # Four sliding layers read the whole ring of 24; the full layer 6 blocks of 8.
-    assert c["attn_window_rows_read"] == [4 * 24] * 3
-    assert c["attn_global_rows_read"] == [48] * 3
-    assert c["attn_rows_read"] == [4 * 24 + 48] * 3
+    # The deep slot: four sliding layers read the whole ring of 24, the full
+    # layer 6 blocks of 8; the shallow one, read to its own row, 1 block of each.
+    assert c["attn_window_rows_read"] == read(4 * 24, 4 * 8)
+    assert c["attn_global_rows_read"] == read(48, 8)
+    assert c["attn_rows_read"] == read(4 * 24 + 48, 5 * 8)
     assert c["attn_window_rows_needed"] == [4 * WINDOW, 0, 4 * 6]
     assert c["attn_global_rows_needed"] == [41, 0, 6]
     # What the engine's counter is told: the mean layer's rows a slot.
-    assert afmoe.decode_rows_read(CFG32, pos, live, L) == pytest.approx((4 * 24 + 48) / 5)
+    assert afmoe.decode_rows_read(CFG32, pos, live, L) == pytest.approx(
+        np.mean(read(4 * 24 + 48, 5 * 8)) / 5)
     assert afmoe.decode_rows_read(CFG32, pos, np.zeros(3, bool), L) == 0
     assert afmoe.prefill_rows_read(CFG32, 8, 8, L) == pytest.approx(16)
     assert afmoe.prefill_rows_read(CFG32, 32, 8, L) == pytest.approx((4 * 24 + 40) / 5)

@@ -33,7 +33,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmarks.reference import nemotron_h_ref, weights  # noqa: E402
-from distributed_tensorflow_examples_tpu.models import nemotron_h  # noqa: E402
+from distributed_tensorflow_examples_tpu.models import nemotron_h, ring_cache  # noqa: E402
 from distributed_tensorflow_examples_tpu.ops import moe as moe_ops  # noqa: E402
 from distributed_tensorflow_examples_tpu.ops import ssd  # noqa: E402
 
@@ -276,13 +276,21 @@ def test_a_row_that_is_not_live_leaves_its_slot_bit_equal_and_a_first_step_start
         assert np.abs(np.asarray(logits)[0] - reference[0, 20 + t]).max() < TOL
 
 
-def test_what_the_chunk_and_the_step_count(programs, params, tokens):
+@pytest.mark.parametrize("form", ["loop", "kernel"])
+def test_what_the_chunk_and_the_step_count(programs, params, tokens, form, monkeypatch):
     """Every layer held is computed by the chunk, the last ``E`` too: three
     expert calls and two calls of the chunked recurrence a chunk, at the
     width it was dispatched at; the step's attention counts the one
-    attention layer's rows; and what the model tells the engine it reads is
-    that layer's blocks."""
+    attention layer's rows in both its forms - "loop" is what
+    ``ring_cache.attend_step`` runs on the CPU, "kernel" what it runs on a
+    TPU (ops/slot_decode.py, interpreted here), with logits equal to the
+    bit; and what the model tells the engine it reads is that layer's
+    blocks as the form that runs reads them."""
     chunk, step = programs
+    if form == "kernel":
+        monkeypatch.setattr(ring_cache, "interpret_mode", lambda: False)
+        step = jax.jit(lambda p, c, t, pos, live: nemotron_h.decode_step_batch(
+            CFG, p, c, t, pos, live))
     cache = nemotron_h.init_cache(CFG, 3, L)
     cache = _prefill(chunk, params, cache, tokens[0, :41], 0, 8)
     cache = _prefill(chunk, params, cache, tokens[1, :6], 2, 8)
@@ -292,12 +300,16 @@ def test_what_the_chunk_and_the_step_count(programs, params, tokens):
     assert c["moe_choices"] == 3 * 4 * (40 + 5) and 0 < c["moe_choices_held"] < c["moe_choices"]
     assert np.abs(np.asarray(cache["handoff"][:5])).max() > 0.5
     pos, live = np.array([40, 9, 5], np.int32), np.array([True, False, True])
-    _, cache = step(params, cache, np.array([1, 2, 3], np.int32), pos, live)
+    want, _ = programs[1](params, cache, np.array([1, 2, 3], np.int32), pos, live)  # the loop's
+    logits, cache = step(params, cache, np.array([1, 2, 3], np.int32), pos, live)
+    np.testing.assert_array_equal(np.asarray(logits)[live], np.asarray(want)[live])
     c = {k: np.asarray(v).tolist() for k, v in cache["counters"].items()}
     assert c["moe_calls"] == 3 * 6 + 3 and c["ssd_calls"] == 12
-    assert c["attn_global_rows_read"] == [48] * 3  # six blocks of 8
+    # Six blocks of 8 - the loop every slot's, the kernel each live slot's own.
+    assert c["attn_global_rows_read"] == ([48] * 3 if form == "loop" else [48, 0, 8])
     assert c["attn_global_rows_needed"] == [41, 0, 6]
-    assert nemotron_h.decode_rows_read(CFG, pos, live, L) == 48
+    assert nemotron_h.decode_rows_read(CFG, pos, live, L) == pytest.approx(
+        48 if form == "loop" else (48 + 8) / 3)
     assert nemotron_h.prefill_rows_read(CFG, 32, 8, L) == 40
     no_rows = dataclasses.replace(CFG, held_layers=(0, 1))
     assert nemotron_h.decode_rows_read(no_rows, pos, live, L) == 0

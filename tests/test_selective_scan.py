@@ -8,6 +8,8 @@ the ``N`` state rows in another order, so it may differ by float32 rounding
 of a sum of ``N`` terms of the state's size.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -286,3 +288,95 @@ def test_latent_attention_compiles_for_a_v5e_at_the_served_widths(
     # chunk's nothing to speak of (a slot's rows are 4.7 MB and more).
     limit = slots * length * 576 * 2 / 16 if kernel == "decode" else 3 << 20
     assert compiled.memory_analysis().temp_size_in_bytes < limit
+
+
+def _cache_sized_moves(text, elements):
+    """The compiled program's ``copy`` and ``transpose`` instructions whose
+    result has ``elements`` elements or more, whatever its shape: a layer's
+    whole cache moved or laid out anew."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]+)\].*? (copy|transpose|copy-start)\(", line)
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) >= elements:
+            found.append(line.strip()[:200])
+    return found
+
+
+@pytest.mark.parametrize("KV,G,rows,window", [
+    (4, 7, 16384, None), (4, 7, 4608, 4096),  # SmallThinker: a global layer, a ring
+    (4, 8, 16384, None), (4, 8, 2560, 2048),  # Trinity-Mini: the full layer, a ring
+    (2, 16, 32768, None),  # Nemotron-3-Super: its attention layer
+])
+def test_slot_decode_attention_compiles_for_a_v5e_at_the_served_shapes(
+    one_chip, monkeypatch, KV, G, rows, window,
+):
+    """Mosaic takes ops/slot_decode.py at the three served models' shapes (32
+    slots and the spare, head size 128, bfloat16, blocks of 512 rows): the
+    grid of a traced extent over the prefetched work list, a query of 7
+    heads a group (padded to 8 sublanes: the query, not the cache), the
+    products over a block read as it lies, and the kernel's name, which the
+    benchmark's reader looks for.  The compiled program holds no copy and no
+    transpose of either cache."""
+    from distributed_tensorflow_examples_tpu.ops import slot_decode as sd
+
+    monkeypatch.setattr(sd, "interpret_mode", lambda: False)
+    S = 32
+    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, ck, cv, pos, live: sd.slot_decode_attention.__wrapped__(
+            q, ck, cv, pos, live, window, block=512)
+    ).lower(s((S, KV, G, 128)), s((S + 1, KV, rows, 128)), s((S + 1, KV, rows, 128)),
+            s((S,), jnp.int32), s((S,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{sd.KERNEL_NAME}" in text
+    assert not _cache_sized_moves(text, (S + 1) * KV * rows * 128)
+    # The temporaries are the work list and the padded query and result.
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+def test_the_smallthinker_step_compiles_for_a_v5e_and_leaves_its_cache_where_it_lies(
+    one_chip, monkeypatch,
+):
+    """The WHOLE step of the ``smallthinker-21b-serve-think`` cell (eight
+    layers of the published widths, 32 slots x 16,384, the engine's selecting
+    wrapper, the cache donated) compiled for the chip: eight calls of the
+    attention kernel and no loop; NO ``copy`` and NO ``transpose`` of a
+    layer's cache (a row read out of it at a traced ROW made the compiler lay
+    the whole cache out position-major, a copy in and another out a step, PR
+    39 - a block the kernel reads must not); every row write
+    (``dynamic-update-slice`` of a ``[1, 4, 1, 128]`` row) still there, and
+    the cache handed back in the arrays it came in (aliased whole), with
+    temporaries a hundredth of it."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from benchmarks.harness import manifest
+    from distributed_tensorflow_examples_tpu.models import ring_cache
+    from distributed_tensorflow_examples_tpu.ops import grouped_ffn, slot_decode
+    from distributed_tensorflow_examples_tpu.serve import model_server
+
+    for module in (grouped_ffn, slot_decode, ring_cache):
+        monkeypatch.setattr(module, "interpret_mode", lambda: False)
+    cell = manifest.Cell("smallthinker-21b-serve-think")
+    cfg, tree_fn = cell.family.build(cell.config)
+    fns = cell.family.decode_fns(cfg)
+    slots, max_len = cell.traffic["server"]["decode_slots"], cell.family.max_len(cell.config)
+    described = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    row = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
+    cache = described(jax.eval_shape(lambda: fns.init_cache(slots, max_len)))
+    compiled = jax.jit(model_server._selecting(fns.step), donate_argnums=1).lower(
+        described(jax.eval_shape(tree_fn, jax.random.key(0))), cache,
+        row(jnp.int32), row(jnp.int32), row(jnp.bool_), row(jnp.int32), row(jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert " while(" not in text
+    assert len([line for line in text.splitlines()
+                if "custom-call(" in line and slot_decode.KERNEL_NAME in line]) == len(cfg.layers)
+    assert not _cache_sized_moves(text, min(a.size for a in jax.tree.leaves(cache) if a.ndim == 4))
+    assert text.count(" dynamic-update-slice(") == 2 * slots * len(cfg.layers)
+    memory = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert memory.temp_size_in_bytes < cache_bytes / 50

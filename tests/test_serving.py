@@ -3339,8 +3339,9 @@ def test_deepseek_at_64_slots_through_the_engine_and_its_counters_add_up(monkeyp
 # ----------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("form", ["loop", "kernel"])
 def test_afmoe_sessions_over_wrapping_rings_through_the_engine_and_its_counters_add_up(
-        monkeypatch):
+        monkeypatch, form):
     """models/afmoe.py behind ``_DecodeEngine``: a window of 8 positions,
     rings of 8 + 8 rows (the engine's chunk is 8) in three layers of four and
     full rows in the fourth; four sessions on two slots, so slots are
@@ -3352,13 +3353,20 @@ def test_afmoe_sessions_over_wrapping_rings_through_the_engine_and_its_counters_
     and the tokens ``generate`` picks (two of them share its program); ``model_attn_*`` say what the step's attention read and needed by
     kind of layer - what was READ is, summed over slots and layers, what the
     model's ``cache_rows_read`` told the engine a slot in the mean layer -
-    and ``model_moe_*`` what the expert layers did: every choice held."""
+    and ``model_moe_*`` what the expert layers did: every choice held.  In
+    both forms of the step's attention: "loop", what
+    ``ring_cache.attend_step`` runs on the CPU (every slot read to the
+    deepest live row's block), and "kernel", what it runs on a TPU
+    (ops/slot_decode.py, interpreted here: each live slot to its own row) -
+    the engine, the sessions alone and ``generate`` all in that form."""
     import jax
 
-    from distributed_tensorflow_examples_tpu.models import afmoe
+    from distributed_tensorflow_examples_tpu.models import afmoe, ring_cache
     from distributed_tensorflow_examples_tpu.serve import model_server
 
     monkeypatch.setattr(model_server, "PREFILL_CHUNK", 8)
+    if form == "kernel":
+        monkeypatch.setattr(ring_cache, "interpret_mode", lambda: False)
     window, slots, max_len = 8, 2, 48
     cfg = afmoe.Config(
         vocab_size=97, hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
@@ -3412,15 +3420,19 @@ def test_afmoe_sessions_over_wrapping_rings_through_the_engine_and_its_counters_
     assert stats["model_attn_global_rows_needed"] == sum(t + 1 for r in at for t in r)
     assert stats["model_attn_window_rows_needed"] == n_sliding * sum(
         min(t + 1, window) for r in at for t in r)
-    # What was read: every slot to the deepest live row's block, a ring at
-    # most whole - as the hook said, launch by launch.
+    # What was read: the loop every slot to the deepest live row's block, the
+    # kernel each live slot to its own, a ring at most whole - as the hook
+    # said, launch by launch.
     counted = launches[:stats["steps"]]
     assert all(not live.any() for _pos, live in launches[stats["steps"]:])
-    deepest = [int(np.where(live, pos + 1, 0).max()) for pos, live in counted]
+    depths = [np.where(live, pos + 1, 0) for pos, live in counted]
+    deepest = [int(d.max()) for d in depths]
+    if form == "loop":
+        depths = [np.full(slots, d) for d in deepest]
     blocks = lambda rows: -(-rows // 4) * 4
-    assert stats["model_attn_global_rows_read"] == slots * sum(blocks(d) for d in deepest)
-    assert stats["model_attn_window_rows_read"] == slots * n_sliding * sum(
-        min(blocks(d), 16) for d in deepest)
+    assert stats["model_attn_global_rows_read"] == sum(blocks(d).sum() for d in depths)
+    assert stats["model_attn_window_rows_read"] == n_sliding * sum(
+        np.minimum(blocks(d), 16).sum() for d in depths)
     assert stats["model_attn_rows_read"] == (
         stats["model_attn_window_rows_read"] + stats["model_attn_global_rows_read"])
     assert stats["cache_rows_read"] == pytest.approx(

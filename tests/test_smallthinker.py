@@ -32,7 +32,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmarks.reference import smallthinker_ref, weights  # noqa: E402
-from distributed_tensorflow_examples_tpu.models import smallthinker  # noqa: E402
+from distributed_tensorflow_examples_tpu.models import ring_cache, smallthinker  # noqa: E402
 from distributed_tensorflow_examples_tpu.ops import moe as moe_ops  # noqa: E402
 
 WINDOW, SLACK, BLOCK = 16, 8, 8
@@ -264,14 +264,24 @@ def test_chunks_then_steps_at_the_cells_cache_geometry_across_the_wrap():
     assert worst < TOL
 
 
+@pytest.mark.parametrize("form", ["loop", "kernel"])
 def test_what_the_step_counts_by_kind_of_layer_and_where_the_window_binds(
-        programs, params, tokens):
-    """Two live slots at depths 5 and 40 and one that is not: every slot's
-    blocks are read to the deepest live row (a ring: at most the ring), each
-    live slot NEEDS ``min(pos + 1, window)`` rows of a window layer and
+        programs, params, tokens, form, monkeypatch):
+    """Two live slots at depths 5 and 40 and one that is not, in both forms
+    of the step's attention - "loop" is what ``ring_cache.attend_step`` runs
+    on the CPU and "kernel" what it runs on a TPU (ops/slot_decode.py,
+    interpreted here): the loop reads every slot's blocks to the deepest live
+    row, the kernel each live slot's to its OWN row and nothing of the one
+    that is not (a ring: at most the ring), and their logits are equal to
+    the bit; each live slot NEEDS ``min(pos + 1, window)`` rows of a window layer and
     ``pos + 1`` of a global one; a live row counts ONE step whatever the
     layers, and one past the window where its position + 1 exceeds it."""
     chunk, step = programs
+    if form == "kernel":
+        monkeypatch.setattr(ring_cache, "interpret_mode", lambda: False)
+        step = jax.jit(lambda p, c, t, pos, live: smallthinker.decode_step_batch(
+            CFG, p, c, t, pos, live))
+    read = lambda deep, shallow: [deep] * 3 if form == "loop" else [deep, 0, shallow]
     cache = smallthinker.init_cache(CFG, 3, L)
     cache = _prefill(chunk, params, cache, tokens[0, :41], 0, 8)
     cache = _prefill(chunk, params, cache, tokens[1, :6], 2, 8)
@@ -279,21 +289,25 @@ def test_what_the_step_counts_by_kind_of_layer_and_where_the_window_binds(
     # The chunk skips the LAST layer's experts and their plan: 7 calls a chunk.
     assert chunks["moe_chunk_calls"] == chunks["moe_calls"] == 7 * (5 + 1)
     pos, live = np.array([40, 9, 5], np.int32), np.array([True, False, True])
-    _, cache = step(params, cache, np.array([1, 2, 3], np.int32), pos, live)
+    want, _ = programs[1](params, cache, np.array([1, 2, 3], np.int32), pos, live)  # the loop's
+    logits, cache = step(params, cache, np.array([1, 2, 3], np.int32), pos, live)
+    np.testing.assert_array_equal(np.asarray(logits)[live], np.asarray(want)[live])
     _, cache = step(params, cache, np.array([1, 2, 3], np.int32),
                     np.array([41, 9, WINDOW - 1], np.int32), live)
     c = {k: np.asarray(v).tolist() for k, v in cache["counters"].items()}
     assert c["attn_live_steps"] == [2, 0, 2]
     assert c["attn_past_window_steps"] == [2, 0, 0]  # 16 positions: the window whole
-    # Six rings read whole (24 rows) in both steps; a global layer 6 blocks of 8.
-    assert c["attn_window_rows_read"] == [2 * 6 * 24] * 3
-    assert c["attn_global_rows_read"] == [2 * 2 * 48] * 3
+    # The deep slot: six rings read whole (24 rows) in both steps, a global
+    # layer 6 blocks of 8; the shallow one, read to its own row, 1 block
+    # (position 5), then 2 (position 15), of every layer.
+    assert c["attn_window_rows_read"] == read(2 * 6 * 24, 6 * (8 + 16))
+    assert c["attn_global_rows_read"] == read(2 * 2 * 48, 2 * (8 + 16))
     assert c["attn_window_rows_needed"] == [2 * 6 * WINDOW, 0, 6 * (6 + WINDOW)]
     assert c["attn_global_rows_needed"] == [2 * (41 + 42), 0, 2 * (6 + WINDOW)]
     assert c["moe_calls"] - chunks["moe_calls"] == 2 * 8
     assert c["moe_choices"] == c["moe_choices_held"]
     assert smallthinker.decode_rows_read(CFG, pos, live, L) == pytest.approx(
-        (6 * 24 + 2 * 48) / 8)
+        np.mean(read(6 * 24 + 2 * 48, 8 * 8)) / 8)
     assert smallthinker.prefill_rows_read(CFG, 32, 8, L) == pytest.approx(
         (6 * 24 + 2 * 40) / 8)
 
